@@ -25,6 +25,12 @@ hier-vs-psum parity contract:
 
 On the CPU harness the "DCN" is localhost gloo — the efficiency number
 is indicative; the parity gate is the hard contract either way.
+
+One process per chip: this is a CPU-only tool. The parent never imports
+JAX (it only spawns and parses), and every child pins
+``jax_platforms="cpu"`` before its first device query — so nothing here
+can take, or wait for, an accelerator that another process holds. Keep
+it that way: a leg that needs the chip must run alone, in one process.
 """
 
 from __future__ import annotations

@@ -21,7 +21,9 @@ Probes:
 4. lane-split    — in-kernel reshape (1, L) → (Bb, 576).
 
 Each probe is wrapped: a Mosaic lowering rejection prints the error
-class, never a crash. Exit code 0 always (informational tool).
+class, never a crash. Exit code 0 once the probes ran (informational
+tool); 2 when there is no TPU to probe — one process, no child, no
+platform switching.
 """
 
 from __future__ import annotations
@@ -209,12 +211,13 @@ def probe_two_dot_baseline():
 
 
 def main():
-    from parallel_cnn_tpu.utils.backend import is_tpu
+    from parallel_cnn_tpu.utils.backend import enable_compile_cache, is_tpu
 
+    enable_compile_cache()
     if not is_tpu():
-        print("mosaic_probe: needs a TPU (compiled Mosaic); current "
-              "backend is not TPU — nothing probed")
-        return 0
+        print("mosaic_probe: needs a TPU (compiled Mosaic); found "
+              f"platform={jax.devices()[0].platform!r} — nothing probed")
+        return 2
     _run("rank3-dot", probe_rank3_dot)
     _run("lane-merge", probe_lane_merge)
     _run("lane-split", probe_lane_split)

@@ -53,10 +53,10 @@ LIVE_DOCS = (
 )
 
 # Host-side drivers included in the env-var scan (they read PCNN_* too).
-ENV_SCAN_DRIVERS = ("bench.py", "__graft_entry__.py")
+ENV_SCAN_DRIVERS = ("bench.py", "__graft_entry__.py", "chip_smoke.py")
 
 PARSER_FILES = ("parallel_cnn_tpu/cli.py", "bench.py", "benches/run.py",
-                "benches/watch.py", "parallel_cnn_tpu/analysis/checker.py")
+                "chip_smoke.py", "parallel_cnn_tpu/analysis/checker.py")
 
 
 def _package_files() -> List[Path]:

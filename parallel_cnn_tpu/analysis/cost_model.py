@@ -279,7 +279,7 @@ def build_seeded_entry(name: str):
             out = jax.lax.ppermute(buf, STAGE_AXIS, perm)
             return jax.lax.pmean(out, (_DA, STAGE_AXIS))
 
-        step = mesh_lib.shard_map(
+        step = jax.shard_map(
             pbody, mesh=pmesh, in_specs=(P(),), out_specs=P(),
             check_vma=False,
         )
@@ -304,7 +304,7 @@ def build_seeded_entry(name: str):
             shard, DATA_AXIS, n, "bfloat16"
         )
 
-    step = mesh_lib.shard_map(
+    step = jax.shard_map(
         body, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(),
         check_vma=False,
     )

@@ -5,10 +5,14 @@ The real train/serve entry points are traced abstractly (via
 jaxprs are walked recursively (into pjit/scan/while/cond/shard_map
 sub-jaxprs) checking:
 
-- ``collective-axis``: every collective's axis name must exist on the
-  nearest enclosing ``shard_map`` mesh (or the declared ``data``/
-  ``model`` axes at top level).  A typo'd axis name surfaces at run
-  time as an unbound-axis error on device — here it's a lint failure.
+- ``collective-axis``: every collective's axis name must be one of the
+  package's DECLARED mesh axes (``data``/``model``/``host``/``stage`` —
+  parallel/mesh.py) and exist on the nearest enclosing ``shard_map``
+  mesh.  The installed JAX already refuses an axis no mesh binds at
+  trace time; what only a lint sees is an ad-hoc axis — ``jax.pmap``
+  (which now lowers through ``shard_map`` over a private mesh) or a
+  hand-built mesh that bypasses parallel/mesh.py and with it every
+  axis-keyed rule, byte table and plan check in this package.
 - ``ring-permutation``: every ``ppermute`` permutation must be a single
   cycle covering all participants — and, when the enclosing shard_map
   mesh gives the axis a size, covering *every rank of its axis*
@@ -95,7 +99,9 @@ def walk_jaxpr(jaxpr, visit: Callable, allowed: Dict[str, Optional[int]]) -> Non
     allowed-axis mapping (axis name -> size, None while unknown) is
     refined at each shard_map from its mesh — inside the body both the
     axis NAMES and their SIZES are known, which is what lets the ring
-    check demand full-axis coverage per axis."""
+    check demand full-axis coverage per axis. Only DECLARED axes are
+    ever allowed: a mesh axis outside the package's vocabulary (a pmap's
+    private axis, say) stays undeclared inside its own shard_map."""
     for eqn in jaxpr.eqns:
         visit(jaxpr, eqn, allowed)
         sub_allowed = allowed
@@ -105,7 +111,10 @@ def walk_jaxpr(jaxpr, visit: Callable, allowed: Dict[str, Optional[int]]) -> Non
             if axis_names:
                 shape = getattr(mesh, "shape", None)
                 sizes = dict(shape) if shape is not None else {}
-                sub_allowed = {a: sizes.get(a) for a in axis_names}
+                sub_allowed = {
+                    a: sizes.get(a) for a in axis_names
+                    if a in DECLARED_AXES
+                }
         for sub in _sub_jaxprs(eqn):
             walk_jaxpr(sub, visit, sub_allowed)
 
@@ -232,8 +241,9 @@ def analyze_closed_jaxpr(name: str, closed) -> List[Diagnostic]:
                     severity=Severity.ERROR,
                     file=file,
                     line=0,
-                    message=f"{prim} uses axis '{axis}' which is not on the "
-                            f"enclosing mesh (axes: {sorted(allowed)})",
+                    message=f"{prim} uses axis '{axis}' which is not a "
+                            "declared axis of the enclosing mesh "
+                            f"(declared axes there: {sorted(allowed)})",
                 ))
         if prim == "ppermute":
             perm = list(eqn.params.get("perm", ()))
@@ -865,8 +875,6 @@ def trace_entry_points(
     # ZeRO-3 param gathers), so both rings must carry f32 on the wire
     # and cover the axis with a single cycle.
     from jax.sharding import PartitionSpec as P
-
-    from parallel_cnn_tpu.parallel.mesh import shard_map
     from parallel_cnn_tpu.train import async_dp
 
     shard_len = 64
@@ -880,7 +888,7 @@ def trace_entry_points(
         )
         return new_w[None], new_c[None]
 
-    easgd_round = shard_map(
+    easgd_round = jax.shard_map(
         _easgd_body, mesh=mesh,
         in_specs=(P("data", None), P("data", None)),
         out_specs=(P("data", None), P("data", None)),

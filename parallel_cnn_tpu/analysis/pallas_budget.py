@@ -24,7 +24,6 @@ Findings:
 from __future__ import annotations
 
 import contextlib
-import os
 from dataclasses import dataclass
 from typing import Callable, Iterator, List, Tuple
 
@@ -44,22 +43,16 @@ class BudgetRecord:
 
 @contextlib.contextmanager
 def _force_tail_kernel() -> Iterator[None]:
-    """``pallas_tail._use_kernel`` reads PCNN_TAIL_KERNEL at call time;
-    force the kernel leg for the duration of an abstract trace so the
-    sizing path runs on CPU too, then restore the previous value."""
-    # graftcheck: disable=env-outside-config -- analyzer-internal save/force/restore around eval_shape, not a tunable knob
-    prev = os.environ.get("PCNN_TAIL_KERNEL")
-    # graftcheck: disable=env-outside-config -- analyzer-internal save/force/restore around eval_shape, not a tunable knob
-    os.environ["PCNN_TAIL_KERNEL"] = "1"
+    """Force ``pallas_tail``'s kernel leg for the duration of an abstract
+    trace so the sizing path runs on CPU too, then restore the hook."""
+    from parallel_cnn_tpu.ops import pallas_tail
+
+    prev = pallas_tail._FORCE_KERNEL
+    pallas_tail._FORCE_KERNEL = True  # graftcheck: disable=global-mutation -- analyzer-internal save/force/restore of a test hook around eval_shape
     try:
         yield
     finally:
-        if prev is None:
-            # graftcheck: disable=env-outside-config -- analyzer-internal save/force/restore around eval_shape, not a tunable knob
-            os.environ.pop("PCNN_TAIL_KERNEL", None)
-        else:
-            # graftcheck: disable=env-outside-config -- analyzer-internal save/force/restore around eval_shape, not a tunable knob
-            os.environ["PCNN_TAIL_KERNEL"] = prev
+        pallas_tail._FORCE_KERNEL = prev  # graftcheck: disable=global-mutation -- analyzer-internal save/force/restore of a test hook around eval_shape
 
 
 @contextlib.contextmanager

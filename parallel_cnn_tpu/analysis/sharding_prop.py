@@ -2,15 +2,15 @@
 
 Abstract interpretation over the ClosedJaxprs from
 ``jaxpr_rules.trace_entry_points(with_specs=True)``: each ``shard_map``
-equation's ``in_names``/``out_names`` declare, per operand and per array
+equation's ``in_specs``/``out_specs`` declare, per operand and per array
 dimension, which mesh axes the value is split over — everything NOT named
 is replicated over that axis.  Propagating the replicated-axes set
 through the body gives every intermediate an inferred PartitionSpec,
 which three rules check:
 
 - ``implicit-reshard`` (error): a ZooState leaf that ENTERS the step
-  sharded (its ``in_names`` entry names mesh axes) must EXIT sharded —
-  state leaves map 1:1 between ``in_names`` and ``out_names`` because the
+  sharded (its ``in_specs`` entry names mesh axes) must EXIT sharded —
+  state leaves map 1:1 between ``in_specs`` and ``out_specs`` because the
   step returns ``(new_state, loss)`` with the state treedef preserved.
   A sharded-in / replicated-out leaf means a ZeRO resident shard was
   gathered and HANDED BACK replicated: GSPMD will silently materialize
@@ -52,9 +52,16 @@ def _var_key(v) -> Optional[int]:
     return id(v) if not hasattr(v, "val") else None
 
 
-def _named_axes(names: Dict) -> FrozenSet[str]:
-    """Mesh axes a shard_map names entry splits an operand over."""
-    return frozenset(a for axs in names.values() for a in axs)
+def _named_axes(spec) -> FrozenSet[str]:
+    """Mesh axes one shard_map ``in_specs``/``out_specs`` entry (a
+    PartitionSpec: per dim None, an axis name, or a tuple of them) splits
+    an operand over."""
+    axes = set()
+    for dim in spec:
+        if dim is None:
+            continue
+        axes.update((dim,) if isinstance(dim, str) else dim)
+    return frozenset(axes)
 
 
 def _eqn_axes(eqn) -> Tuple[str, ...]:
@@ -196,8 +203,8 @@ def analyze_entry_sharding(
     for eqn in find_shard_maps(closed.jaxpr):
         mesh = eqn.params.get("mesh")
         mesh_axes = frozenset(getattr(mesh, "axis_names", ()) or ())
-        in_names = eqn.params.get("in_names") or ()
-        out_names = eqn.params.get("out_names") or ()
+        in_specs = eqn.params["in_specs"]
+        out_specs = eqn.params["out_specs"]
         body = _body_jaxpr(eqn)
         if body is None or not mesh_axes:
             continue
@@ -205,11 +212,11 @@ def analyze_entry_sharding(
         # implicit-reshard: state leaves are the first n_state_leaves
         # positions on BOTH sides ((state, bx, by) -> (new_state, loss)
         # preserves the ZooState treedef).
-        if spec is not None and len(in_names) >= spec.n_state_leaves \
-                and len(out_names) >= spec.n_state_leaves:
+        if spec is not None and len(in_specs) >= spec.n_state_leaves \
+                and len(out_specs) >= spec.n_state_leaves:
             for i in range(spec.n_state_leaves):
-                ins = _named_axes(in_names[i])
-                outs = _named_axes(out_names[i])
+                ins = _named_axes(in_specs[i])
+                outs = _named_axes(out_specs[i])
                 if ins and not outs:
                     diags.append(Diagnostic(
                         rule="implicit-reshard",
@@ -227,7 +234,7 @@ def analyze_entry_sharding(
                     ))
 
         init_repl: Dict[int, FrozenSet[str]] = {}
-        for v, names in zip(body.invars, in_names):
+        for v, names in zip(body.invars, in_specs):
             k = _var_key(v)
             if k is not None:
                 init_repl[k] = mesh_axes - _named_axes(names)

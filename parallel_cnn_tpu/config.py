@@ -120,9 +120,6 @@ class ResilienceConfig:
     # steps (0 = epoch boundaries only). Each check is a host sync, so
     # per-step checking trades dispatch asynchrony for detection latency.
     check_every_steps: int = 0
-    # Compile-failure degrade: when the Pallas kernel path fails, log one
-    # warning and complete the run on the XLA reference path.
-    pallas_fallback: bool = True
 
     def __post_init__(self):
         if self.policy not in ("off", "raise", "skip", "rollback"):
@@ -186,7 +183,7 @@ class CommConfig:
     # None = derive from jax.distributed process topology (one host row
     # per process); an explicit value splits a single process's devices
     # into that many emulated hosts — the 2-process-per-host CPU
-    # emulation path the tests and benches exercise pre-TPU-relay.
+    # emulation path the tests and benches exercise.
     hosts: Optional[int] = None
 
     def __post_init__(self):

@@ -20,6 +20,7 @@ data/pipeline.py falls back to the NumPy parser.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Iterator, Tuple
@@ -38,18 +39,41 @@ _NATIVE_DIR = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir, "nat
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libpcnn_native.so")
 
 
+_STAMP_PATH = _LIB_PATH + ".stamp"
+_SOURCES = ("mnist_loader.cc", "batcher.cc", "Makefile")
+
+
+def _source_digest() -> str:
+    """sha256 over the tracked native sources (and the Makefile whose
+    flags shape the binary) — what a trusted .so must have been built
+    from."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
 def _build() -> None:
-    sources = [
-        os.path.join(_NATIVE_DIR, f) for f in ("mnist_loader.cc", "batcher.cc")
-    ]
-    stale = not os.path.exists(_LIB_PATH) or any(
-        os.path.getmtime(s) > os.path.getmtime(_LIB_PATH) for s in sources
-    )
-    if not stale:
+    """Build the shared library unless THIS code already built it from
+    the sources on disk.
+
+    Staleness is decided by content, not mtime: a checkout or a copied
+    tree does not preserve mtimes meaningfully, and a .so that arrived
+    from elsewhere must not be trusted. The build records the sources'
+    digest in a stamp file next to the .so; a missing library, a missing
+    stamp, or a stamp that does not match the sources all rebuild."""
+    digest = _source_digest()
+    try:
+        with open(_STAMP_PATH) as f:
+            fresh = os.path.exists(_LIB_PATH) and f.read().strip() == digest
+    except OSError:
+        fresh = False
+    if fresh:
         return
     try:
         proc = subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
+            ["make", "-B", "-C", _NATIVE_DIR],
             capture_output=True,
             text=True,
         )
@@ -59,6 +83,10 @@ def _build() -> None:
         raise ImportError(
             f"native build failed:\n{proc.stdout}\n{proc.stderr}"
         )
+    tmp = f"{_STAMP_PATH}.tmp.{os.getpid()}"
+    with open(tmp, "w") as f:
+        f.write(digest + "\n")
+    os.replace(tmp, _STAMP_PATH)
 
 
 def _load_lib() -> ctypes.CDLL:

@@ -64,8 +64,7 @@ class Conv2D(Module):
         return params, {}, tuple(out[1:])
 
     def apply(self, params, state, x, train: bool = False):
-        use_pallas = self.backend == "pallas"
-        if use_pallas:
+        if self.backend == "pallas":
             from parallel_cnn_tpu.ops import pallas_conv
 
             if not pallas_conv.supports(self.kernel, self.strides, self.padding):
@@ -73,15 +72,6 @@ class Conv2D(Module):
                     f"pallas conv backend does not cover kernel={self.kernel} "
                     f"strides={self.strides} padding={self.padding!r}"
                 )
-            # Env-gated stem→XLA hybrid (PCNN_PALLAS_STEM_XLA=1): the
-            # documented escape hatch if a Mosaic regression re-opens
-            # the huge-input stem compile pathology that row-band
-            # tiling closes (docs/kernel_authoring.md).
-            if pallas_conv.prefer_xla_fallback(
-                self.kernel, self.strides, x.shape
-            ):
-                use_pallas = False
-        if use_pallas:
             y = pallas_conv.conv2d(
                 x, params["w"].astype(x.dtype), self.strides[0]
             )
@@ -224,11 +214,7 @@ class ConvBNAct(Module):
         if self.backend == "pallas" and not train:
             from parallel_cnn_tpu.ops import pallas_conv
 
-            if pallas_conv.supports(
-                self.kernel, self.strides, "SAME"
-            ) and not pallas_conv.prefer_xla_fallback(
-                self.kernel, self.strides, x.shape
-            ):
+            if pallas_conv.supports(self.kernel, self.strides, "SAME"):
                 bn_s = state["bn"]
                 # Folded inference-mode BN: y = conv·scale + shift.
                 scale = params["bn"]["scale"] * lax.rsqrt(

@@ -30,7 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from parallel_cnn_tpu.ops import reference as ops
 from parallel_cnn_tpu.ops.activations import apply_grad
 from parallel_cnn_tpu.parallel import collectives
-from parallel_cnn_tpu.parallel.mesh import DATA_AXIS, shard_map
+from parallel_cnn_tpu.parallel.mesh import DATA_AXIS
 
 Params = ops.Params
 
@@ -88,7 +88,7 @@ def make_dp_step(mesh: Mesh, dt: float, global_batch: int,
         return _dp_update(params, x, y, dt, global_batch, compute_dtype,
                           ops_path, comm, n_data)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
@@ -115,7 +115,7 @@ def make_dp_eval(mesh: Mesh):
         pred = jax.vmap(ops.predict, in_axes=(None, 0))(params, x)
         return jax.lax.psum(jnp.sum((pred != y) & mask), DATA_AXIS)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
@@ -147,7 +147,7 @@ def make_dp_epoch(mesh: Mesh, dt: float, global_batch: int):
         params, errs = jax.lax.scan(body, params, (images, labels))
         return params, jnp.mean(errs)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(), P(None, DATA_AXIS), P(None, DATA_AXIS)),

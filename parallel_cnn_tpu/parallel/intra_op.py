@@ -44,7 +44,7 @@ from parallel_cnn_tpu.ops.activations import (
     sigmoid_grad_from_preact,
 )
 from parallel_cnn_tpu.parallel import collectives
-from parallel_cnn_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, shard_map
+from parallel_cnn_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 Params = ops.Params
 
@@ -187,7 +187,7 @@ def make_2d_step(mesh: Mesh, dt: float, global_batch: int,
         mean_grads = jax.tree_util.tree_map(lambda g: g / global_batch, grad_sum)
         return apply_grad(params, mean_grads, dt), err_sum / global_batch
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(PARAM_SPECS, P(DATA_AXIS), P(DATA_AXIS)),
@@ -204,7 +204,7 @@ def make_2d_forward(mesh: Mesh):
         out = jax.vmap(lambda s: _forward_local(params, s)[-1])(x)
         return out
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(PARAM_SPECS, P(DATA_AXIS)),

@@ -41,42 +41,6 @@ HOST_AXIS = "host"
 STAGE_AXIS = "stage"
 
 
-def _resolve_shard_map():
-    """Locate shard_map and its replication-checker kwarg across jax
-    versions: jax>=0.6 exposes `jax.shard_map(..., check_vma=)`, older
-    releases `jax.experimental.shard_map.shard_map(..., check_rep=)`."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # C-accelerated / wrapped callables
-        params = {}
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return sm, kw
-    return sm, None
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map — the single entry point every module in
-    parallel/ and train/ uses (never `jax.shard_map` directly).
-
-    ``check_vma=False`` disables the replication checker under whichever
-    spelling the installed jax uses (`check_vma` / `check_rep`); needed by
-    the Pallas shard bodies (pallas_call's out_shape carries no
-    varying-mesh-axes info) and the ring collectives (ppermute outputs are
-    per-device values the checker cannot prove replicated, even though
-    reduce-scatter + all-gather leaves every device identical)."""
-    kw = {_CHECK_KW: check_vma} if _CHECK_KW is not None else {}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_mesh(cfg: Optional[MeshConfig] = None, devices: Optional[Sequence] = None) -> Mesh:
     """Build the (data, model) mesh from config.
 
@@ -250,20 +214,6 @@ def pad_to_multiple(n: int, k: int) -> int:
     return k * math.ceil(n / k)
 
 
-def _distributed_is_initialized() -> bool:
-    """Version-portable "has jax.distributed.initialize already run":
-    jax>=0.5 exposes jax.distributed.is_initialized(); on older releases
-    the only signal is the private global client handle."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state  # type: ignore
-        return global_state.client is not None
-    except ImportError:  # pragma: no cover - very old/new private layout
-        return False
-
-
 def distributed_init(coordinator: Optional[str] = None, num_processes: Optional[int] = None,
                      process_id: Optional[int] = None,
                      retry: Optional["object"] = None) -> None:
@@ -281,7 +231,7 @@ def distributed_init(coordinator: Optional[str] = None, num_processes: Optional[
     error propagates — still failing fast like MPI_Init, just not on the
     very first race with the coordinator.
     """
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return  # already initialized — idempotent by design
 
     from parallel_cnn_tpu.resilience.retry import RetryPolicy, retry_call

@@ -10,8 +10,7 @@ package makes failure a handled event across six axes:
                   optional LR backoff;
 - ``preempt``   — SIGTERM/SIGINT → flush a final atomic checkpoint and
                   stop at the next epoch boundary (pairs with --resume);
-- ``retry``     — deterministic jittered exponential backoff and the
-                  one-warning permanent Pallas→XLA fallback;
+- ``retry``     — deterministic jittered exponential backoff;
 - ``elastic``   — in-flight re-mesh + ZeRO-3 reshard on preemption
                   resize requests, chaos device loss, or device add: the
                   run continues on the surviving world instead of dying
@@ -35,7 +34,6 @@ from parallel_cnn_tpu.resilience.preempt import PreemptionGuard  # noqa: F401
 from parallel_cnn_tpu.resilience.retry import (  # noqa: F401
     RetryPolicy,
     retry_call,
-    with_fallback,
 )
 from parallel_cnn_tpu.resilience.rollback import (  # noqa: F401
     CheckpointRing,

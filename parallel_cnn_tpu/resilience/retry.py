@@ -1,19 +1,19 @@
-"""Deterministic retry/backoff + permanent-fallback wrappers.
+"""Deterministic retry/backoff.
 
 The reference treats every substrate call as infallible: `MPI_Init` either
 works or the job dies (MPI/Main.cpp:44), a failed data read returns an
 error code that main() ignores. Real long-running jobs see transient
 failures — a coordinator that isn't up yet, an NFS blip during a native
-build, a kernel that compiles on one toolchain and not another. This
-module gives those call sites two disciplined shapes:
+build. This module gives those call sites one disciplined shape:
 
 - ``retry_call`` — bounded, capped exponential backoff with *seeded*
   jitter: the delay sequence is a pure function of the policy, so tests
   (and post-mortems) can replay it exactly. No infinite retry loops by
   construction — attempts is a hard bound.
-- ``with_fallback`` — wrap a primary callable so the first failure flips
-  it permanently to a secondary implementation, logging exactly one
-  warning (the Pallas→XLA kernel-path degrade in train/step.py).
+
+There is deliberately no "fall back to another implementation" wrapper:
+a kernel that fails to compile must fail the run (a Pallas request that
+quietly trains on XLA and exits 0 hides the device from the operator).
 
 Pure stdlib on purpose: imported by data/native.py and parallel/mesh.py
 before/without JAX.
@@ -112,35 +112,3 @@ def retry_call(
                 name, attempt + 1, policy.attempts, type(e).__name__, e, d,
             )
             sleep(d)
-
-
-def with_fallback(
-    primary: Callable,
-    secondary: Callable,
-    *,
-    name: str = "primary",
-    on: Tuple[Type[BaseException], ...] = (Exception,),
-) -> Callable:
-    """Wrap ``primary`` so its first failure permanently switches every
-    subsequent call to ``secondary``, logging exactly one warning.
-
-    Unlike retry_call this never re-tries the primary: a failed kernel
-    compile fails identically on every call, so the switch is one-way and
-    the run completes on the fallback path.
-    """
-    state = {"fallen_back": False}
-
-    def wrapped(*args, **kwargs):
-        if not state["fallen_back"]:
-            try:
-                return primary(*args, **kwargs)
-            except on as e:
-                state["fallen_back"] = True
-                log.warning(
-                    "%s failed (%s: %s); falling back permanently",
-                    name, type(e).__name__, e,
-                )
-        return secondary(*args, **kwargs)
-
-    wrapped.fallback_engaged = lambda: state["fallen_back"]
-    return wrapped

@@ -323,7 +323,12 @@ class Engine:
 
         try:
             raw, in_tree, out_tree = pickle.loads(payload)
-            return se.deserialize_and_load(raw, in_tree, out_tree)
+            # Load for THIS replica's device only: the default is every
+            # local device, which is wrong on any host with more than
+            # one chip.
+            return se.deserialize_and_load(
+                raw, in_tree, out_tree, execution_devices=[self.device]
+            )
         except Exception as e:  # noqa: BLE001 — any load failure degrades
             raise AotCacheError(f"undeserializable entry {path}: {e}")
 

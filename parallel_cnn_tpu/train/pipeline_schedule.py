@@ -46,7 +46,6 @@ from parallel_cnn_tpu.parallel.mesh import (
     DATA_AXIS,
     STAGE_AXIS,
     pipeline_axis_sizes,
-    shard_map,
 )
 from parallel_cnn_tpu.train.zoo import (
     FusedOptState,
@@ -395,7 +394,7 @@ def make_pipeline_step(
             ),
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(state_spec, P(DATA_AXIS), P(DATA_AXIS)),

@@ -186,32 +186,21 @@ def pallas_batched_step(
     return apply_grad(params, mean_grads, dt), err.astype(jnp.float32)
 
 
-def batched_step_fn(ops_path: str, fallback: bool = False,
-                    fused: bool = False):
+def batched_step_fn(ops_path: str, fused: bool = False):
     """The minibatch step for a TrainConfig.ops value.
 
-    ``fallback=True`` (cfg.resilience.pallas_fallback, trainer-driven
-    runs) wraps the Pallas step so a kernel-path failure — typically a
-    Mosaic compile error on a toolchain the kernels don't support — logs
-    a single warning and permanently degrades to the XLA reference step;
-    the run completes instead of dying. Direct callers (the differential
-    kernel tests) keep the strict default: a Pallas failure is a Pallas
-    failure.
+    ``ops_path="pallas"`` IS the Pallas step: a Mosaic compile failure
+    fails the run with the compiler's text — asking for Pallas and
+    silently training on XLA is what this function must never do.
 
     ``fused=True`` (cfg.fused, i.e. --fused-step / PCNN_FUSED_STEP)
     selects the fused bucket-update step on the reference grad engine;
     the Pallas megakernel path keeps its own update (its step is one
     fused program already).
     """
-    if ops_path != "pallas":
-        return fused_batched_step if fused else batched_step
-    if not fallback:
+    if ops_path == "pallas":
         return pallas_batched_step
-    from parallel_cnn_tpu.resilience.retry import with_fallback
-
-    return with_fallback(
-        pallas_batched_step, batched_step, name="pallas batched step"
-    )
+    return fused_batched_step if fused else batched_step
 
 
 @jax.jit

@@ -22,7 +22,6 @@ from parallel_cnn_tpu.data import pipeline
 from parallel_cnn_tpu.models import lenet_ref
 from parallel_cnn_tpu.parallel import data_parallel, intra_op, mesh as mesh_lib
 from parallel_cnn_tpu.resilience import preempt
-from parallel_cnn_tpu.resilience.retry import with_fallback
 from parallel_cnn_tpu.resilience.rollback import (
     RollbackController,
     tree_copy,
@@ -190,12 +189,10 @@ def learn(
     batcher_cls = _native_batcher_cls(tc)
     steps_per_epoch = len(train) // tc.batch_size if tc.batch_size > 1 else 0
     # Which kernel library executes the minibatch step (cfg.train.ops):
-    # path A (jnp/lax) or path B (Pallas/Mosaic). With pallas_fallback a
-    # kernel-path failure (e.g. Mosaic compile error on an unsupported
-    # toolchain) logs one warning and completes the run on path A.
+    # path A (jnp/lax) or path B (Pallas/Mosaic). A kernel that cannot
+    # compile fails the run; there is no quiet degrade to path A.
     batched_step = step_lib.batched_step_fn(
-        tc.ops, fallback=res.pallas_fallback,
-        fused=cfg.fused is not None,
+        tc.ops, fused=cfg.fused is not None,
     )
 
     # dt is a local because auto-rollback may scale it (res.lr_backoff);
@@ -239,21 +236,10 @@ def learn(
                 # cfg.comm routes the gradient allreduce through
                 # parallel/collectives.py (psum vs bucketed ring ± bf16
                 # wire); None keeps the historical monolithic psum.
-                step = data_parallel.make_dp_step(
+                return data_parallel.make_dp_step(
                     mesh, dt=dt_, global_batch=tc.batch_size,
                     compute_dtype=tc.dtype, ops_path=tc.ops, comm=cfg.comm,
                 )
-                if tc.ops == "pallas" and res.pallas_fallback:
-                    step = with_fallback(
-                        step,
-                        data_parallel.make_dp_step(
-                            mesh, dt=dt_, global_batch=tc.batch_size,
-                            compute_dtype=tc.dtype, ops_path="reference",
-                            comm=cfg.comm,
-                        ),
-                        name="pallas DP step",
-                    )
-                return step
 
         mesh_step = build_mesh_step(dt)
         if verbose:

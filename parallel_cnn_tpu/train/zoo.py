@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from parallel_cnn_tpu import obs as obs_lib
 from parallel_cnn_tpu.nn.core import Module
+from parallel_cnn_tpu.parallel import mesh as mesh_lib
 from parallel_cnn_tpu.parallel.mesh import DATA_AXIS, HOST_AXIS, STAGE_AXIS
 
 
@@ -439,7 +440,6 @@ def _make_comm_step(
     is rejected on a hierarchical mesh.
     """
     from parallel_cnn_tpu.parallel import collectives
-    from parallel_cnn_tpu.parallel.mesh import shard_map
 
     has_host = HOST_AXIS in mesh.axis_names
     if comm.impl == "hierarchical" and not has_host:
@@ -492,6 +492,20 @@ def _make_comm_step(
 
     def shard_body(state: ZooState, x, y, key_data=None):
         params, model_state = state.params, state.model_state
+        if not use_ring:
+            # With the replication checker on, params arrive typed
+            # "unvarying" over the mesh and jax.grad of a shard-varying
+            # loss w.r.t. them already returns the cross-shard SUM (the
+            # transpose of the implicit broadcast is a psum). The explicit
+            # collective below would then reduce a second time — an
+            # N-times gradient. Mark the params device-varying so grads
+            # stay LOCAL and the one written-out reduce is the only one:
+            # psum and ring run the same body, as documented above. Only
+            # the differentiated copy is cast; the optimizer below keeps
+            # updating the replicated originals.
+            grad_params = jax.lax.pcast(params, raxes, to="varying")
+        else:
+            grad_params = params
         if augment is not None:
             # Typed keys don't cross the shard_map boundary portably; the
             # raw key data does. Fold in the device index so each shard
@@ -529,7 +543,9 @@ def _make_comm_step(
                     bx, gsum, lsum, model_state = jax.lax.optimization_barrier(
                         (bx, gsum, lsum, model_state)
                     )
-            loss, model_state, grads = grad_fn(params, model_state, bx, by)
+            loss, model_state, grads = grad_fn(
+                grad_params, model_state, bx, by
+            )
             lsum = lsum + loss
             if overlap:
                 if plan is None:
@@ -580,7 +596,7 @@ def _make_comm_step(
         check_vma=not use_ring,
     )
     if augment is not None:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body, in_specs=(P(), batch_spec, batch_spec, P()),
             **specs,
         )
@@ -594,7 +610,7 @@ def _make_comm_step(
             return sharded(state, x, y, jax.random.key_data(key))
 
     else:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body, in_specs=(P(), batch_spec, batch_spec), **specs
         )
 
@@ -652,7 +668,6 @@ def make_fused_train_step(
     """
     from parallel_cnn_tpu.ops import pallas_update
     from parallel_cnn_tpu.parallel import collectives
-    from parallel_cnn_tpu.parallel.mesh import shard_map
 
     if comm is None or comm.impl != "ring":
         raise ValueError(
@@ -779,7 +794,7 @@ def make_fused_train_step(
         check_vma=False,  # ppermute outputs, as in _make_comm_step
     )
     if augment is not None:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             in_specs=(state_spec, P(DATA_AXIS), P(DATA_AXIS), P()),
             **specs,
@@ -794,7 +809,7 @@ def make_fused_train_step(
             return sharded(state, x, y, jax.random.key_data(key))
 
     else:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             in_specs=(state_spec, P(DATA_AXIS), P(DATA_AXIS)),
             **specs,
@@ -958,7 +973,6 @@ def make_zero3_train_step(
     """
     from parallel_cnn_tpu.ops import pallas_update
     from parallel_cnn_tpu.parallel import collectives
-    from parallel_cnn_tpu.parallel.mesh import shard_map
 
     if comm is None or comm.impl not in ("ring", "hierarchical"):
         raise ValueError(
@@ -1101,7 +1115,7 @@ def make_zero3_train_step(
         check_vma=False,  # ppermute outputs, as in _make_comm_step
     )
     if augment is not None:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             in_specs=(state_spec, batch_spec, batch_spec, P()),
             **specs,
@@ -1116,7 +1130,7 @@ def make_zero3_train_step(
             return sharded(state, x, y, jax.random.key_data(key))
 
     else:
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             in_specs=(state_spec, batch_spec, batch_spec),
             **specs,
@@ -1170,6 +1184,16 @@ def evaluate(
         y = jnp.asarray(labels[i : i + batch_size])
         correct += int(ev(state.params, state.model_state, x, y))
     return correct / n * 100.0
+
+
+def _device_ids(tree) -> str:
+    """Comma-joined sorted ids of every device holding a shard of any
+    array in tree."""
+    ids = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            ids.update(d.id for d in leaf.sharding.device_set)
+    return ",".join(str(i) for i in sorted(ids))
 
 
 def _native_epoch_batches(np_images, np_labels, batch_size, steps, seed):
@@ -1782,10 +1806,17 @@ def train(
                         obs.event(
                             "chaos_slow_stage", step=opt_steps, ms=_stall
                         )
+            if mesh is not None:
+                # Lay the batch out over the mesh's batch axes BEFORE the
+                # step (the same placement trainer.learn uses): each
+                # device receives its shard only, instead of the whole
+                # batch landing on device 0 and being re-sliced inside
+                # the program.
+                bx, by = mesh_lib.shard_batch(mesh, (bx, by))
+            else:
+                bx, by = jnp.asarray(bx), jnp.asarray(by)
             with obs.span("zoo.dispatch", cat="step"):
-                state, loss = step(
-                    state, jnp.asarray(bx), jnp.asarray(by), key
-                )
+                state, loss = step(state, bx, by, key)
             opt_steps += 1
             if chaos is not None:
                 state, loss = chaos.after_step(state, loss)
@@ -1877,6 +1908,12 @@ def train(
                        loss=losses[-1], seconds=seconds)
             if eval_data is not None:
                 rec["accuracy"] = accs[-1]
+            # Where the work actually lives (host-side sharding metadata,
+            # no sync): the platform, and the ids of the devices holding
+            # the state and the last batch ("0,1,2,3" — records are flat).
+            rec["platform"] = jax.devices()[0].platform
+            rec["state_devices"] = _device_ids(state)
+            rec["batch_devices"] = _device_ids((bx, by))
             metrics.record(**rec)
         if ring is not None:
             from parallel_cnn_tpu.train import checkpoint

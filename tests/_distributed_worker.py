@@ -3,8 +3,11 @@
 Launched once per rank with PCNN_COORDINATOR / PCNN_NUM_PROCESSES /
 PCNN_PROCESS_ID set — the framework's `mpirun` analog
 (parallel/distributed.py ≙ MPI_Init, MPI/Main.cpp:44). Forces the CPU
-platform BEFORE distributed init (the env-var route is unreliable, see
-tests/conftest.py), joins the coordination service, and runs:
+platform BEFORE distributed init — in this child as in its parent test
+process (tests/conftest.py): a chip belongs to one process at a time, so
+a spawned worker must never be able to reach for an accelerator its
+parent may hold. Keep both sides CPU-pinned. Then joins the coordination
+service, and runs:
 
 1. one real cross-process collective — allgather of the process index over
    the global device mesh (bring-up evidence), and
@@ -413,7 +416,7 @@ def train_trajectory_async():
         )
         return nw[None], nc[None]
 
-    f = jax.jit(mesh_lib.shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data", None), P("data", None)),
         out_specs=(P("data", None), P("data", None)), check_vma=False,
     ))

@@ -61,7 +61,7 @@ def mesh4(host_devices):
 
 
 def _shmap_jaxpr(mesh, body, x, out_specs=P("data")):
-    f = mesh_lib.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=P("data"), out_specs=out_specs,
         check_vma=False,
     )
@@ -135,7 +135,7 @@ def hier_mesh22(host_devices):
 
 
 def _hier_jaxpr(mesh, body, x):
-    f = mesh_lib.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=P(("host", "data")),
         out_specs=P(("host", "data")), check_vma=False,
     )

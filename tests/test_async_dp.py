@@ -202,7 +202,7 @@ def test_easgd_round_sharded_matches_host(host_devices):
         )
         return nw[None], nc[None]
 
-    f = jax.jit(mesh_lib.shard_map(
+    f = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("data", None), P("data", None)),
         out_specs=(P("data", None), P("data", None)), check_vma=False,
     ))

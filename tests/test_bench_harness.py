@@ -45,7 +45,7 @@ def test_headline_promotes_faster_parity_checked_pallas(bench):
         (1_500_000.0, float("nan"), "NaN diff must not compare as ok"),
         (1_500_000.0, None, "diff never measured"),
         ("error: Mosaic", 4e-4, "pallas row errored"),
-        (None, 4e-4, "pallas never timed (CPU fallback)"),
+        (None, 4e-4, "pallas never timed (CPU rehearsal)"),
         (1_500_000.0, "error: X", "diff row errored"),
     ],
 )
@@ -101,3 +101,30 @@ def test_drain_accepts_mixed_pytrees(benchrun):
         "static": 7,  # non-array leaf must be skipped, not crash
     }
     benchrun._drain(tree)  # completing without error is the contract
+
+
+def test_bench_refuses_to_measure_without_a_tpu(bench, monkeypatch):
+    """No probe child, no wait, no CPU fallback: without a TPU the device
+    check exits, naming what it found — unless the caller asked for the
+    CPU by name, and then the device dict says cpu."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        bench._device()
+    assert "no TPU" in str(exc.value) and "'cpu'" in str(exc.value)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    dev = bench._device()
+    assert dev["platform"] == "cpu" and dev["device_count"] >= 1
+
+
+def test_bench_import_starts_nothing(bench, benchrun):
+    """Importing the harnesses configures no compile cache and resolves
+    no platform (benches/run.py used to do both at import)."""
+    import jax
+
+    assert not hasattr(bench, "_resolve_platform")
+    assert not hasattr(bench, "_enable_compile_cache")
+    assert "subprocess" not in vars(bench)
+    # Whatever JAX itself took from the environment — nothing of ours.
+    assert jax.config.jax_compilation_cache_dir == os.environ.get(
+        "JAX_COMPILATION_CACHE_DIR"
+    )

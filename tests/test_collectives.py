@@ -117,7 +117,7 @@ def mesh8(host_devices):
 
 
 def _run_sharded(mesh8, body, x, check=False):
-    f = mesh_lib.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh8, in_specs=(P(AXIS),), out_specs=P(),
         check_vma=check,
     )
@@ -210,7 +210,7 @@ def hier_mesh(host_devices):
 
 
 def _run_hier(mesh, body, x, out_specs=P(), check=False):
-    f = mesh_lib.shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P((mesh_lib.HOST_AXIS, AXIS)),), out_specs=out_specs,
         check_vma=check,

@@ -308,20 +308,31 @@ class TestFusedTail:
                 np.asarray(a), np.asarray(bb), rtol=0, atol=1e-5
             )
 
-    def test_kernel_path_matches_xla_path(self, rng, monkeypatch):
-        # PCNN_TAIL_KERNEL is read at call time: "1" runs the Pallas
-        # kernel (interpret mode on CPU), "0" the XLA twin — the
+    @pytest.mark.parametrize("pool,batch", [
+        ("max2", 8), ("gap", 8), ("none", 8),
+        # A batch that is not a sublane-tile multiple is zero-padded
+        # inside the kernel wrapper; the pad rows must not reach the mean.
+        ("max2", 6), ("gap", 3),
+    ])
+    def test_kernel_path_matches_xla_path(self, rng, monkeypatch, pool,
+                                          batch):
+        # _FORCE_KERNEL runs the Pallas kernel (interpret mode on CPU)
+        # where the platform rule would pick the XLA twin — the
         # differential test of the kernel itself.
-        x, w, b, y = _tail_data(rng)
+        x, w, b, y = _tail_data(rng, B=batch)
+        d = {"max2": w.shape[0], "gap": x.shape[-1],
+             "none": x[0].size}[pool]
+        w = jnp.asarray(
+            (rng.normal(size=(d, w.shape[1])) * 0.01).astype(np.float32)
+        )
         f = jax.value_and_grad(
             lambda x, w, b: pallas_tail.fused_tail_loss(
-                x, w, b, y, pool="max2"
+                x, w, b, y, pool=pool
             ),
             argnums=(0, 1, 2),
         )
-        monkeypatch.setenv("PCNN_TAIL_KERNEL", "0")
         l_xla, g_xla = f(x, w, b)
-        monkeypatch.setenv("PCNN_TAIL_KERNEL", "1")
+        monkeypatch.setattr(pallas_tail, "_FORCE_KERNEL", True)
         l_k, g_k = jax.jit(f)(x, w, b)
         assert abs(float(l_xla) - float(l_k)) <= 1e-5
         for a, bb in zip(g_xla, g_k):

@@ -191,29 +191,15 @@ def test_fused_bf16_store_vs_f32_store(monkeypatch):
     tree_allclose(grads_bf16, grads_f32, atol=1e-4)
 
 
-def test_mxu_conv_engine_mosaic_status(monkeypatch):
-    """Forward-looking guard for the gated MXU conv engine (r5 negative
-    result, docs/future_work.md §4): Mosaic currently lowers the
-    rank-2×rank-3 dot via the lane-merge reshape it rejects. The day a
-    libtpu/Mosaic upgrade makes this COMPILE, this test FAILS loudly —
-    the signal to flip _MXU_CONV's default and re-measure the roof.
-    TPU-only (interpret mode runs the engine fine by design)."""
-    from parallel_cnn_tpu.utils.backend import is_tpu
-
-    if not is_tpu():
-        pytest.skip("compiled-Mosaic capability probe")
+def test_mxu_conv_engine_refusal_is_typed(params, batch, monkeypatch):
+    """Selecting the MXU conv engine where Mosaic is known to refuse it
+    (a TPU — faked here by flipping the interpret switch) raises the typed
+    MosaicRefusal naming the compiler's reason BEFORE any kernel is
+    built: never a quiet interpret or XLA substitute. Whether Mosaic
+    STILL refuses is chip_smoke.py's check (it clears the record and
+    re-tries the real compile on the chip)."""
+    xs, ys = batch
     monkeypatch.setattr(pk, "_MXU_CONV", True)
-    params = lenet_ref.init(jax.random.key(6))
-    rng = np.random.default_rng(12)
-    xs = jnp.asarray(rng.uniform(0, 1, (128, 28, 28)).astype(np.float32))
-    ys = jnp.asarray(rng.integers(0, 10, (128,)).astype(np.int32))
-    try:
-        err, _ = pk.fused_value_and_ref_grads(params, xs, ys)
-        jax.block_until_ready(err)
-    except Exception:
-        return  # still rejected — the documented status quo
-    raise AssertionError(
-        "Mosaic now LOWERS the rank-2×rank-3 conv dot! Flip "
-        "PCNN_FUSED_MXU_CONV's default in ops/pallas.py and re-run the "
-        "megakernel roof measurements (docs/future_work.md §4)."
-    )
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    with pytest.raises(pk.MosaicRefusal, match="unsupported shape cast"):
+        pk.fused_value_and_ref_grads(params, xs, ys)

@@ -469,22 +469,3 @@ def test_cout_tiled_weight_streaming_matches_xla():
         np.testing.assert_allclose(np.asarray(gw), np.asarray(gw_r), atol=1e-4)
     finally:
         pallas_conv._COUT_TILE = old
-
-
-def test_prefer_xla_fallback_gate():
-    """The stem→XLA escape hatch is OFF by default (row-band tiling makes
-    the 224² stem compile); PCNN_PALLAS_STEM_XLA=1 reroutes ONLY the
-    huge-input 7×7-s2 family."""
-    import os
-
-    assert not pallas_conv.prefer_xla_fallback((7, 7), (2, 2), (8, 224, 224, 3))
-    old = pallas_conv._STEM_XLA
-    pallas_conv._STEM_XLA = True
-    try:
-        assert pallas_conv.prefer_xla_fallback((7, 7), (2, 2), (8, 224, 224, 3))
-        assert not pallas_conv.prefer_xla_fallback((7, 7), (2, 2), (8, 64, 64, 3))
-        assert not pallas_conv.prefer_xla_fallback((3, 3), (1, 1), (8, 224, 224, 3))
-    finally:
-        pallas_conv._STEM_XLA = old
-    assert os.environ.get("PCNN_PALLAS_STEM_XLA", "0") in ("", "0"), \
-        "test env leaked the stem escape hatch"

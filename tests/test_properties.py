@@ -12,7 +12,7 @@ import pytest
 hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need the hypothesis package"
 )
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from parallel_cnn_tpu.data import mnist
 from parallel_cnn_tpu.data.augment import random_crop_flip
@@ -103,12 +103,16 @@ def test_batch_block_is_a_divisor_within_bound(n, want):
     taps=st.sampled_from([1, 9]),
     esz=st.sampled_from([2, 4]),
 )
+# hypothesis's falsifying example for the old "budget respected whenever
+# want >= 1" claim: one image + double-buffered weights model 39.5 MB, over
+# the 32 MB budget, and no smaller block exists.
+@example(n=32, rows=672, cin=512, cout=512, taps=9, esz=4)
 def test_pick_bb_divides_batch_and_respects_budget(n, rows, cin, cout, taps, esz):
-    """The conv grid invariants, r5 contract: bb divides n; the block's
-    sublane dim obeys Mosaic's dtype tile rule (legality BEATS the VMEM
-    target — the documented trade-off behind the sublane-tile fix); and
-    among LEGAL divisors, the budget is respected whenever any legal
-    divisor fits it."""
+    """What _pick_bb promises: bb divides n; the block's sublane dim obeys
+    Mosaic's dtype tile rule (legality BEATS the VMEM target — the
+    documented trade-off behind the sublane-tile fix); the budget is
+    respected WHEN a legal divisor that fits it exists, and the largest
+    such is taken; otherwise the smallest legal block is."""
     w_bytes = taps * cin * cout * 4
     bb = pc._pick_bb(
         n, rows, [cin], [cin] * taps, [cout], esz, esz, w_bytes
@@ -119,13 +123,27 @@ def test_pick_bb_divides_batch_and_respects_budget(n, rows, cin, cout, taps, esz
     per_img = rows * (
         esz * (2 * cin + taps * cin) + esz * 2 * cout + 4 * 2 * cout
     )
-    want = max(1, (pc._VMEM_BUDGET - 2 * w_bytes) // max(per_img, 1))
-    legal_within = [
-        d for d in range(1, want + 1)
+    legal = [
+        d for d in range(1, n + 1)
         if n % d == 0 and ((d * rows) % tile == 0 or d == n)
     ]
-    if legal_within:
-        assert bb * per_img + 2 * w_bytes <= pc._VMEM_BUDGET
+    fits = [d for d in legal
+            if d * per_img + 2 * w_bytes <= pc._VMEM_BUDGET]
+    assert bb == (max(fits) if fits else min(legal))
+
+
+def test_pick_bb_over_the_hard_limit_is_never_silent(caplog):
+    """A block that models past _VMEM_LIMIT is returned (something must
+    run) but always with a warning naming the predicted Mosaic OOM —
+    including when the ONLY legal block is bb=1, the case the old
+    early-return skipped."""
+    import logging
+
+    with caplog.at_level(logging.WARNING, "parallel_cnn_tpu.ops.pallas_conv"):
+        bb = pc._pick_bb(4, 100_000, [512], [512] * 9, [512], 4, 4, 0)
+    assert bb == 1
+    assert any("expect a Mosaic OOM" in r.getMessage()
+               for r in caplog.records)
 
 
 @settings(max_examples=100, deadline=None)

@@ -38,7 +38,6 @@ from parallel_cnn_tpu.resilience import (
     preempt,
     retry_call,
     tree_all_finite,
-    with_fallback,
 )
 from parallel_cnn_tpu.resilience import chaos as chaos_lib
 from parallel_cnn_tpu.train import checkpoint
@@ -116,24 +115,6 @@ def test_retry_call_does_not_catch_unlisted_errors():
             sleep=calls.append,
         )
     assert calls == []  # failed on the first attempt, no retries
-
-
-def test_with_fallback_permanent_single_warning(caplog):
-    def primary(x):
-        raise RuntimeError("kernel compile failed")
-
-    def secondary(x):
-        return x + 1
-
-    f = with_fallback(primary, secondary, name="test primary")
-    with caplog.at_level(logging.WARNING, "parallel_cnn_tpu.resilience"):
-        assert f(1) == 2
-        assert f(2) == 3  # permanent: primary never retried
-    warnings = [
-        r for r in caplog.records if "falling back" in r.getMessage()
-    ]
-    assert len(warnings) == 1
-    assert f.fallback_engaged()
 
 
 # -------------------------------------------------------------- sentinel
@@ -483,9 +464,9 @@ def test_preempt_then_resume_is_bit_exact():
 
 
 @pytest.mark.chaos
-def test_pallas_fallback_completes_with_single_warning(caplog, monkeypatch):
-    """A Pallas kernel-path failure degrades to XLA once, loudly, and the
-    run completes (acceptance: one warning, no crash)."""
+def test_pallas_failure_fails_the_run(monkeypatch):
+    """A Pallas kernel-path failure is the run's failure: asking for
+    --ops pallas must never finish on the XLA path with exit 0."""
     from parallel_cnn_tpu.ops import pallas as pk
     from parallel_cnn_tpu.train import trainer
 
@@ -504,16 +485,10 @@ def test_pallas_fallback_completes_with_single_warning(caplog, monkeypatch):
         train=TrainConfig(
             epochs=1, batch_size=12, ops="pallas", dt=1.25e-2
         ),
-        resilience=ResilienceConfig(policy="off", pallas_fallback=True),
+        resilience=ResilienceConfig(policy="off"),
     )
-    with caplog.at_level(logging.WARNING, "parallel_cnn_tpu.resilience"):
-        result = trainer.learn(cfg, _load_synth(cfg), verbose=False)
-    assert len(result.epoch_errors) == 1
-    assert np.isfinite(result.epoch_errors[0])
-    warnings = [
-        r for r in caplog.records if "falling back" in r.getMessage()
-    ]
-    assert len(warnings) == 1
+    with pytest.raises(RuntimeError, match="mosaic compile failed"):
+        trainer.learn(cfg, _load_synth(cfg), verbose=False)
 
 
 # ------------------------------------------- subprocess kill-and-resume
@@ -521,7 +496,7 @@ def test_pallas_fallback_completes_with_single_warning(caplog, monkeypatch):
 
 def _run_cli(args, timeout=300):
     env = dict(os.environ)
-    env["PCNN_JAX_PLATFORMS"] = "cpu"  # see tests/test_aux.py._run_cli
+    env["JAX_PLATFORMS"] = "cpu"  # see tests/test_aux.py._run_cli
     return subprocess.run(
         [sys.executable, "-m", "parallel_cnn_tpu", *args],
         capture_output=True,

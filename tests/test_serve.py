@@ -8,6 +8,7 @@ model so the whole module stays tier-1 fast on CPU.
 
 from __future__ import annotations
 
+import json
 import time
 
 import jax
@@ -435,3 +436,56 @@ def test_lenet_handle_serves_end_to_end():
         y = batcher.submit(x).result(timeout=60.0)
     assert y.shape == (10,)
     assert np.all(np.isfinite(y))
+
+
+# ------------------------------------------------ serve CLI exit codes
+
+
+def _serve_cli(monkeypatch, *extra):
+    from parallel_cnn_tpu import cli
+    from parallel_cnn_tpu.utils import backend
+
+    # In-process CLI under pytest: leave the session's compile cache alone.
+    monkeypatch.setattr(backend, "enable_compile_cache", lambda: "")
+    return cli.main(["serve", "--model", "lenet_ref", "--requests", "8",
+                     "--max-batch", "4", *extra])
+
+
+def test_serve_cli_clean_run_returns_zero(monkeypatch, tmp_path):
+    out = tmp_path / "serve.json"
+    assert _serve_cli(monkeypatch, "--json", str(out)) == 0
+    rep = json.loads(out.read_text())
+    assert rep["parity"] == {"n": 3, "bucket": 4, "max_abs_diff": 0.0}
+    assert rep["report"]["completed"] == rep["report"]["requests"] == 8
+    assert [r["device_id"] for r in rep["replicas"]] == [0]
+
+
+def test_serve_cli_parity_mismatch_returns_one(monkeypatch, capsys):
+    """A padded-bucket MISMATCH used to be printed and forgotten."""
+    from parallel_cnn_tpu import cli
+
+    monkeypatch.setattr(
+        cli, "_padded_bucket_parity",
+        lambda *a, **k: {"n": 3, "bucket": 4, "max_abs_diff": 1e-3},
+    )
+    assert _serve_cli(monkeypatch) == 1
+    assert "MISMATCH" in capsys.readouterr().out
+
+
+def test_serve_cli_all_requests_failed_returns_one(monkeypatch, capsys):
+    """The batcher turns a device exception into failed futures; a run
+    whose requests all failed used to exit 0."""
+    from parallel_cnn_tpu import cli
+    from parallel_cnn_tpu.serve import engine as engine_mod
+
+    monkeypatch.setattr(
+        cli, "_padded_bucket_parity",
+        lambda *a, **k: {"n": 3, "bucket": 4, "max_abs_diff": 0.0},
+    )
+
+    def device_lost(self, x):
+        raise RuntimeError("device lost (injected)")
+
+    monkeypatch.setattr(engine_mod.Engine, "predict", device_lost)
+    assert _serve_cli(monkeypatch) == 1
+    assert "FAILED: 8/8 requests raised" in capsys.readouterr().out
