@@ -15,7 +15,7 @@ special cases.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -36,9 +36,27 @@ class Module:
 
 @dataclasses.dataclass(frozen=True)
 class Sequential(Module):
-    """Compose modules; params/state are lists aligned with `layers`."""
+    """Compose modules; params/state are lists aligned with `layers`.
+
+    `apply` opens a `jax.named_scope` per child, so every HLO instruction
+    of a compiled program carries the layer it came from in its
+    `op_name` metadata (read back by `obs/programs.py`). `names` gives the
+    scopes (one per layer); without it a child is `<index>.<ClassName>`.
+    Scopes are metadata only: they are not part of `init`'s output, so
+    parameter/state pytrees, checkpoints and plan fingerprints do not
+    depend on them, and neither does the compile-cache key."""
 
     layers: Sequence[Module]
+    names: Optional[Sequence[str]] = None
+
+    def scope_names(self) -> List[str]:
+        if self.names is not None:
+            if len(self.names) != len(self.layers):
+                raise ValueError(
+                    f"{len(self.names)} names for {len(self.layers)} layers"
+                )
+            return list(self.names)
+        return [f"{i}.{type(l).__name__}" for i, l in enumerate(self.layers)]
 
     def init(self, key: jax.Array, in_shape: Shape):
         params: List[Params] = []
@@ -53,7 +71,10 @@ class Sequential(Module):
 
     def apply(self, params, state, x, train: bool = False):
         new_state: List[State] = []
-        for layer, p, s in zip(self.layers, params, state, strict=True):
-            x, s = layer.apply(p, s, x, train)
+        for layer, name, p, s in zip(
+            self.layers, self.scope_names(), params, state, strict=True
+        ):
+            with jax.named_scope(name):
+                x, s = layer.apply(p, s, x, train)
             new_state.append(s)
         return x, new_state

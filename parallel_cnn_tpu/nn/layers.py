@@ -231,12 +231,17 @@ class ConvBNAct(Module):
                     self.relu,
                 )
                 return y, state
-        y, _ = self._conv().apply(params["conv"], {}, x, train)
-        y, bn_s = self._bn().apply(params["bn"], state["bn"], y, train)
+        # Scopes are metadata (the op_name obs/programs.py reads back).
+        with jax.named_scope("conv"):
+            y, _ = self._conv().apply(params["conv"], {}, x, train)
+        with jax.named_scope("bn"):
+            y, bn_s = self._bn().apply(params["bn"], state["bn"], y, train)
         if residual is not None:
-            y = y + residual
+            with jax.named_scope("add"):
+                y = y + residual
         if self.relu:
-            y = jax.nn.relu(y)
+            with jax.named_scope("act"):
+                y = jax.nn.relu(y)
         return y, {"bn": bn_s}
 
 

@@ -70,16 +70,23 @@ class BasicBlock(Module):
 
     def apply(self, params, state, x, train: bool = False):
         head, tail, proj = self._parts()
+        # Branch scopes carry the names `_parts()` gives (and
+        # benchmark/flops.py uses for the same convs).
         if "proj" in params:
-            sc, ps = proj.apply(
-                params["proj"][0], state["proj"][0], x, train
-            )
+            with jax.named_scope("proj"):
+                sc, ps = proj.apply(
+                    params["proj"][0], state["proj"][0], x, train
+                )
         else:
             sc = x
-        y, hs = head.apply(params["main"][0], state["main"][0], x, train)
-        y, ts = tail.apply(
-            params["main"][1], state["main"][1], y, train, residual=sc
-        )
+        with jax.named_scope("head"):
+            y, hs = head.apply(
+                params["main"][0], state["main"][0], x, train
+            )
+        with jax.named_scope("tail"):
+            y, ts = tail.apply(
+                params["main"][1], state["main"][1], y, train, residual=sc
+            )
         new_state = {"main": [hs, ts]}
         if "proj" in params:
             new_state["proj"] = [ps]
@@ -133,16 +140,22 @@ class Bottleneck(Module):
     def apply(self, params, state, x, train: bool = False):
         reduce, mid, expand, proj = self._parts()
         if "proj" in params:
-            sc, ps = proj.apply(
-                params["proj"][0], state["proj"][0], x, train
-            )
+            with jax.named_scope("proj"):
+                sc, ps = proj.apply(
+                    params["proj"][0], state["proj"][0], x, train
+                )
         else:
             sc = x
-        y, rs = reduce.apply(params["main"][0], state["main"][0], x, train)
-        y, ms = mid.apply(params["main"][1], state["main"][1], y, train)
-        y, es = expand.apply(
-            params["main"][2], state["main"][2], y, train, residual=sc
-        )
+        with jax.named_scope("reduce"):
+            y, rs = reduce.apply(
+                params["main"][0], state["main"][0], x, train
+            )
+        with jax.named_scope("mid"):
+            y, ms = mid.apply(params["main"][1], state["main"][1], y, train)
+        with jax.named_scope("expand"):
+            y, es = expand.apply(
+                params["main"][2], state["main"][2], y, train, residual=sc
+            )
         new_state = {"main": [rs, ms, es]}
         if "proj" in params:
             new_state["proj"] = [ps]
@@ -180,12 +193,16 @@ def _resnet(
             MaxPool(window=(3, 3), strides=(2, 2), padding="SAME"),
         ]
     layers = list(stem)
+    # Scope names (Sequential.apply): s<stage>b<block>, 1-based.
+    names = ["stem", "pool"][: len(stem)]
     for i, (features, count) in enumerate(zip((64, 128, 256, 512), stage_sizes)):
         layers += _stage(
             block_cls, features, count, 1 if i == 0 else 2, conv_backend
         )
+        names += [f"s{i + 1}b{j + 1}" for j in range(count)]
     layers += [GlobalAvgPool(), Dense(num_classes)]
-    return Sequential(layers)
+    names += ["gap", "fc"]
+    return Sequential(layers, names)
 
 
 def resnet18(

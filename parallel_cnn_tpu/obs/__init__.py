@@ -11,7 +11,15 @@ and normalize with ``obs = obs or NOOP``: the default is the zero-cost
 no-op bundle, so nothing is paid unless ``ObsConfig`` turned it on.
 
 Spans wrap host-side dispatch only; nothing here ever runs inside a
-jitted body (see the ``train.obs_batched_step`` jaxpr-rules entry).
+jitted body (see the ``train.obs_batched_step`` jaxpr-rules entry). The
+one thing tracing puts inside jitted bodies is metadata, and it lives
+outside this package and is always on: the ``jax.named_scope``s of
+``nn/`` and ``train/zoo.py:make_train_step``. :mod:`.programs` reads them
+back from a compiled program's text — a catalog from HLO instruction to
+layer scope and phase, recorded by ``zoo.train`` when tracing is on and
+written by :meth:`Obs.finish` as ``<run>_programs.json`` — because this
+runtime's device trace names ops by instruction and carries no
+``op_name``.
 """
 
 from __future__ import annotations
@@ -19,6 +27,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
+from parallel_cnn_tpu.obs import programs
 from parallel_cnn_tpu.obs.events import (
     NOOP_JOURNAL,
     EventJournal,
@@ -41,6 +50,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge",
     "EventJournal", "NoopJournal", "NOOP_JOURNAL",
     "read_journal", "merge_journals", "conservation",
+    "programs",
 ]
 
 
@@ -72,6 +82,11 @@ class Obs:
         out: Dict[str, str] = {}
         if self.trace_path and self.tracer.enabled:
             out["trace"] = self.tracer.export(self.trace_path)
+            # Beside it, what names a device profile's ops (programs.py).
+            stem = self.trace_path.removesuffix("_trace.json")
+            written = programs.export(f"{stem}_programs.json")
+            if written:
+                out["programs"] = written
         if self.journal.enabled:
             self.journal.close()
             if self.journal.path:

@@ -3,7 +3,9 @@
 Spans wrap *dispatch* on the host — they never run inside a jitted body,
 so the compiled program is byte-identical with tracing on or off (the
 jaxpr-rules entry ``train.obs_batched_step`` proves this invariant
-statically).  Timing uses the monotonic ``time.perf_counter_ns`` clock;
+statically).  What names the DEVICE's time lives elsewhere: metadata-only
+``jax.named_scope``s in ``nn/`` and ``train/zoo.py``, always on, read back
+from the compiled program by :mod:`parallel_cnn_tpu.obs.programs`.  Timing uses the monotonic ``time.perf_counter_ns`` clock;
 every span records the calling thread, and per-thread/process track
 metadata is emitted so the export loads in Perfetto / ``chrome://tracing``
 with readable lanes.
@@ -17,7 +19,8 @@ Two export shapes are produced in one file:
   different threads (serve submit → complete), correlated by ``id``.
 
 When ``mirror_jax=True`` each span also enters a
-``jax.profiler.TraceAnnotation`` so XLA device profiles carry the same
+``jax.profiler.TraceAnnotation`` (with the span's args, e.g. the zoo
+loop's ``step=`` / ``epoch=`` ids) so XLA device profiles carry the same
 semantic names as the host timeline; the import is guarded so the tracer
 works in jax-free contexts (the analysis stubs).
 
@@ -104,7 +107,8 @@ class _Span:
     def __enter__(self) -> "_Span":
         cls = self._tracer._mirror_cls
         if cls is not None:
-            self._mirror = cls(self.name)
+            # The span's args (step/epoch ids) show in the profiler too.
+            self._mirror = cls(self.name, **self.args)
             self._mirror.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self

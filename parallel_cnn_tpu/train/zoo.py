@@ -384,13 +384,18 @@ def make_train_step(
                 )
         if augment is not None:
             x = augment(key, x)
-        loss, model_state, grads = microbatch_grads(
-            state.params, state.model_state, x, y
-        )
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        # `grad` / `optimizer` (and the layer scopes nn/ opens under
+        # `grad`) are op_name metadata: obs/programs.py reads them back
+        # from the compiled program to tell forward, backward and update.
+        with jax.named_scope("grad"):
+            loss, model_state, grads = microbatch_grads(
+                state.params, state.model_state, x, y
+            )
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         return ZooState(params, model_state, opt_state), loss
 
     return jax.jit(step, donate_argnums=(0,))
@@ -543,9 +548,10 @@ def _make_comm_step(
                     bx, gsum, lsum, model_state = jax.lax.optimization_barrier(
                         (bx, gsum, lsum, model_state)
                     )
-            loss, model_state, grads = grad_fn(
-                grad_params, model_state, bx, by
-            )
+            with jax.named_scope("grad"):
+                loss, model_state, grads = grad_fn(
+                    grad_params, model_state, bx, by
+                )
             lsum = lsum + loss
             if overlap:
                 if plan is None:
@@ -583,8 +589,11 @@ def _make_comm_step(
         )
         loss = jax.lax.pmean(lsum / accum_steps, raxes)
         model_state = jax.lax.pmean(model_state, raxes)
-        updates, opt_state = optimizer.update(grads, state.opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, params
+            )
+            params = optax.apply_updates(params, updates)
         return ZooState(params, model_state, opt_state), loss
 
     specs = dict(
@@ -1196,6 +1205,29 @@ def _device_ids(tree) -> str:
     return ",".join(str(i) for i in sorted(ids))
 
 
+def _catalog_step(step, state, bx, by, key) -> None:
+    """Record the step's compiled program in `obs.programs` (tracing on
+    only): the device trace names ops by HLO instruction, and this is what
+    maps an instruction to its layer scope and phase.
+
+    Called after the first step, with the state that step RETURNED: the
+    same jitted function is lowered and compiled once more for exactly
+    the arguments the loop's next call has — under a mesh the second
+    program (state replicated), the one every later step runs. Lowering
+    takes the arrays themselves (nothing executes, nothing is donated):
+    `ShapeDtypeStruct`s of the same shapes and shardings lower to another
+    compile-cache key, and the load becomes a second full compile (39 s
+    for ResNet-50 on the v5e, PERF.md PR 24). With the persistent compile
+    cache on this is a load."""
+    from parallel_cnn_tpu.obs import programs
+
+    if hasattr(step, "lower"):  # else: no single jitted function to name
+        programs.record(
+            f"jit_{getattr(step, '__name__', 'step')}",
+            step.lower(state, bx, by, key).compile(),
+        )
+
+
 def _native_epoch_batches(np_images, np_labels, batch_size, steps, seed):
     """One epoch of host batches from the C++ prefetch ring, or from its
     bit-identical NumPy twin when the native toolchain is unavailable
@@ -1702,6 +1734,7 @@ def train(
     # what elastic triggers (resize@STEP, schedule STEP:WORLD) reference.
     opt_steps = start_epoch * steps
     _chaos_logged = False
+    _cataloged = False
     while epoch < epochs:
         t0 = time.perf_counter()
         # Per-epoch batch geometry: fixed at (batch_size, steps) unless
@@ -1733,7 +1766,8 @@ def train(
         diverged = None
         batch_iter = enumerate(batches)
         while True:
-            with obs.span("zoo.data", cat="data"):
+            with obs.span("zoo.data", cat="data", step=opt_steps,
+                          epoch=epoch + 1):
                 item = next(batch_iter, None)
             if item is None:
                 break
@@ -1812,11 +1846,17 @@ def train(
                 # device receives its shard only, instead of the whole
                 # batch landing on device 0 and being re-sliced inside
                 # the program.
-                bx, by = mesh_lib.shard_batch(mesh, (bx, by))
+                with obs.span("zoo.shard", cat="data", step=opt_steps,
+                              epoch=epoch + 1):
+                    bx, by = mesh_lib.shard_batch(mesh, (bx, by))
             else:
                 bx, by = jnp.asarray(bx), jnp.asarray(by)
-            with obs.span("zoo.dispatch", cat="step"):
+            with obs.span("zoo.dispatch", cat="step", step=opt_steps,
+                          epoch=epoch + 1):
                 state, loss = step(state, bx, by, key)
+            if obs.enabled and not _cataloged:
+                _cataloged = True
+                _catalog_step(step, state, bx, by, key)
             opt_steps += 1
             if chaos is not None:
                 state, loss = chaos.after_step(state, loss)
@@ -1846,7 +1886,7 @@ def train(
                         verdict.reason
                     )
                     break
-        with obs.span("zoo.readback", cat="step"):
+        with obs.span("zoo.readback", cat="step", epoch=epoch + 1):
             mean_loss = float(epoch_loss) / max(esteps, 1)
         if diverged is None and sentinel is not None:
             verdict = health_check(mean_loss, state)
