@@ -20,6 +20,8 @@ step (see microbatch_grads for why not lax.scan).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 import os
 import time
 from typing import Any, Callable, Optional, Tuple
@@ -1250,6 +1252,138 @@ def _native_epoch_batches(np_images, np_labels, batch_size, steps, seed):
         yield from itertools.islice(it, steps)
 
 
+# A TPU tile is (8, 128). The device lays an NHWC image set out with the
+# BATCH on the lanes (bf16[n,224,224,3] gets {0,2,3,1:T(8,128)(2,1)}), so
+# gathering samples from it copies the whole set into a batch-major layout
+# first, every step. A store of shape (n, shards, rows, 128) with rows a
+# multiple of 8 keeps each sample as whole contiguous tiles, and the same
+# gather reads only what it returns. (With rows not a multiple of 8 the
+# device puts the samples on the sublanes instead: the same trouble one
+# level down.)
+_SUBLANES, _LANES = 8, 128
+
+
+def _in_blocks(total, body, init):
+    """``body(at, size, acc)`` over ``total`` samples, at most 128 at a
+    time: a loop over the whole blocks at offsets ``k * 128`` (which XLA
+    can see are tile-aligned: a block written at an offset it cannot see
+    through takes 2.6 times as long on the chip), then a static last block
+    that starts early enough to be whole and rewrites a few samples of
+    the one before with the same values."""
+    size = min(total, _LANES)
+    acc = jax.lax.fori_loop(
+        0, total // size, lambda k, acc: body(k * size, size, acc), init
+    )
+    return body(total - size, size, acc) if total % size else acc
+
+
+@jax.jit
+def _rows128(images):
+    """(n, ...) -> (n, 1, rows, 128), each sample zero-padded to whole
+    tiles, a block of samples at a time so that the re-layout's
+    temporaries are one block's, not the data set's."""
+    n = images.shape[0]
+    rows = math.prod(images.shape[1:]) // _LANES
+    pad = -rows % _SUBLANES
+
+    def block(at, size, store):
+        part = jax.lax.dynamic_slice_in_dim(images, at, size, 0)
+        part = part.reshape(size, 1, rows, _LANES)
+        # Padded here, and not by a narrower update of a zeroed store: XLA
+        # then keeps the store row-major through the loop (else it builds
+        # it batch-minor and re-lays all of it out once more at the end).
+        part = jnp.pad(part, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        return jax.lax.dynamic_update_slice_in_dim(store, part, at, 0)
+
+    return _in_blocks(
+        n, block, jnp.empty((n, 1, rows + pad, _LANES), images.dtype)
+    )
+
+
+def _batch_shards(mesh):
+    """(how many ways `mesh_lib.batch_sharding` cuts a batch, over which
+    mesh axes)."""
+    axes = mesh_lib.batch_sharding(mesh).spec[0]
+    names = axes if isinstance(axes, tuple) else (axes,)
+    return math.prod(mesh.shape[a] for a in names), axes
+
+
+def resident_store(images, mesh=None):
+    """What the device loader keeps in HBM for `select_batch`: ``(store,
+    layout, over)``. Samples of a whole number of 128-lane rows (ImageNet
+    224x224x3, CIFAR 32x32x3) are stored row-major, ``(n, shards, rows,
+    128)`` ("rows128"); any other sample size stays as it came
+    ("indexed"). Under a ``mesh`` whose batch shards cut a sample's
+    leading axis into slabs of whole rows, the store lies across it, each
+    chip holding its slab of every sample (``over`` is that mesh): 1/shards
+    of the set a chip, and `select_batch` then emits the batch already
+    laid out as `mesh_lib.shard_batch` would. Otherwise ``shards`` is 1
+    and ``over`` None. The rule is on the shapes alone."""
+    feat = math.prod(images.shape[1:])
+    if images.shape[0] == 0 or feat % _LANES:
+        return jnp.asarray(images), "indexed", None
+    shards, axes = _batch_shards(mesh) if mesh is not None else (1, None)
+    if shards == 1 or images.shape[1] % shards or (feat // shards) % _LANES:
+        return _rows128(jnp.asarray(images)), "rows128", None
+    # Each chip is sent its slab of every sample and re-lays it out itself.
+    slabs = jax.device_put(images, NamedSharding(mesh, P(None, axes)))
+    return jax.jit(jax.shard_map(
+        _rows128, mesh=mesh, in_specs=P(None, axes),
+        out_specs=P(None, axes, None, None),
+        check_vma=False,  # its loop starts from zeros no chip varies
+    ))(slabs), "rows128", mesh
+
+
+def _fetch(store, idx, in_shape):
+    """Samples ``idx`` of a store (or of one chip's slabs of it), shaped
+    ``(len(idx), *in_shape)``, fetched a block at a time — one lane tile
+    of the batch-minor layout the step takes its images in: the re-layout
+    of a block (77 MB for 256 images of 224x224x3 in bf16 still fits,
+    154 MB does not) then stays in the chip's fast memory, which halves
+    the time at batch 512 and 1,024."""
+    rows = math.prod(in_shape) // _LANES  # without a rows128 store's padding
+
+    def block(at, size, out):
+        part = store[jax.lax.dynamic_slice_in_dim(idx, at, size)]
+        if part.shape[1:] != in_shape:
+            part = part[:, 0, :rows].reshape(size, *in_shape)
+        return jax.lax.dynamic_update_slice_in_dim(out, part, at, 0)
+
+    return _in_blocks(
+        idx.shape[0], block,
+        jnp.empty((idx.shape[0], *in_shape), store.dtype),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("batch", "in_shape", "over"))
+def select_batch(store, labels, perm, i, *, batch, in_shape, over=None):
+    """Batch ``i`` of an epoch shuffled by ``perm``: samples
+    ``perm[i*batch:(i+1)*batch]`` of `resident_store`'s array, shaped
+    ``(batch, *in_shape)``, and their labels. One program a step (``i`` is
+    traced); its module name, ``jit_select_batch``, is what the
+    benchmark's ``batch_select_ms`` and the ledger's breakdown read.
+
+    With the store ``over`` a mesh every chip fetches its slab of the
+    whole batch and one all-to-all trades slabs for samples, so the batch
+    leaves in `mesh_lib.batch_sharding` and no chip ever holds all of it."""
+    idx = jax.lax.dynamic_slice(perm, (i * batch,), (batch,))
+    if over is None:
+        return _fetch(store, idx, in_shape), labels[idx]
+    shards, axes = _batch_shards(over)
+    slab = (in_shape[0] // shards, *in_shape[1:])
+    images = jax.shard_map(
+        lambda rows, idx: jax.lax.all_to_all(
+            _fetch(rows, idx, slab), axes, 0, 1, tiled=True
+        ),
+        mesh=over, in_specs=(P(None, axes, None, None), P()),
+        out_specs=P(axes),
+        check_vma=False,  # _fetch's loop starts from a buffer no chip varies
+    )(store, idx)
+    return images, jax.lax.with_sharding_constraint(
+        labels[idx], mesh_lib.batch_sharding(over)
+    )
+
+
 def train(
     model: Module,
     images,
@@ -1314,8 +1448,26 @@ def train(
       included — no separate reconstruction that could drift), compile
       excluded. Open in XProf/TensorBoard; this is the single-chip MFU
       attribution tool.
-    - ``loader``: "device" (default) keeps the dataset in HBM and gathers
-      each shuffled batch on-device; "native" feeds batches from the C++
+    - ``loader``: "device" (default) keeps the dataset in HBM and takes
+      each shuffled batch from it with one jitted program a step
+      (`select_batch`). What stays resident is `resident_store`'s array:
+      samples of ``F`` elements with ``F % 128 == 0`` (ImageNet 224x224x3,
+      CIFAR 32x32x3) are stored row-major as ``(n, shards, rows, 128)``,
+      so selecting a batch reads a batch and not the data set (an NHWC
+      set lies batch-minor on the device, and a gather from it
+      re-lays-out all of it every step); any other ``F`` (MNIST's 784) is
+      kept as it came. Under a ``mesh`` whose batch shards cut a
+      sample's leading axis into slabs of whole 128-element rows
+      (224 rows over 4 or 8 chips), each chip holds its slab of every
+      sample, 1/shards of the set, and the batch is emitted in
+      `mesh_lib.shard_batch`'s layout by one all-to-all; if not (or
+      under ``elastic``, whose mesh can change) the store stays on one
+      device and `shard_batch` lays each batch out as before. Batches
+      are the same either way: rows ``perm[i*B:(i+1)*B]`` of the
+      ``seed + epoch`` permutation. The loop holds the store only, so
+      host arrays passed in are resident once; a caller who passes
+      device arrays and keeps them holds the
+      set twice. "native" feeds batches from the C++
       prefetch ring (data/native.py — a worker thread assembles the next
       shuffled batch while the device trains, now shape-generic beyond
       28×28). The ring is recreated per epoch with seed
@@ -1715,15 +1867,32 @@ def train(
                 exec_plan, z3_plan.shards, n_hosts=z3_host), lr)
         ] = step
 
-    n = images.shape[0]
+    n, row_shape = images.shape[0], tuple(images.shape[1:])
     if loader == "native":
         import numpy as _np
 
         np_images = _np.ascontiguousarray(images, dtype=_np.float32)
         np_labels = _np.ascontiguousarray(labels, dtype=_np.int32)
     else:
-        images = jnp.asarray(images)
-        labels = jnp.asarray(labels)
+        # Across the mesh unless it can change under the loop (elastic):
+        # the store has to outlive any one mesh.
+        store, layout, over = resident_store(
+            images, mesh if elastic_ctl is None else None
+        )
+        # What every chip reads whole is put on every chip once, not moved
+        # there by each call of select_batch.
+        everywhere = jnp.asarray if over is None else functools.partial(
+            jax.device_put, device=mesh_lib.replicated(over))
+        labels = everywhere(jnp.asarray(labels))
+        if obs.enabled:
+            obs.event(
+                "zoo_loader", layout=layout, rows=n,
+                row_bytes=math.prod(row_shape) * store.dtype.itemsize,
+                shards=1 if over is None else _batch_shards(over)[0],
+            )
+        # The store is the loop's only copy: a caller who passed host
+        # arrays holds the set once, one who passed device arrays twice.
+        images = None
     aug_base = jax.random.key(seed ^ 0x5EED)
     if sentinel is not None:
         last_good = tree_copy(state)
@@ -1757,10 +1926,11 @@ def train(
                 np_images, np_labels, ebatch, esteps, seed + epoch + 1
             )
         else:
-            perm = jax.random.permutation(jax.random.key(seed + epoch), n)
+            perm = everywhere(
+                jax.random.permutation(jax.random.key(seed + epoch), n))
             batches = (
-                (images[perm[i * ebatch : (i + 1) * ebatch]],
-                 labels[perm[i * ebatch : (i + 1) * ebatch]])
+                select_batch(store, labels, perm, i, batch=ebatch,
+                             in_shape=row_shape, over=over)
                 for i in range(esteps)
             )
         diverged = None
@@ -1990,8 +2160,13 @@ def train(
     if profile_trace_dir:
         from parallel_cnn_tpu.utils import profiling
 
-        bx = jnp.asarray(images[:batch_size])
-        by = jnp.asarray(labels[:batch_size])
+        if loader == "native":
+            bx = jnp.asarray(images[:batch_size])
+            by = jnp.asarray(labels[:batch_size])
+        else:
+            bx, by = select_batch(store, labels, jnp.arange(n), 0,
+                                  batch=batch_size, in_shape=row_shape,
+                                  over=over)
         total = epochs * steps
 
         def pkey(i):

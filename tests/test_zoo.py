@@ -510,3 +510,136 @@ def test_batchnorm_normalizes_and_bf16_tracks_f32():
     np.testing.assert_allclose(
         np.asarray(y16, np.float32), np.asarray(y), atol=0.35
     )
+
+
+# ------------------------------------- the device loader: store + select_batch
+
+def _eager_batch(images, labels, perm, i, batch):
+    """The loop's indexing before `select_batch`: the batches it must equal."""
+    idx = perm[i * batch : (i + 1) * batch]
+    return images[idx], labels[idx]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize(
+    "in_shape,layout",
+    [((32, 32, 3), "rows128"), ((16, 16, 8), "rows128"),
+     ((28, 28, 1), "indexed"), ((8, 8, 3), "indexed")],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v,
+)
+def test_select_batch_equals_eager_indexing_on_both_sides_of_the_rule(
+        in_shape, layout, dtype):
+    # neither a whole number of 128-sample blocks (the conversion's) nor of
+    # 128-sample pieces (the selection's): both last ones overlap
+    n, batch = 400, 176
+    rng = np.random.default_rng(7)
+    images = jnp.asarray(rng.uniform(size=(n, *in_shape)), dtype)
+    labels = jnp.asarray(rng.integers(0, 10, n), jnp.int32)
+    store, got_layout, over = zoo.resident_store(images)
+    assert over is None
+    assert got_layout == layout
+    f = int(np.prod(in_shape))
+    assert store.shape == ((n, 1, f // 128, 128) if layout == "rows128" else images.shape)
+    assert store.dtype == images.dtype
+    for epoch in range(2):
+        perm = jax.random.permutation(jax.random.key(5 + epoch), n)
+        for i in range(n // batch):
+            bx, by = zoo.select_batch(store, labels, perm, i, batch=batch,
+                                      in_shape=in_shape)
+            wx, wy = _eager_batch(images, labels, perm, i, batch)
+            assert bx.shape == wx.shape and bx.dtype == wx.dtype
+            assert np.array_equal(np.asarray(bx, np.float32), np.asarray(wx, np.float32))
+            assert np.array_equal(np.asarray(by), np.asarray(wy))
+
+
+def _train_two_epochs(images, labels, **kw):
+    return zoo.train(
+        cifar.cifar_cnn(), images, labels, in_shape=cifar.IN_SHAPE, epochs=2,
+        batch_size=32, lr=0.02, seed=3, verbose=False, **kw)
+
+
+def test_device_loader_losses_equal_eager_indexing_to_the_bit(monkeypatch):
+    imgs, labels = synthetic.make_image_dataset(128, seed=4)  # 32x32x3: rows128
+    _, losses = _train_two_epochs(imgs, labels)
+    x, y = jnp.asarray(imgs), jnp.asarray(labels)
+    monkeypatch.setattr(
+        zoo, "select_batch",
+        lambda store, lab, perm, i, *, batch, in_shape, over: _eager_batch(x, y, perm, i, batch))
+    _, eager = _train_two_epochs(imgs, labels)
+    assert losses == eager and all(np.isfinite(losses))
+
+
+def test_select_batch_is_one_executable_and_the_loader_says_its_layout(tmp_path):
+    from parallel_cnn_tpu import obs as obs_lib
+
+    imgs, labels = synthetic.make_image_dataset(128, seed=4)
+    zoo.select_batch.clear_cache()
+    journal = obs_lib.EventJournal(str(tmp_path / "zoo.jsonl"))
+    bundle = obs_lib.Obs(obs_lib.NOOP_TRACER, obs_lib.MetricsRegistry(), journal,
+                         enabled=True)
+    _train_two_epochs(imgs, labels, obs=bundle)
+    assert zoo.select_batch._cache_size() == 1  # the step index is traced
+    journal.close()
+    events = [e for e in obs_lib.read_journal(journal.path) if e["kind"] == "zoo_loader"]
+    assert len(events) == 1
+    assert (events[0]["layout"], events[0]["rows"], events[0]["row_bytes"]) == (
+        "rows128", 128, 32 * 32 * 3 * 4)
+
+
+@pytest.mark.parametrize(
+    "in_shape,over_mesh",
+    [((32, 32, 3), True),    # 8 slabs of 4 image rows = 3 lane rows each
+     ((16, 16, 8), True),    # 2 image rows = 2 lane rows
+     ((4, 32, 8), False),    # rows128, but 4 image rows do not cut 8 ways
+     ((28, 28, 1), False)],  # indexed: never over the mesh
+    ids=["32x32x3", "16x16x8", "4x32x8", "28x28x1"],
+)
+@pytest.mark.parametrize("hier", [False, True], ids=["data8", "host2xdata4"])
+def test_select_batch_over_a_mesh_equals_eager_indexing(in_shape, over_mesh, hier):
+    """Under a mesh the rows128 store lies across it where a sample cuts
+    into slabs of whole 128-lane rows; the batch then leaves already in
+    `shard_batch`'s sharding, and is the same batch."""
+    n, batch = 400, 176
+    mesh = (mesh_lib.make_hier_mesh(n_hosts=2) if hier
+            else mesh_lib.make_mesh(MeshConfig(data=8, model=1)))
+    rng = np.random.default_rng(11)
+    images = jnp.asarray(rng.uniform(size=(n, *in_shape)), jnp.bfloat16)
+    labels = jnp.asarray(rng.integers(0, 10, n), jnp.int32)
+    store, _, over = zoo.resident_store(images, mesh)
+    assert (over is mesh) == over_mesh
+    want = mesh_lib.batch_sharding(mesh)
+    perm = jax.random.permutation(jax.random.key(9), n)
+    for i in range(n // batch):
+        bx, by = zoo.select_batch(store, labels, perm, i, batch=batch,
+                                  in_shape=in_shape, over=over)
+        wx, wy = _eager_batch(images, labels, perm, i, batch)
+        assert np.array_equal(np.asarray(bx, np.float32), np.asarray(wx, np.float32))
+        assert np.array_equal(np.asarray(by), np.asarray(wy))
+        if over_mesh:
+            assert bx.sharding.is_equivalent_to(want, bx.ndim)
+            assert by.sharding.is_equivalent_to(want, by.ndim)
+            assert max(s.data.shape[0] for s in bx.addressable_shards) == batch // 8
+    if over_mesh:  # 1/8 of the set a chip: its slab of every sample, in whole tiles
+        assert {s.data.shape for s in store.addressable_shards} == {(n, 1, 8, 128)}
+
+
+def test_device_loader_under_a_mesh_places_batch_and_state_as_before(monkeypatch):
+    class Rec:
+        def record(self, **rec):
+            self.last = rec
+
+    imgs, labels = synthetic.make_image_dataset(128, seed=4)
+    mesh = mesh_lib.make_mesh(MeshConfig(data=8, model=1))
+    rec = Rec()
+    _, losses = _train_two_epochs(imgs, labels, mesh=mesh, metrics=rec)
+    everywhere = ",".join(str(d.id) for d in sorted(jax.devices(), key=lambda d: d.id))
+    assert rec.last["state_devices"] == everywhere
+    assert rec.last["batch_devices"] == everywhere
+    # and the losses are those of batches indexed eagerly and laid out by
+    # shard_batch alone, to the bit
+    x, y = jnp.asarray(imgs), jnp.asarray(labels)
+    monkeypatch.setattr(
+        zoo, "select_batch",
+        lambda store, lab, perm, i, *, batch, in_shape, over: _eager_batch(x, y, perm, i, batch))
+    _, eager = _train_two_epochs(imgs, labels, mesh=mesh)
+    assert losses == eager and all(np.isfinite(losses))
