@@ -44,7 +44,7 @@ def build_parser() -> argparse.ArgumentParser:
     d, t = DataConfig(), TrainConfig()
     p.add_argument("--model", default="lenet_ref",
                    choices=["lenet_ref", "cifar_cnn", "resnet18", "resnet34",
-                            "resnet50", "vgg16"],
+                            "resnet50", "vgg16", "convnext_b"],
                    help="lenet_ref = the reference-parity trainer; the rest "
                         "route to the model-zoo trainer (train/zoo.py, "
                         "synthetic CIFAR-shape data, SGD+momentum)")
@@ -596,7 +596,7 @@ def build_serve_parser(cmd: str) -> argparse.ArgumentParser:
     )
     p.add_argument("--model", default=sc.model,
                    choices=["lenet_ref", "cifar_cnn", "resnet18", "resnet34",
-                            "resnet50", "vgg16"],
+                            "resnet50", "vgg16", "convnext_b"],
                    help="registry name (serve/registry.py); must match the "
                         "checkpoint's model [PCNN_SERVE_MODEL]")
     p.add_argument("--checkpoint", default=sc.checkpoint,
@@ -1141,7 +1141,7 @@ def _run_tune(argv: List[str]) -> int:
     )
     p.add_argument("--model", default="cifar_cnn",
                    choices=["cifar_cnn", "resnet18", "resnet34", "resnet50",
-                            "vgg16"],
+                            "vgg16", "convnext_b"],
                    help="zoo model the plan space is profiled for")
     p.add_argument("--global-batch", type=int, default=128, metavar="B",
                    help="global batch size every plan must serve")
@@ -1172,7 +1172,7 @@ def _run_tune(argv: List[str]) -> int:
                         "are identical by construction — debug only)")
     args = p.parse_args(argv)
 
-    from parallel_cnn_tpu.nn import cifar, resnet, vgg
+    from parallel_cnn_tpu.nn import cifar, convnext, resnet, vgg
 
     factories = {
         "cifar_cnn": lambda: cifar.cifar_cnn(),
@@ -1180,6 +1180,7 @@ def _run_tune(argv: List[str]) -> int:
         "resnet34": lambda: resnet.resnet34(10, cifar_stem=True),
         "resnet50": lambda: resnet.resnet50(10, cifar_stem=True),
         "vgg16": lambda: vgg.vgg16(10),
+        "convnext_b": lambda: convnext.convnext_b(10),
     }
     model = factories[args.model]()
     mp = autotune_lib.profile_module(model, cifar.IN_SHAPE, name=args.model)
@@ -1483,7 +1484,8 @@ def _run_async_lenet(args, cfg: Config, train_ds, test_ds, chaos) -> int:
 
 
 def _run_zoo(args: argparse.Namespace, cfg: Config) -> int:
-    """Zoo-model driver branch (--model {cifar_cnn,resnet18,34,50,vgg16}).
+    """Zoo-model driver branch (--model {cifar_cnn,resnet18,34,50,vgg16,
+    convnext_b}).
 
     Trains on the deterministic synthetic CIFAR-shape stand-in (this
     environment cannot fetch CIFAR/ImageNet — BASELINE.md), with the
@@ -1494,7 +1496,7 @@ def _run_zoo(args: argparse.Namespace, cfg: Config) -> int:
     """
     from parallel_cnn_tpu import plan as plan_lib
     from parallel_cnn_tpu.data import synthetic
-    from parallel_cnn_tpu.nn import cifar, resnet, vgg
+    from parallel_cnn_tpu.nn import cifar, convnext, resnet, vgg
     from parallel_cnn_tpu.resilience import ChaosMonkey
     from parallel_cnn_tpu.resilience import preempt
     from parallel_cnn_tpu.train import zoo
@@ -1512,8 +1514,9 @@ def _run_zoo(args: argparse.Namespace, cfg: Config) -> int:
             10, cifar_stem=True, conv_backend=args.conv_backend
         ),
         "vgg16": lambda: vgg.vgg16(10, conv_backend=args.conv_backend),
+        "convnext_b": lambda: convnext.convnext_b(10),
     }
-    if cfg.model == "cifar_cnn" and args.conv_backend != "xla":
+    if cfg.model in ("cifar_cnn", "convnext_b") and args.conv_backend != "xla":
         raise SystemExit(
             "--conv-backend pallas applies to the resnet/vgg models"
         )

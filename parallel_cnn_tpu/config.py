@@ -323,7 +323,7 @@ class ServeConfig:
     has the queueing model and the bucket/padding cost math)."""
 
     # Registry name (serve/registry.py): lenet_ref, cifar_cnn,
-    # resnet18/34/50, vgg16.
+    # resnet18/34/50, vgg16, convnext_b.
     model: str = "cifar_cnn"
     # Checkpoint to restore params (+ BN stats) from; None serves
     # seed-initialized weights (bench/smoke mode).

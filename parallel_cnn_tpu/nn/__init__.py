@@ -22,9 +22,13 @@ from parallel_cnn_tpu.nn.layers import (  # noqa: F401
     BatchNorm,
     Conv2D,
     Dense,
+    DropPath,
     Flatten,
+    GELU,
     GlobalAvgPool,
+    LayerNorm,
+    LayerScale,
     MaxPool,
     ReLU,
 )
-from parallel_cnn_tpu.nn import cifar, resnet, vgg  # noqa: F401
+from parallel_cnn_tpu.nn import cifar, convnext, resnet, vgg  # noqa: F401

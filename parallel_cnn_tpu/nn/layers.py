@@ -11,7 +11,7 @@ kernel, MPI/layer.h)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +22,17 @@ from parallel_cnn_tpu.nn.core import Module, Shape
 
 def _he_normal(key, shape, fan_in, dtype):
     return jax.random.normal(key, shape, dtype) * jnp.sqrt(2.0 / fan_in)
+
+
+def _weight(key, shape, fan_in, init_std):
+    """He normal, or (``init_std`` given) the ViT/ConvNeXt convention: a
+    normal of that std cut at +-2 in absolute terms, as timm's
+    ``trunc_normal_(std=.02)`` draws it."""
+    if init_std is None:
+        return _he_normal(key, shape, fan_in, jnp.float32)
+    cut = 2.0 / init_std
+    return init_std * jax.random.truncated_normal(
+        key, -cut, cut, shape, jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,6 +46,11 @@ class Conv2D(Module):
     config #4). Unsupported shapes raise at construction-use time rather
     than silently falling back, so a "pallas" model is what it claims
     to be.
+
+    ``groups`` > 1 is a grouped conv (``groups == in channels ==
+    features``: depthwise): the weight is ``(kh, kw, cin / groups,
+    features)`` and each output channel reads its own group's inputs
+    only. XLA only: the pallas kernels have no grouped form.
     """
 
     features: int
@@ -43,20 +59,30 @@ class Conv2D(Module):
     padding: str = "SAME"
     use_bias: bool = True
     backend: str = "xla"
+    groups: int = 1
+    init_std: Optional[float] = None
 
     def init(self, key, in_shape: Shape):
         h, w, c = in_shape
         kh, kw = self.kernel
+        if c % self.groups or self.features % self.groups:
+            raise ValueError(
+                f"groups={self.groups} divides neither {c} input channels "
+                f"nor {self.features} features evenly"
+            )
         wkey, _ = jax.random.split(key)
-        fan_in = kh * kw * c
+        cin = c // self.groups
+        fan_in = kh * kw * cin
         params = {
-            "w": _he_normal(wkey, (kh, kw, c, self.features), fan_in, jnp.float32)
+            "w": _weight(wkey, (kh, kw, cin, self.features), fan_in,
+                         self.init_std)
         }
         if self.use_bias:
             params["b"] = jnp.zeros((self.features,), jnp.float32)
+        # (the shape rule knows no groups: one group's channels stand in)
         out = lax.conv_general_shape_tuple(
-            (1, h, w, c),
-            (kh, kw, c, self.features),
+            (1, h, w, cin),
+            (kh, kw, cin, self.features),
             self.strides,
             self.padding,
             ("NHWC", "HWIO", "NHWC"),
@@ -67,10 +93,12 @@ class Conv2D(Module):
         if self.backend == "pallas":
             from parallel_cnn_tpu.ops import pallas_conv
 
-            if not pallas_conv.supports(self.kernel, self.strides, self.padding):
+            if self.groups != 1 or not pallas_conv.supports(
+                    self.kernel, self.strides, self.padding):
                 raise ValueError(
                     f"pallas conv backend does not cover kernel={self.kernel} "
-                    f"strides={self.strides} padding={self.padding!r}"
+                    f"strides={self.strides} padding={self.padding!r} "
+                    f"groups={self.groups}"
                 )
             y = pallas_conv.conv2d(
                 x, params["w"].astype(x.dtype), self.strides[0]
@@ -82,6 +110,7 @@ class Conv2D(Module):
                 self.strides,
                 self.padding,
                 dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                feature_group_count=self.groups,
             )
         if self.use_bias:
             y = y + params["b"].astype(x.dtype)
@@ -90,16 +119,20 @@ class Conv2D(Module):
 
 @dataclasses.dataclass(frozen=True)
 class Dense(Module):
+    """Affine map over the LAST axis of an input of any rank: a classifier
+    head on ``(N, d)``, a pointwise (1x1) layer on ``(N, H, W, d)``."""
+
     features: int
+    init_std: Optional[float] = None
 
     def init(self, key, in_shape: Shape):
-        (d,) = in_shape
+        d = in_shape[-1]
         wkey, _ = jax.random.split(key)
         params = {
-            "w": _he_normal(wkey, (d, self.features), d, jnp.float32),
+            "w": _weight(wkey, (d, self.features), d, self.init_std),
             "b": jnp.zeros((self.features,), jnp.float32),
         }
-        return params, {}, (self.features,)
+        return params, {}, (*in_shape[:-1], self.features)
 
     def apply(self, params, state, x, train: bool = False):
         return x @ params["w"].astype(x.dtype) + params["b"].astype(x.dtype), state
@@ -252,6 +285,103 @@ class ReLU(Module):
 
     def apply(self, params, state, x, train: bool = False):
         return jax.nn.relu(x), state
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerNorm(Module):
+    """Normalise over the LAST axis (channels-last LayerNorm, as ConvNeXt
+    applies it at every position), then scale and shift. Mean and variance
+    are taken in float32; the output is at ``x.dtype``."""
+
+    eps: float = 1e-6
+
+    def init(self, key, in_shape: Shape):
+        c = in_shape[-1]
+        params = {
+            "scale": jnp.ones((c,), jnp.float32),
+            "bias": jnp.zeros((c,), jnp.float32),
+        }
+        return params, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+        y = (xf - mean) * lax.rsqrt(var + self.eps)
+        return (y * params["scale"] + params["bias"]).astype(x.dtype), state
+
+
+@dataclasses.dataclass(frozen=True)
+class GELU(Module):
+    """Exact GELU, x * Phi(x) (the erf form, not the tanh approximation)."""
+
+    def init(self, key, in_shape: Shape):
+        return {}, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        return jax.nn.gelu(x, approximate=False), state
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerScale(Module):
+    """Per-channel learned gain on a residual branch (Touvron et al. 2021),
+    initialised small so that a deep net starts near the identity."""
+
+    init_value: float = 1e-6
+
+    def init(self, key, in_shape: Shape):
+        c = in_shape[-1]
+        return {"gamma": jnp.full((c,), self.init_value, jnp.float32)}, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        return x * params["gamma"].astype(x.dtype), state
+
+
+@dataclasses.dataclass(frozen=True)
+class DropPath(Module):
+    """Stochastic depth (Huang et al. 2016) on a residual branch: in
+    training each SAMPLE's branch is dropped with probability ``rate`` and
+    the kept ones are scaled by ``1 / (1 - rate)``; the identity when
+    ``train=False`` or ``rate == 0``.
+
+    The layer is random in training, and its key lives in its ``state``
+    (the Module protocol's slot for non-trainables, as BatchNorm's running
+    statistics do): ``init`` stores the raw key data of its init key, and
+    each training ``apply`` does exactly
+
+        carry, draw = jax.random.split(jax.random.wrap_key_data(state["key"]))
+        keep = jax.random.bernoulli(draw, 1 - rate, (n, 1, ..., 1))
+        y = x * keep / (1 - rate);   new state = {"key": key_data(carry)}
+
+    so a train step stays ``(state, x, y)``, gradient accumulation draws a
+    fresh mask per microbatch (the state threads through them), a
+    checkpoint carries the stream, and a resumed run continues it. The
+    plain reference (benchmark/reference/convnext.py) repeats these calls
+    to reproduce the masks from the state it is handed."""
+
+    rate: float = 0.0
+
+    def init(self, key, in_shape: Shape):
+        return {}, {"key": jax.random.key_data(key)}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        if not train or self.rate == 0.0:
+            return x, state
+        carry, draw = jax.random.split(jax.random.wrap_key_data(state["key"]))
+        keep = jax.random.bernoulli(
+            draw, 1.0 - self.rate, (x.shape[0],) + (1,) * (x.ndim - 1))
+        y = x * (keep.astype(x.dtype) / (1.0 - self.rate))
+        return y, {"key": jax.random.key_data(carry)}
+
+
+def has_random_state(model_state) -> bool:
+    """True where a model's state holds a layer's PRNG key data (a
+    ``DropPath``): an unsigned-integer leaf. Step builders that average
+    the state over shards use this to refuse such a model by name."""
+    return any(
+        jnp.issubdtype(getattr(leaf, "dtype", jnp.float32), jnp.unsignedinteger)
+        for leaf in jax.tree_util.tree_leaves(model_state)
+    )
 
 
 def _pool_out(in_shape: Shape, window, strides, padding) -> Shape:

@@ -71,7 +71,7 @@ def _zoo_handle(name: str, factory, in_shape, n_outputs) -> ModelHandle:
 
 def available() -> Tuple[str, ...]:
     return ("lenet_ref", "cifar_cnn", "resnet18", "resnet34", "resnet50",
-            "vgg16")
+            "vgg16", "convnext_b")
 
 
 def get(name: str, conv_backend: str = "xla") -> ModelHandle:
@@ -87,7 +87,7 @@ def get(name: str, conv_backend: str = "xla") -> ModelHandle:
             )
         return _lenet_handle()
 
-    from parallel_cnn_tpu.nn import cifar, resnet, vgg
+    from parallel_cnn_tpu.nn import cifar, convnext, resnet, vgg
 
     zoo: Dict[str, Tuple[Callable, Tuple[int, ...], int]] = {
         "cifar_cnn": (lambda: cifar.cifar_cnn(), cifar.IN_SHAPE, 10),
@@ -102,12 +102,13 @@ def get(name: str, conv_backend: str = "xla") -> ModelHandle:
         ), cifar.IN_SHAPE, 10),
         "vgg16": (lambda: vgg.vgg16(10, conv_backend=conv_backend),
                   cifar.IN_SHAPE, 10),
+        "convnext_b": (lambda: convnext.convnext_b(10), cifar.IN_SHAPE, 10),
     }
     if name not in zoo:
         raise KeyError(
             f"unknown model {name!r}; registered: {', '.join(available())}"
         )
-    if name == "cifar_cnn" and conv_backend != "xla":
+    if name in ("cifar_cnn", "convnext_b") and conv_backend != "xla":
         raise ValueError(
             "conv_backend='pallas' applies to the resnet/vgg models"
         )

@@ -234,6 +234,7 @@ def make_pipeline_step(
 
     def shard_body(state: ZooState, x, y):
         params, model_state = state.params, state.model_state
+        zoo.refuse_random_layers(model_state, "pipeline step")
         if x.shape[0] % n_micro:
             raise ValueError(
                 f"per-device batch {x.shape[0]} must be a multiple of "

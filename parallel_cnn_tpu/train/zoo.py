@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from parallel_cnn_tpu import obs as obs_lib
 from parallel_cnn_tpu.nn.core import Module
+from parallel_cnn_tpu.nn.layers import has_random_state
 from parallel_cnn_tpu.parallel import mesh as mesh_lib
 from parallel_cnn_tpu.parallel.mesh import DATA_AXIS, HOST_AXIS, STAGE_AXIS
 
@@ -139,6 +140,9 @@ def _build_loss_fn(model: Module, fused) -> Callable:
     return loss_fn
 
 
+OPTIMIZERS = ("sgd", "adamw")
+
+
 def make_optimizer(
     lr: float = 0.1,
     momentum: float = 0.9,
@@ -146,8 +150,20 @@ def make_optimizer(
     schedule: str = "constant",
     warmup_steps: int = 0,
     total_steps: Optional[int] = None,
+    kind: str = "sgd",
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
 ) -> optax.GradientTransformation:
-    """SGD(+momentum, +decoupled weight decay) with an LR schedule.
+    """The zoo's optimizer under an LR schedule.
+
+    kind: "sgd" — SGD(+``momentum``) with ``weight_decay`` added to the
+    gradient of every parameter (L2); "adamw" — Adam(``b1``, ``b2``,
+    ``eps``, bias-corrected) with DECOUPLED decay, ``p -= lr * (adam +
+    weight_decay * p)``, applied to parameters of rank >= 2 only (conv and
+    linear weights; biases, norm scales and LayerScale gains are not
+    decayed — the ConvNeXt authors' implementation; their paper is
+    silent). ``momentum`` is SGD's, ``b1``/``b2``/``eps`` are AdamW's.
 
     schedule: "constant" (optional linear warmup over `warmup_steps`) or
     "cosine" (linear warmup then cosine decay to 0 over `total_steps` —
@@ -155,6 +171,10 @@ def make_optimizer(
     time; the step count lives in the optimizer state, so it checkpoints
     and resumes with the rest of ZooState).
     """
+    if kind not in OPTIMIZERS:
+        raise ValueError(
+            f"unknown optimizer kind {kind!r}; known: {', '.join(OPTIMIZERS)}"
+        )
     if schedule == "cosine":
         if not total_steps:
             raise ValueError("schedule='cosine' needs total_steps")
@@ -169,6 +189,13 @@ def make_optimizer(
             lr = optax.linear_schedule(0.0, lr, warmup_steps)
     else:
         raise ValueError(f"unknown schedule {schedule!r}")
+    if kind == "adamw":
+        return optax.adamw(
+            lr, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
+            mask=lambda params: jax.tree_util.tree_map(
+                lambda p: p.ndim >= 2, params
+            ),
+        )
     txs = []
     if weight_decay:
         txs.append(optax.add_decayed_weights(weight_decay))
@@ -403,6 +430,26 @@ def make_train_step(
     return jax.jit(step, donate_argnums=(0,))
 
 
+class RandomLayerUnsupported(ValueError):
+    """A step factory that cannot carry a random layer's key was handed a
+    model that has one (nn/layers.py:DropPath)."""
+
+
+def refuse_random_layers(model_state, step: str) -> None:
+    """The explicit shard_map steps run one body per shard and average the
+    model state over the shards: every shard would draw the same
+    per-sample masks from a layer's key, and a mean of key data means
+    nothing. They refuse such a model by name; the GSPMD step
+    (`make_train_step` without ``comm``) is one program over the global
+    batch and needs neither."""
+    if has_random_state(model_state):
+        raise RandomLayerUnsupported(
+            f"the {step} does not run a model with a layer that is random "
+            "in training (DropPath): use the default GSPMD step "
+            "(comm=None, no fused.update, no pipeline)"
+        )
+
+
 def _make_comm_step(
     model: Module,
     optimizer: optax.GradientTransformation,
@@ -499,6 +546,7 @@ def _make_comm_step(
 
     def shard_body(state: ZooState, x, y, key_data=None):
         params, model_state = state.params, state.model_state
+        refuse_random_layers(model_state, "explicit-collective step (comm=...)")
         if not use_ring:
             # With the replication checker on, params arrive typed
             # "unvarying" over the mesh and jax.grad of a shard-varying
@@ -691,6 +739,7 @@ def make_fused_train_step(
     dynamic = fused.act_dtype == "bfloat16"
 
     def shard_body(state: ZooState, x, y, key_data=None):
+        refuse_random_layers(state.model_state, "update-on-arrival step (fused.update)")
         params, model_state = state.params, state.model_state
         opt = state.opt_state
         scale = opt.scale
@@ -1019,6 +1068,7 @@ def make_zero3_train_step(
     dynamic = fused.act_dtype == "bfloat16"
 
     def shard_body(state: ZooState, x, y, key_data=None):
+        refuse_random_layers(state.model_state, "ZeRO-3 step (fused.zero=3)")
         opt = state.opt_state
         scale = opt.scale
         # Just-in-time parameter gathering: local shard rows -> transient
@@ -1395,6 +1445,10 @@ def train(
     lr: float = 0.1,
     momentum: float = 0.9,
     weight_decay: float = 0.0,
+    kind: str = "sgd",
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
     lr_schedule: str = "constant",
     warmup_steps: int = 0,
     augment: bool = False,
@@ -1434,6 +1488,10 @@ def train(
       (kill-and-resume tested in tests/test_zoo.py).
     - ``eval_data=(images, labels)``: in-loop accuracy after each epoch.
     - ``metrics``: a utils.metrics.MetricsLogger; per-epoch records.
+    - ``kind``/``b1``/``b2``/``eps``: make_optimizer's optimizer choice
+      ("sgd" | "adamw") and AdamW's constants. The update-on-arrival
+      steps (``fused.update``: ZeRO-2/3, alone or under the pipeline)
+      carry their own SGD-momentum kernels and refuse any other ``kind``.
     - ``lr_schedule``/``warmup_steps``: make_optimizer's schedule knobs;
       the cosine horizon is the full run (epochs × steps-per-epoch), and
       the schedule's step count rides in opt_state, so resume continues
@@ -1597,11 +1655,12 @@ def train(
                 "fused.update is the explicit data-parallel path; "
                 "model_axis stays on GSPMD (set update=False)"
             )
-        elif lr_schedule != "constant" or warmup_steps or weight_decay:
+        elif (lr_schedule != "constant" or warmup_steps or weight_decay
+              or kind != "sgd"):
             raise ValueError(
                 "fused.update supports constant-LR SGD(+momentum) only — "
-                "lr schedules/warmup/weight decay need the optax path "
-                "(set update=False)"
+                f"lr schedules/warmup/weight decay/kind={kind!r} need the "
+                "optax path (set update=False)"
             )
     use_fused_update = fused is not None and fused.update
     use_zero3 = use_fused_update and fused.zero == 3
@@ -1661,8 +1720,21 @@ def train(
             lr, momentum, weight_decay,
             schedule=lr_schedule, warmup_steps=warmup_steps,
             total_steps=steps * epochs if lr_schedule == "cosine" else None,
+            kind=kind, b1=b1, b2=b2, eps=eps,
         )
         state = init_state(model, jax.random.key(seed), in_shape, optimizer)
+    if obs.enabled:
+        obs.event(
+            "zoo_optimizer",
+            # `optimizer`, not `kind`: that is the journal's own word for
+            # the event's name.
+            optimizer="sgd" if use_fused_update else kind,
+            params=sum(p.size for p in jax.tree_util.tree_leaves(state.params)),
+            state_bytes=sum(
+                a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(state.opt_state)
+            ),
+        )
     aug_fn = None
     if augment:
         from parallel_cnn_tpu.data import augment as aug_lib
