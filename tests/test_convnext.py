@@ -471,17 +471,21 @@ def test_train_hands_the_optimizer_keywords_on_and_journals_the_optimizer(tmp_pa
 
 # sha256 of `make_train_step(...).lower(...).as_text()` (StableHLO without
 # locations: what JAX's compile cache keys on, scopes and line numbers
-# stripped), read at the parent of the PR that brought ConvNeXt (5efafec),
-# under this suite's 8 virtual CPU devices. `Conv2D` and `Dense` gained
-# arguments in that PR; with their defaults every model that was there
-# must trace to the same program, or the benchmark's existing cells
+# stripped), under this suite's 8 virtual CPU devices. First read at the
+# parent of the PR that brought ConvNeXt (5efafec): `Conv2D` and `Dense`
+# gained arguments there, and with their defaults every model that was
+# there must trace to the same program, or the benchmark's existing cells
 # recompile and may move. A PR that changes the step on purpose updates
-# these and says so in PERF.md.
+# these and says so in PERF.md: PR 28 did, for the four models with a
+# BatchNorm (both moments from one read of the activation), and added
+# `convnext_tiny`, which has none and reads the same at PR 28's parent
+# (abfaa9e) — its cell is that PR's control.
 LOWERED = {
-    "resnet18": "9b12e8bc4347f01e479fc6061440dc6a9c163d04a4740f95282fc76805641657",
-    "resnet18_dp4": "b177d3d65ae2864870382e7e3b1bf52eba91ae2743dd83c4a9c2f1bf97bf4e54",
-    "resnet50_accum2": "f52dee2e06abf436e894151ee7f43a57845e4ca93002c2b7ecf0d0bfd1332c49",
-    "vgg16": "7d6105f5ec5f90dd01a260836fbd84929f2fdf2094ff36824b6b8689d09cb814",
+    "resnet18": "dd1a211746370c7de65724ed87ce673a3640d9bc399944d46c45cd1a618feed5",
+    "resnet18_dp4": "8602311ce3688ba0ed93997fbccec2c92b94b657c16226be05baeb40ba0368b6",
+    "resnet50_accum2": "a9efdb2e8ad0bcb697939f6644079d82ca7482d250ff19a193d5b7e606b2e4df",
+    "vgg16": "bb89676f38d9e22aaf08652e497b0d0ff7a48c9d68519804518b43a196b3e33a",
+    "convnext_tiny": "c52d3188fa1c2b4d5d4034e821a41478877f3ba1d394c9c854210c6593a9b4f0",
 }
 
 
@@ -493,6 +497,7 @@ def test_the_lowered_step_of_the_models_that_were_there_is_unchanged(
         "resnet18_dp4": (resnet.resnet18(10, cifar_stem=False), 4, 1),
         "resnet50_accum2": (resnet.resnet50(10, cifar_stem=True), 0, 2),
         "vgg16": (vgg.vgg16(10), 0, 1),
+        "convnext_tiny": (tiny(drop_path_rate=0.1), 0, 1),
     }[name]
     mesh = plan_lib.ExecutionPlan(data=data_mesh).validate().make_mesh(
         devices=host_devices[:data_mesh]) if data_mesh else None

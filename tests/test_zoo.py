@@ -494,7 +494,12 @@ def test_batchnorm_normalizes_and_bf16_tracks_f32():
     mean = jnp.mean(x, axis=(0, 1, 2))
     var = jnp.var(x, axis=(0, 1, 2))
     ref = (x - mean) / jnp.sqrt(var + bn.eps)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-4)
+    # 1e-4 while the layer took its variance in two passes. Both moments
+    # now come from one read (layers._batch_moments), and the channels with
+    # |mean| / std = 100 here pay 6e-8 * 1e4 * sqrt(64 positions) = 5e-4
+    # of their variance to cancellation: 3.7e-4 read in the output.
+    # tests/test_batchnorm_moments.py holds the bounds by ratio.
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), atol=1e-3)
     # running stats moved toward the batch stats
     assert float(jnp.max(jnp.abs(new_state["mean"] - 0.1 * mean))) < 1e-3
 

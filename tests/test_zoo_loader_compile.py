@@ -1,12 +1,16 @@
-"""The device loader's programs compiled for a described v5e (no chip, no
-run): what PR 25 found about layouts is a property of the compiled program,
-so it is held here. `select_batch` reads the store where it lies (no
-temporaries, no copy of the store), on one chip and over a 2x2 mesh, and
-the one-time conversion works through the set in blocks.
+"""Programs compiled for a described v5e (no chip, no run): what PR 25 found
+about layouts and what PR 28 found about BatchNorm's statistics are
+properties of the compiled program, so they are held here. `select_batch`
+reads the store where it lies (no temporaries, no copy of the store), on
+one chip and over a 2x2 mesh, and the one-time conversion works through
+the set in blocks. A residual block's forward and backward have no pass
+that only takes a BatchNorm's statistics: both moments of every sample
+ride the epilogue of the conv that produces the activation.
 
 One file, and the topology is described inside a fixture: only the worker
 that runs this file loads the TPU compiler."""
 
+import collections
 import re
 
 import jax
@@ -15,6 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from parallel_cnn_tpu.nn import resnet
 from parallel_cnn_tpu.train import zoo
 
 IN_SHAPE = (224, 224, 3)
@@ -98,3 +103,110 @@ def test_the_conversion_works_through_the_set_in_blocks(topo, shape, dtype, rows
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 20
     assert re.search(rf"-> \S+\[{shape[0]},1,{rows},128\]", _entry(compiled))
+
+
+# ------------------------------ BatchNorm's statistics cost no pass of their own
+
+# name: block, input of one image, batch a chip (the cells' shapes, stage 1),
+# the scopes of its convs
+BLOCKS = {
+    "bottleneck": (resnet.Bottleneck(64), (56, 56, 256), 256,       # r50_train s1b2
+                   {"reduce", "mid", "expand"}),
+    "bottleneck-proj": (resnet.Bottleneck(64), (56, 56, 64), 256,   # r50_train s1b1
+                        {"reduce", "mid", "expand", "proj"}),
+    "basic": (resnet.BasicBlock(64), (56, 56, 64), 512,             # r18_train s1b1
+              {"head", "tail"}),
+}
+FORWARD_CONV = re.compile(r"/jvp\((\w+)\)/conv/conv_general_dilated$")
+# array shapes of the result as (dtype, dims), opcode, operand names, op_name
+Instruction = collections.namedtuple("Instruction", "result opcode operands op_name")
+_block_entries = {}
+
+
+def _instructions(entry):
+    """name -> Instruction of an entry computation's text."""
+    out = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([a-z][\w-]*)\((.*?)\)", line)
+        if not m:
+            continue
+        name, result, opcode, operands = m.groups()
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        out[name] = Instruction(
+            re.findall(r"(\w+)\[([\d,]*)\]", result), opcode,
+            re.findall(r"%[\w.-]+", operands), op_name.group(1) if op_name else "")
+    return out
+
+
+def _block_program(topo, name):
+    """Forward and backward of one block in training mode, bf16 activations:
+    gradients of a scalar of the output to the parameters and the input."""
+    if name not in _block_entries:
+        block, in_shape, batch, _ = BLOCKS[name]
+        one = SingleDeviceSharding(topo.devices[0])
+        params, state = jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            jax.eval_shape(lambda k: block.init(k, in_shape)[:2], jax.random.key(0)))
+
+        def loss(params, state, x):
+            y, new_state = block.apply(params, state, x, train=True)
+            return jnp.sum(jnp.square(y.astype(jnp.float32))), new_state
+
+        compiled = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 2), has_aux=True)).lower(
+            params, state, jax.ShapeDtypeStruct(
+                (batch, *in_shape), jnp.bfloat16, sharding=one)).compile()
+        _block_entries[name] = _instructions(_entry(compiled))
+    return _block_entries[name]
+
+
+def _rank(shape):
+    return shape[1].count(",") + 1 if shape[1] else 0
+
+
+def _statistics_only(instructions):
+    """Fusions that read a whole activation and give back per-channel
+    vectors (or a vector a sample) only: a pass over the activation for
+    statistics alone."""
+    found = []
+    for name, ins in instructions.items():
+        if ins.opcode != "fusion" or not ins.result:
+            continue
+        reads = [s for o in ins.operands if o in instructions
+                 for s in instructions[o].result]
+        if (all(1 <= _rank(s) <= 2 for s in ins.result)
+                and any(_rank(s) == 4 for s in reads)):
+            found.append((name, ins.op_name))
+    return found
+
+
+@pytest.mark.parametrize("name", list(BLOCKS))
+def test_no_pass_over_an_activation_takes_batchnorm_statistics_alone(topo, name):
+    instructions = _block_program(topo, name)
+    # the two-pass variance's mark, forward (`jvp(..)/bn/jit(_var)/reduce_sum`)
+    # and backward (`transpose(jvp(..))/bn/jit(_var)/reduce_sum`)
+    assert not [n for n, ins in instructions.items() if "_var" in ins.op_name]
+    passes = _statistics_only(instructions)
+    assert not [p for p in passes if "transpose(" not in p[1]], passes
+    # backward: at most the one pass of sums a BatchNorm's gradient needs
+    # (XLA fuses most of those into the conv's backward fusions as well)
+    assert len(passes) <= len(BLOCKS[name][3]), passes
+
+
+@pytest.mark.parametrize("name", list(BLOCKS))
+def test_every_forward_conv_carries_both_moments_in_its_epilogue(topo, name):
+    _, _, batch, scopes = BLOCKS[name]
+    convs = {}
+    for ins in _block_program(topo, name).values():
+        m = FORWARD_CONV.search(ins.op_name)
+        if m and ins.opcode == "fusion":
+            convs[m.group(1)] = ins.result
+    assert set(convs) == scopes
+    for scope, result in convs.items():
+        (activation,) = [dims for _, dims in result if dims.count(",") == 3]
+        channels = activation.split(",")[-1]
+        assert activation.split(",")[0] == str(batch)
+        # every sample's sum of x and of x^2, float32, beside the bf16 activation
+        moments = ("f32", f"{batch},{channels}")
+        assert sorted(result) == sorted(
+            [moments, moments, ("bf16", activation)]), (scope, result)
