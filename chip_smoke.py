@@ -46,8 +46,7 @@ Tolerances (stated here, asserted below):
                  would land near 2e-3 and fail.
   LOW_TOL  1e-2  the same ratio for bf16 kernels (measured ≤ 5.6e-3) and,
                  as max|Δ|, for the LeNet kernels, whose dots run
-                 Precision.DEFAULT by design (bench.py's PALLAS_PARITY_TOL
-                 precedent; measured 1.3e-3).
+                 Precision.DEFAULT by design (measured 1.3e-3).
   serve parity   0.0: the padded bucket must be bit-identical to the same-
                  bucket jit forward (same program, same shape).
   dp             see _leg_dp.

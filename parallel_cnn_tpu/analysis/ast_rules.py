@@ -24,7 +24,7 @@ Per-file rules (:func:`scan_module`):
   outside ``parallel_cnn_tpu/plan/`` (and the constructors' home,
   ``parallel/mesh.py``).  Topology resolves through the ExecutionPlan
   — the single mesh-construction site — so plan fingerprints stay
-  truthful; test/bench sites waive with a mandatory reason.
+  truthful; test sites waive with a mandatory reason.
 
 Repo-level rules (:func:`env_doc_parity`, :func:`doc_xref`):
 
@@ -34,6 +34,9 @@ Repo-level rules (:func:`env_doc_parity`, :func:`doc_xref`):
 - ``doc-xref``: ``--flags`` and ``module.symbol()`` references in the
   live docs must resolve against the argparse definitions / package
   modules they describe.
+- ``doc-path-missing``: a backticked file name in the live docs
+  (`train/zoo.py`, `chip_smoke.py:49`) must exist in the repo or, in
+  the docs' short form, under ``parallel_cnn_tpu/``.
 """
 
 from __future__ import annotations
@@ -256,7 +259,7 @@ def scan_module(path: Path, tree: ast.Module, source: str) -> List[Diagnostic]:
     # elsewhere builds topology the plan cannot see (fingerprints,
     # checkpoint gating, and the elastic recompile-once cache all go
     # blind). parallel/mesh.py itself (the constructors' home) is
-    # exempt; test/bench sites waive with a mandatory reason.
+    # exempt; test sites waive with a mandatory reason.
     rel_posix = Path(rel).as_posix()
     if not (
         "parallel_cnn_tpu/plan" in rel_posix
@@ -289,7 +292,7 @@ def scan_module(path: Path, tree: ast.Module, source: str) -> List[Diagnostic]:
                             "parallel_cnn_tpu/plan/; route topology through "
                             "plan.build_plan(...).make_mesh() — the single "
                             "resolution site — or waive with a reason at a "
-                            "test/bench site",
+                            "test site",
                 ))
 
     jits = jitted_functions(tree)
@@ -512,14 +515,22 @@ def env_doc_parity(
 
 
 # ---------------------------------------------------------------------------
-# Repo-level rule: doc cross-references (flags, suites, symbols)
+# Repo-level rule: doc cross-references (flags, symbols, repo paths)
 # ---------------------------------------------------------------------------
 
 # Our flags are hyphenated; externally-owned flags quoted in docs
 # (e.g. --xla_force_host_platform_device_count) use underscores and are
 # skipped.
 _FLAG_RE = re.compile(r"(?<![\w`-])--[a-z][a-z0-9]*(?:-[a-z0-9]+)*\b")
-_SUITE_RE = re.compile(r"--suite[= ]([a-z0-9_]+)")
+
+# A code span that is one token and looks like a file of this repo:
+# `train/zoo.py`, `chip_smoke.py:49`, `serve/net.py:NetServer`.  Only a
+# whole span counts — a command (a span with spaces), a fenced block and
+# a placeholder path (`<dir>/trace.json`) are not tokens, so a file that
+# a command creates at run time is never taken for a file of the repo.
+_PATH_RE = re.compile(
+    r"`([\w./-]+\.(?:py|sh|jsonl|json|md|toml))(?::[\w.-]+)?`"
+)
 
 # api.md writes calls as `alias.symbol(...)`; map the aliases it uses to
 # importable modules so the references can be resolved.
@@ -573,54 +584,17 @@ def defined_cli_flags(parser_files: Sequence[Path]) -> Set[str]:
     return flags
 
 
-def defined_suites(run_py: Path) -> Set[str]:
-    """Suite names from benches/run.py: the choices= of --suite plus the
-    keys of the suites dict literal."""
-    suites: Set[str] = set()
-    try:
-        tree = ast.parse(run_py.read_text())
-    except (OSError, SyntaxError):
-        return suites
-    for node in ast.walk(tree):
-        if (
-            isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Attribute)
-            and node.func.attr == "add_argument"
-            and any(
-                isinstance(a, ast.Constant) and a.value == "--suite"
-                for a in node.args
-            )
-        ):
-            for kw in node.keywords:
-                if kw.arg == "choices":
-                    for e in ast.walk(kw.value):
-                        if isinstance(e, ast.Constant) and isinstance(e.value, str):
-                            suites.add(e.value)
-        if isinstance(node, ast.Dict):
-            keys = [
-                k.value for k in node.keys
-                if isinstance(k, ast.Constant) and isinstance(k.value, str)
-            ]
-            vals_callable = [
-                isinstance(v, (ast.Name, ast.Attribute, ast.Lambda))
-                for v in node.values
-            ]
-            if len(keys) >= 4 and len(keys) == len(node.keys) and all(vals_callable):
-                suites.update(keys)
-    return suites
-
-
 def doc_xref(
     doc_files: Sequence[Path],
     parser_files: Sequence[Path],
-    run_py: Optional[Path] = None,
+    repo_root: Path = REPO_ROOT,
 ) -> List[Diagnostic]:
     import importlib
 
     diags: List[Diagnostic] = []
     flags = defined_cli_flags(parser_files)
-    suites = defined_suites(run_py) if run_py and run_py.exists() else set()
-    suites.add("all")
+    # Docs write package files in short form (`train/zoo.py`).
+    path_roots = (repo_root, repo_root / "parallel_cnn_tpu")
 
     mod_cache: Dict[str, Optional[object]] = {}
 
@@ -656,17 +630,17 @@ def doc_xref(
                         message=f"doc references CLI flag '{flag}' which no "
                                 "argparse parser defines",
                     ))
-            if suites:
-                for m in _SUITE_RE.finditer(line):
-                    if m.group(1) not in suites:
-                        diags.append(Diagnostic(
-                            rule="doc-xref",
-                            severity=Severity.ERROR,
-                            file=rel,
-                            line=i,
-                            message=f"doc references '--suite {m.group(1)}' but "
-                                    "benches/run.py does not register that suite",
-                        ))
+            for m in _PATH_RE.finditer(line):
+                if not any((r / m.group(1)).exists() for r in path_roots):
+                    diags.append(Diagnostic(
+                        rule="doc-path-missing",
+                        severity=Severity.ERROR,
+                        file=rel,
+                        line=i,
+                        message=f"doc names the file '{m.group(1)}' which is "
+                                "neither in the repo nor in parallel_cnn_tpu/ "
+                                "(deleted or renamed?)",
+                    ))
             for m in _SYMBOL_RE.finditer(line):
                 alias, symbol = m.group(1), m.group(2)
                 mod = _module(alias)

@@ -566,7 +566,7 @@ def choose_for_trace(mp: ModelProfile, *, n_dev: int,
 
 
 # ---------------------------------------------------------------------------
-# Ranking validation (the bench gate's pure core)
+# Ranking validation (the comparison's pure core; ROADMAP Design 9)
 # ---------------------------------------------------------------------------
 
 def pairwise_agreement(predicted: Sequence[float],
@@ -592,11 +592,10 @@ def pairwise_agreement(predicted: Sequence[float],
 def order_gate(predicted: Sequence[float], measured: Sequence[float], *,
                min_ratio: float = 1.10,
                threshold: float = 0.75) -> Tuple[bool, str]:
-    """The AUTOTUNE_GATE pairwise-order check: the measured ordering must
-    agree with the model on ≥ ``threshold`` of the model-separated
-    pairs.  Returns (ok, human summary).  A doctored table that inverts
-    the model's ranking fails this by construction (the dryrun leg
-    proves it)."""
+    """The pairwise-order check: the measured ordering must agree with
+    the model on ≥ ``threshold`` of the model-separated pairs.  Returns
+    (ok, human summary).  A doctored table that inverts the model's
+    ranking fails this by construction (tests/test_autotune.py)."""
     agree, total = pairwise_agreement(predicted, measured,
                                       min_ratio=min_ratio)
     frac = 1.0 if total == 0 else agree / total

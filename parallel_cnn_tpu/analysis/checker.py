@@ -1,11 +1,9 @@
 """graftcheck orchestrator: run the analyzer families, apply waivers
 and the ratchet baseline, render the report, pick the exit code.
 
-Used three ways:
+Used two ways:
 
 - CLI: ``python -m parallel_cnn_tpu check`` (cli.py dispatch).
-- Dryrun: ``__graft_entry__`` runs a fast clean-tree leg (must exit 0)
-  and a seeded-violation tempfile leg (must exit nonzero).
 - Tests: ``tests/test_analysis.py`` calls :func:`run_check` /
   individual families directly.
 """
@@ -34,9 +32,7 @@ from parallel_cnn_tpu.analysis.diagnostics import (
 
 PACKAGE_DIR = REPO_ROOT / "parallel_cnn_tpu"
 
-# Live documentation set for parity/xref rules.  Historical round
-# summaries and bench archives under docs/ are frozen evidence records —
-# deliberately out of scope (they describe the tree as it WAS).
+# Live documentation set for the parity/xref rules.
 LIVE_DOCS = (
     "README.md",
     "docs/api.md",
@@ -53,9 +49,9 @@ LIVE_DOCS = (
 )
 
 # Host-side drivers included in the env-var scan (they read PCNN_* too).
-ENV_SCAN_DRIVERS = ("bench.py", "__graft_entry__.py", "chip_smoke.py")
+ENV_SCAN_DRIVERS = ("__graft_entry__.py", "chip_smoke.py")
 
-PARSER_FILES = ("parallel_cnn_tpu/cli.py", "bench.py", "benches/run.py",
+PARSER_FILES = ("parallel_cnn_tpu/cli.py", "benchmark/run.py",
                 "chip_smoke.py", "parallel_cnn_tpu/analysis/checker.py")
 
 
@@ -65,6 +61,18 @@ def _package_files() -> List[Path]:
 
 def _existing(rel_paths: Sequence[str]) -> List[Path]:
     return [REPO_ROOT / r for r in rel_paths if (REPO_ROOT / r).exists()]
+
+
+def doc_rule_diagnostics(doc_files: Sequence[Path]) -> List[Diagnostic]:
+    """The repo-level doc rules (env-doc parity, flag/symbol/path xref)
+    over ``doc_files``, against the shipped code and parsers."""
+    from parallel_cnn_tpu.analysis import ast_rules
+
+    env_code_files = _package_files() + _existing(ENV_SCAN_DRIVERS)
+    return (
+        ast_rules.env_doc_parity(env_code_files, doc_files)
+        + ast_rules.doc_xref(doc_files, _existing(PARSER_FILES))
+    )
 
 
 def run_check(
@@ -85,7 +93,7 @@ def run_check(
     ``paths`` switches to targeted mode: ONLY the AST + concurrency
     families over exactly those files (no repo-level parity/xref, no
     jaxpr traces, no Pallas budget, no race harness) — the mode the
-    seeded-violation dryrun leg and the rule fixtures use.
+    rule fixtures use.
     ``fast`` keeps all families but trims the expensive configurations
     (zoo traces, deep model budgets, single race seed).
     ``cost`` adds the sharding-propagation and static-cost families
@@ -94,8 +102,8 @@ def run_check(
     the zoo collectives, there is no trimmed configuration that still
     means anything — and share one trace with each other.
     ``cost_seeded`` appends a really-traced mutant entry
-    (cost_model.build_seeded_entry) so the dryrun can prove the gate
-    trips; the mutant also runs under the jaxpr-rule families.
+    (cost_model.build_seeded_entry) to show that the gate trips; the
+    mutant also runs under the jaxpr-rule families.
     """
     from parallel_cnn_tpu.analysis import ast_rules, concurrency
 
@@ -132,17 +140,7 @@ def run_check(
         doc_files = _existing(LIVE_DOCS)
         for p in doc_files:
             waivers_by_file[relpath(p)] = parse_waivers(p.read_text())
-        env_code_files = (
-            _package_files()
-            + _existing(ENV_SCAN_DRIVERS)
-            + sorted((REPO_ROOT / "benches").glob("*.py"))
-        )
-        diags.extend(ast_rules.env_doc_parity(env_code_files, doc_files))
-        diags.extend(ast_rules.doc_xref(
-            doc_files,
-            _existing(PARSER_FILES),
-            REPO_ROOT / "benches" / "run.py",
-        ))
+        diags.extend(doc_rule_diagnostics(doc_files))
 
         from parallel_cnn_tpu.analysis import jaxpr_rules, pallas_budget
 
@@ -250,7 +248,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
     ap.add_argument("--fast", action="store_true",
                     help="trim expensive configurations (zoo traces, deep "
-                         "model budgets); the dryrun leg uses this")
+                         "model budgets)")
     ap.add_argument("--paths", nargs="+", metavar="FILE",
                     help="targeted mode: lint ONLY these python files with "
                          "the AST/concurrency families")
@@ -273,8 +271,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "analysis/cost_report.json)")
     ap.add_argument("--cost-seeded", default=None, metavar="NAME",
                     help="append a seeded mutant entry (bf16-master-gather, "
-                         "partial-stage-ring) — the anti-vacuity leg of "
-                         "the dryrun")
+                         "partial-stage-ring) that must trip the gate")
     ap.add_argument("--plan", type=Path, default=None, metavar="PATH",
                     help="verify an ExecutionPlan file statically (schema, "
                          "legality matrix, cost-table key vs the cost "

@@ -270,7 +270,7 @@ def build_seeded_entry(name: str):
 
         n = len(jax.devices())
         n_stage = 2
-        pmesh = make_pipeline_mesh(n_stage)  # graftcheck: disable=mesh-outside-plan -- seeded-mutant trace mesh (dryrun anti-vacuity leg), not an execution path
+        pmesh = make_pipeline_mesh(n_stage)  # graftcheck: disable=mesh-outside-plan -- seeded-mutant trace mesh (anti-vacuity probe), not an execution path
         a_buf = 256
 
         def pbody(buf):
@@ -296,7 +296,7 @@ def build_seeded_entry(name: str):
     if name != "bf16-master-gather":
         raise ValueError(f"unknown seeded mutation {name!r}")
     n = len(jax.devices())
-    mesh = mesh_lib.make_mesh(MeshConfig(data=n, model=1))  # graftcheck: disable=mesh-outside-plan -- seeded-mutant trace mesh (dryrun anti-vacuity leg), not an execution path
+    mesh = mesh_lib.make_mesh(MeshConfig(data=n, model=1))  # graftcheck: disable=mesh-outside-plan -- seeded-mutant trace mesh (anti-vacuity probe), not an execution path
     elems = 1024 * n
 
     def body(shard):

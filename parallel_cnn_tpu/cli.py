@@ -755,7 +755,7 @@ def _net_config_from_args(args: argparse.Namespace) -> NetConfig:
 def _padded_bucket_parity(engine, handle, max_batch: int, seed: int) -> dict:
     """The padding contract on one padded bucket: n < b requests through
     the engine's AOT bucket executable must equal the same-bucket jit
-    forward on the zero-padded batch (the dryrun leg's cheap twin).
+    forward on the zero-padded batch.
     Returns {"n", "bucket", "max_abs_diff"}; 0.0 means bit-identical."""
     import jax
     import jax.numpy as jnp

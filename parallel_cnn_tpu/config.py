@@ -183,7 +183,7 @@ class CommConfig:
     # None = derive from jax.distributed process topology (one host row
     # per process); an explicit value splits a single process's devices
     # into that many emulated hosts — the 2-process-per-host CPU
-    # emulation path the tests and benches exercise.
+    # emulation path the tests exercise.
     hosts: Optional[int] = None
 
     def __post_init__(self):
@@ -517,7 +517,7 @@ class ElasticConfig:
     enabled: bool = True
     # Deterministic resize schedule: "STEP:WORLD[,STEP:WORLD...]" —
     # before optimizer step STEP (0-based, global across epochs), resize
-    # the data-parallel world to WORLD devices.  The planned test/dryrun
+    # the data-parallel world to WORLD devices.  The planned test
     # surface; preemption signals and chaos `resize@` triggers feed the
     # same controller at runtime.  Empty = no planned resizes.
     schedule: str = ""

@@ -77,7 +77,7 @@ def make_image_dataset(
     """Generic NHWC synthetic image classification set (CIFAR/ImageNet
     stand-ins for the model-zoo configs — this environment has no egress,
     so real CIFAR/ImageNet can't be fetched; shapes and class structure are
-    what the zoo trainer and benches need).
+    what the zoo trainer needs).
 
     Returns (images (N,H,W,C) float32 in [0,1], labels (N,) int32).
     """

@@ -26,7 +26,7 @@ works in jax-free contexts (the analysis stubs).
 
 The disabled path is a module singleton: :data:`NOOP_TRACER` returns the
 same reusable :class:`_NoopSpan` object from every ``span()`` call — no
-per-step allocations are retained, which the dryrun obs leg measures
+per-step allocations are retained, which tests/test_obs.py measures
 with ``tracemalloc``.
 """
 

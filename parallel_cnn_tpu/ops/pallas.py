@@ -44,8 +44,8 @@ Design (empirically validated on TPU v5e Mosaic — see probe notes):
 Numerics contract is identical to ops/reference.py (SURVEY.md §2.1): same
 /576 and /216 grad normalizations, same (onehot − output) error vector.
 Differential tests: tests/test_ops_pallas.py diffs both tiers against the
-jnp path A on an 8-device CPU harness in interpret mode; bench.py diffs
-the fused tier on-chip (`pallas_max_abs_diff`).
+jnp path A on an 8-device CPU harness in interpret mode; chip_smoke.py's
+kernels leg diffs them compiled on the chip.
 
 Flat layout convention: the 6×6×6 pool/FC boundary is flattened
 channel-major, lane = m*36 + x*6 + y — the same C-order flatten the
@@ -782,8 +782,8 @@ def fused_value_and_ref_grads(
 
     Differential contract: matches `staged_value_and_ref_grads` and path A
     (`jax.vmap(ops.reference.value_and_ref_grads)` + tree-mean) to fp
-    tolerance — tests/test_ops_pallas.py, and on-chip in bench.py's
-    `pallas_max_abs_diff` row.
+    tolerance — tests/test_ops_pallas.py, and on-chip in chip_smoke.py's
+    kernels leg.
     """
     n = xs.shape[0]
     f32 = jnp.float32
@@ -855,7 +855,7 @@ def fused_value_and_ref_grads(
     return err_mean, grads
 
 
-# The product fast path (--ops pallas, train/step.py, bench.py) is the
+# The product fast path (--ops pallas, train/step.py) is the
 # fused megakernel; the staged per-op composition stays as the kernel
 # library's differential anchor.
 batched_value_and_ref_grads = fused_value_and_ref_grads

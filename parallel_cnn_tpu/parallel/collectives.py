@@ -34,7 +34,7 @@ Numerics contract: ring reduction reassociates the f32 sum (n partial
 orders instead of psum's fixed tree), so results match psum to roundoff
 (~1e-5 relative for zoo-scale grads), not bit-exactly; bf16 wire adds a
 per-hop requantization, keeping loss parity to ~1e-2. Both bounds are
-pinned by tests/test_collectives.py and the MULTICHIP dryrun leg.
+pinned by tests/test_collectives.py.
 """
 
 from __future__ import annotations

@@ -153,8 +153,7 @@ def stash_high_water(n_stages: int, n_micro: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class LayerCost:
-    """Per-layer static cost row (the splitter's input; also surfaced by
-    `--suite pipeline` so the balance decision is auditable)."""
+    """Per-layer static cost row (the splitter's input)."""
 
     index: int
     name: str

@@ -28,7 +28,7 @@ Because the full view is world-size independent and shard↔full is
 layout-only, a resize that takes zero optimizer steps is **bit-exact**,
 and a resized run under the default "global" scaling policy (fixed
 global batch + LR) tracks the fixed-mesh loss trajectory to reduction-
-order roundoff (the ≤1e-5 dryrun parity gate).
+order roundoff (≤1e-5, tests/test_elastic.py).
 
 What is preserved across a resize: params, momentum, BatchNorm running
 stats, the dynamic loss scale and its counters, the data order (global

@@ -568,7 +568,7 @@ def serve_stack(
     cache_dir=None,
 ):
     """(pool, batcher) wired from a config.ServeConfig — the one-call
-    constructor the CLI, benches, and dryrun share. ``chaos`` (a
+    constructor the CLI, the benchmark and the tests share. ``chaos`` (a
     resilience.chaos.ChaosMonkey) arms kill-replica / slow-replica fault
     injection. ``admission`` overrides the controller instance; by
     default one is built when ``cfg.admission`` is set (the SLO surface

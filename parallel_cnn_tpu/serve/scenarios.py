@@ -6,7 +6,7 @@ hold its SLO through realistic traffic shapes and injected faults".
 Each scenario is a seeded arrival schedule driven through a live
 batcher, measured client-side, cross-checked server-side against the
 request-conservation law, and judged against explicit p99 / shed-rate
-gates (the numbers ``--suite serve`` and the dryrun leg enforce):
+gates (tests/test_serve_slo.py and tests/test_serve_net.py run them):
 
 - **diurnal** — an inhomogeneous Poisson day: the rate sweeps
   trough → peak → trough sinusoidally (piecewise-homogeneous slices,
@@ -26,7 +26,7 @@ gates (the numbers ``--suite serve`` and the dryrun leg enforce):
 - **chaos-slow** — steady traffic with ``slow-replica@SEQ:MS`` armed:
   a straggler stalls one batch. With a stall chosen past the p99 gate
   this scenario MUST trip it — the anti-vacuity probe proving the gate
-  can fail (benches/run.py asserts the trip).
+  can fail (tests/test_serve_slo.py asserts the trip).
 
 Determinism: payloads, arrival gaps, priorities, and retry backoff all
 derive from ``seed``. Wall-clock scheduling jitter moves individual
@@ -48,7 +48,7 @@ conservation judged at the wire tier (WireStats delta) as well:
   ``net_failed``, and the supervisor's bounded-backoff respawn (same
   port) lets client retries carry every logical request through —
   run WITHOUT a supervisor and the gate trips, which is the
-  anti-vacuity control arm the dryrun leg proves.
+  anti-vacuity control arm tests/test_serve_net.py proves.
 - **net-hot-swap-diurnal** — the diurnal shape driven over the wire
   with a weight hot-swap triggered mid-peak: the grow → drain →
   retire roll must finish with ``failed_delta == 0`` and conservation

@@ -36,7 +36,7 @@ This module adds the two standard asynchronous escapes, selected by
 
 **What async mode does NOT preserve:** bitwise parity with the sync
 ring (except stale-0).  The contract is a *bounded loss delta* instead —
-the ``--suite comm`` ablation and the MULTICHIP dryrun pin a seeded
+tests/test_async_dp.py pins a seeded
 3-step |loss − sync| ≤ 1e-2, clean and under a 400 ms straggler.
 
 **Scheduling is a deterministic virtual clock.**  The single-process

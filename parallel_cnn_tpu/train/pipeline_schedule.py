@@ -21,7 +21,7 @@ and gradients depend only on the current batch's statistics, never on
 the incoming running stats (nn/layers.py), so recomputing against the
 tick-current model_state is gradient-exact.
 
-Parity contract (the dryrun/bench gate): the batch shards over the data
+Parity contract (tests/test_pipeline.py): the batch shards over the data
 axis exactly as in the D-device flat data-parallel step, each stage's
 microbatch loop visits the same shards in the same order, the stage-axis
 psum only ever adds exact zeros (each layer's grad/state is owned by one
@@ -411,7 +411,7 @@ def make_pipeline_step(
 
 def stage_plan(model: Module, pipeline, in_shape: Sequence[int]):
     """(boundaries, assignment, per-stage flops) — the audit surface the
-    bench suite and tests print/check against the cost tables."""
+    tests check against the cost tables."""
     boundaries = pp.split_layers(
         model, pipeline.stages, tuple(in_shape), microbatch=1,
         boundaries=pipeline.boundaries(),

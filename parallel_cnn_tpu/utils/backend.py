@@ -1,7 +1,7 @@
 """Backend identification, chip peaks, and the persistent compile cache.
 
-Three things every entry point (cli.main, chip_smoke.py, bench.py, the
-benches/ scripts) needs to agree on:
+Three things every entry point (cli.main, chip_smoke.py,
+benchmark/run.py) needs to agree on:
 
 - what counts as a TPU (`is_tpu`: the first device's platform is "tpu" —
   the single switch between compiled Mosaic and the Pallas interpreter,

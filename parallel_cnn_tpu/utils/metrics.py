@@ -72,8 +72,7 @@ def throughput(n_items: int, seconds: float) -> float:
 class Histogram:
     """Streaming histogram over fixed log-spaced bins, with percentiles.
 
-    Built for latency telemetry (serve/ and the benches/run.py latency
-    rows): O(1) memory regardless of sample count, O(1) record, and
+    Built for latency telemetry (serve/): O(1) memory regardless of sample count, O(1) record, and
     p50/p90/p99 queries whose error is bounded by the bin ratio — with
     ``bins`` spanning [lo, hi), each bin covers a factor of
     (hi/lo)**(1/bins), so the default 96 bins over [1e-5 s, 100 s) put

@@ -45,8 +45,7 @@ def _time_fn(fn: Callable, x: jax.Array, *rest, repeats: int = 10) -> float:
     paper tables): a microsecond phase under a ~ms dispatch + readback
     floor used to clamp to 0.0 when the overhead subtraction went negative — a
     zero that poisoned every downstream speedup column. Now the repeat
-    count auto-scales (×8 per attempt, like benches/run.py._sync_time)
-    until the loop's elapsed time dominates the measured overhead, so
+    count auto-scales (×8 per attempt) until the loop's elapsed time dominates the measured overhead, so
     the subtraction is a ≤25% correction; if even the largest loop is
     overhead-bound, the UN-subtracted mean is returned — an upper
     bound, but honest and NONZERO, so every table row computes.

@@ -175,6 +175,19 @@ def pytest_collection_modifyitems(config, items):
 
 
 @pytest.fixture(autouse=True)
+def _empty_program_catalog():
+    """`obs.programs` is one registry a process: a `zoo.train` handed an
+    enabled `obs` records its `jit_step` there, and a test that starts
+    from "nothing recorded yet" (tests/benchmark/test_scope_metrics.py)
+    fails when such a test ran before it on the same xdist worker."""
+    from parallel_cnn_tpu.obs import programs
+
+    programs.clear()
+    yield
+    programs.clear()
+
+
+@pytest.fixture(autouse=True)
 def _as_the_tree_was_when_pr26_chose_its_examples(request, monkeypatch):
     name = getattr(request.node, "originalname", None) or request.node.name
     if name in _NAMES_A_FAMILY_WITHOUT_FILES:

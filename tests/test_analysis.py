@@ -4,9 +4,8 @@ can't pass is noise.
 
 jaxpr-family fixtures build tiny real jaxprs (shard_map/pmap/jit over
 the suite's 8-device virtual CPU platform); AST/concurrency fixtures
-are tempfiles run through the targeted checker path the dryrun leg
-uses; the Pallas budget and race-harness families get one real run
-plus a synthetic violation.
+are tempfiles run through the targeted checker path; the Pallas budget
+and race-harness families get one real run plus a synthetic violation.
 """
 
 import ast
@@ -27,6 +26,7 @@ from parallel_cnn_tpu.analysis import (
     jaxpr_rules,
     sharding_prop,
 )
+from parallel_cnn_tpu.analysis import checker
 from parallel_cnn_tpu.analysis import pallas_budget as budget_mod
 from parallel_cnn_tpu.analysis.checker import run_check
 from parallel_cnn_tpu.analysis.diagnostics import (
@@ -35,6 +35,7 @@ from parallel_cnn_tpu.analysis.diagnostics import (
     apply_waivers,
     parse_waivers,
     ratchet,
+    relpath,
 )
 from parallel_cnn_tpu.config import MeshConfig
 from parallel_cnn_tpu.parallel import mesh as mesh_lib
@@ -369,7 +370,7 @@ def test_obs_naive_inline_timing_trips_weak_type():
 
 
 # ---------------------------------------------------------------------------
-# AST family (targeted checker path, same as the dryrun seeded leg)
+# AST family (targeted checker path)
 # ---------------------------------------------------------------------------
 
 def _check_file(tmp_path, source, name="fixture.py"):
@@ -731,55 +732,94 @@ def test_env_doc_parity_clean_when_matched(tmp_path):
     assert ast_rules.env_doc_parity([code], [doc]) == []
 
 
-def test_doc_xref_checks_flags_suites_and_symbols(tmp_path):
+def _parser_fixture(tmp_path):
     run_py = tmp_path / "run.py"
     run_py.write_text(textwrap.dedent("""\
         import argparse
         ap = argparse.ArgumentParser()
-        ap.add_argument("--suite", choices=["alpha", "beta"])
         ap.add_argument("--md")
         """))
+    return run_py
+
+
+def test_doc_xref_checks_flags_paths_and_symbols(tmp_path):
+    run_py = _parser_fixture(tmp_path)
     doc = tmp_path / "doc.md"
     doc.write_text(textwrap.dedent("""\
-        Run `run.py --suite gamma --nonexistent-flag` for fun.
+        Run `run.py --nonexistent-flag` for fun, then read `gone/old_harness.py`.
         Call `zoo.no_such_function(cfg)` to train.
         """))
-    diags = ast_rules.doc_xref([doc], [run_py], run_py)
+    diags = ast_rules.doc_xref([doc], [run_py], repo_root=tmp_path)
     msgs = " | ".join(d.message for d in diags)
     assert "--nonexistent-flag" in msgs
-    assert "gamma" in msgs
+    assert "gone/old_harness.py" in msgs
     assert "no_such_function" in msgs
 
 
 def test_doc_xref_clean_on_valid_references(tmp_path):
-    run_py = tmp_path / "run.py"
-    run_py.write_text(textwrap.dedent("""\
-        import argparse
-        ap = argparse.ArgumentParser()
-        ap.add_argument("--suite", choices=["alpha", "beta"])
-        ap.add_argument("--md")
-        """))
+    run_py = _parser_fixture(tmp_path)
     doc = tmp_path / "doc.md"
     doc.write_text(
-        "Run `run.py --suite alpha --md` then `zoo.make_optimizer(0.1)`.\n"
+        "Run `run.py --md` (see `run.py`) then `zoo.make_optimizer(0.1)`.\n"
     )
-    assert ast_rules.doc_xref([doc], [run_py], run_py) == []
+    assert ast_rules.doc_xref([doc], [run_py], repo_root=tmp_path) == []
 
 
-def test_shipped_docs_pass_parity_and_xref():
-    from parallel_cnn_tpu.analysis import checker
-
-    docs = checker._existing(checker.LIVE_DOCS)
-    code_files = (
-        checker._package_files()
-        + checker._existing(checker.ENV_SCAN_DRIVERS)
-        + sorted((checker.REPO_ROOT / "benches").glob("*.py"))
+def _path_diags(tmp_path, text):
+    doc = tmp_path / "doc.md"
+    doc.write_text(textwrap.dedent(text))
+    return _by_rule(
+        ast_rules.doc_xref([doc], [], repo_root=tmp_path), "doc-path-missing"
     )
-    assert ast_rules.env_doc_parity(code_files, docs) == []
-    assert ast_rules.doc_xref(
-        docs, checker._existing(checker.PARSER_FILES),
-        checker.REPO_ROOT / "benches" / "run.py",
-    ) == []
+
+
+def test_doc_path_takes_line_and_name_suffixes_and_the_package_short_form(
+    tmp_path,
+):
+    (tmp_path / "parallel_cnn_tpu" / "train").mkdir(parents=True)
+    (tmp_path / "parallel_cnn_tpu" / "train" / "zoo.py").write_text("")
+    (tmp_path / "smoke.py").write_text("")
+    assert _path_diags(tmp_path, """\
+        `smoke.py`, `smoke.py:49`, `smoke.py:main`, `train/zoo.py:train` and
+        `parallel_cnn_tpu/train/zoo.py:12` are files of the repo.
+        """) == []
+    hits = _path_diags(tmp_path, """\
+        `smoke.py:49` is there, `train/zoo2.py:49` and `RECORD_r02.json` are not.
+        """)
+    assert [d.line for d in hits] == [1, 1]
+    assert "train/zoo2.py" in hits[0].message
+    assert "RECORD_r02.json" in hits[1].message
+
+
+def test_doc_path_leaves_what_a_command_creates_alone(tmp_path):
+    """Only a code span that is one token is a path: commands, fenced
+    blocks and placeholder paths name files that exist after a run."""
+    assert _path_diags(tmp_path, """\
+        Run `python3 tool.py --out chiprun_out/scopes.json`; it writes
+        `<dir>/trace.json` and `chiprun_out/<run>_programs.json`.
+
+        ```bash
+        python3 tool.py --out chiprun_out/scopes.json
+        cat chiprun_out/scopes.json
+        ```
+        """) == []
+
+
+_LIVE_DOCS = checker._existing(checker.LIVE_DOCS)
+
+
+@pytest.fixture(scope="module")
+def shipped_doc_diags():
+    return checker.doc_rule_diagnostics(_LIVE_DOCS)
+
+
+@pytest.mark.parametrize("doc", [relpath(p) for p in _LIVE_DOCS])
+def test_shipped_docs_pass_parity_and_xref(shipped_doc_diags, doc):
+    assert [d for d in shipped_doc_diags if d.file == doc] == []
+
+
+def test_shipped_code_reads_no_undocumented_env_name(shipped_doc_diags):
+    assert [d for d in shipped_doc_diags if not d.file.endswith(".md")] == []
 
 
 # ---------------------------------------------------------------------------
@@ -863,15 +903,35 @@ def test_cost_model_clean_on_matching_schedule(mesh4, tmp_path):
     assert not _by_rule(diags, "cost-model-mismatch")
 
 
-def test_cost_model_mismatch_trips_on_seeded_gather(host_devices, tmp_path):
-    entry = cost_model.build_seeded_entry("bf16-master-gather")
+@pytest.mark.parametrize("mutant,jaxpr_rule", [
+    ("bf16-master-gather", "f32-wire"),
+    ("partial-stage-ring", "ring-permutation"),
+])
+def test_cost_model_mismatch_trips_on_seeded_mutant(
+    host_devices, tmp_path, mutant, jaxpr_rule
+):
+    """Each really-traced mutant `check --cost-seeded` appends trips the
+    byte table and its own jaxpr rule."""
+    name, closed, spec = cost_model.build_seeded_entry(mutant)
     diags = cost_model.run_cost_rules(
-        [entry],
+        [(name, closed, spec)],
         baseline_path=tmp_path / "b.json",
         report_path=tmp_path / "r.json",
     )
     hits = _by_rule(diags, "cost-model-mismatch")
     assert hits and "closed-form" in hits[0].message
+    assert _by_rule(jaxpr_rules.analyze_closed_jaxpr(name, closed), jaxpr_rule)
+
+
+def test_real_zoo_entries_move_their_closed_form_bytes(host_devices, tmp_path):
+    """The clean direction on the shipped tree: every traced zoo, pipeline
+    and tuned entry moves exactly the bytes docs/collectives.md's tables
+    give it, ZeRO's residency ordering holds, and nothing has grown past
+    the shipped ratchet (what `check --cost` exits 0 on)."""
+    entries = jaxpr_rules.trace_entry_points(fast=False, with_specs=True)
+    assert sum(spec is not None for _, _, spec in entries) >= 8
+    diags = cost_model.run_cost_rules(entries, report_path=tmp_path / "r.json")
+    assert [d for d in diags if d.severity == Severity.ERROR] == []
 
 
 def test_cost_ratchet_trips_on_growth_past_baseline(mesh4, tmp_path):

@@ -4,7 +4,7 @@ Three layers under one marker:
 
 - the search (analysis/autotune.py): legality/canonicalization of the
   plan space, the admissible prune (brute-force equality), the hard HBM
-  budget, deterministic ranking, the order gate the bench uses;
+  budget, deterministic ranking, the order gate;
 - the artifacts: cost_report.json autotune section round-trip, the
   schema-version ratchet (stale artifacts fail loudly), AutotuneConfig
   env layering, the hardware-profile registry;
@@ -13,9 +13,8 @@ Three layers under one marker:
   predictive scale-up landing with NO hysteresis while the reactive
   classifier is silent.
 
-Everything here is CPU-pure — no jax tracing, no sockets; the measured
-ranking itself is the bench gate (benches/run.py --suite autotune) and
-the dryrun leg.
+Everything here is CPU-pure — no jax tracing, no sockets; a measured
+ranking to set beside the model's does not exist yet (ROADMAP Design 9).
 """
 
 import json
@@ -146,7 +145,7 @@ class TestSearch:
 
 
 # ---------------------------------------------------------------------------
-# the order gate (the bench's pure core)
+# the order gate
 
 
 class TestOrderGate:
@@ -161,7 +160,7 @@ class TestOrderGate:
         assert not ok
 
     def test_doctored_reciprocal_table_fails(self):
-        """The dryrun's anti-vacuity transform: 1/x keeps separation
+        """The anti-vacuity transform: 1/x keeps separation
         ratios but inverts every ordering."""
         pred = [100.0, 50.0, 20.0]
         meas = [90.0, 45.0, 19.0]

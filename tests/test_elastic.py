@@ -28,6 +28,8 @@ The contract under test, end to end:
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,11 +114,16 @@ def tree_bitequal(a, b):
 # -- the tentpole: resize-lap loss parity -------------------------------
 
 
-def test_resize_lap_matches_fixed_mesh(host_devices):
+@pytest.mark.parametrize(
+    "desync", [False, True], ids=["lap", "seeded_desync_must_fail"]
+)
+def test_resize_lap_matches_fixed_mesh(host_devices, desync):
     """(1,8) → (2,4) → (1,4) → (1,8): six optimizer steps with a
     topology change every two, vs the same six steps on a fixed (1,8)
     mesh. Same data, same seeds, global batch fixed → trajectories agree
-    to ≤1e-5 (observed ~1e-7: reduction-order roundoff only)."""
+    to ≤1e-5 (observed ~1e-7: reduction-order roundoff only). With one
+    resident parameter moved by 1e-3 right after the first reshard the
+    same comparison must fail, or the bound proves nothing."""
     model = _nobn_model()
     comm = CommConfig(**_COMM)
     x, y = _data(96)
@@ -149,9 +156,18 @@ def test_resize_lap_matches_fixed_mesh(host_devices):
                 n_hosts=n_hosts,
             )
             n_host = ctl.n_hosts
+            if desync and i == 2:
+                bumped = st.params[0].at[0, 0].add(1e-3)
+                st = dataclasses.replace(
+                    st, params=[bumped] + list(st.params[1:])
+                )
             step = _make_step(model, mesh, ecomm, plan)
         st, l = step(st, bx, by, None)
         elastic.append(float(l))
+    max_dloss = max(abs(a - b) for a, b in zip(fixed, elastic))
+    if desync:
+        assert max_dloss > 1e-5, (max_dloss, fixed, elastic)
+        return
     # The closing (1,4) → (1,8) leg after the last step.
     st, plan, mesh, ecomm = ctl.resize(
         6, 8, state=st, plan=plan, comm=ecomm, n_hosts=1,
@@ -160,7 +176,6 @@ def test_resize_lap_matches_fixed_mesh(host_devices):
 
     assert [e.new_world for e in ctl.events] == [8, 4, 8]
     assert [e.new_hosts for e in ctl.events] == [2, 1, 1]
-    max_dloss = max(abs(a - b) for a, b in zip(fixed, elastic))
     assert max_dloss <= 1e-5, (max_dloss, fixed, elastic)
     got = _full_np(st, plan, n_host=n_host)
     for a, b in zip(

@@ -371,6 +371,27 @@ def test_obsconfig_from_env_none_sentinel(monkeypatch):
     assert cfg.dir == "elsewhere" and not cfg.jax_annotations
 
 
+def test_noop_bundle_retains_no_allocation_over_10k_spans():
+    """Off must cost nothing that accumulates: 10,000 span + event calls
+    on the disabled bundle leave no allocation behind."""
+    import tracemalloc
+
+    noop = obs_lib.from_config(None)
+    tracemalloc.start()
+    try:
+        with noop.span("warm"):
+            noop.event("warm")
+        before = tracemalloc.get_traced_memory()[0]
+        for _ in range(10000):
+            with noop.span("hot", step=1):
+                noop.event("hot", step=1)
+        retained = tracemalloc.get_traced_memory()[0] - before
+    finally:
+        tracemalloc.stop()
+    assert retained < 16384, retained
+    assert noop.tracer.events() == []
+
+
 def test_from_config_gating_and_noop_identity(tmp_path):
     # off both ways → the shared zero-cost singleton
     assert obs_lib.from_config(None) is obs_lib.NOOP

@@ -38,13 +38,6 @@ pytestmark = pytest.mark.obs
 DATA = os.path.join(ROOT, "tests", "benchmark", "data")
 
 
-@pytest.fixture(autouse=True)
-def _empty_catalog():
-    programs.clear()
-    yield
-    programs.clear()
-
-
 def _like(tree):
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)

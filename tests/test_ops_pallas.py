@@ -126,8 +126,8 @@ def test_fused_multi_grid_step_accumulation(monkeypatch):
     """Shrink FUSED_BLOCK so the fused tier runs a MULTI-step grid with a
     padded tail (grid=3 with 2 pad rows) — exercising the cross-grid-step
     accumulator init/accumulate logic and the Mp persistence that the
-    single-block small-batch tests never reach (on TPU the bench covers
-    grid=32; this is the CPU-harness equivalent)."""
+    single-block small-batch tests never reach (on TPU chip_smoke.py's
+    lenet leg runs grid=16 at b2048; this is the CPU-harness equivalent)."""
     monkeypatch.setattr(pk, "FUSED_BLOCK", 4)
     params = lenet_ref.init(jax.random.key(3))
     rng = np.random.default_rng(9)

@@ -2,7 +2,7 @@
 XLA `lax.conv_general_dilated` — forward, dgrad, and wgrad, plus the full
 ResNet-18 pallas-backend train step (BASELINE.json config #4). Interpret
 mode on the CPU harness; the same code compiles via Mosaic on TPU
-(benchmarked by bench.py's zoo rows)."""
+(chip_smoke.py's kernels leg)."""
 
 import jax
 import jax.numpy as jnp

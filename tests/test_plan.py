@@ -436,8 +436,7 @@ def test_mesh_outside_plan_rule(tmp_path):
 
 
 def test_package_has_single_mesh_site():
-    """The tree itself: no unwaived mesh construction outside plan/ —
-    the package-wide sweep the dryrun's clean leg also enforces."""
+    """The tree itself: no unwaived mesh construction outside plan/."""
     from parallel_cnn_tpu.analysis import ast_rules
     from parallel_cnn_tpu.analysis.checker import _package_files
     from parallel_cnn_tpu.analysis.diagnostics import (
