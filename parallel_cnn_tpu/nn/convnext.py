@@ -12,7 +12,8 @@ built from this package's NHWC layers.
                 LN(DWConv7x7(x))))))
 
 The depthwise conv pads 3 and has a bias (``Conv2D(groups=C)``); LayerNorm
-is over the channel axis (eps 1e-6); GELU is the erf form; ``gamma`` starts
+is over the channel axis (eps 1e-6); GELU is the erf form, one float32
+``erf`` an element each way (nn/layers.py:GELU); ``gamma`` starts
 at ``layer_scale_init``; the drop rate ``p`` rises linearly over all blocks
 from 0 to ``drop_path_rate``. Weights are drawn as the authors do
 (``trunc_normal_(std=.02)``), biases start at 0. The two pointwise layers

@@ -335,15 +335,85 @@ class LayerNorm(Module):
         return (y * params["scale"] + params["bias"]).astype(x.dtype), state
 
 
+_SQRT_HALF = 0.7071067811865476
+_INV_SQRT_2PI = 0.3989422804014327
+# x / sqrt 2 = -4: below it Phi(x) < 8e-9, and a float32 1 + erf has
+# stopped moving (at 0 on the TPU, at 1.8e-7 in XLA:CPU's erf).
+_GELU_TAIL = -5.656854249492381
+
+
+def _normal_cdf(xf):
+    """Phi(x) = 0.5 * (1 + erf(x / sqrt 2)) of a float32 array."""
+    return 0.5 * (1.0 + lax.erf(xf * _SQRT_HALF))
+
+
+def _gelu_f32(xf):
+    """x * Phi(x) of a float32 array. ``x`` is held at ``_GELU_TAIL`` in the
+    product: where a float32 ``erf`` stops a few 1e-8 short of -1, that
+    times -1e30 is no longer small, and so the result stays within 6e-7 of
+    0 however far ``x`` goes. (A ``max``, not a select on ``x < tail``:
+    the backward would share that predicate, and XLA then keeps it from
+    the forward as an array of its own.)"""
+    return jnp.maximum(xf, _GELU_TAIL) * _normal_cdf(xf)
+
+
+def _gelu_slope_f32(xf):
+    """d/dx of x * Phi(x) = Phi(x) + x * phi(x), phi the normal density,
+    of a float32 array."""
+    return _normal_cdf(xf) + xf * (_INV_SQRT_2PI * jnp.exp(-0.5 * xf * xf))
+
+
+@jax.custom_vjp
+def _gelu(x):
+    return _gelu_f32(x.astype(jnp.float32)).astype(x.dtype)
+
+
+def _gelu_fwd(x):
+    return _gelu(x), x
+
+
+def _gelu_bwd(x, dy):
+    slope = _gelu_slope_f32(x.astype(jnp.float32))
+    return ((dy.astype(jnp.float32) * slope).astype(x.dtype),)
+
+
+_gelu.defvjp(_gelu_fwd, _gelu_bwd)
+
+
 @dataclasses.dataclass(frozen=True)
 class GELU(Module):
-    """Exact GELU, x * Phi(x) (the erf form, not the tanh approximation)."""
+    """Exact GELU, ``x * Phi(x)``, as ``0.5 * x * (1 + erf(x / sqrt 2))``:
+    the form of the authors' ``torch.nn.GELU`` and of the benchmark's
+    float32 reference, not the tanh approximation.
+
+    Why ``erf`` and not ``jax.nn.gelu(approximate=False)``: that is
+    ``0.5 * x * erfc(-x / sqrt 2)``, and ``erfc`` is no HLO op. JAX expands
+    it, before any backend sees it, into both of its branches and a select
+    (an ``exp``, two divides and three polynomials for every element, and
+    as much again in the gradient), while ``lax.erf`` stays one native op.
+    ConvNeXt-B's pointwise fusions are bound by the vector unit, so those
+    ops are step time (PERF.md section 5, bottleneck 7).
+
+    The arithmetic is float32 whatever ``x.dtype`` is, cast once at the
+    end. The backward is a ``custom_vjp`` that keeps one residual, the
+    input ``x`` at its own dtype, and computes
+    ``dx = dy * (Phi(x) + x * phi(x))`` from it (one ``erf``, one ``exp``),
+    in float32, cast once. Forward-mode differentiation (``jax.jvp``) is
+    not defined for it; no step needs it.
+
+    The far negative tail: in float32 ``1 + erf(z)`` cancels, so below
+    x = -5.4 the output is 0 to within 6e-7 where the true value lies
+    between -3e-7 and -0, however far ``x`` goes (the guard in
+    ``_gelu_f32``); the ``erfc`` form followed the tail down to the
+    smallest bf16 numbers. Everywhere else the result before the cast is
+    within 5e-7 + 2e-7 |x| of the float64 value (tests/test_convnext.py
+    checks every finite bf16 input)."""
 
     def init(self, key, in_shape: Shape):
         return {}, {}, in_shape
 
     def apply(self, params, state, x, train: bool = False):
-        return jax.nn.gelu(x, approximate=False), state
+        return _gelu(x), state
 
 
 @dataclasses.dataclass(frozen=True)

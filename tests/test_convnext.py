@@ -6,8 +6,10 @@ for the models that were there (the lowered step of the ResNets and VGG).
 The comparison with the plain reference is tests/benchmark/
 test_convnext_reference.py."""
 
+import collections
 import hashlib
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +19,7 @@ import pytest
 
 from parallel_cnn_tpu import config as config_lib
 from parallel_cnn_tpu import plan as plan_lib
-from parallel_cnn_tpu.nn import convnext, resnet, vgg
+from parallel_cnn_tpu.nn import convnext, layers, resnet, vgg
 from parallel_cnn_tpu.nn.core import Sequential
 from parallel_cnn_tpu.nn.layers import (
     GELU,
@@ -32,6 +34,17 @@ from parallel_cnn_tpu.nn.layers import (
 from parallel_cnn_tpu.train import checkpoint, zoo
 
 TINY = dict(depths=(1, 1, 2, 1), dims=(8, 16, 32, 64), num_classes=10)
+
+
+def _plain_reference():
+    """benchmark/reference/convnext.py: plain float32, imports nothing of
+    the program."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark.reference import convnext as reference
+    return reference
 
 
 def tiny(drop_path_rate=0.5, layer_scale_init=1.0):
@@ -133,6 +146,119 @@ def test_gelu_is_the_erf_form():
     np.testing.assert_allclose(y, want, atol=1e-6)
     tanh = jax.nn.gelu(x, approximate=True)
     assert float(jnp.max(jnp.abs(y - tanh))) > 1e-4  # not the approximation
+
+
+# ------------------- GELU: one erf in float32, and a backward of its own
+
+_erfc = np.vectorize(math.erfc)
+
+
+def _every_finite_bf16():
+    x = np.arange(1 << 16, dtype=np.uint16).view(jnp.bfloat16)
+    return x[np.isfinite(x.astype(np.float32))]
+
+
+def _gelu_f64(x):
+    """x * Phi(x) in float64; the erfc form follows the negative tail."""
+    return 0.5 * x * _erfc(-x / math.sqrt(2.0))
+
+
+def _gelu_slope_f64(x):
+    return (0.5 * _erfc(-x / math.sqrt(2.0))
+            + x * np.exp(-0.5 * x * x) / math.sqrt(2.0 * math.pi))
+
+
+def _forward_excess(form):
+    """By how much ``form`` (float32 in, float32 out) misses float64 GELU
+    beyond what the layer promises before its cast, over every finite bf16
+    input: 5e-7 + 2e-7 |x| for x >= 0, 1e-6 absolute for x < 0."""
+    xb = _every_finite_bf16()
+    assert xb.size == 65280
+    x = xb.astype(np.float64)
+    got = np.asarray(jax.jit(form)(jnp.asarray(xb.astype(np.float32))), np.float64)
+    allowed = np.where(x >= 0, 5e-7 + 2e-7 * np.abs(x), 1e-6)
+    return np.abs(got - _gelu_f64(x)) - allowed
+
+
+def test_gelu_before_its_cast_is_float64_gelu_on_every_finite_bf16_input():
+    assert float(np.max(_forward_excess(layers._gelu_f32))) <= 0.0
+    # the guard: a float32 erf stops short of -1, and times -1e30 that shows
+    # (XLA:CPU's 1 + erf(-large) is 1.8e-7: -9e22 without the guard)
+    far = layers._gelu_f32(jnp.asarray([-1e30, -3e38, -6.0], jnp.float32))
+    assert float(jnp.max(jnp.abs(far))) <= 1e-6
+
+
+def test_the_tanh_form_fails_the_forward_bound_the_erf_form_passes():
+    excess = _forward_excess(lambda x: jax.nn.gelu(x, approximate=True))
+    assert float(np.max(excess)) > 1e-4  # 3e-4 off near |x| = 2
+
+
+def test_gelu_after_its_cast_is_the_correctly_rounded_bf16_value():
+    """For every input >= -4 within one bf16 ulp, and equal on >= 99.9 %
+    (XLA flushes subnormal results to zero on the CPU and on the chip, so
+    the float64 value is flushed the same way); below -4 what a float32
+    1 + erf leaves."""
+    xb = _every_finite_bf16()
+    x = xb.astype(np.float64)
+    y, _ = jax.jit(lambda v: GELU().apply({}, {}, v))(jnp.asarray(xb))
+    assert y.dtype == jnp.bfloat16
+    y = np.asarray(y).astype(np.float64)
+    tiny = 2.0 ** -126
+    want = _gelu_f64(x)
+    ref = np.where(np.abs(want) < tiny, 0.0, want).astype(jnp.bfloat16).astype(np.float64)
+    ref = np.where(np.abs(ref) < tiny, 0.0, ref)
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(ref), tiny))) - 7)
+    body = x >= -4.0
+    assert np.all(np.abs(y - ref)[body] <= ulp[body])
+    assert np.mean((y == ref)[body]) >= 0.999
+    assert float(np.max(np.abs(y - want)[~body])) <= 1e-6
+
+
+def test_gelu_derivative_before_its_cast_is_float64_on_every_finite_bf16_input():
+    xb = _every_finite_bf16()
+    got = jax.jit(layers._gelu_slope_f32)(jnp.asarray(xb.astype(np.float32)))
+    want = _gelu_slope_f64(xb.astype(np.float64))
+    assert float(np.max(np.abs(np.asarray(got, np.float64) - want))) <= 5e-7
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_backward_of_gelu_is_its_own_and_casts_once(dtype):
+    """The cotangent is dy * (Phi(x) + x phi(x)) computed in float32 from
+    the saved input, at the input's dtype."""
+    x = jnp.asarray(_every_finite_bf16()).astype(dtype)
+    dy = jnp.linspace(-2.0, 2.0, x.size).astype(dtype)
+    y, vjp = jax.vjp(lambda v: GELU().apply({}, {}, v)[0], x)
+    (dx,) = vjp(dy)
+    assert dx.dtype == dtype and y.dtype == dtype
+    want = dy.astype(jnp.float32) * layers._gelu_slope_f32(x.astype(jnp.float32))
+    np.testing.assert_array_equal(dx, want.astype(dtype))
+
+
+def test_gelu_gradient_in_float32_is_the_reference_gradient():
+    reference = _plain_reference()
+    x = jnp.linspace(-9.0, 9.0, 3601)
+    got = jax.grad(lambda v: jnp.sum(GELU().apply({}, {}, v)[0]))(x)
+    want = jax.grad(lambda v: jnp.sum(reference.gelu(v)))(x)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    y, _ = GELU().apply({}, {}, x)
+    np.testing.assert_allclose(y, reference.gelu(x), rtol=0, atol=1e-6)
+
+
+def test_forward_mode_through_gelu_is_a_clear_error():
+    with pytest.raises(TypeError, match="custom_vjp"):
+        jax.jvp(lambda v: GELU().apply({}, {}, v)[0],
+                (jnp.ones(3),), (jnp.ones(3),))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_gelu_saves_its_input_and_nothing_else(dtype):
+    x = jnp.ones((2, 4, 4, 8), dtype)
+    _, vjp = jax.vjp(lambda v: GELU().apply({}, {}, v)[0], x)
+    saved = jax.tree_util.tree_leaves(vjp)
+    assert [(r.shape, r.dtype) for r in saved] == [(x.shape, x.dtype)]
+    # what autodiff keeps of the erfc form: three arrays of that size
+    _, vjp = jax.vjp(lambda v: jax.nn.gelu(v, approximate=False), x)
+    assert sum(r.shape == x.shape for r in jax.tree_util.tree_leaves(vjp)) == 3
 
 
 def test_layerscale_is_one_gain_a_channel_from_its_initial_value():
@@ -478,15 +604,27 @@ def test_train_hands_the_optimizer_keywords_on_and_journals_the_optimizer(tmp_pa
 # recompile and may move. A PR that changes the step on purpose updates
 # these and says so in PERF.md: PR 28 did, for the four models with a
 # BatchNorm (both moments from one read of the activation), and added
-# `convnext_tiny`, which has none and reads the same at PR 28's parent
-# (abfaa9e) — its cell is that PR's control.
+# `convnext_tiny`, which has none and read the same at PR 28's parent
+# (abfaa9e) — its cell was that PR's control. PR 30 changed
+# `convnext_tiny` on purpose (GELU as one float32 `erf` with a backward of
+# its own, nn/layers.py:GELU; c52d3188… before) and left the other four,
+# which hold no GELU, as they read: they are that PR's control.
 LOWERED = {
     "resnet18": "dd1a211746370c7de65724ed87ce673a3640d9bc399944d46c45cd1a618feed5",
     "resnet18_dp4": "8602311ce3688ba0ed93997fbccec2c92b94b657c16226be05baeb40ba0368b6",
     "resnet50_accum2": "a9efdb2e8ad0bcb697939f6644079d82ca7482d250ff19a193d5b7e606b2e4df",
     "vgg16": "bb89676f38d9e22aaf08652e497b0d0ff7a48c9d68519804518b43a196b3e33a",
-    "convnext_tiny": "c52d3188fa1c2b4d5d4034e821a41478877f3ba1d394c9c854210c6593a9b4f0",
+    "convnext_tiny": "8550d8b7c25f69a2420d6513ccdfe41887e70a1aec3e4f9283fff7906ea18feb",
 }
+
+
+def _lower_step(model, accum=1, mesh=None):
+    opt = zoo.make_optimizer(lr=0.1, momentum=0.9, weight_decay=1e-4)
+    state = jax.eval_shape(
+        lambda k: zoo.init_state(model, k, (32, 32, 3), opt), jax.random.key(0))
+    return zoo.make_train_step(model, opt, accum, mesh).lower(
+        state, jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8,), jnp.int32))
 
 
 @pytest.mark.parametrize("name", list(LOWERED))
@@ -501,13 +639,46 @@ def test_the_lowered_step_of_the_models_that_were_there_is_unchanged(
     }[name]
     mesh = plan_lib.ExecutionPlan(data=data_mesh).validate().make_mesh(
         devices=host_devices[:data_mesh]) if data_mesh else None
-    opt = zoo.make_optimizer(lr=0.1, momentum=0.9, weight_decay=1e-4)
-    state = jax.eval_shape(
-        lambda k: zoo.init_state(model, k, (32, 32, 3), opt), jax.random.key(0))
-    text = zoo.make_train_step(model, opt, accum, mesh).lower(
-        state, jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.bfloat16),
-        jax.ShapeDtypeStruct((8,), jnp.int32)).as_text()
+    text = _lower_step(model, accum, mesh).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == LOWERED[name]
+
+
+def test_the_lowered_convnext_step_holds_two_erf_a_block_and_no_erfc():
+    """The mechanism engages on every call, so its proof is the program:
+    in the lowered train step (StableHLO with its name stacks) every
+    block's `act` scope holds one `erf` forward and one backward, one
+    `exponential` (backward: the normal density), no divide (`erf`
+    itself lowers to one clamped rational polynomial, so one divide at
+    the most after that) and no compare or select (the far-tail guard is
+    a `maximum`: a predicate that forward and backward share is one XLA
+    keeps as an array of its own), and `erfc` appears nowhere, neither as
+    `chlo.erfc` nor as the expansion that brings three divides and four
+    selects with it."""
+    from parallel_cnn_tpu.obs import programs
+
+    text = _lower_step(tiny(drop_path_rate=0.1)).as_text(debug_info=True)
+    assert not re.search(r"chlo\.erfc|/erfc\b", text)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    counts = collections.Counter()
+    for op, loc in re.findall(
+            r"= (?:chlo|stablehlo)\.(erf|divide|exponential|select|compare|maximum) "
+            r".*loc\((#loc\d+)\)",
+            text):
+        scope, phase = programs.scope_of(names.get(loc, ""))
+        if scope.endswith("/act"):
+            counts[scope, phase, op] += 1
+    blocks = [f"s{i + 1}b{j + 1}/act" for i, d in enumerate(TINY["depths"])
+              for j in range(d)]
+    assert {k[0] for k in counts} == set(blocks)
+    for scope in blocks:
+        for phase in ("fwd", "bwd"):
+            assert counts[scope, phase, "erf"] == 1
+            assert not any(counts[scope, phase, op]
+                           for op in ("divide", "compare", "select"))
+        assert counts[scope, "fwd", "maximum"] == 1  # the far-tail guard
+        assert counts[scope, "bwd", "maximum"] == 0
+        assert counts[scope, "fwd", "exponential"] == 0
+        assert counts[scope, "bwd", "exponential"] == 1
 
 
 # --------------------------------------------------------- the front doors
@@ -515,11 +686,6 @@ def test_the_lowered_step_of_the_models_that_were_there_is_unchanged(
 def test_serving_a_convnext_returns_the_reference_logits(monkeypatch):
     """serve/registry.py's handle for a ConvNeXt through Engine: the same
     forward as training's, DropPath off, nothing to fold for LayerNorm."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from benchmark.reference import convnext as reference
     from parallel_cnn_tpu.serve import registry
     from parallel_cnn_tpu.serve.engine import Engine
 
@@ -533,7 +699,7 @@ def test_serving_a_convnext_returns_the_reference_logits(monkeypatch):
     got = eng.predict(x)
     arch = dict(depths=TINY["depths"], dims=TINY["dims"], ln_eps=1e-6,
                 drop_path_rate=0.5)
-    want = reference.eval_logits(arch, eng._params, eng._state, x)
+    want = _plain_reference().eval_logits(arch, eng._params, eng._state, x)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
