@@ -23,12 +23,15 @@ from parallel_cnn_tpu.nn.layers import (  # noqa: F401
     Conv2D,
     Dense,
     DropPath,
+    Embedding,
     Flatten,
+    GatedMLP,
     GELU,
     GlobalAvgPool,
     LayerNorm,
     LayerScale,
     MaxPool,
     ReLU,
+    RMSNorm,
 )
-from parallel_cnn_tpu.nn import cifar, convnext, resnet, vgg  # noqa: F401
+from parallel_cnn_tpu.nn import cifar, convnext, glm_moe, resnet, vgg  # noqa: F401

@@ -1,0 +1,647 @@
+"""GLM-4.7-Flash (`model_type: glm4_moe_lite`; config.json at
+huggingface.co/zai-org/GLM-4.7-Flash) — the zoo's decoder-only language
+model: latent attention with a query latent (MLA, DeepSeek-V2
+arXiv:2405.04434 section 2.1), fine-grained routed experts beside a shared
+one under sigmoid scores and a selection bias that the step balances
+(DeepSeek-V3 arXiv:2412.19437 section 2.1.2, `topk_method: noaux_tc`), and
+one multi-token-prediction module (ibid. section 2.2).
+
+    x      = Emb(t)                                   tokens (N, S) int
+    layer  : h = x + Attn(RMSNorm(x));  y = h + FFN(RMSNorm(h))
+             FFN = GatedMLP(dense_width) in the first `first_dense` layers,
+             the expert layer after them
+    logits = RMSNorm(x_L) W_head                      (untied)
+
+    Attn   : c_q = RMSNorm(x W_qa);  q = c_q W_qb -> heads x (nope | rope)
+             [c_kv | k_pe] = x W_kva;  c_kv = RMSNorm(c_kv)
+             [k_nope | v] = c_kv W_kvb -> heads x (nope | v_dim)
+             RoPE on q_pe and on the one k_pe every head shares
+             softmax_causal((q_nope k_nope + q_pe k_pe) / sqrt(nope + rope))
+             heads concatenated through W_o; no biases anywhere
+    Expert : s = sigmoid(x W_g) in float32; chosen = top-k of s + b
+             g_i = scaling * s_i / sum_chosen s_j   (from s, not s + b)
+             y = sum_chosen g_i E_i(x) + E_shared(x), every E a GatedMLP
+    MTP    : h' = [RMSNorm(Emb(t_{i+1})) | RMSNorm(x_L,i)] W_eh, one expert
+             decoder layer, the model's final norm and head, scored
+             against t_{i+2}
+    loss   = CE + mtp_weight * CE_mtp + balance * sum_layers sum_i f_i P_i
+
+The expert layer is told which experts it HOLDS (`held`, ids of the
+published `n_routed`): it routes over all of them, normalises the gates
+over all the chosen, and adds only what its own experts give — one
+chip's part of an expert-parallel layer, without the exchange. Nothing
+stands in for the absent experts. Tokens are never dropped: assignments
+are sorted by expert into a static row buffer (`rows`; default every
+assignment, at which nothing can overflow) and multiplied by
+`lax.ragged_dot`, a grouped matmul whose cost follows the rows held;
+rows that a smaller buffer cannot take are COUNTED (`overflow_rows`).
+
+State the step updates without a gradient (`ExpertLayer.init`): the
+selection bias `b`, the step's load per expert, and the counters read at
+the end of an epoch. `GlmMoe.finish_step` closes a step: `b += bias_step
+* sign(mean load - load)` over the step's tokens, all microbatches.
+
+The model owns its loss (`GlmMoe.loss`, the seam `train/zoo.py:
+_build_loss_fn` looks for): it needs the hidden states for the MTP
+module, computes the logits a block of positions at a time, and adds the
+layers' balance terms. In training every decoder layer is rematerialised
+(`jax.checkpoint`), and so is every block of attention queries and of
+logits.
+
+Scopes (obs/programs.py; benchmark/shapes/glm_moe.py lists the same):
+`embed`, `l<i>/attn/{norm,q,kv,rope,core,o}`, `l<i>/mlp/...` or
+`l<i>/moe/{norm,route,dispatch,experts,combine,shared}`, `mtp/{embed,
+norm,proj,l0/...}`, `norm`, `head`, `loss`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from parallel_cnn_tpu.nn.core import Module, Shape
+from parallel_cnn_tpu.nn.layers import (
+    Embedding,
+    GatedMLP,
+    RMSNorm,
+    _weight,
+    rope,
+)
+
+INIT_STD = 0.02
+
+
+def _ones(n: int):
+    """A gain of its own: a leaf shared by two layers cannot be donated."""
+    return jnp.ones((n,), jnp.float32)
+
+
+def _norm(eps, scale, x):
+    return RMSNorm(eps).apply({"scale": scale}, {}, x)[0]
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _attend(q, k, v, start: int, scale: float):
+    """Queries `start`... of a sequence against the keys up to their own
+    position: scores and softmax in float32, (N, q, heads, v_dim) out.
+    Rematerialised: the backward recomputes the block's scores."""
+    s = jnp.einsum("nqhd,nkhd->nhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    qi = start + jnp.arange(q.shape[1])
+    s = jnp.where(qi[:, None] >= jnp.arange(k.shape[1])[None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nhqk,nkhd->nqhd", p.astype(v.dtype), v)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLA(Module):
+    """Multi-head latent attention in its training form: keys and values
+    are expanded per head from the latent (the latent form is a cache's
+    concern). Causal, a block of `q_block` queries at a time against the
+    keys up to the block's end, so the scores of a whole sequence never
+    exist at once and the upper triangle is not computed."""
+
+    heads: int = 20
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope: int = 192
+    rope_dim: int = 64
+    v_dim: int = 256
+    theta: float = 1e6
+    eps: float = 1e-5
+    q_block: int = 512
+
+    def init(self, key, in_shape: Shape):
+        d, h = in_shape[-1], self.heads
+        shapes = {
+            "q_a": (d, self.q_rank),
+            "q_b": (self.q_rank, h * (self.nope + self.rope_dim)),
+            "kv_a": (d, self.kv_rank + self.rope_dim),
+            "kv_b": (self.kv_rank, h * (self.nope + self.v_dim)),
+            "o": (h * self.v_dim, d),
+        }
+        params = {
+            n: _weight(k, s, s[0], INIT_STD)
+            for (n, s), k in zip(shapes.items(), jax.random.split(key, 5))
+        }
+        params["q_norm"] = _ones(self.q_rank)
+        params["kv_norm"] = _ones(self.kv_rank)
+        return params, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        n, s, _ = x.shape
+        h = self.heads
+        w = {k: v.astype(x.dtype) for k, v in params.items()}
+        with jax.named_scope("q"):
+            q = _norm(self.eps, w["q_norm"], x @ w["q_a"]) @ w["q_b"]
+            q = q.reshape(n, s, h, self.nope + self.rope_dim)
+        with jax.named_scope("kv"):
+            ckv = x @ w["kv_a"]
+            k_pe = ckv[..., self.kv_rank:].reshape(n, s, 1, self.rope_dim)
+            kv = _norm(self.eps, w["kv_norm"], ckv[..., : self.kv_rank]) @ w["kv_b"]
+            kv = kv.reshape(n, s, h, self.nope + self.v_dim)
+            k_nope, v = kv[..., : self.nope], kv[..., self.nope:]
+        with jax.named_scope("rope"):
+            q = jnp.concatenate(
+                [q[..., : self.nope], rope(q[..., self.nope:], self.theta)], -1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(rope(k_pe, self.theta),
+                                          (n, s, h, self.rope_dim))], -1)
+        with jax.named_scope("core"):
+            scale = (self.nope + self.rope_dim) ** -0.5
+            out = jnp.concatenate([
+                _attend(q[:, a: a + self.q_block], k[:, : a + self.q_block],
+                        v[:, : a + self.q_block], a, scale)
+                for a in range(0, s, self.q_block)], axis=1)
+            # The one activation a rematerialised layer keeps (`GlmMoe._run`):
+            # recomputing it is a third pass over the scores.
+            out = checkpoint_name(out, "attn_core")
+        with jax.named_scope("o"):
+            return out.reshape(n, s, h * self.v_dim) @ w["o"], state
+
+
+@jax.custom_vjp
+def _gather_rows(x, idx, back_idx, back_keep):
+    """`x[idx]`, rows of a 2-D `x`. The caller knows where every row of
+    `x` went — row j is `idx[back_idx[j, m]]` wherever `back_keep[j, m]` —
+    so the gradient is a gather and a sum over m too, not a scatter-add
+    (which the TPU does a row at a time)."""
+    return x[idx]
+
+
+def _gather_rows_fwd(x, idx, back_idx, back_keep):
+    return x[idx], (back_idx, back_keep)
+
+
+def _gather_rows_bwd(res, dy):
+    back_idx, back_keep = res
+    dx = jnp.where(back_keep[..., None], dy[back_idx], 0).sum(axis=1)
+    return dx.astype(dy.dtype), None, None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer(Module):
+    """Routed experts held here beside the whole shared expert (module
+    docstring). `held`: the published ids of the experts this layer
+    holds, in the order of its stacked weights; `rows`: the row buffer.
+
+    `gate_grad=False` is for a share that trains: a gate's gradient is
+    `<dL/dy, E_i(x)>`, and a share has that product for the experts it
+    holds alone, so the scores learn that only those lower the loss and
+    the router moves the tokens onto them (at 1e-4 the held eight of 64
+    took 1.4-2.2 times their share within 32 steps; PERF.md section 6,
+    PR 32). The other seven eighths of that gradient come back with the
+    exchange across chips; a share without the exchange leaves the
+    gates' gradient out whole. The forward is the same either way."""
+
+    width: int = 1536
+    n_routed: int = 64
+    per_token: int = 4
+    held: Tuple[int, ...] = tuple(range(64))
+    n_shared: int = 1
+    scaling: float = 1.8
+    rows: Optional[int] = None
+    bias_step: float = 1e-3
+    balance: float = 1e-4
+    gate_grad: bool = True
+
+    def __post_init__(self):
+        held = tuple(self.held)
+        if (not held or len(set(held)) != len(held)
+                or min(held) < 0 or max(held) >= self.n_routed):
+            raise ValueError(
+                f"held experts {held} are not distinct ids of the "
+                f"{self.n_routed} routed experts")
+        object.__setattr__(self, "held", held)
+
+    def _shared(self) -> GatedMLP:
+        return GatedMLP(self.n_shared * self.width, INIT_STD)
+
+    def init(self, key, in_shape: Shape):
+        d, f = in_shape[-1], self.width
+        rkey, ekey, skey = jax.random.split(key, 3)
+        # An expert's weights are drawn from its published id: every share
+        # of one layer, and the uncut layer, hold the same expert 5.
+        one = GatedMLP(f, INIT_STD)
+        each = [one.init(jax.random.fold_in(ekey, i), in_shape)[0]
+                for i in self.held]
+        params = {
+            "router": _weight(rkey, (d, self.n_routed), d, INIT_STD),
+            "experts": {n: jnp.stack([e[n] for e in each])
+                        for n in ("gate", "up", "down")},
+            "shared": self._shared().init(skey, in_shape)[0],
+        }
+        state = {
+            "bias": jnp.zeros((self.n_routed,), jnp.float32),
+            # assignments per expert (all `n_routed`) since the step began
+            "load": jnp.zeros((self.n_routed,), jnp.float32),
+            # of the last finished step
+            "rows_held": jnp.zeros((), jnp.int32),
+            "load_max_over_mean": jnp.zeros((), jnp.float32),
+            # since init: held assignments the row buffer could not take
+            "overflow_rows": jnp.zeros((), jnp.int32),
+            # the last forward's balance term, for the model's loss
+            "balance": jnp.zeros((), jnp.float32),
+        }
+        return params, state, in_shape
+
+    def route(self, router, bias, xt, n: int):
+        """(ids (T, k), gates (T, k) float32, load (n_routed,), balance
+        term) of tokens `xt` (T, d) that are `n` sequences."""
+        k, e = self.per_token, self.n_routed
+        s = jax.nn.sigmoid(jnp.dot(
+            xt.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
+        _, ids = lax.top_k(s + bias, k)
+        chosen = jnp.take_along_axis(s, ids, axis=1)
+        gates = self.scaling * chosen / jnp.sum(chosen, axis=1, keepdims=True)
+        if not self.gate_grad:
+            gates = lax.stop_gradient(gates)
+        picked = jax.nn.one_hot(ids, e, dtype=jnp.float32).sum(axis=1)
+        # Sequence-wise balance (DeepSeek-V3 eq. 17-20): f_i the share of
+        # a sequence's assignments expert i got (times n_routed), P_i its
+        # mean normalised score; only P carries a gradient.
+        per_seq = picked.reshape(n, -1, e)
+        f = per_seq.sum(axis=1) * (e / (k * per_seq.shape[1]))
+        p = (s / jnp.sum(s, axis=1, keepdims=True)).reshape(n, -1, e).mean(axis=1)
+        balance = self.balance * jnp.mean(jnp.sum(f * p, axis=1))
+        return ids, gates, picked.sum(axis=0), balance
+
+    def apply(self, params, state, x, train: bool = False):
+        n, s, d = x.shape
+        t, k, e = n * s, self.per_token, len(self.held)
+        a = t * k
+        rows = a if self.rows is None else min(self.rows, a)
+        xt = x.reshape(t, d)
+        with jax.named_scope("route"):
+            ids, gates, load, balance = self.route(
+                params["router"], state["bias"], xt, n)
+        with jax.named_scope("dispatch"):
+            lut = [e] * self.n_routed  # published id -> place here, or absent
+            for place, i in enumerate(self.held):
+                lut[i] = place
+            local = jnp.asarray(lut, jnp.int32)[ids].reshape(a)
+            # Held assignments first, expert by expert; absent ones last.
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+            rank = jnp.zeros((a,), jnp.int32).at[order].set(
+                jnp.arange(a, dtype=jnp.int32), unique_indices=True)
+            counts = jnp.sum(local[:, None] == jnp.arange(e)[None, :], axis=0,
+                             dtype=jnp.int32)
+            ends = jnp.minimum(jnp.cumsum(counts), rows)
+            sizes = jnp.diff(ends, prepend=0)
+            row_of = order[:rows]  # the assignment in each row of the buffer
+            row_live = jnp.arange(rows) < ends[-1]
+            in_buffer = ((local < e) & (rank < rows)).reshape(t, k)
+            rank = jnp.minimum(rank, rows - 1)
+            xs = _gather_rows(xt, jnp.where(row_live, row_of // k, 0),
+                              rank.reshape(t, k), in_buffer)
+        with jax.named_scope("experts"):
+            w = {m: params["experts"][m].astype(x.dtype)
+                 for m in ("gate", "up", "down")}
+            hidden = (jax.nn.silu(lax.ragged_dot(xs, w["gate"], sizes))
+                      * lax.ragged_dot(xs, w["up"], sizes))
+            ys = lax.ragged_dot(hidden, w["down"], sizes)
+        with jax.named_scope("combine"):
+            # Rows past the live ones hold nothing defined: every reader
+            # selects, none multiplies by a zero.
+            back = _gather_rows(ys, rank, row_of[:, None], row_live[:, None])
+            back = jnp.where(in_buffer[..., None], back.reshape(t, k, d), 0)
+            y = jnp.einsum("tk,tkd->td", gates.astype(x.dtype), back)
+        with jax.named_scope("shared"):
+            y = y + self._shared().apply(params["shared"], {}, xt)[0]
+        if train:
+            state = dict(
+                state,
+                load=state["load"] + load,
+                overflow_rows=state["overflow_rows"]
+                + jnp.sum(counts) - ends[-1],
+                balance=balance,
+            )
+        return y.reshape(n, s, d), state
+
+    def finish_step(self, state):
+        load = state["load"]
+        mean = jnp.mean(load)
+        return dict(
+            state,
+            bias=state["bias"] + self.bias_step * jnp.sign(mean - load),
+            load=jnp.zeros_like(load),
+            rows_held=jnp.sum(load[jnp.asarray(self.held)]).astype(jnp.int32),
+            load_max_over_mean=jnp.max(load) / jnp.maximum(mean, 1.0),
+        )
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderLayer(Module):
+    """Pre-norm decoder layer. Only an `ExpertLayer` has state."""
+
+    attn: MLA
+    ffn: Module
+    eps: float = 1e-5
+
+    @property
+    def ffn_scope(self) -> str:
+        return "moe" if isinstance(self.ffn, ExpertLayer) else "mlp"
+
+    def init(self, key, in_shape: Shape):
+        akey, fkey = jax.random.split(key)
+        ffn, state, _ = self.ffn.init(fkey, in_shape)
+        params = {"attn_norm": _ones(in_shape[-1]),
+                  "attn": self.attn.init(akey, in_shape)[0],
+                  "ffn_norm": _ones(in_shape[-1]), "ffn": ffn}
+        return params, state, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        with jax.named_scope("attn"):
+            with jax.named_scope("norm"):
+                y = _norm(self.eps, params["attn_norm"].astype(x.dtype), x)
+            h = x + self.attn.apply(params["attn"], {}, y, train)[0]
+        with jax.named_scope(self.ffn_scope):
+            with jax.named_scope("norm"):
+                y = _norm(self.eps, params["ffn_norm"].astype(x.dtype), h)
+            y, state = self.ffn.apply(params["ffn"], state, y, train)
+        return h + y, state
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmMoe(Module):
+    """The language model (module docstring). `in_shape` is `(S,)`; `x`
+    is token ids `(N, S)`; `apply` returns float32 logits `(N, S, vocab)`
+    (small sizes only: all of them at once); `loss(params, state, x, y)`
+    takes `y[n, i]` = the token after `x[n, i]`."""
+
+    vocab: int
+    hidden: int
+    dense_width: int
+    n_layers: int
+    attn: MLA
+    experts: ExpertLayer
+    first_dense: int = 1
+    mtp_modules: int = 1
+    mtp_weight: float = 0.3
+    eps: float = 1e-5
+    dtype: str = "bfloat16"
+    loss_block: int = 2048
+
+    def __post_init__(self):
+        if self.mtp_modules not in (0, 1):
+            raise ValueError(
+                f"{self.mtp_modules} multi-token-prediction modules: 0 or 1")
+
+    def _embed(self) -> Embedding:
+        return Embedding(self.vocab, self.hidden, INIT_STD, self.dtype)
+
+    def _layers(self) -> List[DecoderLayer]:
+        dense = GatedMLP(self.dense_width, INIT_STD)
+        return [
+            DecoderLayer(self.attn, dense if i < self.first_dense
+                         else self.experts, self.eps)
+            for i in range(self.n_layers)
+        ]
+
+    def _mtp_layer(self) -> DecoderLayer:
+        return DecoderLayer(self.attn, self.experts, self.eps)
+
+    def init(self, key, in_shape: Shape):
+        d = self.hidden
+        hid = (*in_shape, d)
+        ekey, hkey, mkey, *lkeys = jax.random.split(key, 3 + self.n_layers)
+        inits = [l.init(k, hid) for l, k in zip(self._layers(), lkeys)]
+        params = {
+            "embed": self._embed().init(ekey, in_shape)[0],
+            "layers": [p for p, _, _ in inits],
+            "norm": _ones(d),
+            "head": _weight(hkey, (d, self.vocab), d, INIT_STD),
+        }
+        state = {"layers": [s for _, s, _ in inits]}
+        if self.mtp_modules:
+            pkey, lkey = jax.random.split(mkey)
+            layer, state["mtp"], _ = self._mtp_layer().init(lkey, hid)
+            params["mtp"] = {
+                "enorm": _ones(d), "hnorm": _ones(d),
+                "proj": _weight(pkey, (2 * d, d), 2 * d, INIT_STD),
+                "layer": layer,
+            }
+        return params, state, (*in_shape, self.vocab)
+
+    def _run(self, layer: DecoderLayer, params, state, x, train: bool):
+        fn = lambda p, s, x: layer.apply(p, s, x, train)  # noqa: E731
+        if train:
+            fn = jax.checkpoint(
+                fn, policy=jax.checkpoint_policies.save_only_these_names(
+                    "attn_core"))
+        return fn(params, state, x)
+
+    def hidden_states(self, params, state, x, train: bool = False):
+        """(the residual stream after every decoder layer, the layers' new
+        states)."""
+        with jax.named_scope("embed"):
+            h = self._embed().apply(params["embed"], {}, x)[0]
+        hidden, new = [], []
+        for i, (layer, p, s) in enumerate(zip(
+                self._layers(), params["layers"], state["layers"], strict=True)):
+            with jax.named_scope(f"l{i}"):
+                h, s = self._run(layer, p, s, h, train)
+            hidden.append(h)
+            new.append(s)
+        return hidden, new
+
+    def _trunk(self, params, state, x, train: bool):
+        hidden, new = self.hidden_states(params, state, x, train)
+        return hidden[-1], new
+
+    def _mtp(self, params, state, h, y, train: bool):
+        """Hidden states of the module that, at position i, has seen the
+        trunk's state there and token i + 1 (`y[:, i]`)."""
+        p = params["mtp"]
+        with jax.named_scope("embed"):
+            e = self._embed().apply(params["embed"], {}, y)[0]
+        with jax.named_scope("norm"):
+            both = jnp.concatenate(
+                [_norm(self.eps, p["enorm"].astype(h.dtype), e),
+                 _norm(self.eps, p["hnorm"].astype(h.dtype), h)], axis=-1)
+        with jax.named_scope("proj"):
+            h2 = both @ p["proj"].astype(h.dtype)
+        with jax.named_scope("l0"):
+            return self._run(self._mtp_layer(), p["layer"], state, h2, train)
+
+    def _logits(self, params, h):
+        with jax.named_scope("norm"):
+            h = _norm(self.eps, params["norm"].astype(h.dtype), h)
+        with jax.named_scope("head"):
+            return jnp.matmul(h, params["head"].astype(h.dtype),
+                              preferred_element_type=jnp.float32)
+
+    def apply(self, params, state, x, train: bool = False):
+        h, layers = self._trunk(params, state, x, train)
+        return self._logits(params, h), dict(state, layers=layers)
+
+    def _cross_entropy(self, params, h, y, keep):
+        """Sum over the kept positions of the cross-entropy of `h`
+        (N, S, d) through the final norm and the head against `y`, a block
+        of `loss_block` positions at a time: the logits of all positions
+        never exist at once, forward or backward."""
+
+        @jax.checkpoint
+        def block(head, h, y, keep):
+            z = self._logits(head, h)
+            with jax.named_scope("loss"):
+                nll = (jax.nn.logsumexp(z, axis=-1)
+                       - jnp.take_along_axis(z, y[:, None], axis=1)[:, 0])
+                return jnp.sum(jnp.where(keep, nll, 0.0))
+
+        head = {"norm": params["norm"], "head": params["head"]}
+        h, y, keep = h.reshape(-1, h.shape[-1]), y.reshape(-1), keep.reshape(-1)
+        return sum(
+            block(head, h[a: a + self.loss_block], y[a: a + self.loss_block],
+                  keep[a: a + self.loss_block])
+            for a in range(0, h.shape[0], self.loss_block))
+
+    def loss(self, params, state, x, y):
+        """(loss, new state) of a training forward: the mean next-token
+        cross-entropy, `mtp_weight` times the MTP module's (position i
+        against `y[:, i + 1]`, the last position has no target), and the
+        balance terms of every expert layer."""
+        n, s = x.shape
+        h, layers = self._trunk(params, state, x, True)
+        every = jnp.ones((n, s), bool)
+        total = self._cross_entropy(params, h, y, every) / (n * s)
+        new = dict(state, layers=layers)
+        moe = [st for st in layers if st]
+        if self.mtp_modules:
+            with jax.named_scope("mtp"):
+                h2, new["mtp"] = self._mtp(params, state["mtp"], h, y, True)
+                after = jnp.concatenate([y[:, 1:], y[:, :1]], axis=1)
+                keep = every.at[:, -1].set(False)
+                total = total + self.mtp_weight * self._cross_entropy(
+                    params, h2, after, keep) / max(n * (s - 1), 1)
+            moe.append(new["mtp"])
+        return total + sum(st["balance"] for st in moe), new
+
+    def _expert_states(self, state) -> List[Dict]:
+        return [s for s in state["layers"] if s] + (
+            [state["mtp"]] if self.mtp_modules else [])
+
+    def finish_step(self, state):
+        """Close an optimizer step (after the last microbatch's forward):
+        every expert layer moves its selection bias by the step's load and
+        starts counting again."""
+        fin = self.experts.finish_step
+        new = dict(state, layers=[fin(s) if s else s for s in state["layers"]])
+        if self.mtp_modules:
+            new["mtp"] = fin(state["mtp"])
+        return new
+
+    def counters(self, state) -> Dict[str, List[float]]:
+        """The expert layers' counters, one value a layer (the MTP
+        module's last), as an epoch record carries them."""
+        got = jax.device_get([
+            (s["rows_held"], s["load_max_over_mean"], s["overflow_rows"])
+            for s in self._expert_states(state)])
+        return {
+            "moe_rows_held": [int(r) for r, _, _ in got],
+            "moe_load_max_over_mean": [float(m) for _, m, _ in got],
+            "moe_overflow_rows": [int(o) for _, _, o in got],
+        }
+
+    def describe(self, tokens_per_step: int) -> Dict[str, int]:
+        """What the `zoo_moe` journal event says once at set-up."""
+        ex = self.experts
+        assignments = tokens_per_step * ex.per_token
+        return dict(
+            experts_held=len(ex.held), experts_published=ex.n_routed,
+            experts_per_token=ex.per_token, expert_layers=len(
+                self._layers()) - self.first_dense + self.mtp_modules,
+            row_buffer=assignments if ex.rows is None
+            else min(ex.rows, assignments),
+            tokens_per_step=tokens_per_step,
+        )
+
+
+def glm_moe_lite(
+    *,
+    vocab_size: int,
+    hidden_size: int,
+    intermediate_size: int,
+    moe_intermediate_size: int,
+    num_hidden_layers: int,
+    num_attention_heads: int,
+    q_lora_rank: int,
+    kv_lora_rank: int,
+    qk_nope_head_dim: int,
+    qk_rope_head_dim: int,
+    v_head_dim: int,
+    n_routed_experts: int,
+    num_experts_per_tok: int,
+    n_shared_experts: int = 1,
+    routed_scaling_factor: float = 1.0,
+    first_k_dense_replace: int = 1,
+    num_nextn_predict_layers: int = 1,
+    rope_theta: float = 1e6,
+    rms_norm_eps: float = 1e-5,
+    held_experts: Optional[Sequence[int]] = None,
+    row_buffer: Optional[int] = None,
+    bias_update_speed: float = 1e-3,
+    balance_weight: float = 1e-4,
+    mtp_weight: float = 0.3,
+    gate_gradient: bool = True,
+    dtype: str = "bfloat16",
+    q_block: int = 512,
+    loss_block: int = 2048,
+) -> GlmMoe:
+    """A `glm4_moe_lite` decoder by its config.json's keys. `held_experts`
+    (default: all) is the share of the routed experts this model holds;
+    `gate_gradient=False` leaves the gates' gradient out of a share that
+    trains without the exchange (`ExpertLayer`)."""
+    held = range(n_routed_experts) if held_experts is None else held_experts
+    return GlmMoe(
+        vocab=vocab_size, hidden=hidden_size, dense_width=intermediate_size,
+        n_layers=num_hidden_layers,
+        attn=MLA(num_attention_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta, rms_norm_eps, q_block),
+        experts=ExpertLayer(
+            moe_intermediate_size, n_routed_experts, num_experts_per_tok,
+            tuple(held), n_shared_experts, routed_scaling_factor, row_buffer,
+            bias_update_speed, balance_weight, gate_gradient),
+        first_dense=first_k_dense_replace,
+        mtp_modules=num_nextn_predict_layers, mtp_weight=mtp_weight,
+        eps=rms_norm_eps, dtype=dtype, loss_block=loss_block,
+    )
+
+
+def glm_4_7_flash(
+    num_hidden_layers: int = 47,
+    vocab_size: int = 154880,
+    held_experts: Optional[Sequence[int]] = None,
+    row_buffer: Optional[int] = None,
+    **overrides,
+) -> GlmMoe:
+    """GLM-4.7-Flash at its published widths (30 B parameters whole, 3 B
+    active a token): hidden 2,048, 20 heads of 192 + 64 / 256 over a
+    768-wide query latent and a 512-wide key/value latent, one dense
+    layer 10,240 wide, then 64 routed experts 1,536 wide, 4 a token
+    (gates scaled 1.8), beside 1 shared, and 1 MTP module. Depth, the
+    vocabulary's rows and the experts held are the caller's cut: one chip
+    of an eight-way expert-parallel group holds `held_experts=range(8)`
+    and 19,360 rows."""
+    kwargs = dict(
+        vocab_size=vocab_size, hidden_size=2048, intermediate_size=10240,
+        moe_intermediate_size=1536, num_hidden_layers=num_hidden_layers,
+        num_attention_heads=20, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        n_routed_experts=64, num_experts_per_tok=4, n_shared_experts=1,
+        routed_scaling_factor=1.8, first_k_dense_replace=1,
+        num_nextn_predict_layers=1, rope_theta=1e6, rms_norm_eps=1e-5,
+        held_experts=held_experts, row_buffer=row_buffer,
+    )
+    kwargs.update(overrides)
+    return glm_moe_lite(**kwargs)

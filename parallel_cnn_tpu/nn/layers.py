@@ -124,18 +124,21 @@ class Dense(Module):
 
     features: int
     init_std: Optional[float] = None
+    use_bias: bool = True
 
     def init(self, key, in_shape: Shape):
         d = in_shape[-1]
         wkey, _ = jax.random.split(key)
-        params = {
-            "w": _weight(wkey, (d, self.features), d, self.init_std),
-            "b": jnp.zeros((self.features,), jnp.float32),
-        }
+        params = {"w": _weight(wkey, (d, self.features), d, self.init_std)}
+        if self.use_bias:
+            params["b"] = jnp.zeros((self.features,), jnp.float32)
         return params, {}, (*in_shape[:-1], self.features)
 
     def apply(self, params, state, x, train: bool = False):
-        return x @ params["w"].astype(x.dtype) + params["b"].astype(x.dtype), state
+        y = x @ params["w"].astype(x.dtype)
+        if self.use_bias:
+            y = y + params["b"].astype(x.dtype)
+        return y, state
 
 
 def _batch_moments(x):
@@ -340,6 +343,88 @@ _INV_SQRT_2PI = 0.3989422804014327
 # x / sqrt 2 = -4: below it Phi(x) < 8e-9, and a float32 1 + erf has
 # stopped moving (at 0 on the TPU, at 1.8e-7 in XLA:CPU's erf).
 _GELU_TAIL = -5.656854249492381
+
+
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(Module):
+    """Root-mean-square norm over the LAST axis with a learned gain (Zhang
+    & Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * scale``. The mean and
+    the division are float32 whatever ``x.dtype`` is; the result is cast
+    to ``x.dtype`` before the gain, as the published decoder code of the
+    models that use it does."""
+
+    eps: float = 1e-5
+
+    def init(self, key, in_shape: Shape):
+        return {"scale": jnp.ones((in_shape[-1],), jnp.float32)}, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        xf = x.astype(jnp.float32)
+        inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                        + self.eps)
+        return (xf * inv).astype(x.dtype) * params["scale"].astype(x.dtype), state
+
+
+@dataclasses.dataclass(frozen=True)
+class Embedding(Module):
+    """``rows`` learned vectors of ``features``; integer ids ``(N, ...)`` in,
+    ``(N, ..., features)`` out at ``dtype`` (ids carry no float dtype, so
+    the layer names the one the model computes at). ``rows`` may be a
+    slice of a published vocabulary: the ids are then the slice's own."""
+
+    rows: int
+    features: int
+    init_std: float = 0.02
+    dtype: str = "bfloat16"
+
+    def init(self, key, in_shape: Shape):
+        w = self.init_std * jax.random.normal(
+            key, (self.rows, self.features), jnp.float32)
+        return {"w": w}, {}, (*in_shape, self.features)
+
+    def apply(self, params, state, x, train: bool = False):
+        return params["w"][x].astype(self.dtype), state
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedMLP(Module):
+    """``down(silu(gate x) * up x)`` over the last axis (Shazeer 2020,
+    "GLU variants"), ``width`` wide inside, no biases."""
+
+    width: int
+    init_std: Optional[float] = None
+
+    def init(self, key, in_shape: Shape):
+        d, f = in_shape[-1], self.width
+        kg, ku, kd = jax.random.split(key, 3)
+        params = {
+            "gate": _weight(kg, (d, f), d, self.init_std),
+            "up": _weight(ku, (d, f), d, self.init_std),
+            "down": _weight(kd, (f, d), f, self.init_std),
+        }
+        return params, {}, in_shape
+
+    def apply(self, params, state, x, train: bool = False):
+        gate, up, down = (
+            params[n].astype(x.dtype) for n in ("gate", "up", "down"))
+        return (jax.nn.silu(x @ gate) * (x @ up)) @ down, state
+
+
+def rope(x, theta: float):
+    """Rotary position embedding (Su et al. 2021) of ``x`` ``(N, S, ...,
+    d)`` at positions 0..S-1 along axis 1: feature ``i`` is paired with
+    ``i + d/2`` (the "rotate half" convention) and the pair turned by
+    ``position * theta ** (-2i / d)``. Angles and the turn are float32,
+    the result is cast back to ``x.dtype``."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
+    ang = ang.reshape(1, x.shape[1], *(1,) * (x.ndim - 3), d // 2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., : d // 2], xf[..., d // 2:]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
 
 def _normal_cdf(xf):

@@ -105,9 +105,26 @@ def scope_of(op_name: str) -> Tuple[str, str]:
         return "optimizer", "opt"
     if path[0] != "grad":
         return "", ""
-    while path and path[0] == "grad":
-        path = path[1:]
+    path = _collapse([c for c in path
+                      if c not in _TRANSFORM_SCOPES and "->" not in c])
     return "/".join(path) or "grad", "bwd" if "transpose(" in op_name else "fwd"
+
+
+# Components of a name stack that name no layer: `grad` (the step's own
+# root, which `jax.checkpoint` repeats inside a rematerialised layer's
+# backward), the two scopes `jax.checkpoint` opens, and (the test on "->")
+# the subscripts `jnp.einsum` opens a scope with.
+_TRANSFORM_SCOPES = frozenset({"grad", "checkpoint", "rematted_computation"})
+
+
+def _collapse(path: List[str]) -> List[str]:
+    """The backward of a rematerialised layer names it twice
+    (`transpose(jvp(l1))/grad/jvp(l1)/checkpoint/.../moe` -> l1, l1, moe):
+    a leading run that repeats itself is said once."""
+    for n in range(len(path) // 2, 0, -1):
+        if path[:n] == path[n:2 * n]:
+            return path[:n] + path[2 * n:]
+    return path
 
 
 def _computations(hlo_text: str):
@@ -155,6 +172,8 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
                 yield from members(inner.group(1), seen | {comp})
 
     out: Dict[str, Entry] = {}
+    lines: Dict[str, str] = {}
+    kernels: Dict[str, str] = {}
     todo, done = [entry], set()
     while todo:
         comp = todo.pop()
@@ -184,7 +203,52 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
                 if opcode == "call":
                     todo.extend(_TO_APPLY.findall(line))
             out[name] = Entry(scope, phase, opcode, has_conv)
+            lines[name] = line
+            if opcode == "custom-call" and op_name and not scope:
+                kernels[name] = op_name
+    # A kernel the compiler itself put in carries its own name in place
+    # of a name stack (`lax.ragged_dot` becomes a `custom-call` whose
+    # `op_name` is `ragged-dot-none`): it is the layer's that made its
+    # operands, and says what it is (`l1/moe/experts/ragged-dot-none`).
+    # A custom-call with no `op_name` at all stays unnamed.
+    for name, kernel in kernels.items():
+        scope, phase = _of_operands(lines[name], out, lines)
+        if scope:
+            out[name] = dataclasses.replace(
+                out[name], scope=f"{scope}/{kernel}", phase=phase)
     return out
+
+
+_OPERAND = re.compile(r"%([^\s,()]+)")
+
+
+def _operands(line: str) -> List[str]:
+    """Names of an instruction's operands, in order."""
+    m = _INSTR.match(line)
+    depth = 0
+    for i in range(m.end() - 1, len(line)):
+        depth += {"(": 1, ")": -1}.get(line[i], 0)
+        if depth == 0:
+            return _OPERAND.findall(line[m.end():i])
+    return []
+
+
+def _of_operands(line: str, entries: Dict[str, Entry], lines: Dict[str, str],
+                 depth: int = 3) -> Tuple[str, str]:
+    """(scope, phase) of the last operand that has one (a matmul's weights
+    come last), looked for through operands that have none themselves
+    (copies between memories, tuple elements) a few levels down."""
+    for name in reversed(_operands(line)):
+        entry = entries.get(name)
+        if entry is None:
+            continue
+        if entry.scope:
+            return entry.scope, entry.phase
+        if depth and entry.opcode != "parameter":
+            found = _of_operands(lines[name], entries, lines, depth - 1)
+            if found[0]:
+                return found
+    return "", ""
 
 
 # ------------------------------------------------------------------ store

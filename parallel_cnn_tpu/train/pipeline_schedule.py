@@ -108,6 +108,8 @@ def make_pipeline_step(
     from parallel_cnn_tpu.parallel import collectives
     from parallel_cnn_tpu.train import zoo
 
+    zoo.refuse_step_state(model, "pipeline step")
+
     comm = comm or _default_comm()
     n_stages = int(pipeline.stages)
     if fused is not None:

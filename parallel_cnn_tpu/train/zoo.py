@@ -89,6 +89,17 @@ def _build_loss_fn(model: Module, fused) -> Callable:
     directly), degrading to the unfused composition — with a one-time
     note — when the model's head doesn't match a supported pattern.
     """
+    own = getattr(model, "loss", None)
+    if own is not None:
+        # A model whose loss is more than the cross-entropy of its output
+        # (nn/glm_moe.py: a second prediction head on the hidden states,
+        # balance terms of its layers, logits a block at a time) owns it:
+        # `loss(params, model_state, x, y) -> (loss, new model state)`.
+        if fused is not None:
+            raise ValueError(
+                f"{type(model).__name__} computes its own loss; the fused "
+                "tail and the bf16 cast of `fused=` do not apply to it")
+        return own
     if fused is None:
         def loss_fn(params, model_state, x, y):
             logits, new_state = model.apply(params, model_state, x,
@@ -380,6 +391,10 @@ def make_train_step(
         grads = jax.tree_util.tree_map(lambda g: g / accum_steps, gsum)
         return lsum / accum_steps, model_state, grads
 
+    # Layer state that a whole optimizer step settles, once its last
+    # microbatch has run (nn/glm_moe.py: the experts' selection bias).
+    finish_step = getattr(model, "finish_step", None)
+
     def step(state: ZooState, x, y, key=None):
         if augment is not None and key is None:
             raise ValueError(
@@ -425,6 +440,8 @@ def make_train_step(
                 grads, state.opt_state, state.params
             )
             params = optax.apply_updates(state.params, updates)
+        if finish_step is not None:
+            model_state = finish_step(model_state)
         return ZooState(params, model_state, opt_state), loss
 
     return jax.jit(step, donate_argnums=(0,))
@@ -447,6 +464,27 @@ def refuse_random_layers(model_state, step: str) -> None:
             f"the {step} does not run a model with a layer that is random "
             "in training (DropPath): use the default GSPMD step "
             "(comm=None, no fused.update, no pipeline)"
+        )
+
+
+class StepStateUnsupported(ValueError):
+    """A step factory that averages the model state over shards was handed
+    a model whose state a whole step settles (`finish_step`)."""
+
+
+def refuse_step_state(model: Module, step: str) -> None:
+    """A model with a `finish_step` (nn/glm_moe.py:GlmMoe) keeps counts of
+    the step's tokens in its state and moves its experts' selection bias
+    by their sign once a step: the explicit shard_map steps `pmean` the
+    state over shards, which makes fractions of the counts and a bias no
+    shard computed. They refuse such a model by name, as they refuse a
+    random layer; the GSPMD step calls `finish_step` itself."""
+    if hasattr(model, "finish_step"):
+        raise StepStateUnsupported(
+            f"the {step} does not run {type(model).__name__}: its layers "
+            "keep per-step counts and a selection bias in the model state, "
+            "which only the default GSPMD step (comm=None, no "
+            "fused.update, no pipeline) settles"
         )
 
 
@@ -493,6 +531,7 @@ def _make_comm_step(
     shard-local BN statistics). The flat impl="ring" is single-axis and
     is rejected on a hierarchical mesh.
     """
+    refuse_step_state(model, "explicit-collective step (comm=...)")
     from parallel_cnn_tpu.parallel import collectives
 
     has_host = HOST_AXIS in mesh.axis_names
@@ -725,6 +764,7 @@ def make_fused_train_step(
     kernels as static scalars; train() rejects schedules/weight-decay on
     this path.
     """
+    refuse_step_state(model, "update-on-arrival step (fused.update)")
     from parallel_cnn_tpu.ops import pallas_update
     from parallel_cnn_tpu.parallel import collectives
 
@@ -1031,6 +1071,7 @@ def make_zero3_train_step(
     Dynamic loss scaling follows make_fused_train_step: overflow skips
     the update via jnp.where agreement over all batch axes.
     """
+    refuse_step_state(model, "ZeRO-3 step (fused.zero=3)")
     from parallel_cnn_tpu.ops import pallas_update
     from parallel_cnn_tpu.parallel import collectives
 
@@ -1735,6 +1776,9 @@ def train(
                 for a in jax.tree_util.tree_leaves(state.opt_state)
             ),
         )
+        if hasattr(model, "describe"):
+            obs.event("zoo_moe", **model.describe(
+                batch_size * math.prod(in_shape)))
     aug_fn = None
     if augment:
         from parallel_cnn_tpu.data import augment as aug_lib
@@ -2196,6 +2240,9 @@ def train(
             rec["platform"] = jax.devices()[0].platform
             rec["state_devices"] = _device_ids(state)
             rec["batch_devices"] = _device_ids((bx, by))
+            if hasattr(model, "counters"):
+                # (nn/glm_moe.py: rows held, load, overflow, a value a layer)
+                rec.update(model.counters(state.model_state))
             metrics.record(**rec)
         if ring is not None:
             from parallel_cnn_tpu.train import checkpoint

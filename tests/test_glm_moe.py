@@ -1,0 +1,526 @@
+"""nn/glm_moe.py (GLM-4.7-Flash's mechanisms) at toy widths on the CPU,
+seeded random weights, against the plain float32 reference the benchmark
+keeps (benchmark/reference/glm_moe.py): latent attention, the expert layer
+and its share, the multi-token-prediction module, the whole model's loss,
+gradients, two AdamW steps and held-row counts; what the step settles
+without a gradient; and which step factories run the model."""
+
+import dataclasses
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference import glm_moe as ref  # noqa: E402
+from benchmark.tools.compare_glm_moe import random_leaves  # noqa: E402
+from benchmark.tools.compare_reference import leaf_gaps  # noqa: E402
+from parallel_cnn_tpu import config as config_lib, nn, plan as plan_lib  # noqa: E402
+from parallel_cnn_tpu.nn import glm_moe  # noqa: E402
+from parallel_cnn_tpu.train import zoo  # noqa: E402
+
+S, VOCAB = 16, 96
+ARCH = {
+    "hidden_size": 32, "intermediate_size": 64, "moe_intermediate_size": 16,
+    "num_attention_heads": 2, "n_shared_experts": 1,
+    "routed_scaling_factor": 1.8, "num_experts_per_tok": 2,
+    "first_k_dense_replace": 1, "num_hidden_layers": 3,
+    "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-5, "rope_theta": 1e6,
+    "q_lora_rank": 12, "kv_lora_rank": 8, "qk_nope_head_dim": 6,
+    "qk_rope_head_dim": 4, "v_head_dim": 8, "vocab_size": VOCAB,
+    "router_experts": 8, "held_experts": [0, 1, 2], "row_buffer": None,
+    "bias_update_speed": 1e-3, "balance_weight": 1e-4, "mtp_weight": 0.3,
+}
+HYPER = dict(lr=1e-3, kind="adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
+# float32 on both sides, the highest matmul precision: what differs is the
+# order of float32 sums (a grouped matmul over sorted rows against a loop
+# over experts, blocked against whole softmax). Seen: 1e-7 on the loss,
+# 6e-7 on the worst leaf's gradient; every fault below moves 100 x TOL.
+TOL = 2e-5
+
+
+def build(**over):
+    arch = dict(ARCH, **over)
+    keys = [k for k in arch if k not in ("router_experts", "held_experts")]
+    return glm_moe.glm_moe_lite(
+        **{k: arch[k] for k in keys}, n_routed_experts=arch["router_experts"],
+        held_experts=arch["held_experts"], dtype="float32", q_block=8,
+        loss_block=16), arch
+
+
+@pytest.fixture(scope="module")
+def small():
+    """The toy model with EVERY leaf drawn at random (weights of std
+    1 / sqrt(fan_in), gains 1 + 0.1 n, selection biases 0.01 n): at the
+    published init of std 0.02 a 32-wide model is all embedding."""
+    import types
+
+    model, arch = build()
+    params, state, _ = model.init(jax.random.key(1), (S,))
+    params, state = random_leaves(params, state, jax.random.key(2))
+    tokens = jax.random.randint(jax.random.key(3), (4, S + 1), 0, VOCAB)
+    return types.SimpleNamespace(model=model, arch=arch, params=params,
+                                 state=state, x=tokens[:, :-1], y=tokens[:, 1:])
+
+
+def _highest(fn, *args):
+    with jax.default_matmul_precision("highest"):
+        return fn(*args)
+
+
+def _system(s, model=None):
+    (loss, new), grads = _highest(jax.value_and_grad(
+        zoo._build_loss_fn(model or s.model, None), has_aux=True),
+        s.params, s.state, s.x, s.y)
+    return float(loss), grads, new
+
+
+# ------------------------------------------------------------- the pieces
+
+def test_latent_attention_agrees_with_the_reference(small):
+    attn = small.model.attn
+    p = small.params["layers"][0]["attn"]
+    x = jax.random.normal(jax.random.key(5), (2, S, 32))
+    got = _highest(lambda: attn.apply(p, {}, x)[0])
+    want = _highest(ref.attention, small.arch, p, x)
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    np.testing.assert_allclose(got, want, atol=TOL)
+    # causal: position i does not see what follows it
+    later = x.at[:, S // 2:].add(1.0)
+    moved = _highest(lambda: attn.apply(p, {}, later)[0])
+    np.testing.assert_allclose(moved[:, : S // 2], got[:, : S // 2], atol=1e-6)
+
+
+def test_the_expert_layer_agrees_with_the_reference(small):
+    layer = small.model.experts
+    p, st = small.params["layers"][1]["ffn"], small.state["layers"][1]
+    x = jax.random.normal(jax.random.key(6), (4, S, 32))
+    got, new = _highest(lambda: layer.apply(p, st, x, train=True))
+    want, balance, load = _highest(ref.experts, small.arch, p, st["bias"], x)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_allclose(new["load"], load)
+    assert float(new["balance"]) == pytest.approx(float(balance), rel=1e-5)
+    assert float(load.sum()) == 4 * S * 2 and int(new["overflow_rows"]) == 0
+
+
+def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    """One layer cut eight ways: each share routes over all 8 experts and
+    adds only its own expert's part, the shared expert is computed by
+    every share alike. The shares' routed parts plus the shared expert
+    ONCE are what the uncut reference gives for the whole layer."""
+    whole = glm_moe.ExpertLayer(width=16, n_routed=8, per_token=2,
+                                held=tuple(range(8)), scaling=1.8)
+    shape = (S, 32)
+    key = jax.random.key(7)
+    p, st, _ = whole.init(key, shape)
+    st = dict(st, bias=0.01 * jax.random.normal(jax.random.key(8), (8,)))
+    x = jax.random.normal(jax.random.key(9), (2, S, 32)) * 4.0
+    arch = dict(ARCH, held_experts=list(range(8)))
+    want, _, _ = _highest(ref.experts, arch, p, st["bias"], x)
+    shared = _highest(ref.gated_mlp, p["shared"], x)
+    total = shared
+    for i in range(8):
+        share = dataclasses.replace(whole, held=(i,))
+        sp, _, _ = share.init(key, shape)  # an expert's weights come from its id
+        for m in ("gate", "up", "down"):
+            np.testing.assert_array_equal(sp["experts"][m][0], p["experts"][m][i])
+        np.testing.assert_array_equal(sp["router"], p["router"])
+        y, _ = _highest(lambda: share.apply(sp, st, x))
+        total = total + (y - shared)
+    assert float(jnp.max(jnp.abs(want - shared))) > 0.05  # the experts matter
+    np.testing.assert_allclose(total, want, atol=TOL)
+
+
+def test_all_tokens_routed_to_one_held_expert_lose_none():
+    """The default row buffer is every assignment: overflow 0 however the
+    tokens fall. A buffer that cannot take them counts what it left out."""
+    layer = glm_moe.ExpertLayer(width=16, n_routed=8, per_token=2,
+                                held=(0, 5), scaling=1.8)
+    p, st, _ = layer.init(jax.random.key(1), (S, 32))
+    st = dict(st, bias=st["bias"].at[5].set(10.0).at[3].set(9.0))
+    x = jax.random.normal(jax.random.key(2), (2, S, 32))
+    y, new = layer.apply(p, st, x, train=True)
+    assert float(new["load"][5]) == 2 * S and int(new["overflow_rows"]) == 0
+    arch = dict(ARCH, held_experts=[0, 5])
+    want, _, _ = ref.experts(arch, p, st["bias"], x)
+    np.testing.assert_allclose(y, want, atol=1e-4)
+    small_buffer = dataclasses.replace(layer, rows=20)
+    _, new = small_buffer.apply(p, st, x, train=True)
+    assert int(new["overflow_rows"]) == 2 * S - 20
+
+
+def test_the_bias_moves_by_u_toward_balance_and_takes_no_gradient(small):
+    model = small.model
+    loss, grads, new = _system(small)
+    assert set(small.params["layers"][1]["ffn"]) == {"router", "experts", "shared"}
+    done = model.finish_step(new)
+    for before, mid, after in zip(
+            model._expert_states(small.state), model._expert_states(new),
+            model._expert_states(done)):
+        load = mid["load"]
+        assert float(load.sum()) == 4 * S * 2
+        np.testing.assert_array_equal(mid["bias"], before["bias"])
+        np.testing.assert_allclose(
+            after["bias"] - before["bias"],
+            1e-3 * np.sign(float(load.mean()) - np.asarray(load)), atol=1e-9)
+        assert float(after["load"].sum()) == 0
+        assert int(after["rows_held"]) == int(load[jnp.asarray([0, 1, 2])].sum())
+        assert float(after["load_max_over_mean"]) == pytest.approx(
+            float(load.max() / load.mean()))
+    # the loss does not move with the bias except through the routing: a
+    # bias too small to flip a choice leaves it where it was
+    nudged = jax.tree_util.tree_map_with_path(
+        lambda path, a: a + 1e-9 if path[-1].key == "bias" else a, small.state)
+    assert _highest(model.loss, small.params, nudged, small.x, small.y)[0] == \
+        pytest.approx(loss, rel=1e-6)
+
+
+def test_a_sliced_vocabulary_is_a_smaller_vocabulary(small):
+    """The head and the embedding hold `vocab_size` rows and nothing else:
+    the loss of a model built with the slice is the cross-entropy over the
+    slice's logits alone."""
+    logits, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    assert logits.shape == (4, S, VOCAB) and logits.dtype == jnp.float32
+    assert small.params["embed"]["w"].shape == (VOCAB, 32)
+    assert small.params["head"].shape == (32, VOCAB)
+    main = float(jnp.mean(ref.nll(logits, small.y)))
+    terms = _highest(ref.loss_fn, small.arch, small.params, small.state,
+                     small.x, small.y)[1][0]
+    assert main == pytest.approx(float(terms["main"]), rel=TOL)
+
+
+def test_the_mtp_module_scores_position_i_against_token_i_plus_two(small):
+    terms = _highest(ref.loss_fn, small.arch, small.params, small.state,
+                     small.x, small.y)[1][0]
+    off, _ = build(mtp_weight=0.0)
+    with_mtp = _system(small)[0]
+    without = _system(small, off)[0]
+    assert with_mtp - without == pytest.approx(0.3 * float(terms["mtp"]), rel=1e-4)
+    # the last position has no target: its token changes nothing of the term
+    y2 = small.y.at[:, 0].set((small.y[:, 0] + 1) % VOCAB)  # token 1: an input
+    moved = _highest(small.model.loss, small.params, small.state, small.x, y2)[0]
+    assert abs(float(moved) - with_mtp) > 1e-4
+
+
+# --------------------------------------------------------- the whole model
+
+def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
+    loss, grads, _ = _system(small)
+    want, want_grads = ref.loss_and_grads(
+        small.arch, small.params, small.state, small.x, small.y)
+    assert loss == pytest.approx(float(want), rel=TOL)
+    gaps = leaf_gaps(grads, want_grads)
+    assert len(gaps) == 66 and max(gaps.values()) < TOL, max(gaps, key=gaps.get)
+    assert min(float(jnp.linalg.norm(g)) for g in
+               jax.tree_util.tree_leaves(want_grads)) > 0  # every leaf is used
+
+
+def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference(small):
+    """`gate_gradient=False` (a share that trains without the exchange):
+    the forward is the published one, the loss the same number, and every
+    leaf's gradient the reference's under the same key of `arch`; what the
+    router keeps is the balance term's gradient alone."""
+    model, arch = build(gate_gradient=False)
+    loss, grads, _ = _system(small, model)
+    whole, whole_grads, _ = _system(small)
+    assert loss == whole
+    want, want_grads = ref.loss_and_grads(
+        arch, small.params, small.state, small.x, small.y)
+    assert loss == pytest.approx(float(want), rel=TOL)
+    gaps = leaf_gaps(grads, want_grads)
+    assert len(gaps) == 66 and max(gaps.values()) < TOL, max(gaps, key=gaps.get)
+    router = lambda g: float(jnp.linalg.norm(g["layers"][1]["ffn"]["router"]))  # noqa: E731
+    assert router(grads) < 0.05 * router(whole_grads)  # alpha = 1e-4 is left
+    # and the reference with the gradient in stands 100 x TOL off
+    assert max(leaf_gaps(grads, whole_grads).values()) > 100 * TOL
+
+
+def _held_share(gate_gradient, steps=50):
+    """Mean over the expert layers and over steps 21..`steps` of rows held
+    over the balanced share, training the toy at its published init."""
+    model, _ = build(gate_gradient=gate_gradient)
+    optimizer = zoo.make_optimizer(**HYPER)
+    state = zoo.init_state(model, jax.random.key(5), (S,), optimizer)
+    tokens = jax.random.randint(jax.random.key(6), (8, S + 1), 0, VOCAB)
+    step = zoo.make_train_step(model, optimizer, 1, None)
+    balanced = 4 * S * 2 / 8 * 3
+    seen = []
+    for i in range(steps):
+        at = 4 * (i % 2)
+        state, _ = step(state, tokens[at: at + 4, :-1], tokens[at: at + 4, 1:])
+        if i >= 20:
+            rows = model.counters(state.model_state)["moe_rows_held"]
+            seen.append(sum(rows) / len(rows) / balanced)
+    return sum(seen) / len(seen)
+
+
+def test_a_share_that_trains_its_gates_pulls_the_tokens_onto_what_it_holds():
+    """Why `gate_gradient=False` exists (PERF.md section 6, PR 32): only a
+    held expert's gate has a gradient in a share, so the router learns
+    that only those lower the loss, and the held experts' rows rise above
+    the balanced share while it trains; with the gates' gradient left out
+    they stay at it."""
+    with_gradient, without = _held_share(True), _held_share(False)
+    assert with_gradient > 1.1 and with_gradient > without + 0.08
+    assert abs(without - 1) < 0.08
+
+
+def test_logits_and_hidden_states_agree_with_the_reference(small):
+    want = ref.eval_logits(small.arch, small.params, small.state, small.x)
+    got, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
+    hidden, _ = _highest(small.model.hidden_states, small.params, small.state,
+                         small.x)
+    for a, b in zip(hidden, ref.hidden_states(
+            small.arch, small.params, small.state, small.x), strict=True):
+        np.testing.assert_allclose(a, b, atol=TOL * float(jnp.max(jnp.abs(b))))
+
+
+def _two_steps(s, model=None):
+    model = model or s.model
+    opt = zoo.make_optimizer(**HYPER)
+    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
+    state = zoo.ZooState(copy(s.params), copy(s.state), opt.init(s.params))
+    step = zoo.make_train_step(model, opt, 1, None)
+    losses, rows = [], []
+    for _ in range(3):
+        state, loss = _highest(step, state, s.x, s.y)
+        losses.append(float(loss))
+        rows.append(model.counters(state.model_state)["moe_rows_held"])
+    return losses, rows, state
+
+
+def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
+    want = ref.train_report(small.arch, small.params, small.state, small.x,
+                            small.y, steps=3, **HYPER)
+    losses, rows, state = _two_steps(small)
+    assert losses == pytest.approx(want["losses"], rel=TOL)
+    assert rows == want["rows_held"]
+    assert want["losses"][2] < want["losses"][1] < want["losses"][0]
+    # two steps alone keep no moment: the same first two losses
+    two = ref.train_losses(small.arch, small.params, small.state, small.x,
+                           small.y, steps=2, **HYPER)
+    assert two == pytest.approx(want["losses"][:2], rel=1e-6)
+    assert model_overflow(small.model, state) == 0
+
+
+def model_overflow(model, state):
+    return sum(model.counters(state.model_state)["moe_overflow_rows"])
+
+
+def test_accumulation_settles_the_bias_once_a_step_over_all_its_tokens(small):
+    """Two microbatches of two sequences: the same loads as one batch of
+    four, one move of the bias."""
+    opt = zoo.make_optimizer(**HYPER)
+    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
+    out = []
+    for accum in (1, 2):
+        state = zoo.ZooState(copy(small.params), copy(small.state),
+                             opt.init(small.params))
+        state, _ = _highest(zoo.make_train_step(small.model, opt, accum, None),
+                            state, small.x, small.y)
+        out.append(state.model_state)
+    for a, b in zip(small.model._expert_states(out[0]),
+                    small.model._expert_states(out[1])):
+        np.testing.assert_array_equal(a["bias"], b["bias"])
+        assert int(a["rows_held"]) == int(b["rows_held"])
+
+
+FAULTS = ["shared_dropped", "scaling_skipped", "softmax_for_sigmoid",
+          "q_norm_missing", "rope_off", "mtp_off", "gates_from_biased_scores"]
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
+    model = small.model
+    # before any patch: some of them reach the reference's `jax.numpy` too
+    want, want_grads = ref.loss_and_grads(
+        small.arch, small.params, small.state, small.x, small.y)
+    if fault == "shared_dropped":
+        class Nothing(nn.GatedMLP):
+            def apply(self, params, state, x, train=False):
+                return jnp.zeros_like(x), state
+
+        monkeypatch.setattr(glm_moe.ExpertLayer, "_shared",
+                            lambda self: Nothing(self.width))
+    elif fault == "scaling_skipped":
+        model, _ = build(routed_scaling_factor=1.0)
+    elif fault == "softmax_for_sigmoid":
+        monkeypatch.setattr(jax.nn, "sigmoid",
+                            lambda a: jax.nn.softmax(a, axis=-1))
+    elif fault == "q_norm_missing":
+        real = glm_moe._norm
+        monkeypatch.setattr(
+            glm_moe, "_norm",
+            lambda eps, scale, x: x if scale.shape == (12,) else real(eps, scale, x))
+    elif fault == "rope_off":
+        monkeypatch.setattr(glm_moe, "rope", lambda x, theta: x)
+    elif fault == "mtp_off":
+        model, _ = build(mtp_weight=0.0)
+    elif fault == "gates_from_biased_scores":
+        real_take = jnp.take_along_axis
+        monkeypatch.setattr(
+            glm_moe.jnp, "take_along_axis",
+            lambda a, i, axis: real_take(a, i, axis) + 0.01 if a.shape[-1] == 8
+            else real_take(a, i, axis))
+    loss, grads, _ = _system(small, model)
+    loss_gap = abs(loss / float(want) - 1)
+    grad_gap = max(leaf_gaps(grads, want_grads).values())
+    assert max(loss_gap, grad_gap) > 100 * TOL, (loss_gap, grad_gap)
+
+
+def test_a_float8_reference_fails_the_comparison(small, monkeypatch):
+    want = ref.train_losses(small.arch, small.params, small.state, small.x,
+                            small.y, steps=2, **HYPER)
+    monkeypatch.setattr(ref, "ROUND", jnp.float8_e4m3fn)
+    ref._programs.cache_clear()
+    try:
+        low = ref.train_losses(small.arch, small.params, small.state, small.x,
+                               small.y, steps=2, **HYPER)
+    finally:
+        monkeypatch.undo()
+        ref._programs.cache_clear()
+    assert max(abs(a / b - 1) for a, b in zip(low, want)) > 100 * TOL
+
+
+# ------------------------------------------------- bf16, the step factories
+
+def test_bfloat16_activations_change_rounding_only(small):
+    """The cell's precision: bf16 activations over float32 masters, whose
+    gradients stay float32."""
+    loss, _, _ = _system(small)
+    half = dataclasses.replace(small.model, dtype="bfloat16")
+    loss3, grads3, _ = _system(small, half)
+    assert abs(loss3 / loss - 1) < 5e-3
+    assert all(g.dtype == jnp.float32 for g in jax.tree_util.tree_leaves(grads3))
+
+
+def test_the_published_model_has_the_counted_parameters():
+    model = glm_moe.glm_4_7_flash(num_hidden_layers=5, vocab_size=19360,
+                                  held_experts=range(8), row_buffer=16384)
+    params, state = jax.eval_shape(
+        lambda k: model.init(k, (4096,))[:2], jax.random.key(0))
+    count = lambda t: sum(l.size for l in jax.tree_util.tree_leaves(t))  # noqa: E731
+    attn = 2048 * 768 + 768 * 5120 + 2048 * 576 + 512 * 8960 + 5120 * 2048
+    assert count(params["layers"][1]["attn"]) == attn + 768 + 512 == 21_759_232
+    assert count(params["layers"][0]) == 84_677_888  # the dense layer
+    assert count(params["layers"][1]) == 106_829_056  # 8 of 64 experts + shared
+    assert count(params["mtp"]) == 115_221_760
+    assert count(params) == 706_516_480
+    assert model.describe(4 * 4096) == dict(
+        experts_held=8, experts_published=64, experts_per_token=4,
+        expert_layers=5, row_buffer=16384, tokens_per_step=16384)
+    whole = glm_moe.glm_4_7_flash()
+    assert (whole.n_layers, whole.vocab, len(whole.experts.held)) == (47, 154880, 64)
+    with pytest.raises(ValueError, match="distinct ids"):
+        glm_moe.glm_4_7_flash(held_experts=[0, 64])
+
+
+@pytest.mark.parametrize("factory", ["comm_psum", "comm_ring", "fused_update",
+                                     "zero3", "pipeline"])
+def test_the_other_step_factories_refuse_the_model_by_name(host_devices, factory):
+    model, _ = build()
+    opt = zoo.make_optimizer(**HYPER)
+    mesh = plan_lib.ExecutionPlan(data=2).validate().make_mesh(
+        devices=host_devices[:2])
+    fused = config_lib.FusedStepConfig(update=True)
+    comm = config_lib.CommConfig(impl="ring")
+    with pytest.raises(zoo.StepStateUnsupported, match="GlmMoe"):
+        if factory.startswith("comm"):
+            zoo.make_train_step(model, opt, 1, mesh, comm=config_lib.CommConfig(
+                impl=factory.split("_")[1]))
+        elif factory == "fused_update":
+            zoo.make_fused_train_step(
+                model, lr=0.1, momentum=0.9, accum_steps=1, mesh=mesh,
+                augment=None, comm=comm, fused=fused, n_buckets=1)
+        elif factory == "zero3":
+            zoo.make_zero3_train_step(
+                model, lr=0.1, momentum=0.9, accum_steps=1, mesh=mesh,
+                augment=None, comm=comm, fused=fused, plan=None)
+        else:
+            from parallel_cnn_tpu.train.pipeline_schedule import make_pipeline_step
+
+            make_pipeline_step(model, opt, accum_steps=2, mesh=mesh,
+                               pipeline=config_lib.PipelineConfig(stages=2),
+                               in_shape=(S,))
+
+
+def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
+        host_devices, tmp_path):
+    model, _ = build()
+    mesh = plan_lib.ExecutionPlan(data=2).validate().make_mesh(
+        devices=host_devices[:2])
+    tokens = np.asarray(jax.random.randint(jax.random.key(3), (8, S + 1), 0, VOCAB))
+
+    class Rec:
+        epochs = []
+
+        def record(self, **rec):
+            self.epochs.append(rec)
+
+    from parallel_cnn_tpu import obs as obs_lib
+
+    class Journal:
+        enabled = True
+        events = []
+
+        def emit(self, kind, **fields):
+            self.events.append((kind, fields))
+
+        def flush(self):
+            pass
+
+    obs = obs_lib.Obs(obs_lib.Tracer(), obs_lib.MetricsRegistry(), Journal(),
+                      enabled=True)
+    _, losses = zoo.train(
+        model, tokens[:, :-1], tokens[:, 1:], in_shape=(S,), epochs=2,
+        batch_size=4, mesh=mesh, **HYPER, seed=3, verbose=False,
+        metrics=Rec(), obs=obs)
+    assert all(math.isfinite(v) for v in losses) and losses[1] < losses[0]
+    last = Rec.epochs[-1]
+    assert len(last["moe_rows_held"]) == 3 and sum(last["moe_overflow_rows"]) == 0
+    assert all(m >= 1.0 for m in last["moe_load_max_over_mean"])
+    (event,) = [f for k, f in Journal.events if k == "zoo_moe"]
+    assert (event["experts_held"], event["experts_published"],
+            event["tokens_per_step"], event["row_buffer"]) == (3, 8, 4 * S, 4 * S * 2)
+
+
+def test_the_scopes_are_the_ones_the_catalog_reads_through_rematerialisation():
+    """Layer scopes survive `jax.checkpoint` (which names a layer twice in
+    its backward and adds scopes of its own) and `jnp.einsum` (which opens
+    one with its subscripts)."""
+    from parallel_cnn_tpu.obs import programs
+
+    of = programs.scope_of
+    assert of("jit(step)/grad/transpose(jvp(l1))/grad/jvp(l1)/checkpoint/"
+              "rematted_computation/moe/dispatch/gather") == ("l1/moe/dispatch", "bwd")
+    assert of("jit(step)/grad/transpose(jvp(mtp))/l0/grad/jvp(mtp)/l0/checkpoint/"
+              "attn/core/checkpoint/nhqk,nkhd->nqhd/dot_general") == (
+        "mtp/l0/attn/core", "bwd")
+    assert of("jit(step)/grad/jvp(l0)/attn/core/checkpoint/jit(_where)/select_n") == (
+        "l0/attn/core", "fwd")
+    assert of("jit(step)/grad/transpose(jvp(grad))/jvp()/checkpoint/"
+              "rematted_computation/norm/mul") == ("norm", "bwd")
+    model, _ = build()
+    opt = zoo.make_optimizer(**HYPER)
+    state = jax.eval_shape(lambda k: zoo.init_state(model, k, (S,), opt),
+                           jax.random.key(0))
+    x = jax.ShapeDtypeStruct((4, S), jnp.int32)
+    text = zoo.make_train_step(model, opt, 1, None).lower(state, x, x).as_text(
+        debug_info=True)
+    import re
+
+    scopes = {of(name)[0] for name in re.findall(r'loc\("([^"]*)"', text)}
+    for want in ("embed", "l0/attn/q", "l0/attn/kv", "l0/attn/rope",
+                 "l0/attn/core", "l0/attn/o", "l0/mlp", "l1/moe/route",
+                 "l1/moe/dispatch", "l1/moe/experts", "l1/moe/combine",
+                 "l1/moe/shared", "mtp/proj", "mtp/l0/moe/experts", "mtp/head",
+                 "norm", "head", "loss", "optimizer"):
+        assert want in scopes, (want, sorted(scopes))
+    assert not any("checkpoint" in s or "->" in s for s in scopes)

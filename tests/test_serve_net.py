@@ -143,6 +143,10 @@ def test_bad_request_is_failed_not_crash(stack):
             assert reply["ok"] is True
         finally:
             s.close()
+        # the endpoint counts an outcome after it has written the reply
+        deadline = time.monotonic() + 2.0
+        while wire.snapshot()["completed"] < 1 and time.monotonic() < deadline:
+            time.sleep(0.01)
         snap = wire.snapshot()
         assert snap["failed"] == 1 and snap["completed"] == 1
         assert wire.balanced()

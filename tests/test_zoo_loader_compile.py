@@ -210,3 +210,72 @@ def test_every_forward_conv_carries_both_moments_in_its_epilogue(topo, name):
         moments = ("f32", f"{batch},{channels}")
         assert sorted(result) == sorted(
             [moments, moments, ("bf16", activation)]), (scope, result)
+
+
+# ------------------- the catalog of the programs that were there (PR 32)
+
+def _scope_of_at_pr30(op_name):
+    """`obs/programs.py:scope_of` as PR 30 left it: only leading `grad`s
+    go; nothing repeated is collapsed, no transform's scope is dropped."""
+    from parallel_cnn_tpu.obs import programs
+
+    parts = programs._split(op_name)
+    if len(parts) < 2 or not parts[0].startswith(("jit(", "pjit(")):
+        return "", ""
+    body = parts[1:]
+    if not programs._WRAPPED.match(body[-1]):
+        body = body[:-1]
+    path = [c for part in body for c in programs._unwrap(part)]
+    if not path:
+        return "", ""
+    if path[0] == "optimizer":
+        return "optimizer", "opt"
+    if path[0] != "grad":
+        return "", ""
+    while path and path[0] == "grad":
+        path = path[1:]
+    return "/".join(path) or "grad", "bwd" if "transpose(" in op_name else "fwd"
+
+
+def _step_text(topo, model, optimizer, in_shape, batch, mesh):
+    where = (SingleDeviceSharding(topo.devices[0]) if mesh is None
+             else NamedSharding(mesh, P()))
+    rows = where if mesh is None else NamedSharding(mesh, P("data"))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where),
+        jax.eval_shape(lambda k: zoo.init_state(model, k, in_shape, optimizer),
+                       jax.random.key(0)))
+    step = zoo.make_train_step(model, optimizer, 1, mesh)
+    return step.lower(
+        state, jax.ShapeDtypeStruct((batch, *in_shape), jnp.bfloat16, sharding=rows),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rows)).compile().as_text()
+
+
+@pytest.mark.parametrize("name", ["convnext_b", "resnet50_dp4"])
+def test_the_catalog_of_a_step_that_was_there_is_what_pr30_read(topo, name, monkeypatch):
+    """PR 32 taught the catalog a rematerialised layer's name stack and a
+    kernel the compiler names itself. Neither rule touches a step that was
+    there: every instruction of ConvNeXt-B's step for one chip, and of
+    ResNet-50's over the 2x2 mesh (collectives, the compiler's own
+    custom-calls), has the scope and the phase PR 30's rules gave it."""
+    from parallel_cnn_tpu import nn
+    from parallel_cnn_tpu.obs import programs
+
+    if name == "convnext_b":
+        model, mesh = nn.convnext.convnext_b(num_classes=1000), None
+        optimizer = zoo.make_optimizer(lr=1e-3, kind="adamw", weight_decay=0.05)
+    else:
+        model = resnet.resnet50(num_classes=1000, cifar_stem=False)
+        mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+        optimizer = zoo.make_optimizer(lr=0.1, weight_decay=1e-4)
+    text = _step_text(topo, model, optimizer, (64, 64, 3), 8, mesh)
+    now = programs.parse(text)
+    monkeypatch.setattr(programs, "scope_of", _scope_of_at_pr30)
+    monkeypatch.setattr(programs, "_of_operands", lambda *a, **k: ("", ""))
+    then = programs.parse(text)
+    assert len(now) > 500 and set(now) == set(then)
+    assert {n: e for n, e in now.items() if e != then[n]} == {}
+    named = [e for e in now.values() if e.scope]
+    assert len(named) > 1000
+    if mesh is not None:
+        assert any(e.opcode.startswith("all-reduce") for e in now.values())
