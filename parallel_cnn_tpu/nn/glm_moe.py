@@ -45,8 +45,11 @@ The model owns its loss (`GlmMoe.loss`, the seam `train/zoo.py:
 _build_loss_fn` looks for): it needs the hidden states for the MTP
 module, computes the logits a block of positions at a time, and adds the
 layers' balance terms. In training every decoder layer is rematerialised
-(`jax.checkpoint`), and so is every block of attention queries and of
-logits.
+(`jax.checkpoint`) and keeps one activation, its attention core's output
+(with the rows' log-sum-exp where the core is the fused kernels of
+ops/pallas_attention.py: `MLA.core` — shapes that tile, a step lowered
+for a TPU; elsewhere a block of queries at a time in plain XLA, each
+block rematerialised); so is every block of logits.
 
 Scopes (obs/programs.py; benchmark/shapes/glm_moe.py lists the same):
 `embed`, `l<i>/attn/{norm,q,kv,rope,core,o}`, `l<i>/mlp/...` or
@@ -73,6 +76,7 @@ from parallel_cnn_tpu.nn.layers import (
     _weight,
     rope,
 )
+from parallel_cnn_tpu.ops import pallas_attention
 
 INIT_STD = 0.02
 
@@ -88,24 +92,26 @@ def _norm(eps, scale, x):
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
 def _attend(q, k, v, start: int, scale: float):
-    """Queries `start`... of a sequence against the keys up to their own
-    position: scores and softmax in float32, (N, q, heads, v_dim) out.
-    Rematerialised: the backward recomputes the block's scores."""
-    s = jnp.einsum("nqhd,nkhd->nhqk", q, k,
+    """Queries `start`... of a sequence (N, heads, q, ·) against the keys
+    up to their own position (N, heads, k, ·): scores and softmax in
+    float32, (N, heads, q, v_dim) out. Rematerialised: the backward
+    recomputes the block's scores."""
+    s = jnp.einsum("nhqd,nhkd->nhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
-    qi = start + jnp.arange(q.shape[1])
-    s = jnp.where(qi[:, None] >= jnp.arange(k.shape[1])[None, :], s, -jnp.inf)
+    qi = start + jnp.arange(q.shape[2])
+    s = jnp.where(qi[:, None] >= jnp.arange(k.shape[2])[None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("nhqk,nkhd->nqhd", p.astype(v.dtype), v)
+    return jnp.einsum("nhqk,nhkd->nhqd", p.astype(v.dtype), v)
 
 
 @dataclasses.dataclass(frozen=True)
 class MLA(Module):
     """Multi-head latent attention in its training form: keys and values
     are expanded per head from the latent (the latent form is a cache's
-    concern). Causal, a block of `q_block` queries at a time against the
-    keys up to the block's end, so the scores of a whole sequence never
-    exist at once and the upper triangle is not computed."""
+    concern). Causal; the scores of a whole sequence never exist at once
+    and the upper triangle is not computed: a tile at a time inside the
+    fused kernels where `core` says so, else a block of `q_block` queries
+    at a time against the keys up to the block's end."""
 
     heads: int = 20
     q_rank: int = 768
@@ -134,36 +140,60 @@ class MLA(Module):
         params["kv_norm"] = _ones(self.kv_rank)
         return params, {}, in_shape
 
+    def core(self, s: int) -> Tuple[str, int]:
+        """(`"fused"` | `"blocks"`, the tile's side) for `s` positions: what
+        the shapes allow. The fused kernels run where the step is lowered
+        for a TPU (ops/pallas_attention.py); elsewhere the same shapes run
+        the blocks."""
+        t = pallas_attention.tile(s, self.nope + self.rope_dim, self.v_dim)
+        return ("blocks", self.q_block) if t is None else ("fused", t)
+
+    def _blocks(self, q, k, v):
+        """(N, heads, S, ·) in and out: `q_block` queries at a time."""
+        scale = (self.nope + self.rope_dim) ** -0.5
+        return jnp.concatenate([
+            _attend(q[:, :, a: a + self.q_block], k[:, :, : a + self.q_block],
+                    v[:, :, : a + self.q_block], a, scale)
+            for a in range(0, q.shape[2], self.q_block)], axis=2)
+
     def apply(self, params, state, x, train: bool = False):
-        n, s, _ = x.shape
-        h = self.heads
+        """Heads ahead of positions throughout, as the fused core wants
+        them: the projections hand over and take back (N, heads, S, ·)
+        through their matmuls' own output layouts, so no tensor is
+        transposed on the way in or out."""
         w = {k: v.astype(x.dtype) for k, v in params.items()}
+        n, s, _ = x.shape
+        h, nope = self.heads, self.nope
         with jax.named_scope("q"):
-            q = _norm(self.eps, w["q_norm"], x @ w["q_a"]) @ w["q_b"]
-            q = q.reshape(n, s, h, self.nope + self.rope_dim)
+            q = jnp.einsum(
+                "nsr,rhd->nhsd", _norm(self.eps, w["q_norm"], x @ w["q_a"]),
+                w["q_b"].reshape(self.q_rank, h, nope + self.rope_dim))
         with jax.named_scope("kv"):
             ckv = x @ w["kv_a"]
-            k_pe = ckv[..., self.kv_rank:].reshape(n, s, 1, self.rope_dim)
-            kv = _norm(self.eps, w["kv_norm"], ckv[..., : self.kv_rank]) @ w["kv_b"]
-            kv = kv.reshape(n, s, h, self.nope + self.v_dim)
-            k_nope, v = kv[..., : self.nope], kv[..., self.nope:]
+            k_pe = ckv[:, None, :, self.kv_rank:]
+            c = _norm(self.eps, w["kv_norm"], ckv[..., : self.kv_rank])
+            kv_b = w["kv_b"].reshape(self.kv_rank, h, nope + self.v_dim)
+            k_nope = jnp.einsum("nsr,rhd->nhsd", c, kv_b[..., :nope])
+            v = jnp.einsum("nsr,rhd->nhsd", c, kv_b[..., nope:])
         with jax.named_scope("rope"):
             q = jnp.concatenate(
-                [q[..., : self.nope], rope(q[..., self.nope:], self.theta)], -1)
+                [q[..., :nope], rope(q[..., nope:], self.theta)], -1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(rope(k_pe, self.theta),
-                                          (n, s, h, self.rope_dim))], -1)
+                                          (n, h, s, self.rope_dim))], -1)
         with jax.named_scope("core"):
-            scale = (self.nope + self.rope_dim) ** -0.5
-            out = jnp.concatenate([
-                _attend(q[:, a: a + self.q_block], k[:, : a + self.q_block],
-                        v[:, : a + self.q_block], a, scale)
-                for a in range(0, s, self.q_block)], axis=1)
-            # The one activation a rematerialised layer keeps (`GlmMoe._run`):
-            # recomputing it is a third pass over the scores.
-            out = checkpoint_name(out, "attn_core")
+            # The one activation a rematerialised layer keeps (`GlmMoe._run`;
+            # the kernels' is named inside `causal_attention`, with its rows'
+            # log-sum-exp): recomputing it is a third pass over the scores.
+            kind, t = self.core(s)
+            if kind == "fused":
+                out = pallas_attention.causal_attention(
+                    q, k, v, (nope + self.rope_dim) ** -0.5, t, self._blocks)
+            else:
+                out = checkpoint_name(self._blocks(q, k, v), "attn_core")
         with jax.named_scope("o"):
-            return out.reshape(n, s, h * self.v_dim) @ w["o"], state
+            return jnp.einsum("nhsd,hdm->nsm", out,
+                              w["o"].reshape(h, self.v_dim, -1)), state
 
 
 @jax.custom_vjp
@@ -552,11 +582,25 @@ class GlmMoe(Module):
             "moe_overflow_rows": [int(o) for _, _, o in got],
         }
 
-    def describe(self, tokens_per_step: int) -> Dict[str, int]:
-        """What the `zoo_moe` journal event says once at set-up."""
+    def describe(self, tokens_per_step: int, seq_len: int,
+                 platform: str) -> Dict[str, object]:
+        """What the `zoo_moe` journal event says once at set-up, of steps
+        of `seq_len`-token sequences: what the shapes allow on `platform`,
+        the platform of the devices that hold the state (`zoo.train`
+        compiles its step for those; the program itself decides where it
+        is lowered, and a step lowered for another platform than the
+        caller names here runs the other core). The attention tiles are
+        one core's, one (sequence, head)'s: the part of the score square
+        that is computed."""
         ex = self.experts
         assignments = tokens_per_step * ex.per_token
+        kind, t = self.attn.core(seq_len)
+        if platform != "tpu":
+            kind, t = "blocks", self.attn.q_block
         return dict(
+            attention_core=kind,
+            attention_tiles_visited=pallas_attention.tiles_visited(seq_len, t),
+            attention_tiles_total=(-(-seq_len // t)) ** 2,
             experts_held=len(ex.held), experts_published=ex.n_routed,
             experts_per_token=ex.per_token, expert_layers=len(
                 self._layers()) - self.first_dense + self.mtp_modules,

@@ -411,15 +411,14 @@ class GatedMLP(Module):
 
 
 def rope(x, theta: float):
-    """Rotary position embedding (Su et al. 2021) of ``x`` ``(N, S, ...,
-    d)`` at positions 0..S-1 along axis 1: feature ``i`` is paired with
-    ``i + d/2`` (the "rotate half" convention) and the pair turned by
-    ``position * theta ** (-2i / d)``. Angles and the turn are float32,
-    the result is cast back to ``x.dtype``."""
+    """Rotary position embedding (Su et al. 2021) of ``x`` ``(..., S, d)``
+    at positions 0..S-1 along the axis before the last: feature ``i`` is
+    paired with ``i + d/2`` (the "rotate half" convention) and the pair
+    turned by ``position * theta ** (-2i / d)``. Angles and the turn are
+    float32, the result is cast back to ``x.dtype``."""
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
-    ang = ang.reshape(1, x.shape[1], *(1,) * (x.ndim - 3), d // 2)
+    ang = jnp.arange(x.shape[-2], dtype=jnp.float32)[:, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
     a, b = xf[..., : d // 2], xf[..., d // 2:]

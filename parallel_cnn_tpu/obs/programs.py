@@ -97,7 +97,10 @@ def scope_of(op_name: str) -> Tuple[str, str]:
         return "", ""
     body = parts[1:]
     if not _WRAPPED.match(body[-1]):
-        body = body[:-1]  # the primitive's own name
+        # the primitive's own name; a Pallas kernel's call comes with the
+        # kernel's name before it (`.../core/causal_attention_fwd/
+        # pallas_call`), which names the instruction, not a layer
+        body = body[:-2] if body[-1] == "pallas_call" else body[:-1]
     path = [c for part in body for c in _unwrap(part)]
     if not path:
         return "", ""
@@ -105,8 +108,7 @@ def scope_of(op_name: str) -> Tuple[str, str]:
         return "optimizer", "opt"
     if path[0] != "grad":
         return "", ""
-    path = _collapse([c for c in path
-                      if c not in _TRANSFORM_SCOPES and "->" not in c])
+    path = _collapse(_layers(path))
     return "/".join(path) or "grad", "bwd" if "transpose(" in op_name else "fwd"
 
 
@@ -115,6 +117,21 @@ def scope_of(op_name: str) -> Tuple[str, str]:
 # backward), the two scopes `jax.checkpoint` opens, and (the test on "->")
 # the subscripts `jnp.einsum` opens a scope with.
 _TRANSFORM_SCOPES = frozenset({"grad", "checkpoint", "rematted_computation"})
+_BRANCH = re.compile(r"^branch_\d+_fun$")
+
+
+def _layers(path: List[str]) -> List[str]:
+    """`path` without what names no layer: `_TRANSFORM_SCOPES`, and the
+    pair a `lax.cond` / `switch` opens around a branch (`cond/
+    branch_0_fun`: a `lax.platform_dependent` is one) — a `cond` that no
+    branch follows is somebody's scope and stays."""
+    out: List[str] = []
+    for c in path:
+        if _BRANCH.match(c) and out and out[-1] == "cond":
+            out.pop()
+        elif c not in _TRANSFORM_SCOPES and "->" not in c:
+            out.append(c)
+    return out
 
 
 def _collapse(path: List[str]) -> List[str]:

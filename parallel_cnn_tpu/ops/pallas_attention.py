@@ -1,0 +1,299 @@
+"""Causal attention core as fused TPU kernels (Pallas/Mosaic): for
+head-major bf16 `q, k (N, H, S, Dk)` and `v (N, H, S, Dv)`,
+
+    out = softmax_causal(q k^T * scale) v
+
+with the scores in float32, the softmax statistics and every accumulator
+in float32, and the probabilities cast to `v.dtype` before `p v` — what
+`nn/glm_moe.py:_attend` computes a block of queries at a time in plain
+XLA. Two roundings are not that path's: `p` is cast before it is
+normalised (the division by the row's sum is the accumulator's, at the
+end), and the backward casts `ds` to `q.dtype` before the `dk` and `dq`
+products, where the blocks' autodiff hands the dots a float32 `ds`. Here
+the scores never leave VMEM:
+
+  forward   grid (N, H, query tile, key tile): an online softmax over the
+            key tiles at or below the diagonal. A tile wholly above it is
+            not visited (its grid step does nothing and fetches nothing:
+            the index maps stay on the diagonal's tile), a tile the
+            diagonal crosses is masked in the kernel. Writes `out` and
+            the rows' log-sum-exp `lse (N, H, S)` float32.
+  backward  grid (N, H, key tile, query tile), from `q, k, v, out, lse,
+            d_out` alone: `delta = sum(out * d_out)` (plain XLA, one pass),
+            then per visited tile the scores again, transposed (keys on
+            the sublanes, so `lse` and `delta` are rows that broadcast
+            down them), `p`, `dv += p^T d_out`, `dp`, `ds = p (dp -
+            delta) scale` (cast to `q.dtype`), `dk += ds^T q`, `dq += ds
+            k`. `dk`/`dv` accumulate in VMEM over the inner query tiles;
+            `dq` of a whole (sequence, head) accumulates in one float32
+            VMEM buffer `(S, Dk)` over all of its tiles and is written
+            once.
+
+`causal_attention` is the `jax.custom_vjp` over both. Its forward rule
+names `out` and `lse` `"attn_core"` (`jax.ad_checkpoint.checkpoint_name`)
+ON THE VALUES IT RETURNS AS RESIDUALS: a layer rematerialised under
+`save_only_these_names("attn_core")` keeps both, so its backward re-runs
+no forward kernel.
+
+Which execution runs is decided by what the code can see, never by an
+option. `tile(S, Dk, Dv)` is the tile for shapes the kernels take (`S` a
+multiple of it, head widths multiples of 128 lanes, `dq`'s buffer within
+VMEM) and None otherwise — the caller then keeps its own blocked path.
+Where the shapes tile, the platform is decided where the program is
+LOWERED (`jax.lax.platform_dependent`): the kernels for a TPU, the
+caller's `otherwise` (the same mathematics in plain XLA) for anything
+else, so an off-chip compile for a described TPU gets the kernels and a
+CPU host with tiling shapes still runs.
+
+Tiles were swept on the v5e once, at 4 x 20 x 4,096 x 256 (PERF.md section
+6, PR 33: forward / backward 9.64 / 15.54 ms at 256, 6.26 / 12.07 at 512,
+5.99 / 12.32 at 1,024; the blocked XLA form 22.2 / 47.1 forward / both),
+and are fixed here as a function of `(S, head widths)`.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+RESIDUAL_NAME = "attn_core"
+# exp(MASKED - m) is 0.0 exactly for every finite m, and MASKED - MASKED
+# is 0, not NaN (a -inf would make one of a fully masked stretch).
+MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+# What one (sequence, head)'s float32 `dq` may take of VMEM in the backward.
+DQ_BUFFER_BYTES = 16 << 20
+VMEM_LIMIT_BYTES = 64 << 20
+
+_NN = (((1,), (0,)), ((), ()))  # a (m, k) x b (k, n) -> (m, n)
+_NT = (((1,), (1,)), ((), ()))  # a (m, d) x b (n, d) -> (m, n)
+_TN = (((0,), (0,)), ((), ()))  # a (k, m) x b (k, n) -> (m, n)
+
+
+def _dot(a, b, dims):
+    """One MXU product with a float32 result. The precision is said, not
+    taken from `jax_default_matmul_precision`: the operands are what the
+    configuration states, and Mosaic refuses a bf16 product asked for at
+    `highest`."""
+    return lax.dot_general(a, b, dims, precision=lax.Precision.DEFAULT,
+                           preferred_element_type=jnp.float32)
+
+
+def tile(s: int, qk_width: int, v_width: int) -> Optional[int]:
+    """The square tile (queries x keys) the kernels run `S` positions of
+    these head widths at, or None where they do not take the shapes."""
+    if qk_width % LANES or v_width % LANES or s * qk_width * 4 > DQ_BUFFER_BYTES:
+        return None
+    for t in (512, 256, 128):
+        if s % t == 0:
+            return t
+    return None
+
+
+def tiles_visited(s: int, t: int) -> int:
+    """Of the `ceil(S/t)^2` tiles of one (sequence, head)'s score square,
+    those at or below the diagonal: what a causal core computes."""
+    n = -(-s // t)
+    return n * (n + 1) // 2
+
+
+def _causal(s, rows_are_keys: bool):
+    """A square tile ON the diagonal: `s` where key <= query, MASKED
+    elsewhere (both indices count from the tile's own corner)."""
+    r = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    c = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(r <= c if rows_are_keys else c <= r, s, MASKED)
+
+
+def _lanes(x, width: int):
+    """A lane-replicated column `(rows, LANES)` as `(rows, width)`."""
+    return x if width == LANES else jnp.tile(x, (1, width // LANES))
+
+
+# ---------------------------------------------------------------- forward
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, scale: float, t: int):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(on_diagonal: bool):
+        s = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale
+        if on_diagonal:
+            s = _causal(s, rows_are_keys=False)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+        p = jnp.exp(s - _lanes(m_next, t))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_prev + p.sum(axis=-1)[:, None]
+        m_ref[...] = m_next
+        v = v_ref[0, 0]
+        acc_ref[...] = (_lanes(alpha, acc_ref.shape[-1]) * acc_ref[...]
+                        + _dot(p.astype(v.dtype), v, _NN))
+
+    pl.when(ki < qi)(functools.partial(step, False))
+
+    @pl.when(ki == qi)
+    def _():
+        step(True)
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
+                       ).astype(o_ref.dtype)
+        # the statistics are columns, `lse` is stored as a row
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def forward(q, k, v, *, scale: float, t: int, interpret: bool = False):
+    """(out (N, H, S, Dv) in `v.dtype`, lse (N, H, S) float32)."""
+    n, h, s, dk = q.shape
+    dv = v.shape[-1]
+    at_q = lambda n, h, qi, ki: (n, h, qi, 0)  # noqa: E731
+    # above the diagonal nothing is fetched: the map stays where it was
+    at_k = lambda n, h, qi, ki: (n, h, jnp.minimum(ki, qi), 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, t=t),
+        grid=(n, h, s // t, s // t),
+        in_specs=[pl.BlockSpec((1, 1, t, dk), at_q),
+                  pl.BlockSpec((1, 1, t, dk), at_k),
+                  pl.BlockSpec((1, 1, t, dv), at_k)],
+        out_specs=[pl.BlockSpec((1, 1, t, dv), at_q),
+                   pl.BlockSpec((1, 1, 1, t), lambda n, h, qi, ki: (n, h, 0, qi))],
+        out_shape=[jax.ShapeDtypeStruct((n, h, s, dv), v.dtype),
+                   jax.ShapeDtypeStruct((n, h, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((t, LANES), jnp.float32),
+                        pltpu.VMEM((t, LANES), jnp.float32),
+                        pltpu.VMEM((t, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="causal_attention_fwd",
+    )(q, k, v)
+    return out, lse.reshape(n, h, s)
+
+
+# --------------------------------------------------------------- backward
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                *, scale: float, t: int):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    last = pl.num_programs(3) - 1
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step(on_diagonal: bool):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        # keys on the sublanes: (keys, queries)
+        s = _dot(k, q, _NT) * scale
+        if on_diagonal:
+            s = _causal(s, rows_are_keys=True)
+        p = jnp.exp(s - lse_ref[0, 0])
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
+        ds = (p * (_dot(v, do, _NT) - delta_ref[0, 0]) * scale).astype(q.dtype)
+        dk_acc[...] += _dot(ds, q, _NN)
+        rows = pl.ds(pl.multiple_of(qi * t, t), t)
+        dq_acc[rows, :] += _dot(ds, k, _TN)
+
+    pl.when(qi > ki)(functools.partial(step, False))
+    pl.when(qi == ki)(functools.partial(step, True))
+
+    @pl.when(qi == last)
+    def _():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when((ki == last) & (qi == last))
+    def _():
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def backward(q, k, v, out, lse, d_out, *, scale: float, t: int,
+             interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v`."""
+    n, h, s, dk = q.shape
+    dv = v.shape[-1]
+    delta = jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32),
+                    axis=-1).reshape(n, h, 1, s)
+    # a query tile above the diagonal is not fetched: the map waits on
+    # the diagonal's
+    at_q = lambda n, h, ki, qi: (n, h, jnp.maximum(qi, ki), 0)  # noqa: E731
+    at_row = lambda n, h, ki, qi: (n, h, 0, jnp.maximum(qi, ki))  # noqa: E731
+    at_k = lambda n, h, ki, qi: (n, h, ki, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, t=t),
+        grid=(n, h, s // t, s // t),
+        in_specs=[pl.BlockSpec((1, 1, t, dk), at_q),
+                  pl.BlockSpec((1, 1, t, dk), at_k),
+                  pl.BlockSpec((1, 1, t, dv), at_k),
+                  pl.BlockSpec((1, 1, t, dv), at_q),
+                  pl.BlockSpec((1, 1, 1, t), at_row),
+                  pl.BlockSpec((1, 1, 1, t), at_row)],
+        out_specs=[pl.BlockSpec((1, 1, s, dk), lambda n, h, ki, qi: (n, h, 0, 0)),
+                   pl.BlockSpec((1, 1, t, dk), at_k),
+                   pl.BlockSpec((1, 1, t, dv), at_k)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((s, dk), jnp.float32),
+                        pltpu.VMEM((t, dk), jnp.float32),
+                        pltpu.VMEM((t, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="causal_attention_bwd",
+    )(q, k, v, d_out, lse.reshape(n, h, 1, s), delta)
+
+
+# ------------------------------------------------------------ custom_vjp
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_attention(q, k, v, scale: float, t: int, otherwise: Callable):
+    """`out (N, H, S, Dv)` of head-major `q, k, v` whose shapes `tile`
+    accepted (`t`): the kernels where the program is lowered for a TPU,
+    `otherwise(q, k, v)` — the caller's plain-XLA form — elsewhere."""
+    return _forward_rule(q, k, v, scale, t, otherwise)[0]
+
+
+def _forward_rule(q, k, v, scale, t, otherwise):
+    def plain(q, k, v):
+        n, h, s, _ = q.shape
+        return otherwise(q, k, v), jnp.zeros((n, h, s), jnp.float32)
+
+    out, lse = lax.platform_dependent(
+        q, k, v, tpu=functools.partial(forward, scale=scale, t=t), default=plain)
+    out = checkpoint_name(out, RESIDUAL_NAME)
+    lse = checkpoint_name(lse, RESIDUAL_NAME)
+    return out, (q, k, v, out, lse)
+
+
+def _backward_rule(scale, t, otherwise, residuals, d_out):
+    def plain(q, k, v, out, lse, d_out):
+        return jax.vjp(otherwise, q, k, v)[1](d_out)
+
+    return lax.platform_dependent(
+        *residuals, d_out,
+        tpu=functools.partial(backward, scale=scale, t=t), default=plain)
+
+
+causal_attention.defvjp(_forward_rule, _backward_rule)

@@ -1777,8 +1777,11 @@ def train(
             ),
         )
         if hasattr(model, "describe"):
+            held_on = next(iter(
+                jax.tree_util.tree_leaves(state.params)[0].devices()))
             obs.event("zoo_moe", **model.describe(
-                batch_size * math.prod(in_shape)))
+                batch_size * math.prod(in_shape), in_shape[-1],
+                held_on.platform))
     aug_fn = None
     if augment:
         from parallel_cnn_tpu.data import augment as aug_lib

@@ -97,6 +97,21 @@ def test_latent_attention_agrees_with_the_reference(small):
     np.testing.assert_allclose(moved[:, : S // 2], got[:, : S // 2], atol=1e-6)
 
 
+@pytest.mark.parametrize("shape", [(2, 3, S, 8), (2, 1, S, 8), (S, 4)],
+                         ids=["heads", "the-shared-key", "one-sequence"])
+def test_rope_turns_the_positions_of_the_axis_before_the_last(shape):
+    """`nn.layers.rope` of head-major `(N, heads, S, d)` (and of anything
+    `(..., S, d)`) against the reference's position-major `rotary`."""
+    x = jax.random.normal(jax.random.key(8), shape)
+    x4 = x.reshape((1,) * (4 - x.ndim) + shape)
+    want = jnp.swapaxes(ref.rotary(jnp.swapaxes(x4, 1, 2), jnp.float32(1e6)), 1, 2)
+    got = nn.layers.rope(x, 1e6)
+    assert got.shape == shape and float(jnp.max(jnp.abs(got - x))) > 0.1
+    np.testing.assert_allclose(got.reshape(x4.shape), want, atol=1e-6)
+    # position 0 is not turned, position 1 is
+    np.testing.assert_array_equal(got[..., 0, :], x[..., 0, :])
+
+
 def test_the_expert_layer_agrees_with_the_reference(small):
     layer = small.model.experts
     p, st = small.params["layers"][1]["ffn"], small.state["layers"][1]
@@ -413,9 +428,17 @@ def test_the_published_model_has_the_counted_parameters():
     assert count(params["layers"][1]) == 106_829_056  # 8 of 64 experts + shared
     assert count(params["mtp"]) == 115_221_760
     assert count(params) == 706_516_480
-    assert model.describe(4 * 4096) == dict(
-        experts_held=8, experts_published=64, experts_per_token=4,
-        expert_layers=5, row_buffer=16384, tokens_per_step=16384)
+    moe = dict(experts_held=8, experts_published=64, experts_per_token=4,
+               expert_layers=5, row_buffer=16384, tokens_per_step=16384)
+    # 4,096 positions of 256-wide heads tile at 512: the kernels visit the
+    # 36 tiles at or below the diagonal of 64; off the chip the 512-query
+    # blocks compute as many
+    assert model.describe(4 * 4096, 4096, "tpu") == dict(
+        moe, attention_core="fused", attention_tiles_visited=36,
+        attention_tiles_total=64)
+    assert model.describe(4 * 4096, 4096, "cpu") == dict(
+        moe, attention_core="blocks", attention_tiles_visited=36,
+        attention_tiles_total=64)
     whole = glm_moe.glm_4_7_flash()
     assert (whole.n_layers, whole.vocab, len(whole.experts.held)) == (47, 154880, 64)
     with pytest.raises(ValueError, match="distinct ids"):
@@ -489,6 +512,9 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     (event,) = [f for k, f in Journal.events if k == "zoo_moe"]
     assert (event["experts_held"], event["experts_published"],
             event["tokens_per_step"], event["row_buffer"]) == (3, 8, 4 * S, 4 * S * 2)
+    # 16 positions in blocks of 8 queries, on the CPU: 3 of 4 tiles
+    assert (event["attention_core"], event["attention_tiles_visited"],
+            event["attention_tiles_total"]) == ("blocks", 3, 4)
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads_through_rematerialisation():
@@ -507,6 +533,24 @@ def test_the_scopes_are_the_ones_the_catalog_reads_through_rematerialisation():
         "l0/attn/core", "fwd")
     assert of("jit(step)/grad/transpose(jvp(grad))/jvp()/checkpoint/"
               "rematted_computation/norm/mul") == ("norm", "bwd")
+    # the fused core's kernels (ops/pallas_attention.py), as the step compiled
+    # for a v5e names them: inside the `lax.platform_dependent`'s branch, under
+    # the kernel's own name; the backward through the rematerialised layer
+    assert of("jit(step)/grad/jvp(l1)/attn/core/cond/branch_0_fun/"
+              "causal_attention_fwd/pallas_call") == ("l1/attn/core", "fwd")
+    assert of("jit(step)/grad/transpose(jvp(l1))/grad/jvp(l1)/checkpoint/attn/core/"
+              "cond/branch_0_fun/causal_attention_bwd/pallas_call") == (
+        "l1/attn/core", "bwd")
+    assert of("jit(step)/grad/transpose(jvp(mtp))/l0/grad/jvp(mtp)/l0/checkpoint/"
+              "attn/core/cond/branch_0_fun/causal_attention_bwd/pallas_call") == (
+        "mtp/l0/attn/core", "bwd")
+    assert of("jit(step)/grad/transpose(jvp(l1))/grad/jvp(l1)/checkpoint/attn/core/"
+              "cond/branch_0_fun/reshape") == ("l1/attn/core", "bwd")
+    # only the pair a branch opens names no layer: a scope somebody called
+    # `cond` stays, and so does a `branch_0_fun` that no `cond` precedes
+    assert of("jit(step)/grad/jvp(cond)/conv/dot_general") == ("cond/conv", "fwd")
+    assert of("jit(step)/grad/jvp(l1)/branch_0_fun/mul") == (
+        "l1/branch_0_fun", "fwd")
     model, _ = build()
     opt = zoo.make_optimizer(**HYPER)
     state = jax.eval_shape(lambda k: zoo.init_state(model, k, (S,), opt),

@@ -1,0 +1,253 @@
+"""ops/pallas_attention.py on the CPU: the kernels in Pallas interpret
+mode (the code the chip runs, tile by tile) against `nn/glm_moe.py`'s
+blocked `_attend` and against a one-shot float32 masked softmax; the
+`custom_vjp` as a CPU host lowers it (the caller's plain form, by
+`lax.platform_dependent`); which shapes tile, and that a model whose
+shapes do not says so. The compiled program is held in
+tests/test_zoo_loader_compile.py."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.reference import glm_moe as ref  # noqa: E402
+from parallel_cnn_tpu.nn import glm_moe  # noqa: E402
+from parallel_cnn_tpu.ops import pallas_attention as pa  # noqa: E402
+
+# (N, S, H, D, tile): the issue's shape at the tile the module picks for it
+# (one tile, all diagonal), and three tiles a side (tiles under, on and
+# above the diagonal; `dq` gathered from several key tiles, `dk` and `dv`
+# from several query tiles)
+SHAPES = {"one-tile": (2, 512, 2, 128, pa.tile(512, 128, 128)),
+          "three-a-side": (1, 384, 2, 128, 128)}
+
+
+def _mla(h, d, block=128):
+    return glm_moe.MLA(heads=h, nope=d - 32, rope_dim=32, v_dim=d, q_block=block)
+
+
+def _blocks(q, k, v):
+    return _mla(q.shape[1], q.shape[-1])._blocks(q, k, v)
+
+
+def _one_shot(q, k, v):
+    s = q.shape[2]
+    scores = jnp.einsum("nhqd,nhkd->nhqk", q, k, preferred_element_type=jnp.float32,
+                        precision="highest") * q.shape[-1] ** -0.5
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    return jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(scores, axis=-1),
+                      v.astype(jnp.float32), precision="highest")
+
+
+REFERENCES = {"blocks": _blocks, "one-shot": _one_shot}
+
+
+def _draw(shape, dtype=jnp.float32, seed=0):
+    n, s, h, d, _ = shape
+    return [jax.random.normal(key, (n, h, s, d), jnp.float32).astype(dtype)
+            for key in jax.random.split(jax.random.key(seed), 4)]
+
+
+def _kernels(q, k, v, d_out, t):
+    scale = q.shape[-1] ** -0.5
+    out, lse = pa.forward(q, k, v, scale=scale, t=t, interpret=True)
+    return out, lse, pa.backward(q, k, v, out, lse, d_out, scale=scale, t=t,
+                                 interpret=True)
+
+
+def _gap(got, want):
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("reference", list(REFERENCES))
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_the_forward_kernel_agrees(shape, reference):
+    q, k, v, d_out = _draw(SHAPES[shape])
+    out, lse, _ = _kernels(q, k, v, d_out, SHAPES[shape][-1])
+    assert out.shape == q.shape and lse.shape == q.shape[:3]
+    assert lse.dtype == jnp.float32
+    assert _gap(out, REFERENCES[reference](q, k, v)) < 2e-6
+    # the rows' log-sum-exp is that of the visible scores
+    scores = jnp.einsum("nhqd,nhkd->nhqk", q, k, precision="highest") * 128 ** -0.5
+    s = q.shape[2]
+    want = jax.nn.logsumexp(
+        jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf), axis=-1)
+    assert float(jnp.max(jnp.abs(lse - want))) < 1e-5
+
+
+@pytest.mark.parametrize("reference", list(REFERENCES))
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_the_backward_kernel_agrees(shape, reference):
+    q, k, v, d_out = _draw(SHAPES[shape])
+    _, _, got = _kernels(q, k, v, d_out, SHAPES[shape][-1])
+    want = jax.vjp(REFERENCES[reference], q, k, v)[1](d_out)
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _gap(g, w) < 5e-6, name
+
+
+def test_a_row_whose_only_visible_key_is_itself():
+    """Position 0 sees key 0 alone: its output is `v[0]` whatever the
+    scores, its log-sum-exp its one score, and keys it does not see take
+    no gradient from it."""
+    shape = SHAPES["three-a-side"]
+    q, k, v, _ = _draw(shape, seed=1)
+    only_row_0 = jnp.zeros_like(q).at[:, :, 0].set(1.0)
+    out, lse, (dq, dk, dv) = _kernels(q, k, v, only_row_0, shape[-1])
+    assert float(jnp.max(jnp.abs(out[:, :, 0] - v[:, :, 0]))) == 0.0
+    s00 = jnp.sum(q[:, :, 0] * k[:, :, 0], axis=-1) * 128 ** -0.5
+    assert float(jnp.max(jnp.abs(lse[:, :, 0] - s00))) < 1e-5
+    assert float(jnp.max(jnp.abs(dv[:, :, 0] - 1.0))) < 1e-6
+    assert float(jnp.max(jnp.abs(dv[:, :, 1:]))) == 0.0
+    # one key: the softmax is constant, so nothing flows into q or k
+    assert float(jnp.max(jnp.abs(dq))) < 1e-6 and float(jnp.max(jnp.abs(dk))) < 1e-6
+    assert bool(jnp.all(jnp.isfinite(out))) and bool(jnp.all(jnp.isfinite(lse)))
+
+
+def test_bfloat16_inputs_are_accumulated_in_float32():
+    """bf16 `q, k, v` (what the model hands over): the kernel's results
+    lie within 2e-2 of what float32 inputs of the same values give, the
+    scores and sums being float32 either way and `p` rounded to bf16
+    before `p v` only."""
+    shape = SHAPES["three-a-side"]
+    half = _draw(shape, jnp.bfloat16, seed=2)
+    full = [a.astype(jnp.float32) for a in half]
+    out16, lse16, grads16 = _kernels(*half, shape[-1])
+    out32, lse32, grads32 = _kernels(*full, shape[-1])
+    assert out16.dtype == jnp.bfloat16 and lse16.dtype == jnp.float32
+    assert all(g.dtype == jnp.bfloat16 for g in grads16)
+    assert float(jnp.max(jnp.abs(lse16 - lse32))) < 1e-5  # the same float32 scores
+    assert _gap(out16, out32) < 2e-2
+    for g16, g32 in zip(grads16, grads32):
+        assert _gap(g16, g32) < 2e-2
+
+
+@pytest.mark.parametrize("s,qk,v,want", [
+    (4096, 256, 256, 512), (512, 128, 128, 512), (768, 128, 256, 256),
+    (384, 128, 128, 128),
+    (520, 128, 128, None),    # no tile divides the sequence
+    (4096, 192, 256, None),   # a head width that is no multiple of 128 lanes
+    (512, 64, 64, None),
+    (32768, 256, 256, None),  # dq of one (sequence, head) past its VMEM buffer
+], ids=str)
+def test_which_shapes_tile(s, qk, v, want):
+    assert pa.tile(s, qk, v) == want
+    if want:
+        side = s // want
+        assert pa.tiles_visited(s, want) == side * (side + 1) // 2
+
+
+def test_the_published_shapes_visit_36_of_64_tiles():
+    assert (pa.tile(4096, 256, 256), pa.tiles_visited(4096, 512)) == (512, 36)
+    assert pa.tiles_visited(4096, 256) == 136
+
+
+def _toy(**attn):
+    attn = dict(dict(heads=2, q_rank=12, kv_rank=8, nope=96, rope_dim=32,
+                     v_dim=128, q_block=8), **attn)
+    return glm_moe.GlmMoe(
+        vocab=64, hidden=32, dense_width=64, n_layers=2,
+        attn=glm_moe.MLA(**attn),
+        experts=glm_moe.ExpertLayer(width=16, n_routed=4, per_token=2,
+                                    held=(0, 1)), dtype="float32")
+
+
+@pytest.mark.parametrize("seq,attn,platform,want", [
+    (512, {}, "tpu", ("fused", 1, 1)),
+    (1024, {}, "tpu", ("fused", 3, 4)),
+    (1024, {}, "cpu", ("blocks", 8256, 16384)),      # 128 blocks of 8 queries
+    (520, {}, "tpu", ("blocks", 2145, 4225)),        # 65 blocks of 8
+    (512, dict(nope=32, v_dim=64), "tpu", ("blocks", 2080, 4096)),
+], ids=["fused", "fused-2-a-side", "cpu", "odd-length", "width-64"])
+def test_describe_says_which_core_runs_and_what_it_visits(seq, attn, platform, want):
+    model = _toy(**attn)
+    said = model.describe(4 * seq, seq, platform)
+    assert (said["attention_core"], said["attention_tiles_visited"],
+            said["attention_tiles_total"]) == want
+
+
+WIDTHS = {"tile": dict(nope=96, rope_dim=32, v_dim=128),
+          "width-64": dict(nope=32, rope_dim=32, v_dim=64)}
+
+
+@pytest.mark.parametrize("widths", list(WIDTHS))
+def test_latent_attention_agrees_with_the_reference_around_either_core(widths):
+    """`MLA.apply` — one body, heads ahead of positions — at head widths
+    that tile (3 tiles a side: on this host `lax.platform_dependent` takes
+    the blocks, the only place the program forks) and at ones that do not
+    (no fork in the program at all): values and every gradient against the
+    benchmark's independent position-major float32 reference."""
+    kw = WIDTHS[widths]
+    mla = glm_moe.MLA(heads=2, q_rank=12, kv_rank=8, q_block=128, **kw)
+    arch = dict(num_attention_heads=2, rms_norm_eps=mla.eps, rope_theta=mla.theta,
+                qk_nope_head_dim=kw["nope"], qk_rope_head_dim=32, kv_lora_rank=8,
+                v_head_dim=kw["v_dim"])
+    params, _, _ = mla.init(jax.random.key(0), (384, 32))
+    params = {n: jax.random.normal(jax.random.key(i), p.shape) * p.shape[0] ** -0.5
+              if p.ndim == 2 else 1 + 0.1 * jax.random.normal(jax.random.key(i), p.shape)
+              for i, (n, p) in enumerate(sorted(params.items()))}
+    x = jax.random.normal(jax.random.key(9), (2, 384, 32))
+    d_out = jax.random.normal(jax.random.key(10), (2, 384, 32))
+    assert mla.core(384) == (("fused", 128) if widths == "tile" else ("blocks", 128))
+    forks = "stablehlo.case" in jax.jit(
+        lambda p, x: mla.apply(p, {}, x)[0]).lower(params, x).as_text()
+    assert forks == (widths == "tile")
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(lambda p, x: mla.apply(p, {}, x)[0], params, x)
+        want, vjp_want = jax.vjp(lambda p, x: ref.attention(arch, p, x), params, x)
+        grads, grads_want = vjp(d_out), vjp_want(d_out)
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(grads_want)):
+        assert _gap(g, w) < 2e-5
+
+
+def test_the_custom_vjp_on_a_cpu_host_runs_the_callers_plain_form():
+    """Shapes that tile, lowered for the CPU: `lax.platform_dependent`
+    takes `otherwise` forward and its own vjp backward, no kernel is
+    lowered, and values and gradients are the blocked path's."""
+    shape = SHAPES["three-a-side"]
+    q, k, v, d_out = _draw(shape, seed=3)
+    blocks = _mla(2, 128)._blocks
+
+    def fused(q, k, v):
+        return pa.causal_attention(q, k, v, 128 ** -0.5, 128, blocks)
+
+    assert "tpu_custom_call" not in jax.jit(fused).lower(q, k, v).as_text()
+    got, vjp = jax.vjp(fused, q, k, v)
+    want, vjp_want = jax.vjp(blocks, q, k, v)
+    assert float(jnp.max(jnp.abs(got - want))) == 0.0
+    for g, w in zip(vjp(d_out), vjp_want(d_out)):
+        assert _gap(g, w) < 1e-6
+
+
+def test_a_rematerialised_layer_keeps_the_output_and_the_log_sum_exp(capsys):
+    """Under `save_only_these_names("attn_core")` the residuals the
+    backward rule reads — `out` and `lse` — are saved by name, so the
+    rematerialised backward has no forward core left to run."""
+    shape = SHAPES["three-a-side"]
+    q, k, v, _ = _draw(shape, seed=4)
+    blocks = _mla(2, 128)._blocks
+
+    def loss(q, k, v):
+        return jnp.sum(pa.causal_attention(q, k, v, 128 ** -0.5, 128, blocks))
+
+    named = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(pa.RESIDUAL_NAME))
+    jax.ad_checkpoint.print_saved_residuals(named, q, k, v)
+    kept = capsys.readouterr().out.splitlines()
+    # q, k, v as they came, out (jax keeps a named value that is also the
+    # function's result behind a `reduce_precision`) and lse: nothing else
+    assert sorted(line.split()[0] for line in kept) == (
+        ["f32[1,2,384,128]"] * 4 + ["f32[1,2,384]"])
+    assert sum("from the argument" in line for line in kept) == 3
+    (lse,) = [line for line in kept if line.startswith("f32[1,2,384] ")]
+    assert f"named '{pa.RESIDUAL_NAME}'" in lse

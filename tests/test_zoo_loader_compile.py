@@ -237,7 +237,7 @@ def _scope_of_at_pr30(op_name):
     return "/".join(path) or "grad", "bwd" if "transpose(" in op_name else "fwd"
 
 
-def _step_text(topo, model, optimizer, in_shape, batch, mesh):
+def _step_text(topo, model, optimizer, in_shape, batch, mesh, tokens=False):
     where = (SingleDeviceSharding(topo.devices[0]) if mesh is None
              else NamedSharding(mesh, P()))
     rows = where if mesh is None else NamedSharding(mesh, P("data"))
@@ -247,8 +247,10 @@ def _step_text(topo, model, optimizer, in_shape, batch, mesh):
                        jax.random.key(0)))
     step = zoo.make_train_step(model, optimizer, 1, mesh)
     return step.lower(
-        state, jax.ShapeDtypeStruct((batch, *in_shape), jnp.bfloat16, sharding=rows),
-        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rows)).compile().as_text()
+        state, jax.ShapeDtypeStruct(
+            (batch, *in_shape), jnp.int32 if tokens else jnp.bfloat16, sharding=rows),
+        jax.ShapeDtypeStruct((batch, *in_shape) if tokens else (batch,), jnp.int32,
+                             sharding=rows)).compile().as_text()
 
 
 @pytest.mark.parametrize("name", ["convnext_b", "resnet50_dp4"])
@@ -279,3 +281,85 @@ def test_the_catalog_of_a_step_that_was_there_is_what_pr30_read(topo, name, monk
     assert len(named) > 1000
     if mesh is not None:
         assert any(e.opcode.startswith("all-reduce") for e in now.values())
+
+
+# --------------- the language model's attention core is two kernels (PR 33)
+
+_glm_step = {}
+
+
+def _glm_program(topo):
+    """GLM-4.7-Flash's GSPMD train step at published widths, two layers
+    (one dense, one of experts) and the MTP module's — three attention
+    cores —, one sequence of 4,096 tokens, compiled for one described
+    v5e: (the text, its catalog)."""
+    if not _glm_step:
+        from parallel_cnn_tpu.nn import glm_moe
+        from parallel_cnn_tpu.obs import programs
+
+        model = glm_moe.glm_4_7_flash(num_hidden_layers=2, vocab_size=19360,
+                                      held_experts=range(8), row_buffer=4096)
+        optimizer = zoo.make_optimizer(lr=2e-4, kind="adamw", b1=0.9, b2=0.95,
+                                       weight_decay=0.1)
+        # as the chip compiles it: conftest's `highest`, which the CPU tests'
+        # comparisons want, is no precision the compiler's own grouped-matmul
+        # kernel takes for bf16 operands
+        with jax.default_matmul_precision("default"):
+            text = _step_text(topo, model, optimizer, (4096,), 1, None, tokens=True)
+        _glm_step.update(text=text, catalog=programs.parse(text),
+                         instructions=_instructions(text.split("ENTRY")[1]))
+    return _glm_step
+
+
+CORES = ("l0/attn/core", "l1/attn/core", "mtp/l0/attn/core")
+
+
+def test_the_attention_kernels_carry_their_layers_scope_and_phase(topo):
+    """The kernels are `custom-call`s whose `op_name` carries the name
+    stack: the catalog gives each its core's scope and a phase, so
+    `attn_core_device_ms` reads them."""
+    catalog = _glm_program(topo)["catalog"]
+    kernels = {n: e for n, e in catalog.items()
+               if e.opcode == "custom-call" and "attn/core" in e.scope}
+    assert len(kernels) == 6
+    assert {(e.scope, e.phase) for e in kernels.values()} == {
+        (scope, phase) for scope in CORES for phase in ("fwd", "bwd")}
+
+
+def test_the_forward_kernel_runs_once_a_core_and_never_in_the_backward(topo):
+    """A rematerialised layer keeps the core's output and log-sum-exp:
+    its backward re-runs no forward kernel."""
+    catalog = _glm_program(topo)["catalog"]
+    for kernel, phase in (("causal_attention_fwd", "fwd"),
+                          ("causal_attention_bwd", "bwd")):
+        ran = sorted((e.scope, e.phase) for n, e in catalog.items()
+                     if n.startswith(kernel) and e.opcode == "custom-call")
+        assert ran == [(scope, phase) for scope in CORES], kernel
+
+
+def test_no_tile_of_float32_scores_reaches_hbm(topo):
+    """No instruction anywhere in the step, forward or backward, has a
+    float32 result `(N, 20, q, k)` of a query tile by a key range (`k` of
+    512 or more: a head is 256 wide, and `(N, 20, 4096, 256)` in float32
+    is an activation inside a fusion, not scores)."""
+    text = _glm_program(topo)["text"]
+    per_head = [(int(q), int(k)) for q, k in
+                re.findall(r"f32\[\d+,20,(\d+),(\d+)\]", text)]
+    assert (4096, 64) in per_head  # the pattern sees what is per head: RoPE's turn
+    assert [qk for qk in per_head
+            if qk[1] >= 512 and qk[0] * qk[1] >= 512 * 512] == []
+    # ... and no probabilities in bf16 either
+    assert not re.search(r"bf16\[\d+,20,(512|4096),(512|1024|2048|4096)\]", text)
+
+
+def test_no_position_major_tensor_is_transposed_for_the_kernels(topo):
+    """`q`, `k`, `v` and the output are born and consumed head-major, by
+    the projections' own matmuls: under `attn/*` nothing `(., 4096, 20,
+    256)` is copied or transposed (nor exists at all)."""
+    program = _glm_program(topo)
+    moved = [(name, ins.result) for name, ins in program["instructions"].items()
+             if ins.opcode in ("copy", "transpose")
+             and "attn" in program["catalog"].get(name.lstrip("%")).scope
+             and any(dims.endswith("4096,20,256") for _, dims in ins.result)]
+    assert moved == []
+    assert not re.search(r"bf16\[\d+,4096,20,256\]", program["text"])
