@@ -34,4 +34,11 @@ from parallel_cnn_tpu.nn.layers import (  # noqa: F401
     ReLU,
     RMSNorm,
 )
-from parallel_cnn_tpu.nn import cifar, convnext, glm_moe, resnet, vgg  # noqa: F401
+from parallel_cnn_tpu.nn import (  # noqa: F401
+    cifar,
+    convnext,
+    glm_moe,
+    resnet,
+    sdar_moe,
+    vgg,
+)

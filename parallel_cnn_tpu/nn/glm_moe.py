@@ -224,6 +224,13 @@ class ExpertLayer(Module):
     docstring). `held`: the published ids of the experts this layer
     holds, in the order of its stacked weights; `rows`: the row buffer.
 
+    What the factory chooses: `scoring` (`"sigmoid"`, each expert's own, or
+    `"softmax"` over all `n_routed`; either in float32, the gates the
+    chosen scores over their sum times `scaling`), `n_shared` (0: no
+    shared expert, and no leaf for one), `bias_step` (0: the selection
+    bias stays where it is). The balance term is `sum_i f_i P_i` a
+    sequence either way — under softmax scores `P` is the scores' mean.
+
     `gate_grad=False` is for a share that trains: a gate's gradient is
     `<dL/dy, E_i(x)>`, and a share has that product for the experts it
     holds alone, so the scores learn that only those lower the loss and
@@ -243,8 +250,11 @@ class ExpertLayer(Module):
     bias_step: float = 1e-3
     balance: float = 1e-4
     gate_grad: bool = True
+    scoring: str = "sigmoid"
 
     def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
         held = tuple(self.held)
         if (not held or len(set(held)) != len(held)
                 or min(held) < 0 or max(held) >= self.n_routed):
@@ -268,8 +278,9 @@ class ExpertLayer(Module):
             "router": _weight(rkey, (d, self.n_routed), d, INIT_STD),
             "experts": {n: jnp.stack([e[n] for e in each])
                         for n in ("gate", "up", "down")},
-            "shared": self._shared().init(skey, in_shape)[0],
         }
+        if self.n_shared:
+            params["shared"] = self._shared().init(skey, in_shape)[0]
         state = {
             "bias": jnp.zeros((self.n_routed,), jnp.float32),
             # assignments per expert (all `n_routed`) since the step began
@@ -288,7 +299,9 @@ class ExpertLayer(Module):
         """(ids (T, k), gates (T, k) float32, load (n_routed,), balance
         term) of tokens `xt` (T, d) that are `n` sequences."""
         k, e = self.per_token, self.n_routed
-        s = jax.nn.sigmoid(jnp.dot(
+        score = (jax.nn.sigmoid if self.scoring == "sigmoid"
+                 else functools.partial(jax.nn.softmax, axis=-1))
+        s = score(jnp.dot(
             xt.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
         _, ids = lax.top_k(s + bias, k)
         chosen = jnp.take_along_axis(s, ids, axis=1)
@@ -345,8 +358,9 @@ class ExpertLayer(Module):
             back = _gather_rows(ys, rank, row_of[:, None], row_live[:, None])
             back = jnp.where(in_buffer[..., None], back.reshape(t, k, d), 0)
             y = jnp.einsum("tk,tkd->td", gates.astype(x.dtype), back)
-        with jax.named_scope("shared"):
-            y = y + self._shared().apply(params["shared"], {}, xt)[0]
+        if self.n_shared:
+            with jax.named_scope("shared"):
+                y = y + self._shared().apply(params["shared"], {}, xt)[0]
         if train:
             state = dict(
                 state,
@@ -371,9 +385,10 @@ class ExpertLayer(Module):
 
 @dataclasses.dataclass(frozen=True)
 class DecoderLayer(Module):
-    """Pre-norm decoder layer. Only an `ExpertLayer` has state."""
+    """Pre-norm decoder layer around an attention module (`MLA`;
+    nn/sdar_moe.py:GQA). Only an `ExpertLayer` has state."""
 
-    attn: MLA
+    attn: Module
     ffn: Module
     eps: float = 1e-5
 
@@ -514,26 +529,30 @@ class GlmMoe(Module):
         h, layers = self._trunk(params, state, x, train)
         return self._logits(params, h), dict(state, layers=layers)
 
-    def _cross_entropy(self, params, h, y, keep):
+    def _cross_entropy(self, params, h, y, keep, weight=None):
         """Sum over the kept positions of the cross-entropy of `h`
-        (N, S, d) through the final norm and the head against `y`, a block
-        of `loss_block` positions at a time: the logits of all positions
+        (N, S, d) through the final norm and the head against `y` (times
+        `weight`, a float32 a position, where one is given), a block of
+        `loss_block` positions at a time: the logits of all positions
         never exist at once, forward or backward."""
 
         @jax.checkpoint
-        def block(head, h, y, keep):
+        def block(head, h, y, keep, *weight):
             z = self._logits(head, h)
             with jax.named_scope("loss"):
                 nll = (jax.nn.logsumexp(z, axis=-1)
                        - jnp.take_along_axis(z, y[:, None], axis=1)[:, 0])
+                for w in weight:
+                    nll = nll * w
                 return jnp.sum(jnp.where(keep, nll, 0.0))
 
         head = {"norm": params["norm"], "head": params["head"]}
-        h, y, keep = h.reshape(-1, h.shape[-1]), y.reshape(-1), keep.reshape(-1)
+        rows = [h.reshape(-1, h.shape[-1]), y.reshape(-1), keep.reshape(-1)]
+        if weight is not None:
+            rows.append(weight.reshape(-1))
         return sum(
-            block(head, h[a: a + self.loss_block], y[a: a + self.loss_block],
-                  keep[a: a + self.loss_block])
-            for a in range(0, h.shape[0], self.loss_block))
+            block(head, *(r[a: a + self.loss_block] for r in rows))
+            for a in range(0, rows[0].shape[0], self.loss_block))
 
     def loss(self, params, state, x, y):
         """(loss, new state) of a training forward: the mean next-token

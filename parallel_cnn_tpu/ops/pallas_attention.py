@@ -1,5 +1,10 @@
-"""Causal attention core as fused TPU kernels (Pallas/Mosaic): for
-head-major bf16 `q, k (N, H, S, Dk)` and `v (N, H, S, Dv)`,
+"""Attention cores as fused TPU kernels (Pallas/Mosaic), two masks of one
+family: the causal core (`causal_attention`, below) and, under its own
+heading at the end of the file, the block-diffusion core over grouped
+key/value heads (`block_diffusion_attention`), which shares this
+arithmetic, these roundings, the residuals' names and the rule that
+decides what runs. The causal core: for head-major bf16 `q, k (N, H, S,
+Dk)` and `v (N, H, S, Dv)`,
 
     out = softmax_causal(q k^T * scale) v
 
@@ -297,3 +302,294 @@ def _backward_rule(scale, t, otherwise, residuals, d_out):
 
 
 causal_attention.defvjp(_forward_rule, _backward_rule)
+
+
+# ===================================================== block diffusion
+#
+# The second mask of the family, with the second head layout. A block-
+# diffusion model (nn/sdar_moe.py) trains on the stream `[x^t ; x^0]`: `l`
+# noised positions, then the same `l` positions clean, in blocks of
+# `block`. With beta(i) the block of position i (within its half), query i
+# may see key j iff
+#
+#     both noised and beta(i) == beta(j)          (SAME)
+#     i noised, j clean and beta(j) <  beta(i)    (BEFORE)
+#     both clean and beta(j) <= beta(i)           (UPTO)
+#
+# and a clean query never sees a noised key. `q (N, H, 2l, D)` is `H`
+# query heads over `k, v (N, KV, 2l, D)`: query head a reads key/value head
+# `a // (H // KV)`, through the index maps alone.
+#
+# The mask reaches the kernels as static structure: `schedule(l, t)` lists,
+# query tile by query tile, the tiles that hold an allowed pair and how
+# each is masked (FULL: not at all); the three lists are scalar-prefetched,
+# the grid's last axis walks them, and the index maps read the tile to
+# fetch from them — a tile the mask excludes is no grid step at all. A tile
+# that a block boundary crosses is masked in the kernel from the positions
+# of its rows and columns (`t % block == 0`, so a tile's own corner starts a
+# block and local indices do).
+#
+#   forward   grid (N, H, step): the online softmax over a query tile's
+#             listed key tiles; `out` and `lse` written at its last one.
+#   backward  grid (N, KV, head of the group, step), the same list: scores
+#             transposed (keys on the sublanes) as in the causal backward;
+#             `dq` of a query tile accumulates in VMEM over its key tiles;
+#             `dk`, `dv` of one (sequence, key/value head) accumulate in two
+#             float32 VMEM buffers `(2l, D)` over every step of every query
+#             head of the group — the sum over the group is the
+#             accumulator's — and are written once.
+
+FULL, SAME, BEFORE, UPTO = 0, 1, 2, 3
+_FIRST, _LAST = 4, 8  # flags beside the kind: a query tile's first/last step
+
+
+def bd_tile(l: int, block: int, width: int) -> Optional[int]:
+    """The square tile the block-diffusion kernels run a stream of `2 l`
+    positions at (blocks of `block`, heads `width` wide), or None where
+    they do not take the shapes: a tile holds whole blocks and lies in one
+    half, and `dk` and `dv` of one (sequence, head) fit their buffers."""
+    if width % LANES or 2 * (2 * l * width * 4) > DQ_BUFFER_BYTES:
+        return None
+    for t in (512, 256, 128):
+        if l % t == 0 and t % block == 0:
+            return t
+    return None
+
+
+def schedule(l: int, t: int):
+    """[(query tile, key tile, kind)] of the stream's `(2l / t)^2` tiles
+    that hold an allowed pair, query-major. A query tile's first entry
+    leaves no row without a visible key (the online softmax starts from
+    it): a noised tile's is its own noised tile, a clean tile's holds
+    key 0."""
+    half = l // t
+    steps = []
+    for i in range(half):
+        steps.append((i, i, SAME))
+        steps += [(i, half + j, FULL) for j in range(i)]
+        steps.append((i, half + i, BEFORE))
+    for i in range(half):
+        steps += [(half + i, half + j, FULL) for j in range(i)]
+        steps.append((half + i, half + i, UPTO))
+    return steps
+
+
+def bd_tiles_visited(l: int, t: int) -> int:
+    """Of the `(2l / t)^2` tiles of one (sequence, head)'s score square,
+    those the block-diffusion kernels compute."""
+    return len(schedule(l, t))
+
+
+def _tables(l: int, t: int):
+    steps = schedule(l, t)
+    what = []
+    for i, (qi, _, kind) in enumerate(steps):
+        first = i == 0 or steps[i - 1][0] != qi
+        last = i == len(steps) - 1 or steps[i + 1][0] != qi
+        what.append(kind | (_FIRST if first else 0) | (_LAST if last else 0))
+    as_i32 = lambda xs: jnp.asarray(xs, jnp.int32)  # noqa: E731
+    return (as_i32([s[0] for s in steps]), as_i32([s[1] for s in steps]),
+            as_i32(what))
+
+
+def _bd_mask(s, kind: int, block: int, rows_are_keys: bool):
+    """A tile whose corner starts a block on both sides: `s` where the
+    kind's rule holds between the query's block and the key's."""
+    shift = block.bit_length() - 1  # a power of two: it divides the tile
+    r = lax.broadcasted_iota(jnp.int32, s.shape, 0) >> shift
+    c = lax.broadcasted_iota(jnp.int32, s.shape, 1) >> shift
+    qb, kb = (c, r) if rows_are_keys else (r, c)
+    seen = {SAME: kb == qb, BEFORE: kb < qb, UPTO: kb <= qb}[kind]
+    return jnp.where(seen, s, MASKED)
+
+
+def _each_kind(what, step):
+    """Run `step(kind)` for the kind this grid step's entry names."""
+    for kind in (FULL, SAME, BEFORE, UPTO):
+        pl.when((what & 3) == kind)(functools.partial(step, kind))
+
+
+def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
+                   lse_ref, m_ref, l_ref, acc_ref, *, scale: float, t: int,
+                   block: int):
+    what = what_ref[pl.program_id(2)]
+
+    @pl.when((what & _FIRST) != 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(kind: int):
+        s = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale
+        if kind != FULL:
+            s = _bd_mask(s, kind, block, rows_are_keys=False)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+        p = jnp.exp(s - _lanes(m_next, t))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_prev + p.sum(axis=-1)[:, None]
+        m_ref[...] = m_next
+        v = v_ref[0, 0]
+        acc_ref[...] = (_lanes(alpha, acc_ref.shape[-1]) * acc_ref[...]
+                        + _dot(p.astype(v.dtype), v, _NN))
+
+    _each_kind(what, step)
+
+    @pl.when((what & _LAST) != 0)
+    def _():
+        l = l_ref[...]
+        o_ref[0, 0] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
+                       ).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
+               interpret: bool = False):
+    """(out (N, H, 2l, D) in `v.dtype`, lse (N, H, 2l) float32)."""
+    n, h, s, d = q.shape
+    group = h // k.shape[1]
+    tables = _tables(l, t)
+    at_q = lambda n, h, i, qt, kt, what: (n, h, qt[i], 0)  # noqa: E731
+    at_k = lambda n, h, i, qt, kt, what: (n, h // group, kt[i], 0)  # noqa: E731
+    out, lse = pl.pallas_call(
+        functools.partial(_bd_fwd_kernel, scale=scale, t=t, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n, h, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+                      pl.BlockSpec((1, 1, t, d), at_k),
+                      pl.BlockSpec((1, 1, t, d), at_k)],
+            out_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+                       pl.BlockSpec((1, 1, 1, t),
+                                    lambda n, h, i, qt, kt, what: (n, h, 0, qt[i]))],
+            scratch_shapes=[pltpu.VMEM((t, LANES), jnp.float32),
+                            pltpu.VMEM((t, LANES), jnp.float32),
+                            pltpu.VMEM((t, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, v.dtype),
+                   jax.ShapeDtypeStruct((n, h, 1, s), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="block_diffusion_attention_fwd",
+    )(*tables, q, k, v)
+    return out, lse.reshape(n, h, s)
+
+
+def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
+                   dv_acc, *, scale: float, t: int, block: int):
+    g, i = pl.program_id(2), pl.program_id(3)
+    what = what_ref[i]
+    end = (g == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
+
+    @pl.when((g == 0) & (i == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when((what & _FIRST) != 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def step(kind: int):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        # keys on the sublanes: (keys, queries)
+        s = _dot(k, q, _NT) * scale
+        if kind != FULL:
+            s = _bd_mask(s, kind, block, rows_are_keys=True)
+        p = jnp.exp(s - lse_ref[0, 0])
+        rows = pl.ds(pl.multiple_of(kt_ref[i] * t, t), t)
+        dv_acc[rows, :] += _dot(p.astype(do.dtype), do, _NN)
+        ds = (p * (_dot(v, do, _NT) - delta_ref[0, 0]) * scale).astype(q.dtype)
+        dk_acc[rows, :] += _dot(ds, q, _NN)
+        dq_acc[...] += _dot(ds, k, _TN)
+
+    _each_kind(what, step)
+
+    @pl.when((what & _LAST) != 0)
+    def _():
+        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(end)
+    def _():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def bd_backward(q, k, v, out, lse, d_out, *, scale: float, l: int, block: int,
+                t: int, interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v`; `dk`, `dv` summed over each
+    key/value head's group of query heads."""
+    n, h, s, d = q.shape
+    kv = k.shape[1]
+    group = h // kv
+    delta = jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32),
+                    axis=-1).reshape(n, h, 1, s)
+    tables = _tables(l, t)
+    at_q = lambda n, c, g, i, qt, kt, what: (n, c * group + g, qt[i], 0)  # noqa: E731
+    at_row = lambda n, c, g, i, qt, kt, what: (n, c * group + g, 0, qt[i])  # noqa: E731
+    at_k = lambda n, c, g, i, qt, kt, what: (n, c, kt[i], 0)  # noqa: E731
+    whole = lambda n, c, g, i, qt, kt, what: (n, c, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bd_bwd_kernel, scale=scale, t=t, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n, kv, group, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+                      pl.BlockSpec((1, 1, t, d), at_k),
+                      pl.BlockSpec((1, 1, t, d), at_k),
+                      pl.BlockSpec((1, 1, t, d), at_q),
+                      pl.BlockSpec((1, 1, 1, t), at_row),
+                      pl.BlockSpec((1, 1, 1, t), at_row)],
+            out_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+                       pl.BlockSpec((1, 1, s, d), whole),
+                       pl.BlockSpec((1, 1, s, d), whole)],
+            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                            pltpu.VMEM((s, d), jnp.float32),
+                            pltpu.VMEM((s, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="block_diffusion_attention_bwd",
+    )(*tables, q, k, v, d_out, lse.reshape(n, h, 1, s), delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def block_diffusion_attention(q, k, v, scale: float, l: int, block: int,
+                              t: int, otherwise: Callable):
+    """`out (N, H, 2l, D)` of head-major `q (N, H, 2l, D)`, `k, v (N, KV,
+    2l, D)` whose shapes `bd_tile` accepted (`t`): the kernels where the
+    program is lowered for a TPU, `otherwise(q, k, v)` — the caller's
+    plain-XLA form of the same mask — elsewhere. Residuals as
+    `causal_attention` names them."""
+    return _bd_forward_rule(q, k, v, scale, l, block, t, otherwise)[0]
+
+
+def _bd_forward_rule(q, k, v, scale, l, block, t, otherwise):
+    def plain(q, k, v):
+        return otherwise(q, k, v), jnp.zeros(q.shape[:3], jnp.float32)
+
+    out, lse = lax.platform_dependent(
+        q, k, v, default=plain,
+        tpu=functools.partial(bd_forward, scale=scale, l=l, block=block, t=t))
+    out = checkpoint_name(out, RESIDUAL_NAME)
+    lse = checkpoint_name(lse, RESIDUAL_NAME)
+    return out, (q, k, v, out, lse)
+
+
+def _bd_backward_rule(scale, l, block, t, otherwise, residuals, d_out):
+    def plain(q, k, v, out, lse, d_out):
+        return jax.vjp(otherwise, q, k, v)[1](d_out)
+
+    return lax.platform_dependent(
+        *residuals, d_out, default=plain,
+        tpu=functools.partial(bd_backward, scale=scale, l=l, block=block, t=t))
+
+
+block_diffusion_attention.defvjp(_bd_forward_rule, _bd_backward_rule)

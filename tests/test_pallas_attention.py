@@ -251,3 +251,193 @@ def test_a_rematerialised_layer_keeps_the_output_and_the_log_sum_exp(capsys):
     assert sum("from the argument" in line for line in kept) == 3
     (lse,) = [line for line in kept if line.startswith("f32[1,2,384] ")]
     assert f"named '{pa.RESIDUAL_NAME}'" in lse
+
+
+# ------------------- block diffusion: grouped key/value heads, a second mask
+
+from parallel_cnn_tpu.nn import sdar_moe  # noqa: E402
+
+# (N, H, KV, l, D, B, tile): grouped heads with two tiles a half (every kind
+# of tile: own noised, whole clean, the clean one a noised block boundary
+# crosses, the clean diagonal; `dk`/`dv` summed over a group of two); one
+# key/value head a query head with wider blocks; one tile a half at the
+# tile the module picks
+BD_SHAPES = {"grouped": (1, 4, 2, 256, 128, 4, 128),
+             "ungrouped-b32": (2, 2, 2, 256, 128, 32, 128),
+             "one-tile-a-half": (1, 2, 1, 512, 128, 4, pa.bd_tile(512, 4, 128))}
+
+
+def _bd_draw(shape, dtype=jnp.float32, seed=0):
+    n, h, kv, l, d, _, _ = shape
+    keys = jax.random.split(jax.random.key(seed), 4)
+    make = lambda k, heads: jax.random.normal(  # noqa: E731
+        k, (n, heads, 2 * l, d), jnp.float32).astype(dtype)
+    return make(keys[0], h), make(keys[1], kv), make(keys[2], kv), make(keys[3], h)
+
+
+def _bd_plain(shape):
+    n, h, kv, l, d, b, _ = shape
+    return sdar_moe.GQA(h, kv, d, b, q_block=64)._blocks
+
+
+def _bd_one_shot(shape):
+    n, h, kv, l, d, b, _ = shape
+
+    def attend(q, k, v):
+        qg = q.reshape(n, kv, h // kv, 2 * l, d)
+        s = jnp.einsum("ncgqd,nckd->ncgqk", qg, k, precision="highest") * d ** -0.5
+        s = jnp.where(sdar_moe.allowed(l, b), s, -jnp.inf)
+        return jnp.einsum("ncgqk,nckd->ncgqd", jax.nn.softmax(s, axis=-1), v,
+                          precision="highest").reshape(q.shape)
+
+    return attend
+
+
+def _bd_kernels(q, k, v, d_out, shape):
+    _, _, _, l, d, b, t = shape
+    kw = dict(scale=d ** -0.5, l=l, block=b, t=t, interpret=True)
+    out, lse = pa.bd_forward(q, k, v, **kw)
+    return out, lse, pa.bd_backward(q, k, v, out, lse, d_out, **kw)
+
+
+@pytest.mark.parametrize("reference", ["blocks", "one-shot"])
+@pytest.mark.parametrize("shape", list(BD_SHAPES))
+def test_the_block_diffusion_kernels_agree(shape, reference):
+    shape = BD_SHAPES[shape]
+    q, k, v, d_out = _bd_draw(shape)
+    plain = (_bd_plain if reference == "blocks" else _bd_one_shot)(shape)
+    out, lse, got = _bd_kernels(q, k, v, d_out, shape)
+    want, vjp = jax.vjp(plain, q, k, v)
+    assert out.shape == q.shape and lse.shape == q.shape[:3]
+    assert _gap(out, want) < 2e-6
+    for name, g, w in zip(("dq", "dk", "dv"), got, vjp(d_out)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _gap(g, w) < 5e-6, name
+    # the rows' log-sum-exp is that of the scores the mask allows
+    n, h, kv, l, d, b, _ = shape
+    scores = jnp.einsum("ncgqd,nckd->ncgqk", q.reshape(n, kv, h // kv, 2 * l, d), k,
+                        precision="highest") * d ** -0.5
+    lse_want = jax.nn.logsumexp(
+        jnp.where(sdar_moe.allowed(l, b), scores, -jnp.inf), axis=-1)
+    assert float(jnp.max(jnp.abs(lse - lse_want.reshape(lse.shape)))) < 1e-5
+
+
+def test_the_first_noised_block_sees_itself_alone():
+    """Noised block 0 has no clean block before it: its rows are a softmax
+    over their own B noised keys, whatever the clean half holds, and the
+    clean keys take no gradient from them."""
+    shape = BD_SHAPES["grouped"]
+    q, k, v, _ = _bd_draw(shape, seed=1)
+    only = jnp.zeros_like(q).at[:, :, :4].set(1.0)
+    out, lse, (dq, dk, dv) = _bd_kernels(q, k, v, only, shape)
+    assert bool(jnp.all(jnp.isfinite(out))) and bool(jnp.all(jnp.isfinite(lse)))
+    s = jnp.einsum("nhqd,nhkd->nhqk", q[:, :, :4], jnp.repeat(k, 2, 1)[:, :, :4],
+                   precision="highest") * 128 ** -0.5
+    want = jnp.einsum("nhqk,nhkd->nhqd", jax.nn.softmax(s, -1),
+                      jnp.repeat(v, 2, 1)[:, :, :4], precision="highest")
+    assert _gap(out[:, :, :4], want) < 2e-6
+    assert float(jnp.max(jnp.abs(dv[:, :, 4:]))) == 0.0
+    assert float(jnp.max(jnp.abs(dk[:, :, 4:]))) == 0.0
+    assert float(jnp.max(jnp.abs(dq[:, :, 4:]))) == 0.0
+
+
+def test_bfloat16_block_diffusion_inputs_are_accumulated_in_float32():
+    shape = BD_SHAPES["grouped"]
+    half = _bd_draw(shape, jnp.bfloat16, seed=2)
+    full = [a.astype(jnp.float32) for a in half]
+    out16, lse16, grads16 = _bd_kernels(*half, shape)
+    out32, lse32, grads32 = _bd_kernels(*full, shape)
+    assert out16.dtype == jnp.bfloat16 and lse16.dtype == jnp.float32
+    assert all(g.dtype == jnp.bfloat16 for g in grads16)
+    assert float(jnp.max(jnp.abs(lse16 - lse32))) < 1e-5
+    assert _gap(out16, out32) < 2e-2
+    for g16, g32 in zip(grads16, grads32):
+        assert _gap(g16, g32) < 2e-2
+
+
+@pytest.mark.parametrize("l,block,width,want,visited", [
+    (4096, 4, 128, 512, 80),     # the cell: 8 + 36 + 36 of 256 tiles
+    (4096, 1024, 128, None, None),  # a block wider than any tile
+    (512, 4, 128, 512, 3), (768, 4, 128, 256, 15), (384, 32, 128, 128, 15),
+    (4096, 4, 64, None, None),   # a head width that is no multiple of 128 lanes
+    (4096, 3, 128, None, None),  # no tile holds whole blocks of 3
+    (520, 4, 128, None, None),
+    (16384, 4, 128, None, None),  # dk and dv of one head past their VMEM buffers
+], ids=str)
+def test_which_streams_tile_and_what_they_visit(l, block, width, want, visited):
+    assert pa.bd_tile(l, block, width) == want
+    if want:
+        half = l // want
+        assert pa.bd_tiles_visited(l, want) == visited == half * (half + 2)
+        steps = pa.schedule(l, want)
+        assert len(set(steps)) == len(steps) == visited
+        # query-major, and a query tile's first step leaves no row empty
+        assert [s[0] for s in steps] == sorted(s[0] for s in steps)
+        firsts = {}
+        for qi, ki, kind in steps:
+            firsts.setdefault(qi, (ki, kind))
+        for qi, (ki, kind) in firsts.items():
+            assert (kind == pa.SAME and ki == qi) if qi < half else (
+                ki == half and kind == (pa.UPTO if qi == half else pa.FULL))
+
+
+def test_the_schedule_holds_every_allowed_pair_and_no_other_tile():
+    l, b, t = 512, 4, 128
+    seen = np.asarray(sdar_moe.allowed(l, b))
+    tiles = {(qi, ki): kind for qi, ki, kind in pa.schedule(l, t)}
+    for qi in range(2 * l // t):
+        for ki in range(2 * l // t):
+            part = seen[qi * t: (qi + 1) * t, ki * t: (ki + 1) * t]
+            assert part.any() == ((qi, ki) in tiles)
+            if (qi, ki) in tiles:
+                assert part.all() == (tiles[qi, ki] == pa.FULL)
+    assert pa.bd_tiles_visited(4096, 512) == 80
+    assert 80 * 512 * 512 / (4096 * 4100) == pytest.approx(1.2488, abs=1e-4)
+
+
+def test_the_block_diffusion_custom_vjp_on_a_cpu_host_runs_the_plain_form():
+    shape = BD_SHAPES["grouped"]
+    q, k, v, d_out = _bd_draw(shape, seed=3)
+    plain = _bd_plain(shape)
+
+    def fused(q, k, v):
+        return pa.block_diffusion_attention(q, k, v, 128 ** -0.5, 256, 4, 128, plain)
+
+    assert "tpu_custom_call" not in jax.jit(fused).lower(q, k, v).as_text()
+    got, vjp = jax.vjp(fused, q, k, v)
+    want, vjp_want = jax.vjp(plain, q, k, v)
+    assert float(jnp.max(jnp.abs(got - want))) == 0.0
+    for g, w in zip(vjp(d_out), vjp_want(d_out)):
+        assert _gap(g, w) < 1e-6
+
+
+def test_a_rematerialised_block_diffusion_layer_keeps_out_and_lse(capsys):
+    shape = BD_SHAPES["grouped"]
+    q, k, v, _ = _bd_draw(shape, seed=4)
+    plain = _bd_plain(shape)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.block_diffusion_attention(
+            q, k, v, 128 ** -0.5, 256, 4, 128, plain))
+
+    named = jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(pa.RESIDUAL_NAME))
+    jax.ad_checkpoint.print_saved_residuals(named, q, k, v)
+    kept = capsys.readouterr().out.splitlines()
+    assert sorted(line.split()[0] for line in kept) == (
+        ["f32[1,2,512,128]"] * 2 + ["f32[1,4,512,128]"] * 2 + ["f32[1,4,512]"])
+    assert sum("from the argument" in line for line in kept) == 3
+    (lse,) = [line for line in kept if line.startswith("f32[1,4,512] ")]
+    assert f"named '{pa.RESIDUAL_NAME}'" in lse
+
+
+def test_grouped_attention_forks_only_where_the_shapes_tile():
+    fused = sdar_moe.GQA(heads=4, kv_heads=2, head_dim=128, block=4, q_block=128)
+    plain = sdar_moe.GQA(heads=4, kv_heads=2, head_dim=64, block=4, q_block=128)
+    assert fused.core(256) == ("fused", 256) and plain.core(256) == ("blocks", 128)
+    assert fused.core(260) == ("blocks", 260)  # no turn of whole blocks divides it
+    for att, forks in ((fused, True), (plain, False)):
+        p, _, _ = att.init(jax.random.key(0), (512, 32))
+        x = jnp.zeros((1, 512, 32))
+        text = jax.jit(lambda p, x: att.apply(p, {}, x)[0]).lower(p, x).as_text()
+        assert ("stablehlo.case" in text) == forks

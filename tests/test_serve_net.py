@@ -122,6 +122,10 @@ def test_round_trip_and_wire_conservation(stack):
             # Explicit deadline rides the guaranteed class; absent one
             # rides best-effort — both resolve as completed.
             nc.request(np.ones(IN_SHAPE, np.float32), deadline_ms=2000.0)
+        # the endpoint counts an outcome after it has written the reply
+        deadline = time.monotonic() + 2.0
+        while wire.snapshot()["completed"] < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
         snap = wire.snapshot()
         assert snap["submitted"] == 2 == snap["completed"]
         assert wire.balanced()

@@ -363,3 +363,55 @@ def test_no_position_major_tensor_is_transposed_for_the_kernels(topo):
              and any(dims.endswith("4096,20,256") for _, dims in ins.result)]
     assert moved == []
     assert not re.search(r"bf16\[\d+,4096,20,256\]", program["text"])
+
+
+# ------- the block-diffusion model's attention core is two kernels (PR 34)
+
+_sdar_step = {}
+
+
+def _sdar_program(topo):
+    """SDAR-30B-A3B's GSPMD train step at published widths, two layers, one
+    sequence of 4,096 clean tokens (a stream of 8,192), compiled for one
+    described v5e: (the text, its catalog)."""
+    if not _sdar_step:
+        from parallel_cnn_tpu.nn import sdar_moe
+        from parallel_cnn_tpu.obs import programs
+
+        model = sdar_moe.sdar_30b_a3b(
+            num_hidden_layers=2, vocab_size=18992, held_experts=range(16),
+            row_buffer=16384, gate_gradient=False)
+        optimizer = zoo.make_optimizer(lr=2e-4, kind="adamw", b1=0.9, b2=0.95,
+                                       weight_decay=0.1)
+        with jax.default_matmul_precision("default"):
+            text = _step_text(topo, model, optimizer, (4096,), 1, None, tokens=True)
+        _sdar_step.update(text=text, catalog=programs.parse(text))
+    return _sdar_step
+
+
+def test_the_block_diffusion_kernels_carry_their_layers_scope_and_phase(topo):
+    """One forward and one backward kernel a core, each under its layer's
+    `attn/core` with its phase (`bd_attn_core_device_ms` reads them), and
+    the rematerialised backward re-runs no forward kernel."""
+    catalog = _sdar_program(topo)["catalog"]
+    for kernel, phase in (("block_diffusion_attention_fwd", "fwd"),
+                          ("block_diffusion_attention_bwd", "bwd")):
+        ran = sorted((e.scope, e.phase) for n, e in catalog.items()
+                     if n.startswith(kernel) and e.opcode == "custom-call")
+        assert ran == [("l0/attn/core", phase), ("l1/attn/core", phase)], kernel
+    assert not any(n.startswith("causal_attention") for n in catalog)
+    named = {e.scope for e in catalog.values()}
+    for scope in ("noise", "l0/attn/qk_norm", "l1/moe/route", "head", "loss"):
+        assert scope in named, scope
+
+
+def test_no_tile_of_the_streams_scores_reaches_hbm(topo):
+    """Nothing `(N, 32, q, k)` in float32 or bf16 with `k` of 512 keys or
+    more exists anywhere in the step (a head is 128 wide): the (2L)^2
+    square, its mask included, lives in VMEM a tile at a time."""
+    text = _sdar_program(topo)["text"]
+    per_head = [(int(q), int(k)) for q, k in
+                re.findall(r"(?:f32|bf16|pred)\[\d+,(?:32|4),(\d+),(\d+)\]", text)]
+    assert (8192, 128) in per_head  # the pattern sees what is per head
+    assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
+    assert not re.search(r"\[8192,8192\]", text)
