@@ -30,11 +30,19 @@ The expert layer is told which experts it HOLDS (`held`, ids of the
 published `n_routed`): it routes over all of them, normalises the gates
 over all the chosen, and adds only what its own experts give — one
 chip's part of an expert-parallel layer, without the exchange. Nothing
-stands in for the absent experts. Tokens are never dropped: assignments
-are sorted by expert into a static row buffer (`rows`; default every
-assignment, at which nothing can overflow) and multiplied by
-`lax.ragged_dot`, a grouped matmul whose cost follows the rows held;
-rows that a smaller buffer cannot take are COUNTED (`overflow_rows`).
+stands in for the absent experts. Tokens are never dropped: the held
+assignments are counted into a static row buffer (`rows`; default every
+assignment, at which nothing can overflow), expert by expert and token
+by token (`ExpertLayer.plan`: running sums over a (tokens, held) table
+give every slot its row, one sort of those rows gives every row its
+slot), and multiplied by `lax.ragged_dot`, a grouped matmul whose
+cost follows the rows held; rows that a smaller buffer cannot take are
+COUNTED (`overflow_rows`). Whatever moves a token's row into the buffer,
+and both gradients of the way back, are sized by `rows`, not by the
+assignments: the plan knows the slot in every row. What still visits
+every assignment is the sum of a token's rows out of the buffer
+(`_combine` forward, `_gather_rows` backward: gathers, where the
+transpose would be a scatter-add).
 
 State the step updates without a gradient (`ExpertLayer.init`): the
 selection bias `b`, the step's load per expert, and the counters read at
@@ -49,7 +57,10 @@ layers' balance terms. In training every decoder layer is rematerialised
 (with the rows' log-sum-exp where the core is the fused kernels of
 ops/pallas_attention.py: `MLA.core` — shapes that tile, a step lowered
 for a TPU; elsewhere a block of queries at a time in plain XLA, each
-block rematerialised); so is every block of logits.
+block rematerialised), and what its expert layer decided (`"moe_plan"`:
+the chosen ids, the gates, the buffer's plan — integers, 4 MB a layer at
+262,144 assignments), so a step routes and plans once; so is every
+block of logits.
 
 Scopes (obs/programs.py; benchmark/shapes/glm_moe.py lists the same):
 `embed`, `l<i>/attn/{norm,q,kv,rope,core,o}`, `l<i>/mlp/...` or
@@ -198,10 +209,10 @@ class MLA(Module):
 
 @jax.custom_vjp
 def _gather_rows(x, idx, back_idx, back_keep):
-    """`x[idx]`, rows of a 2-D `x`. The caller knows where every row of
-    `x` went — row j is `idx[back_idx[j, m]]` wherever `back_keep[j, m]` —
-    so the gradient is a gather and a sum over m too, not a scatter-add
-    (which the TPU does a row at a time)."""
+    """`x[idx]`, rows of a 2-D `x` (tokens into the row buffer). The caller
+    knows where every row of `x` went — row j is `idx[back_idx[j, m]]`
+    wherever `back_keep[j, m]` — so the gradient is a gather and a sum over
+    m too, not a scatter-add (which the TPU does a row at a time)."""
     return x[idx]
 
 
@@ -218,11 +229,56 @@ def _gather_rows_bwd(res, dy):
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
+@jax.custom_vjp
+def _combine(ys, gates, rank, in_buffer, row_of, row_live):
+    """`y[t] = sum_j gates[t, j] * ys[rank[t, j]]` over the slots
+    `in_buffer` (buffer rows back onto their tokens). Rows that are not
+    live hold nothing defined: every reader selects, none multiplies by a
+    zero. The caller knows the slot in each live row (`row_of`, a flat
+    `t * k + j`), so the gradients are made in buffer space: a row's is
+    its gate times its token's `dy`, a gate's the product of its row with
+    its token's `dy` — one gather of `rows` rows of `dy`, and no array
+    with a row an assignment."""
+    back = jnp.where(in_buffer[..., None], ys[rank], 0)
+    return jnp.einsum("tk,tkd->td", gates, back)
+
+
+def _combine_fwd(ys, gates, rank, in_buffer, row_of, row_live):
+    return (_combine(ys, gates, rank, in_buffer, row_of, row_live),
+            (ys, gates, rank, in_buffer, row_of, row_live))
+
+
+def _combine_bwd(res, dy):
+    ys, gates, rank, in_buffer, row_of, row_live = res
+    dy_rows = dy[row_of // gates.shape[1]]
+    d_ys = jnp.where(row_live[:, None],
+                     gates.reshape(-1)[row_of][:, None] * dy_rows, 0)
+    d_row = jnp.einsum("rd,rd->r", dy_rows, ys,
+                       preferred_element_type=jnp.float32)
+    d_gates = jnp.where(in_buffer, d_row[rank], 0)
+    return (d_ys.astype(ys.dtype), d_gates.astype(gates.dtype),
+            None, None, None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _planned(value):
+    """Names what the expert layer decides — integers, and the gates: a
+    rematerialised layer keeps these (`GlmMoe._run`), so its backward
+    neither routes nor plans again."""
+    return checkpoint_name(value, "moe_plan")
+
+
 @dataclasses.dataclass(frozen=True)
 class ExpertLayer(Module):
     """Routed experts held here beside the whole shared expert (module
     docstring). `held`: the published ids of the experts this layer
     holds, in the order of its stacked weights; `rows`: the row buffer.
+    `apply` is `route` (who goes where, at what gate), `plan` (which row
+    of the buffer), a gather of `rows` token rows, three grouped matmuls
+    and `_combine`; the work of all but the last follows `rows` and the
+    experts held, forward and backward.
 
     What the factory chooses: `scoring` (`"sigmoid"`, each expert's own, or
     `"softmax"` over all `n_routed`; either in float32, the gates the
@@ -304,6 +360,7 @@ class ExpertLayer(Module):
         s = score(jnp.dot(
             xt.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
         _, ids = lax.top_k(s + bias, k)
+        ids = _planned(ids)
         chosen = jnp.take_along_axis(s, ids, axis=1)
         gates = self.scaling * chosen / jnp.sum(chosen, axis=1, keepdims=True)
         if not self.gate_grad:
@@ -311,41 +368,59 @@ class ExpertLayer(Module):
         picked = jax.nn.one_hot(ids, e, dtype=jnp.float32).sum(axis=1)
         # Sequence-wise balance (DeepSeek-V3 eq. 17-20): f_i the share of
         # a sequence's assignments expert i got (times n_routed), P_i its
-        # mean normalised score; only P carries a gradient.
+        # mean normalised score; only P carries a gradient, so of all this
+        # a rematerialised backward computes the scores again, no more.
         per_seq = picked.reshape(n, -1, e)
-        f = per_seq.sum(axis=1) * (e / (k * per_seq.shape[1]))
+        gates = _planned(gates)
+        f = _planned(per_seq.sum(axis=1) * (e / (k * per_seq.shape[1])))
         p = (s / jnp.sum(s, axis=1, keepdims=True)).reshape(n, -1, e).mean(axis=1)
         balance = self.balance * jnp.mean(jnp.sum(f * p, axis=1))
         return ids, gates, picked.sum(axis=0), balance
 
+    def plan(self, ids, rows: int):
+        """Where the assignments `ids` (T, k) go in a buffer of `rows` rows,
+        held assignments expert by expert in the order of `held`, a token
+        before a later one: (`rank` (T, k) the row of each slot, `in_buffer`
+        (T, k) whether the slot has one, `row_of` (rows,) the flat slot
+        `t * k + j` in each row, `row_live` (rows,), `sizes` (held,) the
+        live rows an expert, `overflow` the held assignments past `rows`).
+        Counted: a token picks an expert at most once, so the tokens of an
+        expert are a boolean column, a slot's place among them is that
+        column's running sum, and an expert's first row is the sum of the
+        columns before it. `rank` is 0 where `in_buffer` is not, and
+        `row_of` where `row_live` is not."""
+        t, k = ids.shape
+        hit = ids[:, :, None] == jnp.asarray(self.held, ids.dtype)  # (T, k, held)
+        mine = hit.any(axis=1).astype(jnp.int32)
+        counts = mine.sum(axis=0)
+        ends = jnp.cumsum(counts)
+        place = (ends - counts)[None, :] + jnp.cumsum(mine, axis=0) - mine
+        rank = jnp.sum(jnp.where(hit, place[:, None, :], 0), axis=2)
+        in_buffer = hit.any(axis=2) & (rank < rows)
+        rank = jnp.where(in_buffer, rank, 0)
+        # The inverse: the slots in the order of their rows (those without a
+        # row last). On the v5e this sort of t * k keys costs a sixth of the
+        # scatter that writes every slot into its row (PERF.md, PR 35).
+        row_of = jnp.argsort(jnp.where(in_buffer, rank, t * k).reshape(-1))[
+            :rows].astype(jnp.int32)
+        live = jnp.minimum(ends, rows)
+        row_live = jnp.arange(rows) < live[-1]
+        plan = (rank, in_buffer, jnp.where(row_live, row_of, 0), row_live,
+                jnp.diff(live, prepend=0))
+        return (*map(_planned, plan), ends[-1] - live[-1])
+
     def apply(self, params, state, x, train: bool = False):
         n, s, d = x.shape
-        t, k, e = n * s, self.per_token, len(self.held)
-        a = t * k
-        rows = a if self.rows is None else min(self.rows, a)
+        t, k = n * s, self.per_token
+        rows = t * k if self.rows is None else min(self.rows, t * k)
         xt = x.reshape(t, d)
         with jax.named_scope("route"):
             ids, gates, load, balance = self.route(
                 params["router"], state["bias"], xt, n)
         with jax.named_scope("dispatch"):
-            lut = [e] * self.n_routed  # published id -> place here, or absent
-            for place, i in enumerate(self.held):
-                lut[i] = place
-            local = jnp.asarray(lut, jnp.int32)[ids].reshape(a)
-            # Held assignments first, expert by expert; absent ones last.
-            order = jnp.argsort(local, stable=True).astype(jnp.int32)
-            rank = jnp.zeros((a,), jnp.int32).at[order].set(
-                jnp.arange(a, dtype=jnp.int32), unique_indices=True)
-            counts = jnp.sum(local[:, None] == jnp.arange(e)[None, :], axis=0,
-                             dtype=jnp.int32)
-            ends = jnp.minimum(jnp.cumsum(counts), rows)
-            sizes = jnp.diff(ends, prepend=0)
-            row_of = order[:rows]  # the assignment in each row of the buffer
-            row_live = jnp.arange(rows) < ends[-1]
-            in_buffer = ((local < e) & (rank < rows)).reshape(t, k)
-            rank = jnp.minimum(rank, rows - 1)
-            xs = _gather_rows(xt, jnp.where(row_live, row_of // k, 0),
-                              rank.reshape(t, k), in_buffer)
+            rank, in_buffer, row_of, row_live, sizes, overflow = self.plan(
+                ids, rows)
+            xs = _gather_rows(xt, row_of // k, rank, in_buffer)
         with jax.named_scope("experts"):
             w = {m: params["experts"][m].astype(x.dtype)
                  for m in ("gate", "up", "down")}
@@ -353,11 +428,8 @@ class ExpertLayer(Module):
                       * lax.ragged_dot(xs, w["up"], sizes))
             ys = lax.ragged_dot(hidden, w["down"], sizes)
         with jax.named_scope("combine"):
-            # Rows past the live ones hold nothing defined: every reader
-            # selects, none multiplies by a zero.
-            back = _gather_rows(ys, rank, row_of[:, None], row_live[:, None])
-            back = jnp.where(in_buffer[..., None], back.reshape(t, k, d), 0)
-            y = jnp.einsum("tk,tkd->td", gates.astype(x.dtype), back)
+            y = _combine(ys, gates.astype(x.dtype), rank, in_buffer, row_of,
+                         row_live)
         if self.n_shared:
             with jax.named_scope("shared"):
                 y = y + self._shared().apply(params["shared"], {}, xt)[0]
@@ -365,8 +437,7 @@ class ExpertLayer(Module):
             state = dict(
                 state,
                 load=state["load"] + load,
-                overflow_rows=state["overflow_rows"]
-                + jnp.sum(counts) - ends[-1],
+                overflow_rows=state["overflow_rows"] + overflow,
                 balance=balance,
             )
         return y.reshape(n, s, d), state
@@ -482,7 +553,7 @@ class GlmMoe(Module):
         if train:
             fn = jax.checkpoint(
                 fn, policy=jax.checkpoint_policies.save_only_these_names(
-                    "attn_core"))
+                    "attn_core", "moe_plan"))
         return fn(params, state, x)
 
     def hidden_states(self, params, state, x, train: bool = False):

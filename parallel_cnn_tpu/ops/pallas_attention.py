@@ -37,8 +37,9 @@ the scores never leave VMEM:
 `causal_attention` is the `jax.custom_vjp` over both. Its forward rule
 names `out` and `lse` `"attn_core"` (`jax.ad_checkpoint.checkpoint_name`)
 ON THE VALUES IT RETURNS AS RESIDUALS: a layer rematerialised under
-`save_only_these_names("attn_core")` keeps both, so its backward re-runs
-no forward kernel.
+`save_only_these_names("attn_core", ...)` (nn/glm_moe.py:GlmMoe._run,
+whose policy also keeps the expert layer's `"moe_plan"`) keeps both, so
+its backward re-runs no forward kernel.
 
 Which execution runs is decided by what the code can see, never by an
 option. `tile(S, Dk, Dv)` is the tile for shapes the kernels take (`S` a

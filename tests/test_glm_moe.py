@@ -170,6 +170,204 @@ def test_all_tokens_routed_to_one_held_expert_lose_none():
     assert int(new["overflow_rows"]) == 2 * S - 20
 
 
+def _sorted_plan(layer, ids, rows):
+    """The plan by a stable sort of all assignments, the plain reference
+    of `ExpertLayer.plan` (and what the layer ran until PR 35): held
+    assignments first, expert by expert, the absent ones last."""
+    t, k = ids.shape
+    e, a = len(layer.held), t * k
+    lut = np.full((layer.n_routed,), e, np.int32)
+    lut[list(layer.held)] = np.arange(e)
+    local = lut[np.asarray(ids)].reshape(a)
+    order = np.argsort(local, kind="stable").astype(np.int32)
+    rank = np.zeros((a,), np.int32)
+    rank[order] = np.arange(a)
+    counts = (local[:, None] == np.arange(e)[None, :]).sum(axis=0)
+    ends = np.minimum(np.cumsum(counts), rows)
+    return dict(
+        rank=rank.reshape(t, k), in_buffer=((local < e) & (rank < rows)).reshape(t, k),
+        row_of=order[:rows], row_live=np.arange(rows) < ends[-1],
+        sizes=np.diff(ends, prepend=0), overflow=counts.sum() - ends[-1])
+
+
+def _routed(case, t, k, layer):
+    """Assignments (T, k), an expert at most once a token."""
+    draw = lambda key, among: jnp.asarray(among)[jax.lax.top_k(  # noqa: E731
+        jax.random.normal(jax.random.key(key), (t, len(among))), k)[1]]
+    absent = [i for i in range(layer.n_routed) if i not in layer.held]
+    if case == "none_held":
+        return draw(3, absent)
+    ids = draw(4, list(range(layer.n_routed)))
+    if case == "all_on_one":  # every token's second choice is held expert 5
+        others = draw(5, [i for i in range(layer.n_routed) if i != 5])
+        ids = others.at[:, 1].set(5)
+    return ids
+
+
+@pytest.mark.parametrize("case,rows", [
+    ("balanced", None), ("all_on_one", None), ("none_held", None),
+    ("balanced", 20), ("all_on_one", 37), ("balanced", 1)])
+def test_the_counted_plan_is_the_stable_sorts(case, rows):
+    """`ExpertLayer.plan` counts where the sort it replaced sorted: the row
+    of every slot in the buffer, the slot in every live row, the experts'
+    sizes and the overflow are the stable argsort's, whether the buffer
+    takes every held assignment or not; rows past the live ones and slots
+    outside the buffer are marked, and carry a 0."""
+    t, k = 48, 3
+    layer = glm_moe.ExpertLayer(width=16, n_routed=12, per_token=k,
+                                held=(7, 0, 5, 9), scaling=1.8)
+    ids = _routed(case, t, k, layer)
+    rows = t * k if rows is None else rows
+    rank, in_buffer, row_of, row_live, sizes, overflow = jax.jit(
+        layer.plan, static_argnums=1)(ids, rows)
+    want = _sorted_plan(layer, ids, rows)
+    np.testing.assert_array_equal(in_buffer, want["in_buffer"])
+    np.testing.assert_array_equal(rank, np.where(want["in_buffer"], want["rank"], 0))
+    np.testing.assert_array_equal(row_live, want["row_live"])
+    np.testing.assert_array_equal(row_of, np.where(want["row_live"], want["row_of"], 0))
+    np.testing.assert_array_equal(sizes, want["sizes"])
+    assert int(overflow) == want["overflow"]
+    held = int(np.isin(np.asarray(ids), layer.held).sum())
+    assert int(sizes.sum()) == int(row_live.sum()) == min(held, rows)
+    assert int(overflow) == held - min(held, rows)
+    assert {"balanced": held not in (0, t), "all_on_one": held >= t,
+            "none_held": held == 0}[case]
+    if case == "all_on_one":
+        assert int(sizes[2]) == min(t, max(0, rows - int(sizes[:2].sum())))
+
+
+def _plain_combine(ys, gates, rank, in_buffer, row_of, row_live):
+    """What `_combine` computes, as autodiff sees it: a gather of every
+    assignment's row and a sum over a token's slots."""
+    back = jnp.where(in_buffer[..., None], ys[rank], 0)
+    return jnp.einsum("tk,tkd->td", gates, back)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [30, 100], ids=["overflowed", "dead_rows"])
+def test_the_combines_gradients_are_autodiffs_made_in_buffer_space(dtype, rows):
+    """`_combine`'s own backward (a gather of `rows` rows of `dy`) gives
+    the rows' and the gates' gradients that `jax.vjp` of the plain
+    expression gives: a buffer that overflows, and one with rows past the
+    live ones, which hold NaN here (nothing reads them). The rows' to the
+    bit (one product either way); the gates' to the bit in bfloat16 (a
+    float32 sum rounded once either way) and to the order of a float32
+    sum over `d` in float32 (a row's product with its token's `dy` here,
+    a token's with its k rows there: this backend's two dot loops)."""
+    t, k, d = 48, 3, 16
+    layer = glm_moe.ExpertLayer(width=16, n_routed=12, per_token=k,
+                                held=(7, 0, 5, 9), scaling=1.8)
+    ids = _routed("balanced", t, k, layer)
+    rank, in_buffer, row_of, row_live, _, overflow = layer.plan(ids, rows)
+    assert (int(overflow) > 0) == (rows == 30) and bool(row_live.all()) == (rows == 30)
+    ys = jax.random.normal(jax.random.key(1), (rows, d)).astype(dtype)
+    ys = jnp.where(row_live[:, None], ys, jnp.nan)
+    gates = jax.random.uniform(jax.random.key(2), (t, k)).astype(dtype)
+    dy = jax.random.normal(jax.random.key(3), (t, d)).astype(dtype)
+    plan = (rank, in_buffer, row_of, row_live)
+    y, vjp = jax.vjp(lambda ys, g: glm_moe._combine(ys, g, *plan), ys, gates)
+    want_y, want_vjp = jax.vjp(lambda ys, g: _plain_combine(ys, g, *plan), ys, gates)
+    np.testing.assert_array_equal(y, want_y)
+    (d_ys, d_gates), (want_ys, want_gates) = vjp(dy), want_vjp(dy)
+    assert d_ys.dtype == want_ys.dtype == ys.dtype and d_gates.dtype == gates.dtype
+    np.testing.assert_array_equal(d_ys, want_ys)
+    np.testing.assert_array_equal(d_gates == 0, want_gates == 0)
+    np.testing.assert_allclose(d_gates, want_gates, atol=0,
+                               rtol=0 if dtype == "bfloat16" else 1e-5)
+    assert not bool(jnp.isnan(d_ys).any()) and float(jnp.abs(d_gates).max()) > 0
+    assert float(jnp.abs(d_ys[~row_live]).sum()) == 0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("gate_grad", [True, False], ids=["gates_in", "gates_out"])
+def test_the_layers_gradients_are_those_of_the_plain_combine(
+        dtype, gate_grad, monkeypatch):
+    """Through the whole expert layer, the gates' gradient in or out: every
+    leaf's gradient and the input's are what autodiff of the plain
+    gather-and-sum gives, with a buffer that overflows: to the bit, but in
+    float32 with the gates' gradient in, where the router's and the
+    input's stand the order of a float32 sum apart (the test above)."""
+    layer = glm_moe.ExpertLayer(width=16, n_routed=8, per_token=2, held=(0, 5, 6),
+                                scaling=1.8, rows=40, gate_grad=gate_grad)
+    p, st, _ = layer.init(jax.random.key(1), (S, 32))
+    p = jax.tree_util.tree_map(lambda a: a * 8, p)
+    x = jax.random.normal(jax.random.key(2), (4, S, 32)).astype(dtype)
+    cot = jax.random.normal(jax.random.key(3), (4, S, 32)).astype(dtype)
+
+    def grads():
+        def loss(p, x):
+            y, new = layer.apply(p, st, x, train=True)
+            return jnp.sum((y * cot).astype(jnp.float32)) + new["balance"], new
+        return _highest(jax.grad(loss, argnums=(0, 1), has_aux=True), p, x)
+
+    got, new = grads()
+    assert int(new["overflow_rows"]) > 0
+    monkeypatch.setattr(glm_moe, "_combine", _plain_combine)
+    want, _ = grads()
+    gaps = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))),
+        got, want)
+    router = float(jnp.linalg.norm(got[0]["router"]))
+    exact = dtype == "bfloat16" or not gate_grad
+    assert max(jax.tree_util.tree_leaves(gaps)) <= (0 if exact else 1e-6 * router), gaps
+    assert router > 0 and all(
+        float(jnp.linalg.norm(g.astype(jnp.float32))) > 0
+        for g in jax.tree_util.tree_leaves(got))
+
+
+def _equations(jaxpr, path=""):
+    """Every equation under `jaxpr`, with the name stack that leads to it."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(filter(None, [path, str(eqn.source_info.name_stack)]))
+        yield here, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, here)
+
+
+@pytest.mark.parametrize("gate_gradient", [True, False], ids=["gates_in", "gates_out"])
+def test_a_rematerialised_layer_plans_once_and_combines_back_in_buffer_space(
+        gate_gradient):
+    """The jaxpr of a training gradient of one rematerialised expert
+    decoder layer: the plan is named and kept, so `top_k` and the plan's
+    sort appear once, the backward's `route` has the scores' matmul and
+    no one-hot; and under `combine` the backward makes nothing with a row an
+    assignment — no `(T * k, d)`, no `(T, k, d)`."""
+    model, _ = build(gate_gradient=gate_gradient, row_buffer=48)
+    layer = model._mtp_layer()
+    p, st, _ = layer.init(jax.random.key(0), (S, 32))
+    x = jax.random.normal(jax.random.key(1), (4, S, 32))
+    t, k, d = 4 * S, 2, 32
+
+    def loss(p, x):
+        y, new = model._run(layer, p, st, x, True)
+        return jnp.sum(y * y) + new["balance"]
+
+    eqns = list(_equations(jax.make_jaxpr(jax.grad(loss))(p, x).jaxpr))
+    count = lambda name: sum(e.primitive.name == name for _, e in eqns)  # noqa: E731
+    assert count("top_k") == 1 and count("cumsum") == 2 and count("scatter") == 0
+    assert count("sort") == 1  # the plan's inverse, once
+    kept = [e.params["name"] for _, e in eqns if e.primitive.name == "name"]
+    assert kept.count("moe_plan") == 8 and kept.count("attn_core") == 1
+    backward = [(where, e) for where, e in eqns if where.startswith("transpose(")
+                and "rematted_computation" not in where]
+    recomputed = [(where, e) for where, e in eqns if "rematted_computation" in where]
+    assert {e.primitive.name for where, e in recomputed
+            if where.endswith("moe/route")} >= {"dot_general"}
+    assert not any(e.primitive.name in ("top_k", "eq", "sort", "cumsum")
+                   for where, e in recomputed)
+    per_assignment = {(t * k, d), (t, k, d)}
+    shapes = lambda found: {tuple(v.aval.shape) for _, e in found  # noqa: E731
+                            for v in e.outvars}
+    combine = [(w, e) for w, e in backward if "moe/combine" in w]
+    assert len(combine) >= 4 and not shapes(combine) & per_assignment
+    assert (48, d) in shapes(combine)  # a gather of the buffer's rows of dy
+    # ... where the dispatch's backward still has one (the next issue's)
+    assert shapes([(w, e) for w, e in backward if "moe/dispatch" in w]) & per_assignment
+
+
 def test_the_bias_moves_by_u_toward_balance_and_takes_no_gradient(small):
     model = small.model
     loss, grads, new = _system(small)

@@ -415,3 +415,42 @@ def test_no_tile_of_the_streams_scores_reaches_hbm(topo):
     assert (8192, 128) in per_head  # the pattern sees what is per head
     assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
     assert not re.search(r"\[8192,8192\]", text)
+
+
+# -- the expert layer plans once a step and goes back in buffer space (PR 35)
+
+def _expert_layer_shapes(program, assignments, width=2048):
+    """(sorts by (scope, phase), scopes and phases under which an
+    instruction's result has a row an assignment) of a compiled step."""
+    instructions = _instructions(program["text"].split("ENTRY")[1])
+    sorts, per_assignment = collections.Counter(), set()
+    for name, ins in instructions.items():
+        entry = program["catalog"].get(name.lstrip("%"))
+        if entry is None or "/moe/" not in entry.scope:
+            continue
+        scope = entry.scope[entry.scope.index("moe/"):]
+        if ins.opcode == "sort":
+            sorts[scope, entry.phase] += 1
+        if any(dims == f"{assignments},{width}" for _, dims in ins.result):
+            per_assignment.add((scope, entry.phase))
+    return sorts, per_assignment
+
+
+@pytest.mark.parametrize("which,layers,assignments", [
+    ("sdar", 2, 8192 * 8), ("glm", 2, 4096 * 4)])
+def test_a_step_plans_once_an_expert_layer_and_combines_back_by_rows(
+        topo, which, layers, assignments):
+    """As the chip's compiler leaves it: an expert layer sorts twice, both
+    in the forward (`top_k`, and the plan's slots by row: the plan is kept
+    through the layer's rematerialisation), and no instruction under
+    `moe/combine` in the backward has a row an assignment; the forward's
+    sum of a token's rows, and its transpose in `moe/dispatch`'s backward,
+    still do."""
+    program = _sdar_program(topo) if which == "sdar" else _glm_program(topo)
+    sorts, per_assignment = _expert_layer_shapes(program, assignments)
+    assert sorts == {("moe/route", "fwd"): layers,
+                     ("moe/dispatch", "fwd"): layers}, sorts
+    assert ("moe/combine", "fwd") in per_assignment
+    assert ("moe/dispatch", "bwd") in per_assignment
+    assert ("moe/combine", "bwd") not in per_assignment
+    assert ("moe/dispatch", "fwd") not in per_assignment
