@@ -27,7 +27,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
-from parallel_cnn_tpu.obs import programs
+from parallel_cnn_tpu.obs import compiles, programs
 from parallel_cnn_tpu.obs.events import (
     NOOP_JOURNAL,
     EventJournal,
@@ -50,7 +50,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge",
     "EventJournal", "NoopJournal", "NOOP_JOURNAL",
     "read_journal", "merge_journals", "conservation",
-    "programs",
+    "programs", "compiles",
 ]
 
 
@@ -81,13 +81,19 @@ class Obs:
         """Export every configured artifact; returns {kind: path}."""
         out: Dict[str, str] = {}
         if self.trace_path and self.tracer.enabled:
-            out["trace"] = self.tracer.export(self.trace_path)
+            # The tracer's own events, and on lanes beside them what the
+            # process traced, lowered and compiled since it was made.
+            out["trace"] = self.tracer.export(
+                self.trace_path,
+                compiles.trace_events(self.tracer.pid, since=self.tracer.made),
+            )
             # Beside it, what names a device profile's ops (programs.py).
             stem = self.trace_path.removesuffix("_trace.json")
             written = programs.export(f"{stem}_programs.json")
             if written:
                 out["programs"] = written
         if self.journal.enabled:
+            compiles.detach(self.journal)
             self.journal.close()
             if self.journal.path:
                 out["journal"] = self.journal.path
@@ -112,12 +118,14 @@ def from_config(cfg, run: str = "run", process_index: int = 0,
         return NOOP
     if mirror_jax is None:
         mirror_jax = cfg.jax_annotations
+    compiles.install()
     if cfg.trace:
         tracer = Tracer(process_name=f"pcnn:{run}", mirror_jax=mirror_jax)
         journal = EventJournal(
             os.path.join(cfg.dir, f"{run}_journal.jsonl"),
             process_index=process_index,
         )
+        compiles.attach(journal)
         trace_path = os.path.join(cfg.dir, f"{run}_trace.json")
     else:
         tracer = NOOP_TRACER

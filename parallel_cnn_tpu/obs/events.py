@@ -8,8 +8,14 @@ sequence ids don't).
 
 Event kinds written by the wired hot paths: ``epoch`` / ``step_loss``
 (trainer + zoo), ``loss_scale`` (dynamic loss-scaling skip/rescale),
+``zoo_setup`` (zoo, once after the first completed epoch: the process's
+compile totals and the call's set-up spans' seconds), ``compile`` (one a
+compile request, from obs/compiles.py: ``fun_name``, ``seconds``,
+``cache``, ``load_s``, ``within`` and that span's ids),
 ``verdict`` (sentinel health checks), ``rollback``, ``checkpoint``,
-``preempt``, ``chaos`` (injections), ``comm_plan`` / ``comm_bucket``
+``preempt``, ``chaos`` (injections), ``chaos_slow_stage`` (the pipeline's
+straggler injection), ``plan_step_cache`` (elastic resize: the jitted step
+found by plan equality, or not), ``comm_plan`` / ``comm_bucket``
 (bucket schedule), ``aot_compile`` (serve engine), the elastic runtime's
 ``resize_begin`` / ``resize_done`` (old/new world + host counts, trigger
 source, ring fallback — bracketing the ``train.resize`` span) and the

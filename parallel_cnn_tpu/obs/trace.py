@@ -24,6 +24,12 @@ loop's ``step=`` / ``epoch=`` ids) so XLA device profiles carry the same
 semantic names as the host timeline; the import is guarded so the tracer
 works in jax-free contexts (the analysis stubs).
 
+An enabled tracer also keeps each thread's open spans (:func:`innermost`),
+so that the process's compile log (:mod:`parallel_cnn_tpu.obs.compiles`)
+can say which span — which step — a trace, lowering or compile happened
+within. The tracer knows nothing of that log: ``Obs.finish()`` hands
+:meth:`Tracer.export` the log's records as further events to write.
+
 The disabled path is a module singleton: :data:`NOOP_TRACER` returns the
 same reusable :class:`_NoopSpan` object from every ``span()`` call — no
 per-step allocations are retained, which tests/test_obs.py measures
@@ -36,7 +42,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 
 class _NoopSpan:
@@ -63,9 +69,6 @@ class NoopTracer:
     def span(self, name: str, cat: str = "step", **args: Any) -> _NoopSpan:
         return _NOOP_SPAN
 
-    def instant(self, name: str, cat: str = "step", **args: Any) -> None:
-        return None
-
     def begin_async(self, name: str, aid: int, cat: str = "req") -> None:
         return None
 
@@ -75,7 +78,7 @@ class NoopTracer:
     def events(self) -> List[Dict[str, Any]]:
         return []
 
-    def export(self, path: str) -> Optional[str]:
+    def export(self, path: str, extra: Iterable[Dict[str, Any]] = ()) -> Optional[str]:
         return None
 
 
@@ -88,6 +91,18 @@ def _jax_annotation_cls():
         return TraceAnnotation
     except Exception:
         return None
+
+
+# The calling thread's open spans, innermost last: what the compile log
+# (obs/compiles.py) names a record's ``within`` from. Only an enabled
+# tracer's spans are kept; the no-op span touches nothing.
+_OPEN = threading.local()
+
+
+def innermost() -> Optional["_Span"]:
+    """The span the calling thread entered last and has not left, or None."""
+    stack = getattr(_OPEN, "spans", None)
+    return stack[-1] if stack else None
 
 
 class _Span:
@@ -110,11 +125,16 @@ class _Span:
             # The span's args (step/epoch ids) show in the profiler too.
             self._mirror = cls(self.name, **self.args)
             self._mirror.__enter__()
+        stack = getattr(_OPEN, "spans", None)
+        if stack is None:
+            stack = _OPEN.spans = []
+        stack.append(self)
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        _OPEN.spans.pop()  # `with` blocks leave in the order they entered
         if self._mirror is not None:
             self._mirror.__exit__(*exc)
         self._tracer._record_complete(
@@ -134,6 +154,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._pid = os.getpid() if pid is None else int(pid)
+        self.made = time.perf_counter()  # on the spans' clock, seconds
         self._named_tids: set = set()
         self._mirror_cls = _jax_annotation_cls() if mirror_jax else None
         track = process_name if replica is None else (
@@ -171,18 +192,6 @@ class Tracer:
             self._thread_meta_locked(tid)
             self._events.append(ev)
 
-    def instant(self, name: str, cat: str = "step", **args: Any) -> None:
-        tid = threading.get_ident()
-        ev = {
-            "ph": "i", "name": name, "cat": cat, "pid": self._pid,
-            "tid": tid, "ts": time.perf_counter_ns() / 1e3, "s": "t",
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._thread_meta_locked(tid)
-            self._events.append(ev)
-
     def _async(self, ph: str, name: str, aid: int, cat: str) -> None:
         tid = threading.get_ident()
         ev = {
@@ -202,14 +211,21 @@ class Tracer:
 
     # -- export ------------------------------------------------------------
 
+    @property
+    def pid(self) -> int:
+        """The Chrome-trace process id this tracer's events carry."""
+        return self._pid
+
     def events(self) -> List[Dict[str, Any]]:
         with self._lock:
             return list(self._events)
 
-    def export(self, path: str) -> str:
-        """Write the Chrome-trace JSON; returns the path written."""
+    def export(self, path: str, extra: Iterable[Dict[str, Any]] = ()) -> str:
+        """Write the Chrome-trace JSON — this tracer's events, then
+        `extra` (events another recorder made for the same `pid`: the
+        compile log's lanes) — and return the path written."""
         payload = {
-            "traceEvents": self.events(),
+            "traceEvents": self.events() + list(extra),
             "displayTimeUnit": "ms",
         }
         d = os.path.dirname(path)

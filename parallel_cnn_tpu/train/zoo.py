@@ -1298,27 +1298,32 @@ def _device_ids(tree) -> str:
     return ",".join(str(i) for i in sorted(ids))
 
 
-def _catalog_step(step, state, bx, by, key) -> None:
+def _catalog_step(step, state, bx, by, key, obs) -> None:
     """Record the step's compiled program in `obs.programs` (tracing on
     only): the device trace names ops by HLO instruction, and this is what
-    maps an instruction to its layer scope and phase.
+    maps an instruction to its layer scope and phase. Under the span
+    `zoo.catalog`: tracing's own cost, which an untraced run does not pay.
 
-    Called after the first step, with the state that step RETURNED: the
-    same jitted function is lowered and compiled once more for exactly
-    the arguments the loop's next call has — under a mesh the second
-    program (state replicated), the one every later step runs. Lowering
-    takes the arrays themselves (nothing executes, nothing is donated):
+    Called once the first epoch's steps are dispatched, with the state
+    the last of them RETURNED: the same jitted function is lowered and
+    compiled once more for exactly the arguments the loop's next call
+    has — under a mesh the second program (state replicated), the one
+    every step but the first runs. The loop has asked for that program
+    itself by then (at its second step, as an untraced run does), so jit
+    serves this request from memory; only an epoch of ONE step leaves the
+    mesh's second program to be compiled or loaded here. Lowering takes
+    the arrays themselves (nothing executes, nothing is donated):
     `ShapeDtypeStruct`s of the same shapes and shardings lower to another
-    compile-cache key, and the load becomes a second full compile (39 s
-    for ResNet-50 on the v5e, PERF.md PR 24). With the persistent compile
-    cache on this is a load."""
+    compile-cache key, and the request becomes a second full compile
+    (39 s for ResNet-50 on the v5e, PERF.md PR 24)."""
     from parallel_cnn_tpu.obs import programs
 
     if hasattr(step, "lower"):  # else: no single jitted function to name
-        programs.record(
-            f"jit_{getattr(step, '__name__', 'step')}",
-            step.lower(state, bx, by, key).compile(),
-        )
+        with obs.span("zoo.catalog", cat="setup"):
+            programs.record(
+                f"jit_{getattr(step, '__name__', 'step')}",
+                step.lower(state, bx, by, key).compile(),
+            )
 
 
 def _native_epoch_batches(np_images, np_labels, batch_size, steps, seed):
@@ -1740,30 +1745,38 @@ def train(
             "independent full view is what makes in-flight resharding "
             "possible; enable it or drop --elastic"
         )
+    # Set-up as spans (cat "setup": zoo.init, zoo.build_step, zoo.restore,
+    # zoo.store, and zoo.catalog in _catalog_step) and as counts: what the
+    # process's compile log (obs/compiles.py) has seen when this call
+    # starts is what the first epoch record's `compiles` is counted from.
+    t_call_us = time.perf_counter_ns() / 1e3
+    compiles_seen = obs_lib.compiles.requests()
     z3_plan = None
     z3_host = 1
-    if use_zero3:
-        if HOST_AXIS in mesh.axis_names:
-            z3_host = mesh.shape[HOST_AXIS]
-        state, z3_plan = init_zero3_state(
-            model, jax.random.key(seed), in_shape,
-            n_data=mesh.shape[DATA_AXIS], fused=fused,
-            bucket_bytes=comm.bucket_bytes, n_host=z3_host,
-        )
-    elif use_fused_update:
-        state, n_buckets = init_fused_state(
-            model, jax.random.key(seed), in_shape,
-            n_data=mesh.shape[DATA_AXIS], fused=fused,
-            bucket_bytes=comm.bucket_bytes,
-        )
-    else:
-        optimizer = make_optimizer(
-            lr, momentum, weight_decay,
-            schedule=lr_schedule, warmup_steps=warmup_steps,
-            total_steps=steps * epochs if lr_schedule == "cosine" else None,
-            kind=kind, b1=b1, b2=b2, eps=eps,
-        )
-        state = init_state(model, jax.random.key(seed), in_shape, optimizer)
+    with obs.span("zoo.init", cat="setup"):
+        if use_zero3:
+            if HOST_AXIS in mesh.axis_names:
+                z3_host = mesh.shape[HOST_AXIS]
+            state, z3_plan = init_zero3_state(
+                model, jax.random.key(seed), in_shape,
+                n_data=mesh.shape[DATA_AXIS], fused=fused,
+                bucket_bytes=comm.bucket_bytes, n_host=z3_host,
+            )
+        elif use_fused_update:
+            state, n_buckets = init_fused_state(
+                model, jax.random.key(seed), in_shape,
+                n_data=mesh.shape[DATA_AXIS], fused=fused,
+                bucket_bytes=comm.bucket_bytes,
+            )
+        else:
+            optimizer = make_optimizer(
+                lr, momentum, weight_decay,
+                schedule=lr_schedule, warmup_steps=warmup_steps,
+                total_steps=steps * epochs if lr_schedule == "cosine" else None,
+                kind=kind, b1=b1, b2=b2, eps=eps,
+            )
+            state = init_state(
+                model, jax.random.key(seed), in_shape, optimizer)
     if obs.enabled:
         obs.event(
             "zoo_optimizer",
@@ -1789,37 +1802,38 @@ def train(
         def aug_fn(key, x):
             return aug_lib.random_crop_flip(key, x, pad=augment_pad)
 
-    if pipeline is not None:
-        from parallel_cnn_tpu.train.pipeline_schedule import (
-            make_pipeline_step,
-        )
+    with obs.span("zoo.build_step", cat="setup"):
+        if pipeline is not None:
+            from parallel_cnn_tpu.train.pipeline_schedule import (
+                make_pipeline_step,
+            )
 
-        step = make_pipeline_step(
-            model,
-            None if use_fused_update else optimizer,
-            accum_steps=accum_steps, mesh=mesh, pipeline=pipeline,
-            in_shape=in_shape, comm=comm,
-            fused=fused if use_fused_update else None,
-            lr=lr, momentum=momentum,
-        )
-    elif use_zero3:
-        step = make_zero3_train_step(
-            model, lr=lr, momentum=momentum, accum_steps=accum_steps,
-            mesh=mesh, augment=aug_fn, comm=comm, fused=fused,
-            plan=z3_plan,
-        )
-    elif use_fused_update:
-        step = make_fused_train_step(
-            model, lr=lr, momentum=momentum, accum_steps=accum_steps,
-            mesh=mesh, augment=aug_fn, comm=comm, fused=fused,
-            n_buckets=n_buckets,
-        )
-    else:
-        step = make_train_step(
-            model, optimizer, accum_steps, mesh, aug_fn,
-            model_axis=model_axis, comm=comm, fused=fused,
-        )
-    ev_step = make_eval_step(model) if eval_data is not None else None
+            step = make_pipeline_step(
+                model,
+                None if use_fused_update else optimizer,
+                accum_steps=accum_steps, mesh=mesh, pipeline=pipeline,
+                in_shape=in_shape, comm=comm,
+                fused=fused if use_fused_update else None,
+                lr=lr, momentum=momentum,
+            )
+        elif use_zero3:
+            step = make_zero3_train_step(
+                model, lr=lr, momentum=momentum, accum_steps=accum_steps,
+                mesh=mesh, augment=aug_fn, comm=comm, fused=fused,
+                plan=z3_plan,
+            )
+        elif use_fused_update:
+            step = make_fused_train_step(
+                model, lr=lr, momentum=momentum, accum_steps=accum_steps,
+                mesh=mesh, augment=aug_fn, comm=comm, fused=fused,
+                n_buckets=n_buckets,
+            )
+        else:
+            step = make_train_step(
+                model, optimizer, accum_steps, mesh, aug_fn,
+                model_axis=model_axis, comm=comm, fused=fused,
+            )
+        ev_step = make_eval_step(model) if eval_data is not None else None
 
     if (obs.enabled and comm is not None
             and comm.impl in ("ring", "hierarchical")):
@@ -1928,29 +1942,35 @@ def train(
 
         path = checkpoint.latest(checkpoint_dir)
         if path:
-            if use_zero3:
-                # Sharded resume: restore the world-size-independent view
-                # and re-shard it for THIS run's mesh (reshard-on-restore
-                # — the writing run's world size is irrelevant).
-                template = zero3_full_view(state, z3_plan, n_host=z3_host)
-                # The elastic reshard path recomputes sharding from the
-                # world-size-independent view anyway — exempt from the
-                # plan-fingerprint gate (ring files written after a
-                # resize carry the derived plan's fingerprint).
-                view, tstate, _ = checkpoint.restore_sharded(
-                    path, template, plan_fingerprint=_plan_fp,
-                    replan=replan or (elastic is not None and elastic.enabled),
-                )
-                state, z3_plan = zero3_from_view(
-                    view, n_data=mesh.shape[DATA_AXIS],
-                    bucket_bytes=comm.bucket_bytes, n_host=z3_host,
-                )
-            else:
-                # `state` is the restore template: full-state structure
-                # (params + opt_state + BN stats) validated leaf-for-leaf.
-                state, tstate = checkpoint.restore(
-                    path, state, plan_fingerprint=_plan_fp, replan=replan
-                )
+            with obs.span("zoo.restore", cat="setup"):
+                if use_zero3:
+                    # Sharded resume: restore the world-size-independent
+                    # view and re-shard it for THIS run's mesh (reshard-
+                    # on-restore — the writing run's world size is
+                    # irrelevant).
+                    template = zero3_full_view(
+                        state, z3_plan, n_host=z3_host)
+                    # The elastic reshard path recomputes sharding from
+                    # the world-size-independent view anyway — exempt from
+                    # the plan-fingerprint gate (ring files written after
+                    # a resize carry the derived plan's fingerprint).
+                    view, tstate, _ = checkpoint.restore_sharded(
+                        path, template, plan_fingerprint=_plan_fp,
+                        replan=replan or (
+                            elastic is not None and elastic.enabled),
+                    )
+                    state, z3_plan = zero3_from_view(
+                        view, n_data=mesh.shape[DATA_AXIS],
+                        bucket_bytes=comm.bucket_bytes, n_host=z3_host,
+                    )
+                else:
+                    # `state` is the restore template: full-state
+                    # structure (params + opt_state + BN stats) validated
+                    # leaf-for-leaf.
+                    state, tstate = checkpoint.restore(
+                        path, state, plan_fingerprint=_plan_fp,
+                        replan=replan,
+                    )
             start_epoch = tstate.epoch
             losses = list(tstate.epoch_errors)
             accs = list(tstate.extra.get("epoch_accs", []))
@@ -1990,19 +2010,21 @@ def train(
     if loader == "native":
         import numpy as _np
 
-        np_images = _np.ascontiguousarray(images, dtype=_np.float32)
-        np_labels = _np.ascontiguousarray(labels, dtype=_np.int32)
+        with obs.span("zoo.store", cat="setup"):
+            np_images = _np.ascontiguousarray(images, dtype=_np.float32)
+            np_labels = _np.ascontiguousarray(labels, dtype=_np.int32)
     else:
-        # Across the mesh unless it can change under the loop (elastic):
-        # the store has to outlive any one mesh.
-        store, layout, over = resident_store(
-            images, mesh if elastic_ctl is None else None
-        )
-        # What every chip reads whole is put on every chip once, not moved
-        # there by each call of select_batch.
-        everywhere = jnp.asarray if over is None else functools.partial(
-            jax.device_put, device=mesh_lib.replicated(over))
-        labels = everywhere(jnp.asarray(labels))
+        with obs.span("zoo.store", cat="setup"):
+            # Across the mesh unless it can change under the loop
+            # (elastic): the store has to outlive any one mesh.
+            store, layout, over = resident_store(
+                images, mesh if elastic_ctl is None else None
+            )
+            # What every chip reads whole is put on every chip once, not
+            # moved there by each call of select_batch.
+            everywhere = jnp.asarray if over is None else functools.partial(
+                jax.device_put, device=mesh_lib.replicated(over))
+            labels = everywhere(jnp.asarray(labels))
         if obs.enabled:
             obs.event(
                 "zoo_loader", layout=layout, rows=n,
@@ -2143,9 +2165,6 @@ def train(
             with obs.span("zoo.dispatch", cat="step", step=opt_steps,
                           epoch=epoch + 1):
                 state, loss = step(state, bx, by, key)
-            if obs.enabled and not _cataloged:
-                _cataloged = True
-                _catalog_step(step, state, bx, by, key)
             opt_steps += 1
             if chaos is not None:
                 state, loss = chaos.after_step(state, loss)
@@ -2175,6 +2194,12 @@ def train(
                         verdict.reason
                     )
                     break
+        if obs.enabled and not _cataloged and opt_steps > start_epoch * steps:
+            # Once, when the first epoch's steps are all dispatched (while
+            # the device still runs them): by now the loop has asked for
+            # every program of its step itself, as an untraced run does.
+            _cataloged = True
+            _catalog_step(step, state, bx, by, key, obs)
         with obs.span("zoo.readback", cat="step", epoch=epoch + 1):
             mean_loss = float(epoch_loss) / max(esteps, 1)
         if diverged is None and sentinel is not None:
@@ -2216,6 +2241,21 @@ def train(
         losses.append(mean_loss)
         seconds = time.perf_counter() - t0
         if obs.enabled:
+            if t_call_us is not None:
+                # Once, after the first epoch's readback: what set-up was.
+                # The process's compile totals so far, and this call's
+                # set-up spans by name (`init_s`, `build_step_s`, ...).
+                obs.event(
+                    "zoo_setup", **obs_lib.compiles.summary(),
+                    **{
+                        ev["name"].removeprefix("zoo.") + "_s":
+                            ev["dur"] / 1e6
+                        for ev in obs.tracer.events()
+                        if ev.get("cat") == "setup"
+                        and ev["ts"] >= t_call_us
+                    },
+                )
+                t_call_us = None
             obs.event(
                 "epoch", epoch=epoch + 1, loss=mean_loss, seconds=seconds
             )
@@ -2243,6 +2283,13 @@ def train(
             rec["platform"] = jax.devices()[0].platform
             rec["state_devices"] = _device_ids(state)
             rec["batch_devices"] = _device_ids((bx, by))
+            # Compile requests since the previous record (the call's start
+            # for the first): the epoch that recompiled says so, with no
+            # tracing at all. Counted once an entry point has installed
+            # the log (utils/backend.py:enable_compile_cache).
+            compiles_now = obs_lib.compiles.requests()
+            rec["compiles"] = compiles_now - compiles_seen
+            compiles_seen = compiles_now
             if hasattr(model, "counters"):
                 # (nn/glm_moe.py: rows held, load, overflow, a value a layer)
                 rec.update(model.counters(state.model_state))

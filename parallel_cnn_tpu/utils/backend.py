@@ -71,7 +71,13 @@ def enable_compile_cache() -> str:
     cache key, so it must not be overridden). Otherwise the cache is the
     fixed ``<checkout>/.jax_cache``. The serve tier's ``--aot-cache-dir``
     (serialized executables) is a different, opt-in thing.
+
+    Also starts the process's compile log (``obs/compiles.py``): what
+    the cache saves, and what it does not, is recorded from here on.
     """
+    from parallel_cnn_tpu.obs import compiles
+
+    compiles.install()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")  # graftcheck: disable=env-outside-config -- JAX's own deployment variable, read only to decide NOT to override it
     if placed:
         return placed

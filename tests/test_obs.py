@@ -102,14 +102,13 @@ def test_tracer_export_is_loadable_chrome_trace(tmp_path):
         pass
     tracer.begin_async("request", 0xBEEF)
     tracer.end_async("request", 0xBEEF)
-    tracer.instant("marker", cat="train")
     path = tracer.export(str(tmp_path / "t" / "trace.json"))
     with open(path) as f:
         payload = json.load(f)
     assert payload["displayTimeUnit"] == "ms"
     evs = payload["traceEvents"]
     phases = {e["ph"] for e in evs}
-    assert {"M", "X", "b", "e", "i"} <= phases
+    assert {"M", "X", "b", "e"} <= phases
     proc = [e for e in evs if e["ph"] == "M" and e["name"] == "process_name"]
     assert proc and proc[0]["args"]["name"] == "pcnn:test"
     span = next(e for e in evs if e["ph"] == "X")
