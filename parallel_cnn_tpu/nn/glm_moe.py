@@ -37,12 +37,13 @@ by token (`ExpertLayer.plan`: running sums over a (tokens, held) table
 give every slot its row, one sort of those rows gives every row its
 slot), and multiplied by `lax.ragged_dot`, a grouped matmul whose
 cost follows the rows held; rows that a smaller buffer cannot take are
-COUNTED (`overflow_rows`). Whatever moves a token's row into the buffer,
-and both gradients of the way back, are sized by `rows`, not by the
-assignments: the plan knows the slot in every row. What still visits
-every assignment is the sum of a token's rows out of the buffer
-(`_combine` forward, `_gather_rows` backward: gathers, where the
-transpose would be a scatter-add).
+COUNTED (`overflow_rows`). Whatever moves a token's row into the buffer
+or back, forward and backward, is sized by `rows`, not by the
+assignments: the plan knows the slot in every row, and the sum of a
+token's rows out of the buffer (`_token_sums`: `_combine` forward,
+`_gather_rows` backward) reads each buffer row where it lies — the fused
+kernel of ops/pallas_rowsum.py where the shapes tile and the step is
+lowered for a TPU, a float32 scatter-add of the rows elsewhere.
 
 State the step updates without a gradient (`ExpertLayer.init`): the
 selection bias `b`, the step's load per expert, and the counters read at
@@ -58,9 +59,9 @@ layers' balance terms. In training every decoder layer is rematerialised
 ops/pallas_attention.py: `MLA.core` — shapes that tile, a step lowered
 for a TPU; elsewhere a block of queries at a time in plain XLA, each
 block rematerialised), and what its expert layer decided (`"moe_plan"`:
-the chosen ids, the gates, the buffer's plan — integers, 4 MB a layer at
-262,144 assignments), so a step routes and plans once; so is every
-block of logits.
+the chosen ids, the gates, the buffer's plan and the fused sum's lists
+— integers, what the backward reads of them), so a step routes and plans
+once; so is every block of logits.
 
 Scopes (obs/programs.py; benchmark/shapes/glm_moe.py lists the same):
 `embed`, `l<i>/attn/{norm,q,kv,rope,core,o}`, `l<i>/mlp/...` or
@@ -87,7 +88,7 @@ from parallel_cnn_tpu.nn.layers import (
     _weight,
     rope,
 )
-from parallel_cnn_tpu.ops import pallas_attention
+from parallel_cnn_tpu.ops import pallas_attention, pallas_rowsum
 
 INIT_STD = 0.02
 
@@ -207,60 +208,138 @@ class MLA(Module):
                               w["o"].reshape(h, self.v_dim, -1)), state
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["rank", "in_buffer", "row_of", "row_live", "sizes",
+                 "overflow", "sums"],
+    meta_fields=["tokens", "per_token"])
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """Where an expert layer's `tokens * per_token` assignments lie in its
+    row buffer (`ExpertLayer.plan`): `rank (T, k)` the row of each slot and
+    `in_buffer (T, k)` whether the slot has one; `row_of (rows,)` the flat
+    slot `t * k + j` in each row and `row_live (rows,)`; `sizes (held,)`
+    the live rows an expert; `overflow`, the held assignments past the
+    buffer; `sums`, what the fused sum of a token's rows reads
+    (ops/pallas_rowsum.py:Schedule) where the shapes tile, else None.
+    `rank` is 0 where `in_buffer` is not, and `row_of` where `row_live` is
+    not. A backward is handed the part it reads (`keeping`)."""
+
+    tokens: int
+    per_token: int
+    rank: Optional[jax.Array]
+    in_buffer: Optional[jax.Array]
+    row_of: jax.Array
+    row_live: jax.Array
+    sizes: Optional[jax.Array]
+    overflow: Optional[jax.Array]
+    sums: Optional[pallas_rowsum.Schedule]
+
+    def keeping(self, *fields: str) -> "Plan":
+        """This plan's rows (`row_of`, `row_live`, `sums`) and `fields`."""
+        return dataclasses.replace(self, **{
+            f: None for f in ("rank", "in_buffer", "sizes", "overflow")
+            if f not in fields})
+
+
+def _token_sums(x, plan: Plan, gates=None):
+    """`out[t] = sum of gates[t, j] * x[rank[t, j]]` over a token's slots
+    `in_buffer` (`gates=None`: of `x[rank[t, j]]`): the buffer's rows `x
+    (rows, d)` back onto their tokens, products and sum in float32,
+    rounded once. Made from the buffer's rows, each sent to the token it
+    holds (`row_of // k`), never from the `T * k` slots: seven of eight
+    slots of a share have no row. Rows that are not live hold nothing
+    defined: they are selected away, never multiplied by a zero. Where the
+    plan has a schedule and the program is lowered for a TPU, the fused
+    kernel; elsewhere a float32 scatter-add of the rows as they lie."""
+    t, k = plan.tokens, plan.per_token
+
+    def as_they_lie(x):
+        rows = x.astype(jnp.float32)
+        if gates is not None:
+            rows = rows * gates.reshape(-1)[plan.row_of][:, None].astype(
+                jnp.float32)
+        rows = jnp.where(plan.row_live[:, None], rows, 0)
+        return jnp.zeros((t, x.shape[1]), jnp.float32).at[
+            plan.row_of // k].add(rows).astype(x.dtype)
+
+    if plan.sums is None:
+        return as_they_lie(x)
+    weight = None
+    if gates is not None:
+        # a token's gate for each held expert, (held, T), tokens along the
+        # lanes: compared and summed slot by slot, not looked up
+        weight = sum(
+            jnp.where(plan.in_buffer[None, :, j]
+                      & (plan.rank[None, :, j] == plan.sums.slot_row),
+                      gates[None, :, j].astype(jnp.float32), 0)
+            for j in range(k))
+    return pallas_rowsum.token_sums(x, weight, plan.sums, as_they_lie)
+
+
 @jax.custom_vjp
-def _gather_rows(x, idx, back_idx, back_keep):
-    """`x[idx]`, rows of a 2-D `x` (tokens into the row buffer). The caller
-    knows where every row of `x` went — row j is `idx[back_idx[j, m]]`
-    wherever `back_keep[j, m]` — so the gradient is a gather and a sum over
-    m too, not a scatter-add (which the TPU does a row at a time)."""
-    return x[idx]
+def _gather_rows(x, plan: Plan):
+    """`x[row_of // k]`, the tokens' rows `x (T, d)` into the row buffer.
+    Its gradient is the sum of a token's rows (`_token_sums`), in float32
+    over the live rows: autodiff's own transpose adds in `x.dtype` and adds
+    the dead rows into token 0."""
+    return x[plan.row_of // plan.per_token]
 
 
-def _gather_rows_fwd(x, idx, back_idx, back_keep):
-    return x[idx], (back_idx, back_keep)
+def _gather_rows_fwd(x, plan):
+    return x[plan.row_of // plan.per_token], plan.keeping()
 
 
-def _gather_rows_bwd(res, dy):
-    back_idx, back_keep = res
-    dx = jnp.where(back_keep[..., None], dy[back_idx], 0).sum(axis=1)
-    return dx.astype(dy.dtype), None, None, None
+def _gather_rows_bwd(plan, dy):
+    return _token_sums(dy, plan), None
 
 
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
-@jax.custom_vjp
-def _combine(ys, gates, rank, in_buffer, row_of, row_live):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(ys, gates, plan: Plan, gate_grad: bool = True):
     """`y[t] = sum_j gates[t, j] * ys[rank[t, j]]` over the slots
-    `in_buffer` (buffer rows back onto their tokens). Rows that are not
-    live hold nothing defined: every reader selects, none multiplies by a
-    zero. The caller knows the slot in each live row (`row_of`, a flat
-    `t * k + j`), so the gradients are made in buffer space: a row's is
-    its gate times its token's `dy`, a gate's the product of its row with
-    its token's `dy` — one gather of `rows` rows of `dy`, and no array
-    with a row an assignment."""
-    back = jnp.where(in_buffer[..., None], ys[rank], 0)
-    return jnp.einsum("tk,tkd->td", gates, back)
+    `in_buffer` (buffer rows back onto their tokens: `_token_sums`). The
+    plan knows the slot in each live row (`row_of`), so the gradients are
+    made in buffer space too: a row's is its gate times its token's `dy`,
+    a gate's (`gate_grad`; else none, and the backward keeps no array with
+    an entry a slot) the product of its row with its token's `dy` — one
+    gather of `rows` rows of `dy`, and no array with a row an assignment."""
+    return _token_sums(ys, plan, gates)
 
 
-def _combine_fwd(ys, gates, rank, in_buffer, row_of, row_live):
-    return (_combine(ys, gates, rank, in_buffer, row_of, row_live),
-            (ys, gates, rank, in_buffer, row_of, row_live))
+def _combine_fwd(ys, gates, plan, gate_grad):
+    kept = plan.keeping("rank", "in_buffer") if gate_grad else plan.keeping()
+    # (the sum itself, not `_combine`: behind a second custom_vjp call a
+    # rematerialised backward would compute everything the call is handed)
+    return _token_sums(ys, plan, gates), (ys, gates, kept)
 
 
-def _combine_bwd(res, dy):
-    ys, gates, rank, in_buffer, row_of, row_live = res
-    dy_rows = dy[row_of // gates.shape[1]]
-    d_ys = jnp.where(row_live[:, None],
-                     gates.reshape(-1)[row_of][:, None] * dy_rows, 0)
+def _combine_bwd(gate_grad, res, dy):
+    ys, gates, plan = res
+    dy_rows = dy[plan.row_of // plan.per_token]
+    d_ys = jnp.where(plan.row_live[:, None],
+                     gates.reshape(-1)[plan.row_of][:, None] * dy_rows, 0)
+    if not gate_grad:
+        return d_ys.astype(ys.dtype), jnp.zeros_like(gates), None
     d_row = jnp.einsum("rd,rd->r", dy_rows, ys,
                        preferred_element_type=jnp.float32)
-    d_gates = jnp.where(in_buffer, d_row[rank], 0)
-    return (d_ys.astype(ys.dtype), d_gates.astype(gates.dtype),
-            None, None, None, None)
+    d_gates = jnp.where(plan.in_buffer, d_row[plan.rank], 0)
+    return d_ys.astype(ys.dtype), d_gates.astype(gates.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _rows_visited(plan: Plan, rows: int):
+    """The buffer rows one `_token_sums` reads: the windows' of the fused
+    kernel where that runs, else every row."""
+    if plan.sums is None:
+        return jnp.asarray(rows, jnp.int32)
+    return lax.platform_dependent(
+        plan.sums.visited, tpu=lambda visited: visited,
+        default=lambda visited: jnp.full_like(visited, rows))
 
 
 def _planned(value):
@@ -277,8 +356,9 @@ class ExpertLayer(Module):
     holds, in the order of its stacked weights; `rows`: the row buffer.
     `apply` is `route` (who goes where, at what gate), `plan` (which row
     of the buffer), a gather of `rows` token rows, three grouped matmuls
-    and `_combine`; the work of all but the last follows `rows` and the
-    experts held, forward and backward.
+    and `_combine` (the buffer's rows summed onto their tokens); the work
+    of all of them follows `rows` and the experts held, forward and
+    backward.
 
     What the factory chooses: `scoring` (`"sigmoid"`, each expert's own, or
     `"softmax"` over all `n_routed`; either in float32, the gates the
@@ -348,6 +428,8 @@ class ExpertLayer(Module):
             "overflow_rows": jnp.zeros((), jnp.int32),
             # the last forward's balance term, for the model's loss
             "balance": jnp.zeros((), jnp.float32),
+            # the buffer rows one sum of a token's rows read in it
+            "sum_rows_visited": jnp.zeros((), jnp.int32),
         }
         return params, state, in_shape
 
@@ -377,18 +459,17 @@ class ExpertLayer(Module):
         balance = self.balance * jnp.mean(jnp.sum(f * p, axis=1))
         return ids, gates, picked.sum(axis=0), balance
 
-    def plan(self, ids, rows: int):
-        """Where the assignments `ids` (T, k) go in a buffer of `rows` rows,
-        held assignments expert by expert in the order of `held`, a token
-        before a later one: (`rank` (T, k) the row of each slot, `in_buffer`
-        (T, k) whether the slot has one, `row_of` (rows,) the flat slot
-        `t * k + j` in each row, `row_live` (rows,), `sizes` (held,) the
-        live rows an expert, `overflow` the held assignments past `rows`).
-        Counted: a token picks an expert at most once, so the tokens of an
-        expert are a boolean column, a slot's place among them is that
-        column's running sum, and an expert's first row is the sum of the
-        columns before it. `rank` is 0 where `in_buffer` is not, and
-        `row_of` where `row_live` is not."""
+    def plan(self, ids, rows: int, tiles=None) -> Plan:
+        """Where the assignments `ids` (T, k) go in a buffer of `rows` rows
+        (`Plan`), held assignments expert by expert in the order of `held`,
+        a token before a later one; with `tiles`
+        (ops/pallas_rowsum.py:tiles), what the fused sum reads too. Counted:
+        a token picks an expert at most once, so the tokens of an expert
+        are a boolean column, a slot's place among them is that column's
+        running sum, and an expert's first row is the sum of the columns
+        before it. Named for a rematerialised layer to keep: what the
+        backward reads, which without the gates' gradient leaves out the
+        slots' own rows."""
         t, k = ids.shape
         hit = ids[:, :, None] == jnp.asarray(self.held, ids.dtype)  # (T, k, held)
         mine = hit.any(axis=1).astype(jnp.int32)
@@ -405,9 +486,15 @@ class ExpertLayer(Module):
             :rows].astype(jnp.int32)
         live = jnp.minimum(ends, rows)
         row_live = jnp.arange(rows) < live[-1]
-        plan = (rank, in_buffer, jnp.where(row_live, row_of, 0), row_live,
-                jnp.diff(live, prepend=0))
-        return (*map(_planned, plan), ends[-1] - live[-1])
+        by_slot = (rank, in_buffer)
+        if self.gate_grad:
+            by_slot = tuple(map(_planned, by_slot))
+        sums = None if tiles is None else jax.tree_util.tree_map(
+            _planned, pallas_rowsum.schedule(place, ends, rows, tiles))
+        return Plan(
+            t, k, *by_slot, _planned(jnp.where(row_live, row_of, 0)),
+            _planned(row_live), _planned(jnp.diff(live, prepend=0)),
+            ends[-1] - live[-1], sums)
 
     def apply(self, params, state, x, train: bool = False):
         n, s, d = x.shape
@@ -418,18 +505,17 @@ class ExpertLayer(Module):
             ids, gates, load, balance = self.route(
                 params["router"], state["bias"], xt, n)
         with jax.named_scope("dispatch"):
-            rank, in_buffer, row_of, row_live, sizes, overflow = self.plan(
-                ids, rows)
-            xs = _gather_rows(xt, row_of // k, rank, in_buffer)
+            plan = self.plan(
+                ids, rows, pallas_rowsum.tiles(t, rows, d, len(self.held)))
+            xs = _gather_rows(xt, plan)
         with jax.named_scope("experts"):
             w = {m: params["experts"][m].astype(x.dtype)
                  for m in ("gate", "up", "down")}
-            hidden = (jax.nn.silu(lax.ragged_dot(xs, w["gate"], sizes))
-                      * lax.ragged_dot(xs, w["up"], sizes))
-            ys = lax.ragged_dot(hidden, w["down"], sizes)
+            hidden = (jax.nn.silu(lax.ragged_dot(xs, w["gate"], plan.sizes))
+                      * lax.ragged_dot(xs, w["up"], plan.sizes))
+            ys = lax.ragged_dot(hidden, w["down"], plan.sizes)
         with jax.named_scope("combine"):
-            y = _combine(ys, gates.astype(x.dtype), rank, in_buffer, row_of,
-                         row_live)
+            y = _combine(ys, gates.astype(x.dtype), plan, self.gate_grad)
         if self.n_shared:
             with jax.named_scope("shared"):
                 y = y + self._shared().apply(params["shared"], {}, xt)[0]
@@ -437,8 +523,9 @@ class ExpertLayer(Module):
             state = dict(
                 state,
                 load=state["load"] + load,
-                overflow_rows=state["overflow_rows"] + overflow,
+                overflow_rows=state["overflow_rows"] + plan.overflow,
                 balance=balance,
+                sum_rows_visited=_rows_visited(plan, rows),
             )
         return y.reshape(n, s, d), state
 
@@ -663,14 +750,14 @@ class GlmMoe(Module):
     def counters(self, state) -> Dict[str, List[float]]:
         """The expert layers' counters, one value a layer (the MTP
         module's last), as an epoch record carries them."""
-        got = jax.device_get([
-            (s["rows_held"], s["load_max_over_mean"], s["overflow_rows"])
-            for s in self._expert_states(state)])
-        return {
-            "moe_rows_held": [int(r) for r, _, _ in got],
-            "moe_load_max_over_mean": [float(m) for _, m, _ in got],
-            "moe_overflow_rows": [int(o) for _, _, o in got],
-        }
+        read = (("moe_rows_held", "rows_held", int),
+                ("moe_load_max_over_mean", "load_max_over_mean", float),
+                ("moe_overflow_rows", "overflow_rows", int),
+                ("moe_sum_rows_visited", "sum_rows_visited", int))
+        got = jax.device_get([[s[key] for _, key, _ in read]
+                              for s in self._expert_states(state)])
+        return {name: [cast(layer[i]) for layer in got]
+                for i, (name, _, cast) in enumerate(read)}
 
     def describe(self, tokens_per_step: int, seq_len: int,
                  platform: str) -> Dict[str, object]:

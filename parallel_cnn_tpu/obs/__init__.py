@@ -27,7 +27,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
-from parallel_cnn_tpu.obs import compiles, programs
+from parallel_cnn_tpu.obs import compiles, epochs, programs
 from parallel_cnn_tpu.obs.events import (
     NOOP_JOURNAL,
     EventJournal,
@@ -50,7 +50,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge",
     "EventJournal", "NoopJournal", "NOOP_JOURNAL",
     "read_journal", "merge_journals", "conservation",
-    "programs", "compiles",
+    "programs", "compiles", "epochs",
 ]
 
 

@@ -2291,8 +2291,11 @@ def train(
             rec["compiles"] = compiles_now - compiles_seen
             compiles_seen = compiles_now
             if hasattr(model, "counters"):
-                # (nn/glm_moe.py: rows held, load, overflow, a value a layer)
+                # (nn/glm_moe.py: rows held, load, overflow, the rows a sum
+                # of the tokens' rows read, a value a layer)
                 rec.update(model.counters(state.model_state))
+            # the process's own copy, for a reader handed no recorder
+            obs_lib.epochs.record(rec)
             metrics.record(**rec)
         if ring is not None:
             from parallel_cnn_tpu.train import checkpoint

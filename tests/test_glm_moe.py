@@ -218,8 +218,11 @@ def test_the_counted_plan_is_the_stable_sorts(case, rows):
                                 held=(7, 0, 5, 9), scaling=1.8)
     ids = _routed(case, t, k, layer)
     rows = t * k if rows is None else rows
-    rank, in_buffer, row_of, row_live, sizes, overflow = jax.jit(
-        layer.plan, static_argnums=1)(ids, rows)
+    plan = jax.jit(layer.plan, static_argnums=1)(ids, rows)
+    assert plan.sums is None  # no tiles asked for: tests/test_pallas_rowsum.py
+    rank, in_buffer, row_of, row_live, sizes, overflow = (
+        plan.rank, plan.in_buffer, plan.row_of, plan.row_live, plan.sizes,
+        plan.overflow)
     want = _sorted_plan(layer, ids, rows)
     np.testing.assert_array_equal(in_buffer, want["in_buffer"])
     np.testing.assert_array_equal(rank, np.where(want["in_buffer"], want["rank"], 0))
@@ -236,38 +239,50 @@ def test_the_counted_plan_is_the_stable_sorts(case, rows):
         assert int(sizes[2]) == min(t, max(0, rows - int(sizes[:2].sum())))
 
 
-def _plain_combine(ys, gates, rank, in_buffer, row_of, row_live):
-    """What `_combine` computes, as autodiff sees it: a gather of every
-    assignment's row and a sum over a token's slots."""
-    back = jnp.where(in_buffer[..., None], ys[rank], 0)
+def _plain_combine(ys, gates, plan, gate_grad=True):
+    """What `_combine` computes, as autodiff sees it (and as the layer ran
+    it until PR 37): a gather of every assignment's row and a sum over a
+    token's slots."""
+    back = jnp.where(plan.in_buffer[..., None], ys[plan.rank], 0)
     return jnp.einsum("tk,tkd->td", gates, back)
+
+
+def one_rounding(dtype):
+    """Two float32 sums of the same few terms in two orders, each rounded
+    once to `dtype`: a last place of `dtype` apart at the most."""
+    return dict(atol=1e-6, rtol=2.0 ** -7 if dtype == "bfloat16" else 1e-6)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("rows", [30, 100], ids=["overflowed", "dead_rows"])
 def test_the_combines_gradients_are_autodiffs_made_in_buffer_space(dtype, rows):
-    """`_combine`'s own backward (a gather of `rows` rows of `dy`) gives
-    the rows' and the gates' gradients that `jax.vjp` of the plain
-    expression gives: a buffer that overflows, and one with rows past the
-    live ones, which hold NaN here (nothing reads them). The rows' to the
-    bit (one product either way); the gates' to the bit in bfloat16 (a
-    float32 sum rounded once either way) and to the order of a float32
-    sum over `d` in float32 (a row's product with its token's `dy` here,
-    a token's with its k rows there: this backend's two dot loops)."""
+    """`_combine` (the buffer's rows summed onto their tokens, and its own
+    backward: a gather of `rows` rows of `dy`) gives the output and the
+    rows' and the gates' gradients that `jax.vjp` of the plain expression
+    gives: a buffer that overflows, and one with rows past the live ones,
+    which hold NaN here (nothing reads them). The output to the order of a
+    float32 sum rounded once (a token's rows as they lie in the buffer
+    here, its slots in order there); the rows' gradient to the bit (one
+    product either way); the gates' to the bit in bfloat16 (a float32 sum
+    rounded once either way) and to the order of a float32 sum over `d` in
+    float32 (a row's product with its token's `dy` here, a token's with
+    its k rows there: this backend's two dot loops)."""
     t, k, d = 48, 3, 16
     layer = glm_moe.ExpertLayer(width=16, n_routed=12, per_token=k,
                                 held=(7, 0, 5, 9), scaling=1.8)
     ids = _routed("balanced", t, k, layer)
-    rank, in_buffer, row_of, row_live, _, overflow = layer.plan(ids, rows)
-    assert (int(overflow) > 0) == (rows == 30) and bool(row_live.all()) == (rows == 30)
+    plan = layer.plan(ids, rows)
+    assert (int(plan.overflow) > 0) == (rows == 30)
+    assert bool(plan.row_live.all()) == (rows == 30)
     ys = jax.random.normal(jax.random.key(1), (rows, d)).astype(dtype)
-    ys = jnp.where(row_live[:, None], ys, jnp.nan)
+    ys = jnp.where(plan.row_live[:, None], ys, jnp.nan)
     gates = jax.random.uniform(jax.random.key(2), (t, k)).astype(dtype)
     dy = jax.random.normal(jax.random.key(3), (t, d)).astype(dtype)
-    plan = (rank, in_buffer, row_of, row_live)
-    y, vjp = jax.vjp(lambda ys, g: glm_moe._combine(ys, g, *plan), ys, gates)
-    want_y, want_vjp = jax.vjp(lambda ys, g: _plain_combine(ys, g, *plan), ys, gates)
-    np.testing.assert_array_equal(y, want_y)
+    y, vjp = jax.vjp(lambda ys, g: glm_moe._combine(ys, g, plan), ys, gates)
+    want_y, want_vjp = jax.vjp(lambda ys, g: _plain_combine(ys, g, plan), ys, gates)
+    assert y.dtype == want_y.dtype and not bool(jnp.isnan(y).any())
+    np.testing.assert_allclose(y.astype(jnp.float32), want_y.astype(jnp.float32),
+                               **one_rounding(dtype))
     (d_ys, d_gates), (want_ys, want_gates) = vjp(dy), want_vjp(dy)
     assert d_ys.dtype == want_ys.dtype == ys.dtype and d_gates.dtype == gates.dtype
     np.testing.assert_array_equal(d_ys, want_ys)
@@ -275,7 +290,7 @@ def test_the_combines_gradients_are_autodiffs_made_in_buffer_space(dtype, rows):
     np.testing.assert_allclose(d_gates, want_gates, atol=0,
                                rtol=0 if dtype == "bfloat16" else 1e-5)
     assert not bool(jnp.isnan(d_ys).any()) and float(jnp.abs(d_gates).max()) > 0
-    assert float(jnp.abs(d_ys[~row_live]).sum()) == 0
+    assert float(jnp.abs(d_ys[~plan.row_live]).sum()) == 0
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -333,8 +348,13 @@ def test_a_rematerialised_layer_plans_once_and_combines_back_in_buffer_space(
     """The jaxpr of a training gradient of one rematerialised expert
     decoder layer: the plan is named and kept, so `top_k` and the plan's
     sort appear once, the backward's `route` has the scores' matmul and
-    no one-hot; and under `combine` the backward makes nothing with a row an
-    assignment — no `(T * k, d)`, no `(T, k, d)`."""
+    no one-hot; what is named is what the backward reads (without the
+    gates' gradient the slots' own rows are not); and under no `moe/` scope
+    does the backward make anything with a row an assignment — no `(T * k,
+    d)`, no `(T, k, d)`: the sum of a token's rows, `moe/combine` forward
+    and `moe/dispatch` backward, is one scatter-add of the buffer's rows
+    each (at these widths; the fused kernel where the shapes tile:
+    tests/test_pallas_rowsum.py, tests/test_zoo_loader_compile.py)."""
     model, _ = build(gate_gradient=gate_gradient, row_buffer=48)
     layer = model._mtp_layer()
     p, st, _ = layer.init(jax.random.key(0), (S, 32))
@@ -350,22 +370,29 @@ def test_a_rematerialised_layer_plans_once_and_combines_back_in_buffer_space(
     assert count("top_k") == 1 and count("cumsum") == 2 and count("scatter") == 0
     assert count("sort") == 1  # the plan's inverse, once
     kept = [e.params["name"] for _, e in eqns if e.primitive.name == "name"]
-    assert kept.count("moe_plan") == 8 and kept.count("attn_core") == 1
+    # ids, gates, the balance term's f, row_of, row_live, sizes; with the
+    # gates' gradient rank and in_buffer too
+    assert kept.count("moe_plan") == (8 if gate_gradient else 6)
+    assert kept.count("attn_core") == 1
     backward = [(where, e) for where, e in eqns if where.startswith("transpose(")
                 and "rematted_computation" not in where]
     recomputed = [(where, e) for where, e in eqns if "rematted_computation" in where]
     assert {e.primitive.name for where, e in recomputed
             if where.endswith("moe/route")} >= {"dot_general"}
-    assert not any(e.primitive.name in ("top_k", "eq", "sort", "cumsum")
+    assert not any(e.primitive.name in ("top_k", "eq", "sort", "cumsum", "scatter-add")
                    for where, e in recomputed)
     per_assignment = {(t * k, d), (t, k, d)}
     shapes = lambda found: {tuple(v.aval.shape) for _, e in found  # noqa: E731
                             for v in e.outvars}
     combine = [(w, e) for w, e in backward if "moe/combine" in w]
-    assert len(combine) >= 4 and not shapes(combine) & per_assignment
-    assert (48, d) in shapes(combine)  # a gather of the buffer's rows of dy
-    # ... where the dispatch's backward still has one (the next issue's)
-    assert shapes([(w, e) for w, e in backward if "moe/dispatch" in w]) & per_assignment
+    assert len(combine) >= 4 and (48, d) in shapes(combine)  # rows of dy
+    dispatch = [(w, e) for w, e in backward if "moe/dispatch" in w]
+    assert (t, d) in shapes(dispatch)  # the rows of d xs, summed by token
+    assert not shapes([(w, e) for w, e in backward if "/moe/" in w]) & per_assignment
+    sums = [w for w, e in eqns if e.primitive.name == "scatter-add" and "moe" in w
+            and any(v.aval.shape == (t, d) for v in e.outvars)]
+    assert [w.rsplit("/", 1)[1] for w in sums] == ["combine", "dispatch"]
+    assert sums[0].startswith("jvp(") and sums[1].startswith("transpose(")
 
 
 def test_the_bias_moves_by_u_toward_balance_and_takes_no_gradient(small):
@@ -707,6 +734,10 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     last = Rec.epochs[-1]
     assert len(last["moe_rows_held"]) == 3 and sum(last["moe_overflow_rows"]) == 0
     assert all(m >= 1.0 for m in last["moe_load_max_over_mean"])
+    # every row of the buffer on the plain path (this CPU), a value a layer;
+    # and the program's own copy of the record is the recorder's
+    assert last["moe_sum_rows_visited"] == [4 * S * 2] * 3
+    assert obs_lib.epochs.newest() == [last]
     (event,) = [f for k, f in Journal.events if k == "zoo_moe"]
     assert (event["experts_held"], event["experts_published"],
             event["tokens_per_step"], event["row_buffer"]) == (3, 8, 4 * S, 4 * S * 2)

@@ -598,8 +598,13 @@ def test_the_scopes_are_the_ones_the_catalog_reads():
 # through the layer's rematerialisation under the name "moe_plan", and
 # the way back out of the buffer has a backward rule of its own
 # (`glm_moe._combine`). A PR that does not touch the expert layer, the
-# attention or the loss leaves it where PR 35 put it.
-GLM_LOWERED = "5af67e9f10144f81b67e29bd34d3e1c3e527a075300f62c6e8c251c4eea91cb2"
+# attention or the loss leaves it where PR 35 put it. Moved by design
+# again by PR 37 (from 5af67e9f...): the sum of a token's rows out of the
+# buffer, `_combine` forward and the dispatch's backward, is made from
+# the buffer's rows (at these widths a float32 scatter-add), no longer
+# from a gather of every assignment slot, and the layers' state has one
+# more counter.
+GLM_LOWERED = "236a7bdc9a325c4474534d8afdbffb657c307bfd1afa00f7ad17417c4c2e916c"
 
 
 def test_the_lowered_step_of_the_glm_model_is_unchanged():

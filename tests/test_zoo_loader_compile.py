@@ -417,13 +417,15 @@ def test_no_tile_of_the_streams_scores_reaches_hbm(topo):
     assert not re.search(r"\[8192,8192\]", text)
 
 
-# -- the expert layer plans once a step and goes back in buffer space (PR 35)
+# -- the expert layer plans once a step and goes back in buffer space (PR 35);
+# -- the sum of a token's rows is one fused kernel, both directions (PR 37)
 
-def _expert_layer_shapes(program, assignments, width=2048):
+def _expert_layer_shapes(program, tokens, k, width=2048):
     """(sorts by (scope, phase), scopes and phases under which an
-    instruction's result has a row an assignment) of a compiled step."""
+    instruction's result has a row an assignment — `(T * k, d)` or `(T, k,
+    d)` —, the row-sum kernels' (scope, phase)) of a compiled step."""
     instructions = _instructions(program["text"].split("ENTRY")[1])
-    sorts, per_assignment = collections.Counter(), set()
+    sorts, per_assignment, kernels = collections.Counter(), set(), []
     for name, ins in instructions.items():
         entry = program["catalog"].get(name.lstrip("%"))
         if entry is None or "/moe/" not in entry.scope:
@@ -431,26 +433,55 @@ def _expert_layer_shapes(program, assignments, width=2048):
         scope = entry.scope[entry.scope.index("moe/"):]
         if ins.opcode == "sort":
             sorts[scope, entry.phase] += 1
-        if any(dims == f"{assignments},{width}" for _, dims in ins.result):
+        if any(dims in (f"{tokens * k},{width}", f"{tokens},{k},{width}")
+               for _, dims in ins.result):
             per_assignment.add((scope, entry.phase))
-    return sorts, per_assignment
+        if ins.opcode == "custom-call" and name.lstrip("%").startswith("moe_token_sums"):
+            kernels.append((scope, entry.phase))
+    return sorts, per_assignment, sorted(kernels)
 
 
-@pytest.mark.parametrize("which,layers,assignments", [
-    ("sdar", 2, 8192 * 8), ("glm", 2, 4096 * 4)])
+@pytest.mark.parametrize("which,layers,tokens,k", [
+    ("sdar", 2, 8192, 8), ("glm", 2, 4096, 4)])
 def test_a_step_plans_once_an_expert_layer_and_combines_back_by_rows(
-        topo, which, layers, assignments):
+        topo, which, layers, tokens, k):
     """As the chip's compiler leaves it: an expert layer sorts twice, both
     in the forward (`top_k`, and the plan's slots by row: the plan is kept
-    through the layer's rematerialisation), and no instruction under
-    `moe/combine` in the backward has a row an assignment; the forward's
-    sum of a token's rows, and its transpose in `moe/dispatch`'s backward,
-    still do."""
+    through the layer's rematerialisation), and no instruction under any
+    `moe/` scope of either phase has a row an assignment: the sum of a
+    token's rows is the fused kernel, once in the forward under
+    `moe/combine` and once in the backward under `moe/dispatch` (the
+    rematerialised backward runs no forward sum again)."""
     program = _sdar_program(topo) if which == "sdar" else _glm_program(topo)
-    sorts, per_assignment = _expert_layer_shapes(program, assignments)
+    sorts, per_assignment, kernels = _expert_layer_shapes(program, tokens, k)
     assert sorts == {("moe/route", "fwd"): layers,
                      ("moe/dispatch", "fwd"): layers}, sorts
-    assert ("moe/combine", "fwd") in per_assignment
-    assert ("moe/dispatch", "bwd") in per_assignment
-    assert ("moe/combine", "bwd") not in per_assignment
-    assert ("moe/dispatch", "fwd") not in per_assignment
+    assert per_assignment == set()
+    assert kernels == sorted([("moe/combine", "fwd"),
+                              ("moe/dispatch", "bwd")] * layers)
+
+
+@pytest.mark.parametrize("t,k,rows,held,weighted", [
+    (32768, 8, 65536, 16, True), (32768, 8, 65536, 16, False),
+    (16384, 4, 16384, 8, True), (4096, 4, 4096, 8, False)],
+    ids=["sdar_gated", "sdar_plain", "glm_gated", "glm_check_plain"])
+def test_the_row_sum_kernel_compiles_at_the_cells_shapes(
+        topo, t, k, rows, held, weighted):
+    """Mosaic takes the kernel of ops/pallas_rowsum.py at both token cells'
+    shapes (and at the check's one sequence), bf16 rows 2,048 wide, with
+    the gates and without."""
+    from parallel_cnn_tpu.ops import pallas_rowsum
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tl = pallas_rowsum.tiles(t, rows, 2048, held)
+    assert tl == pallas_rowsum.Tiles(512, 16, 16)
+    shape = lambda *s, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+    c = pallas_rowsum.chunks(t, rows, held, tl)
+    sched = pallas_rowsum.Schedule(
+        tl, shape(held, t), shape(c), shape(c), shape(c * tl.g),
+        shape(c * tl.g), shape(1), shape())
+    weight = shape(held, t, dtype=jnp.float32) if weighted else None
+    compiled = jax.jit(pallas_rowsum.sums).lower(
+        shape(rows, 2048, dtype=jnp.bfloat16), weight, sched).compile()
+    assert "moe_token_sums" in compiled.as_text()
