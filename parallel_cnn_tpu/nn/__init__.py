@@ -35,6 +35,7 @@ from parallel_cnn_tpu.nn.layers import (  # noqa: F401
     RMSNorm,
 )
 from parallel_cnn_tpu.nn import (  # noqa: F401
+    afmoe,
     cifar,
     convnext,
     glm_moe,

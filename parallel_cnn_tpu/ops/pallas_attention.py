@@ -1,10 +1,12 @@
-"""Attention cores as fused TPU kernels (Pallas/Mosaic), two masks of one
-family: the causal core (`causal_attention`, below) and, under its own
-heading at the end of the file, the block-diffusion core over grouped
-key/value heads (`block_diffusion_attention`), which shares this
-arithmetic, these roundings, the residuals' names and the rule that
-decides what runs. The causal core: for head-major bf16 `q, k (N, H, S,
-Dk)` and `v (N, H, S, Dv)`,
+"""Attention cores as fused TPU kernels (Pallas/Mosaic), masks of one
+family: the causal core (`causal_attention`, below); under its own
+heading, the block-diffusion core over grouped key/value heads
+(`block_diffusion_attention`), whose kernels take the mask as a schedule;
+and, at the end of the file, two more schedules of that second pair, the
+causal and the sliding-window core over grouped heads
+(`grouped_causal_attention`). All share this arithmetic, these roundings,
+the residuals' names and the rule that decides what runs. The causal
+core: for head-major bf16 `q, k (N, H, S, Dk)` and `v (N, H, S, Dv)`,
 
     out = softmax_causal(q k^T * scale) v
 
@@ -321,9 +323,11 @@ causal_attention.defvjp(_forward_rule, _backward_rule)
 # query heads over `k, v (N, KV, 2l, D)`: query head a reads key/value head
 # `a // (H // KV)`, through the index maps alone.
 #
-# The mask reaches the kernels as static structure: `schedule(l, t)` lists,
-# query tile by query tile, the tiles that hold an allowed pair and how
-# each is masked (FULL: not at all); the three lists are scalar-prefetched,
+# The mask reaches the kernels as static structure: a schedule (this
+# mask's is `schedule(l, t)`; `scheduled_forward` / `scheduled_backward`
+# take any) lists, query tile by query tile, the tiles that hold an allowed
+# pair and how each is masked (FULL: not at all), each kind one of the
+# `kinds` the call dispatches over; the three lists are scalar-prefetched,
 # the grid's last axis walks them, and the index maps read the tile to
 # fetch from them — a tile the mask excludes is no grid step at all. A tile
 # that a block boundary crosses is masked in the kernel from the positions
@@ -340,8 +344,11 @@ causal_attention.defvjp(_forward_rule, _backward_rule)
 #             head of the group — the sum over the group is the
 #             accumulator's — and are written once.
 
-FULL, SAME, BEFORE, UPTO = 0, 1, 2, 3
-_FIRST, _LAST = 4, 8  # flags beside the kind: a query tile's first/last step
+FULL, SAME, BEFORE, UPTO, AFTER = 0, 1, 2, 3, 4
+BD_KINDS = (FULL, SAME, BEFORE, UPTO)
+# An entry of the third table: the kind's place among the kinds its call
+# dispatches over (two bits: a schedule holds at most four), and two flags.
+_FIRST, _LAST = 4, 8  # a query tile's first/last step
 
 
 def bd_tile(l: int, block: int, width: int) -> Optional[int]:
@@ -381,13 +388,13 @@ def bd_tiles_visited(l: int, t: int) -> int:
     return len(schedule(l, t))
 
 
-def _tables(l: int, t: int):
-    steps = schedule(l, t)
+def _tables(steps, kinds):
     what = []
     for i, (qi, _, kind) in enumerate(steps):
         first = i == 0 or steps[i - 1][0] != qi
         last = i == len(steps) - 1 or steps[i + 1][0] != qi
-        what.append(kind | (_FIRST if first else 0) | (_LAST if last else 0))
+        what.append(kinds.index(kind) | (_FIRST if first else 0)
+                    | (_LAST if last else 0))
     as_i32 = lambda xs: jnp.asarray(xs, jnp.int32)  # noqa: E731
     return (as_i32([s[0] for s in steps]), as_i32([s[1] for s in steps]),
             as_i32(what))
@@ -395,24 +402,26 @@ def _tables(l: int, t: int):
 
 def _bd_mask(s, kind: int, block: int, rows_are_keys: bool):
     """A tile whose corner starts a block on both sides: `s` where the
-    kind's rule holds between the query's block and the key's."""
+    kind's rule holds between the query's block and the key's (blocks of
+    1: between the query's offset in its tile and the key's in its own)."""
     shift = block.bit_length() - 1  # a power of two: it divides the tile
     r = lax.broadcasted_iota(jnp.int32, s.shape, 0) >> shift
     c = lax.broadcasted_iota(jnp.int32, s.shape, 1) >> shift
     qb, kb = (c, r) if rows_are_keys else (r, c)
-    seen = {SAME: kb == qb, BEFORE: kb < qb, UPTO: kb <= qb}[kind]
+    seen = {SAME: kb == qb, BEFORE: kb < qb, UPTO: kb <= qb, AFTER: kb > qb}[kind]
     return jnp.where(seen, s, MASKED)
 
 
-def _each_kind(what, step):
-    """Run `step(kind)` for the kind this grid step's entry names."""
-    for kind in (FULL, SAME, BEFORE, UPTO):
-        pl.when((what & 3) == kind)(functools.partial(step, kind))
+def _each_kind(what, kinds, step):
+    """Run `step(kind)` for the one of the call's `kinds` that this grid
+    step's entry names."""
+    for i, kind in enumerate(kinds):
+        pl.when((what & 3) == i)(functools.partial(step, kind))
 
 
 def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
                    lse_ref, m_ref, l_ref, acc_ref, *, scale: float, t: int,
-                   block: int):
+                   block: int, kinds):
     what = what_ref[pl.program_id(2)]
 
     @pl.when((what & _FIRST) != 0)
@@ -435,7 +444,7 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = (_lanes(alpha, acc_ref.shape[-1]) * acc_ref[...]
                         + _dot(p.astype(v.dtype), v, _NN))
 
-    _each_kind(what, step)
+    _each_kind(what, kinds, step)
 
     @pl.when((what & _LAST) != 0)
     def _():
@@ -445,16 +454,21 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
 
 
-def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
-               interpret: bool = False):
-    """(out (N, H, 2l, D) in `v.dtype`, lse (N, H, 2l) float32)."""
+def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
+                      kinds=BD_KINDS, name: str = "block_diffusion_attention_fwd",
+                      interpret: bool = False):
+    """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32) of `q (N, H,
+    S, D)` over `k, v (N, KV, S, D)` under the schedule `steps` ([(query
+    tile, key tile, kind)], query-major, a query tile's first entry
+    leaving no row without a visible key), its kinds among `kinds`."""
     n, h, s, d = q.shape
     group = h // k.shape[1]
-    tables = _tables(l, t)
+    tables = _tables(steps, kinds)
     at_q = lambda n, h, i, qt, kt, what: (n, h, qt[i], 0)  # noqa: E731
     at_k = lambda n, h, i, qt, kt, what: (n, h // group, kt[i], 0)  # noqa: E731
     out, lse = pl.pallas_call(
-        functools.partial(_bd_fwd_kernel, scale=scale, t=t, block=block),
+        functools.partial(_bd_fwd_kernel, scale=scale, t=t, block=block,
+                          kinds=kinds),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n, h, tables[0].shape[0]),
@@ -473,14 +487,21 @@ def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="block_diffusion_attention_fwd",
+        name=name,
     )(*tables, q, k, v)
     return out, lse.reshape(n, h, s)
 
 
+def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
+               interpret: bool = False):
+    """(out (N, H, 2l, D) in `v.dtype`, lse (N, H, 2l) float32)."""
+    return scheduled_forward(q, k, v, schedule(l, t), scale=scale, block=block,
+                             t=t, interpret=interpret)
+
+
 def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
-                   dv_acc, *, scale: float, t: int, block: int):
+                   dv_acc, *, scale: float, t: int, block: int, kinds):
     g, i = pl.program_id(2), pl.program_id(3)
     what = what_ref[i]
     end = (g == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
@@ -507,7 +528,7 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[rows, :] += _dot(ds, q, _NN)
         dq_acc[...] += _dot(ds, k, _TN)
 
-    _each_kind(what, step)
+    _each_kind(what, kinds, step)
 
     @pl.when((what & _LAST) != 0)
     def _():
@@ -519,22 +540,26 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def bd_backward(q, k, v, out, lse, d_out, *, scale: float, l: int, block: int,
-                t: int, interpret: bool = False):
-    """(dq, dk, dv) in the dtypes of `q, k, v`; `dk`, `dv` summed over each
-    key/value head's group of query heads."""
+def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
+                       block: int, t: int, kinds=BD_KINDS,
+                       name: str = "block_diffusion_attention_bwd",
+                       interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v` under the schedule `steps`
+    (`scheduled_forward`); `dk`, `dv` summed over each key/value head's
+    group of query heads."""
     n, h, s, d = q.shape
     kv = k.shape[1]
     group = h // kv
     delta = jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32),
                     axis=-1).reshape(n, h, 1, s)
-    tables = _tables(l, t)
+    tables = _tables(steps, kinds)
     at_q = lambda n, c, g, i, qt, kt, what: (n, c * group + g, qt[i], 0)  # noqa: E731
     at_row = lambda n, c, g, i, qt, kt, what: (n, c * group + g, 0, qt[i])  # noqa: E731
     at_k = lambda n, c, g, i, qt, kt, what: (n, c, kt[i], 0)  # noqa: E731
     whole = lambda n, c, g, i, qt, kt, what: (n, c, 0, 0)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_bd_bwd_kernel, scale=scale, t=t, block=block),
+        functools.partial(_bd_bwd_kernel, scale=scale, t=t, block=block,
+                          kinds=kinds),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n, kv, group, tables[0].shape[0]),
@@ -557,8 +582,16 @@ def bd_backward(q, k, v, out, lse, d_out, *, scale: float, l: int, block: int,
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="block_diffusion_attention_bwd",
+        name=name,
     )(*tables, q, k, v, d_out, lse.reshape(n, h, 1, s), delta)
+
+
+def bd_backward(q, k, v, out, lse, d_out, *, scale: float, l: int, block: int,
+                t: int, interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v`; `dk`, `dv` summed over each
+    key/value head's group of query heads."""
+    return scheduled_backward(q, k, v, out, lse, d_out, schedule(l, t),
+                              scale=scale, block=block, t=t, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -594,3 +627,118 @@ def _bd_backward_rule(scale, l, block, t, otherwise, residuals, d_out):
 
 
 block_diffusion_attention.defvjp(_bd_forward_rule, _bd_backward_rule)
+
+
+# ============================================= causal over grouped heads
+#
+# Two more schedules of the pair above, over one copy of the sequence: `q
+# (N, H, S, D)` over `k, v (N, KV, S, D)` where query i sees key j iff
+#
+#     j <= i                        every key up to its own (`window` None)
+#     i - window < j <= i           the `window` keys that end with its own
+#
+# (nn/afmoe.py mixes layers of both in one model). A window is a schedule,
+# not a third pair of kernels: `causal_schedule` lists, for every query
+# tile, its own tile first (UPTO at blocks of 1: the causal rule, under
+# which every row sees its own key), then the tiles between, FULL, and —
+# where the window's far end falls `window / t` tiles back — that tile
+# under the one rule this mask adds, AFTER (a key strictly after the
+# query's offset in its own tile: the keys of that tile still inside the
+# window). A tile wholly outside the band is no grid step, forward or
+# backward, and a call dispatches over the kinds its own schedule holds
+# (two without a window, three with one).
+
+def causal_tile(s: int, window: Optional[int], width: int) -> Optional[int]:
+    """The square tile the kernels run `s` positions of heads `width` wide
+    at under a window of `window` keys (None: every key before), or None
+    where they do not take the shapes: the window's far end falls on a
+    tile's edge, and `dk` and `dv` of one (sequence, key/value head) fit
+    their buffers."""
+    if width % LANES or 2 * (s * width * 4) > DQ_BUFFER_BYTES:
+        return None
+    for t in (512, 256, 128):
+        if s % t == 0 and (window is None or (window > 0 and window % t == 0)):
+            return t
+    return None
+
+
+def causal_schedule(s: int, t: int, window: Optional[int] = None):
+    """[(query tile, key tile, kind)] of the `(s / t)^2` tiles that hold a
+    pair the mask allows, query-major; a query tile's first entry is its
+    own tile, where every row sees its own key."""
+    n = s // t
+    w = n if window is None else window // t
+    steps = []
+    for i in range(n):
+        steps.append((i, i, UPTO))
+        steps += [(i, j, FULL) for j in range(max(i - w + 1, 0), i)]
+        if i >= w:
+            steps.append((i, i - w, AFTER))
+    return steps
+
+
+def causal_tiles_visited(s: int, t: int, window: Optional[int] = None) -> int:
+    """Of the `(s / t)^2` tiles of one (sequence, head)'s score square,
+    those the kernels compute under this mask."""
+    return len(causal_schedule(s, t, window))
+
+
+def _causal_call(s: int, t: int, window: Optional[int]):
+    """What both directions hand the pair: the schedule, and as keywords
+    the tile, the mask's blocks of 1 and the kinds the schedule holds."""
+    steps = causal_schedule(s, t, window)
+    return steps, dict(
+        t=t, block=1, kinds=tuple(sorted({kind for _, _, kind in steps})))
+
+
+def gc_forward(q, k, v, *, scale: float, window: Optional[int], t: int,
+               interpret: bool = False):
+    """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32)."""
+    steps, call = _causal_call(q.shape[2], t, window)
+    return scheduled_forward(q, k, v, steps, scale=scale, interpret=interpret,
+                             name="grouped_causal_attention_fwd", **call)
+
+
+def gc_backward(q, k, v, out, lse, d_out, *, scale: float,
+                window: Optional[int], t: int, interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v`."""
+    steps, call = _causal_call(q.shape[2], t, window)
+    return scheduled_backward(q, k, v, out, lse, d_out, steps, scale=scale,
+                              interpret=interpret,
+                              name="grouped_causal_attention_bwd", **call)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def grouped_causal_attention(q, k, v, scale: float, window: Optional[int],
+                             t: int, otherwise: Callable):
+    """`out (N, H, S, D)` of head-major `q (N, H, S, D)`, `k, v (N, KV, S,
+    D)` whose shapes `causal_tile` accepted (`t`), each query over the
+    `window` keys that end with its own (None: every key up to its own):
+    the kernels where the program is lowered for a TPU, `otherwise(q, k,
+    v)` — the caller's plain-XLA form of the same mask — elsewhere.
+    Residuals as `causal_attention` names them."""
+    return _gc_forward_rule(q, k, v, scale, window, t, otherwise)[0]
+
+
+def _gc_forward_rule(q, k, v, scale, window, t, otherwise):
+    def plain(q, k, v):
+        return otherwise(q, k, v), jnp.zeros(q.shape[:3], jnp.float32)
+
+    out, lse = lax.platform_dependent(
+        q, k, v, default=plain,
+        tpu=functools.partial(gc_forward, scale=scale, window=window, t=t))
+    out = checkpoint_name(out, RESIDUAL_NAME)
+    lse = checkpoint_name(lse, RESIDUAL_NAME)
+    return out, (q, k, v, out, lse)
+
+
+def _gc_backward_rule(scale, window, t, otherwise, residuals, d_out):
+    def plain(q, k, v, out, lse, d_out):
+        return jax.vjp(otherwise, q, k, v)[1](d_out)
+
+    return lax.platform_dependent(
+        *residuals, d_out, default=plain,
+        tpu=functools.partial(gc_backward, scale=scale, window=window, t=t))
+
+
+grouped_causal_attention.defvjp(_gc_forward_rule, _gc_backward_rule)

@@ -485,3 +485,89 @@ def test_the_row_sum_kernel_compiles_at_the_cells_shapes(
     compiled = jax.jit(pallas_rowsum.sums).lower(
         shape(rows, 2048, dtype=jnp.bfloat16), weight, sched).compile()
     assert "moe_token_sums" in compiled.as_text()
+
+
+# -- a window is a schedule of the second attention kernel pair (PR 41)
+
+@pytest.mark.parametrize("window,visited", [(2048, 150), (None, 528)],
+                         ids=["window_2048", "full"])
+def test_the_grouped_causal_kernels_compile_at_the_cells_shapes(
+        topo, window, visited):
+    """Mosaic takes both directions at 16,384 positions of 32 query heads
+    over 4 key/value heads of 128 (`dk` and `dv` of one (sequence,
+    key/value head) fill their VMEM buffers exactly), with the window and
+    without; the grid's last axis is the schedule's length."""
+    from parallel_cnn_tpu.ops import pallas_attention as pa
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    s, h, kv, d = 16384, 32, 4, 128
+    t = pa.causal_tile(s, window, d)
+    assert t == 512 and pa.causal_tiles_visited(s, t, window) == visited
+    like = lambda heads, *rest, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, heads, s, *rest), dtype, sharding=one_chip)
+    q, k = like(h, d), like(kv, d)
+    kw = dict(scale=d ** -0.5, window=window, t=t)
+    fwd = jax.jit(lambda q, k, v: pa.gc_forward(q, k, v, **kw)).lower(
+        q, k, k).compile().as_text()
+    bwd = jax.jit(lambda *a: pa.gc_backward(*a, **kw)).lower(
+        q, k, k, q, like(h, dtype=jnp.float32), q).compile().as_text()
+    assert "grouped_causal_attention_fwd" in fwd
+    assert "grouped_causal_attention_bwd" in bwd
+    assert not re.search(r"\[16384,16384\]", fwd + bwd)
+
+
+_afmoe_step = {}
+
+
+def _afmoe_program(topo):
+    """Trinity-Mini's GSPMD train step at published widths, two layers (a
+    dense sliding one, a full one with experts), one sequence of 16,384
+    tokens, compiled for one described v5e: (the text, its catalog)."""
+    if not _afmoe_step:
+        from parallel_cnn_tpu.nn import afmoe
+        from parallel_cnn_tpu.obs import programs
+
+        model = afmoe.trinity_mini(
+            layer_types=[afmoe.SLIDING, afmoe.FULL], num_dense_layers=1,
+            vocab_size=25024, held_experts=range(16), row_buffer=32768,
+            gate_gradient=False)
+        optimizer = zoo.make_optimizer(lr=2e-4, kind="adamw", b1=0.9, b2=0.95,
+                                       weight_decay=0.1)
+        with jax.default_matmul_precision("default"):
+            text = _step_text(topo, model, optimizer, (16384,), 1, None, tokens=True)
+        _afmoe_step.update(text=text, catalog=programs.parse(text))
+    return _afmoe_step
+
+
+def test_the_window_and_full_kernels_carry_their_layers_scope_and_phase(topo):
+    """One forward and one backward kernel a core, each under its layer's
+    `attn/core` with its phase (`win_attn_core_device_ms` and
+    `full_attn_core_device_ms` read them by the layer's kind), the
+    rematerialised backward re-runs no forward kernel, and the scopes the
+    architecture adds are there to be read."""
+    catalog = _afmoe_program(topo)["catalog"]
+    for kernel, phase in (("grouped_causal_attention_fwd", "fwd"),
+                          ("grouped_causal_attention_bwd", "bwd")):
+        ran = sorted((e.scope, e.phase) for n, e in catalog.items()
+                     if n.startswith(kernel) and e.opcode == "custom-call")
+        assert ran == [("l0/attn/core", phase), ("l1/attn/core", phase)], kernel
+    assert not any(n.startswith(("causal_attention", "block_diffusion"))
+                   for n in catalog)
+    named = {e.scope for e in catalog.values()}
+    for scope in ("embed", "l0/attn/qk_norm", "l0/attn/rope", "l0/attn/post_norm",
+                  "l0/mlp/post_norm", "l1/attn/qk_norm", "l1/moe/route",
+                  "l1/moe/shared", "l1/moe/post_norm", "head", "loss"):
+        assert scope in named, scope
+    assert "l1/attn/rope" not in named  # a full layer carries no position
+
+
+def test_no_tile_of_either_kinds_scores_reaches_hbm(topo):
+    """Nothing `(N, 32, q, k)` or `(N, 4, 8, q, k)` with `k` of 512 keys or
+    more exists anywhere in the step (a head is 128 wide): the score
+    square, its window included, lives in VMEM a tile at a time."""
+    text = _afmoe_program(topo)["text"]
+    per_head = [(int(q), int(k)) for q, k in re.findall(
+        r"(?:f32|bf16|pred)\[\d+,(?:32|4|4,8),(\d+),(\d+)\]", text)]
+    assert (16384, 128) in per_head  # the pattern sees what is per head
+    assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
+    assert not re.search(r"\[16384,16384\]", text)
