@@ -62,7 +62,8 @@ from parallel_cnn_tpu.nn.glm_moe import (
     _norm,
     _ones,
 )
-from parallel_cnn_tpu.nn.layers import Embedding, GatedMLP, _weight, rope
+from parallel_cnn_tpu.nn.layers import (
+    Embedding, GatedMLP, _weight, rope, row_major)
 from parallel_cnn_tpu.ops import pallas_attention
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -132,6 +133,11 @@ class GatedGQA(Module):
         params["k_norm"] = _ones(wide)
         return params, {}, in_shape
 
+    @property
+    def rope_dim(self) -> int:
+        """RoPE turns all of a head's features (`MLA.rope_dim`)."""
+        return self.head_dim
+
     def core(self, s: int) -> Tuple[str, int]:
         """(`"fused"` | `"blocks"`, the tile's side or the queries a turn)
         for `s` positions: what the shapes allow (`MLA.core`)."""
@@ -177,6 +183,7 @@ class GatedGQA(Module):
                 jnp.einsum("nsm,mhd->nhsd", x, w[name].reshape(-1, heads, wide))
                 for name, heads in (("q", self.heads), ("k", self.kv_heads),
                                     ("v", self.kv_heads), ("gate", self.heads)))
+            q, k = row_major(q), row_major(k)
         with jax.named_scope("qk_norm"):
             q = _norm(self.eps, w["q_norm"], q)
             k = _norm(self.eps, w["k_norm"], k)

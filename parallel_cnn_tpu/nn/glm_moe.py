@@ -88,7 +88,7 @@ from parallel_cnn_tpu.nn.layers import (
     _weight,
     rope,
 )
-from parallel_cnn_tpu.ops import pallas_attention, pallas_rowsum
+from parallel_cnn_tpu.ops import pallas_attention, pallas_rope, pallas_rowsum
 
 INIT_STD = 0.02
 
@@ -766,16 +766,19 @@ class GlmMoe(Module):
         the platform of the devices that hold the state (`zoo.train`
         compiles its step for those; the program itself decides where it
         is lowered, and a step lowered for another platform than the
-        caller names here runs the other core). The attention tiles are
-        one core's, one (sequence, head)'s: the part of the score square
-        that is computed."""
+        caller names here runs the other core and the other turn). The
+        attention tiles are one core's, one (sequence, head)'s: the part
+        of the score square that is computed. `rope_turn` is `"kernel"`
+        where `layers.rope` runs ops/pallas_rope.py, else `"plain"`."""
         ex = self.experts
         assignments = tokens_per_step * ex.per_token
         kind, t = self.attn.core(seq_len)
+        turn = pallas_rope.tile(seq_len, self.attn.rope_dim)
         if platform != "tpu":
-            kind, t = "blocks", self.attn.q_block
+            kind, t, turn = "blocks", self.attn.q_block, None
         return dict(
             attention_core=kind,
+            rope_turn="plain" if turn is None else "kernel",
             attention_tiles_visited=pallas_attention.tiles_visited(seq_len, t),
             attention_tiles_total=(-(-seq_len // t)) ** 2,
             experts_held=len(ex.held), experts_published=ex.n_routed,

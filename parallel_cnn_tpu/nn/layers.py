@@ -16,8 +16,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from parallel_cnn_tpu.nn.core import Module, Shape
+from parallel_cnn_tpu.ops import pallas_rope
 
 
 def _he_normal(key, shape, fan_in, dtype):
@@ -410,16 +412,34 @@ class GatedMLP(Module):
         return (jax.nn.silu(x @ gate) * (x @ up)) @ down, state
 
 
+def row_major(x):
+    """``x`` with its layout in memory pinned to the order of its axes,
+    the last one minor. A projection's output that a kernel reads next
+    (`rope`'s, the attention cores') is then written that way by the
+    matmul itself: left to choose, the TPU's compiler laid ``q (N, H, S,
+    D)`` out position-minor for the norm in between and re-laid all of it
+    out ahead of the kernel, forward, rematerialised forward and backward
+    (PERF.md section 6, PR 42). The cotangent is pinned the same way."""
+    return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def rope(x, theta: float):
     """Rotary position embedding (Su et al. 2021) of ``x`` ``(..., S, d)``
     at positions 0..S-1 along the axis before the last: feature ``i`` is
     paired with ``i + d/2`` (the "rotate half" convention) and the pair
     turned by ``position * theta ** (-2i / d)``. Angles and the turn are
-    float32, the result is cast back to ``x.dtype``."""
+    float32, the result is cast back to ``x.dtype``. Shapes that
+    ``pallas_rope.tile`` takes are turned by its kernel where the program
+    is lowered for a TPU, and by the plain body below everywhere else."""
+    if pallas_rope.tile(*x.shape[-2:]) is None:
+        return _rope(x, theta)
+    return pallas_rope.turn(x, theta, _rope)
+
+
+def _rope(x, theta: float):
+    """`rope`'s plain body: two halves turned and joined, in plain XLA."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = jnp.arange(x.shape[-2], dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    cos, sin = pallas_rope.cos_sin(x.shape[-2], d, theta)
     xf = x.astype(jnp.float32)
     a, b = xf[..., : d // 2], xf[..., d // 2:]
     return jnp.concatenate(

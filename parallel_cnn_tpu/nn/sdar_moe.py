@@ -65,7 +65,7 @@ from parallel_cnn_tpu.nn.glm_moe import (
     _norm,
     _ones,
 )
-from parallel_cnn_tpu.nn.layers import _weight, rope
+from parallel_cnn_tpu.nn.layers import _weight, rope, row_major
 from parallel_cnn_tpu.ops import pallas_attention
 
 NOISE_EPS = 1e-3
@@ -128,6 +128,11 @@ class GQA(Module):
         params["k_norm"] = _ones(wide)
         return params, {}, in_shape
 
+    @property
+    def rope_dim(self) -> int:
+        """RoPE turns all of a head's features (`MLA.rope_dim`)."""
+        return self.head_dim
+
     def core(self, l: int) -> Tuple[str, int]:
         """(`"fused"` | `"blocks"`, the tile's side) for a stream of `2 l`
         positions: what the shapes allow (`MLA.core`)."""
@@ -172,6 +177,7 @@ class GQA(Module):
                 jnp.einsum("nsm,mhd->nhsd", x, w[name].reshape(-1, heads, wide))
                 for name, heads in (("q", self.heads), ("k", self.kv_heads),
                                     ("v", self.kv_heads)))
+            q, k = row_major(q), row_major(k)
         with jax.named_scope("qk_norm"):
             q = _norm(self.eps, w["q_norm"], q)
             k = _norm(self.eps, w["k_norm"], k)

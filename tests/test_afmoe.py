@@ -481,6 +481,7 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     assert event["attention_layer_kinds"] == KINDS
     assert (event["attention_core"], event["attention_window"],
             event["attention_tile"]) == ("blocks", WINDOW, 8)
+    assert event["rope_turn"] == "plain"  # a CPU, and a toy head besides
     # 32 positions in turns of 8 queries, on the CPU: a turn of a full layer
     # reads up to its end (1 + 2 + 3 + 4 tiles), a window of 8 two tiles but
     # the first turn's one

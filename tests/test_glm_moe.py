@@ -657,13 +657,14 @@ def test_the_published_model_has_the_counted_parameters():
                expert_layers=5, row_buffer=16384, tokens_per_step=16384)
     # 4,096 positions of 256-wide heads tile at 512: the kernels visit the
     # 36 tiles at or below the diagonal of 64; off the chip the 512-query
-    # blocks compute as many
+    # blocks compute as many; RoPE's 64 features are half a register, so the
+    # plain body turns them on either platform
     assert model.describe(4 * 4096, 4096, "tpu") == dict(
         moe, attention_core="fused", attention_tiles_visited=36,
-        attention_tiles_total=64)
+        attention_tiles_total=64, rope_turn="plain")
     assert model.describe(4 * 4096, 4096, "cpu") == dict(
         moe, attention_core="blocks", attention_tiles_visited=36,
-        attention_tiles_total=64)
+        attention_tiles_total=64, rope_turn="plain")
     whole = glm_moe.glm_4_7_flash()
     assert (whole.n_layers, whole.vocab, len(whole.experts.held)) == (47, 154880, 64)
     with pytest.raises(ValueError, match="distinct ids"):
@@ -744,6 +745,7 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     # 16 positions in blocks of 8 queries, on the CPU: 3 of 4 tiles
     assert (event["attention_core"], event["attention_tiles_visited"],
             event["attention_tiles_total"]) == ("blocks", 3, 4)
+    assert event["rope_turn"] == "plain"  # a CPU, and a toy head besides
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads_through_rematerialisation():

@@ -463,8 +463,11 @@ def test_the_published_model_has_the_counted_parameters():
                 block_length=4, experts_held=16, experts_published=128,
                 experts_per_token=8, expert_layers=6, row_buffer=65536,
                 tokens_per_step=16384, stream_rows_per_step=32768)
-    assert model.describe(4 * 4096, 4096, "tpu") == dict(said, attention_core="fused")
-    assert model.describe(4 * 4096, 4096, "cpu") == dict(said, attention_core="blocks")
+    # each half's 4,096 positions of 128-wide heads tile: the kernel turns them
+    assert model.describe(4 * 4096, 4096, "tpu") == dict(
+        said, attention_core="fused", rope_turn="kernel")
+    assert model.describe(4 * 4096, 4096, "cpu") == dict(
+        said, attention_core="blocks", rope_turn="plain")
     assert 80 * 512 * 512 / (4096 * 4100) == pytest.approx(1.249, abs=1e-3)
     whole = sdar_moe.sdar_30b_a3b()
     assert (whole.n_layers, whole.vocab, len(whole.experts.held)) == (48, 151936, 128)
@@ -557,6 +560,7 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     assert (event["attention_core"], event["attention_tiles_visited"],
             event["attention_tiles_total"], event["attention_pairs_allowed"],
             event["block_length"]) == ("blocks", 8, 16, L * (L + B), B)
+    assert event["rope_turn"] == "plain"  # a CPU, and a toy head besides
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads():

@@ -571,3 +571,96 @@ def test_no_tile_of_either_kinds_scores_reaches_hbm(topo):
     assert (16384, 128) in per_head  # the pattern sees what is per head
     assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
     assert not re.search(r"\[16384,16384\]", text)
+
+
+# ------------------------------ RoPE's turn is one kernel a direction (PR 42)
+
+def _attention_layer(topo, att, n, s, width=2048):
+    """An attention layer's forward and backward as a decoder layer runs
+    them (rematerialised; the cotangent an input), compiled for one
+    described v5e: its text."""
+    one = SingleDeviceSharding(topo.devices[0])
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)  # noqa: E731
+    params = jax.tree_util.tree_map(like, jax.eval_shape(
+        lambda k: att.init(k, (s, width))[0], jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((n, s, width), jnp.bfloat16, sharding=one)
+
+    def layer(params, x, cotangent):
+        # the scopes of a step, for the catalog: grad/l0/attn
+        with jax.named_scope("grad"), jax.named_scope("l0"), jax.named_scope("attn"):
+            out, vjp = jax.vjp(jax.checkpoint(
+                lambda p, x: att.apply(p, {}, x, True)[0]), params, x)
+            return out, vjp(cotangent)
+
+    with jax.default_matmul_precision("default"):
+        return jax.jit(layer).lower(params, x, x).compile().as_text()
+
+
+def test_sdars_attention_turns_q_and_k_in_one_kernel_a_pass_and_nothing_float32(topo):
+    """`GQA` at `sdar_bd_train`'s shape (4 sequences, a stream of 8,192):
+    q and k are each turned by one `rope_turn` kernel in the forward, the
+    rematerialised forward and the backward, under the `rope` scope; no
+    instruction of the entry has a float32 result of `q`'s size (the plain
+    body's `f32[4,32,8192,128]` re-layout and its 64-wide halves are
+    gone), and nothing of `q`'s size is copied at all: the projection
+    writes `q` row-major (`layers.row_major`), where the kernels read it."""
+    from parallel_cnn_tpu.nn import sdar_moe
+    from parallel_cnn_tpu.obs import programs
+
+    text = _attention_layer(topo, sdar_moe.GQA(), 4, 8192)
+    catalog = programs.parse(text)
+    turns = sorted((e.scope, e.phase) for n, e in catalog.items()
+                   if n.startswith("rope_turn") and e.opcode == "custom-call")
+    assert turns == [("l0/attn/rope", "bwd")] * 4 + [("l0/attn/rope", "fwd")] * 2
+    q_size = 4 * 32 * 8192 * 128
+    instructions = _instructions(text.split("ENTRY")[1])
+    wide = [(name, ins.opcode, ins.result) for name, ins in instructions.items()
+            for dtype, dims in ins.result
+            if dtype == "f32" and dims and np.prod(
+                [int(d) for d in dims.split(",")]) >= q_size]
+    assert wide == []
+    assert not re.search(r"\[4,32,2,4096,64\]", text)  # no 64-wide halves
+    copies = [(name, ins.result) for name, ins in instructions.items()
+              if ins.opcode in ("copy", "transpose") and any(
+                  dims and np.prod([int(d) for d in dims.split(",")]) >= q_size
+                  for _, dims in ins.result)]
+    assert copies == []
+
+
+def test_glms_attention_compiles_to_what_the_plain_body_compiles_to(topo, monkeypatch):
+    """`MLA`'s 64-wide turn is none of the kernel's shapes: the layer
+    compiled through `rope` is, instruction for instruction, the layer
+    compiled with the plain body in `rope`'s place (the parent's)."""
+    from parallel_cnn_tpu.nn import glm_moe, layers
+
+    def program():
+        """Every instruction of every computation: result, opcode and
+        operands by name (what is left out points into the source: the
+        metadata, and the locations inside the attention kernels' payload)."""
+        text = _attention_layer(topo, glm_moe.MLA(), 4, 4096)
+        return text, {name: ins[:3] for name, ins in _instructions(text).items()}
+
+    text, through_rope = program()
+    assert "rope_turn" not in text and "f32[4,20,4096,64]" in text
+    assert len(through_rope) > 1000
+    monkeypatch.setattr(glm_moe, "rope", layers._rope)
+    assert program()[1] == through_rope
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 32, 2, 4096, 128), (4, 4, 2, 4096, 128), (1, 32, 16384, 128),
+    (1, 4, 16384, 128), (1, 32, 2, 4096, 128)],
+    ids=["sdar_q", "sdar_k", "trinity_q", "trinity_k", "sdar_check_q"])
+def test_the_rope_kernel_compiles_at_the_cells_shapes(topo, shape):
+    """Mosaic takes the kernel of ops/pallas_rope.py at both cells' shapes
+    (and at the check's one sequence), both directions, and the program
+    around it keeps nothing: the tables are its only other arrays."""
+    from parallel_cnn_tpu.ops import pallas_rope
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    assert pallas_rope.tile(*shape[-2:]) == 512
+    for back in (False, True):
+        compiled = pallas_rope.rotate.lower(x, theta=1e6, back=back).compile()
+        assert pallas_rope.NAME in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes <= 2 * shape[-2] * 128 * 4
