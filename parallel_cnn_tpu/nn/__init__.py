@@ -36,6 +36,7 @@ from parallel_cnn_tpu.nn.layers import (  # noqa: F401
 )
 from parallel_cnn_tpu.nn import (  # noqa: F401
     afmoe,
+    bailing_hybrid,
     cifar,
     convnext,
     glm_moe,

@@ -86,6 +86,7 @@ from parallel_cnn_tpu.nn.layers import (
     GatedMLP,
     RMSNorm,
     _weight,
+    gated_unit,
     rope,
 )
 from parallel_cnn_tpu.ops import pallas_attention, pallas_rope, pallas_rowsum
@@ -100,6 +101,13 @@ def _ones(n: int):
 
 def _norm(eps, scale, x):
     return RMSNorm(eps).apply({"scale": scale}, {}, x)[0]
+
+
+def _head_gated(out, gate):
+    """`out (N, H, S, D)` times the sigmoid (float32) of `gate (N, H, S)`,
+    a gate a head."""
+    return out * jax.nn.sigmoid(
+        gate.astype(jnp.float32)).astype(out.dtype)[..., None]
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
@@ -123,10 +131,20 @@ class MLA(Module):
     concern). Causal; the scores of a whole sequence never exist at once
     and the upper triangle is not computed: a tile at a time inside the
     fused kernels where `core` says so, else a block of `q_block` queries
-    at a time against the keys up to the block's end."""
+    at a time against the keys up to the block's end.
+
+    What a factory may choose beside the widths: `q_rank=None`, no query
+    latent (one projection `q` and no norm on the way); `gated`, the
+    core's output times the sigmoid of one more projection of the layer's
+    input, a gate a head, before `o`; `interleaved`, RoPE's pairs are the
+    ADJACENT features (2i, 2i + 1) of `q_pe` and `k_pe` — turned as
+    `rope` turns its halves, over the even features then the odd ones: a
+    score is a sum over features, so the same order on both sides leaves
+    every one of them as it is, and the order is taken from the weights'
+    columns, not from the activations."""
 
     heads: int = 20
-    q_rank: int = 768
+    q_rank: Optional[int] = 768
     kv_rank: int = 512
     nope: int = 192
     rope_dim: int = 64
@@ -134,31 +152,55 @@ class MLA(Module):
     theta: float = 1e6
     eps: float = 1e-5
     q_block: int = 512
+    gated: bool = False
+    interleaved: bool = False
 
     def init(self, key, in_shape: Shape):
         d, h = in_shape[-1], self.heads
-        shapes = {
-            "q_a": (d, self.q_rank),
-            "q_b": (self.q_rank, h * (self.nope + self.rope_dim)),
+        wide = h * (self.nope + self.rope_dim)
+        shapes = {"q": (d, wide)} if self.q_rank is None else {
+            "q_a": (d, self.q_rank), "q_b": (self.q_rank, wide)}
+        shapes.update({
             "kv_a": (d, self.kv_rank + self.rope_dim),
             "kv_b": (self.kv_rank, h * (self.nope + self.v_dim)),
             "o": (h * self.v_dim, d),
-        }
-        params = {
-            n: _weight(k, s, s[0], INIT_STD)
-            for (n, s), k in zip(shapes.items(), jax.random.split(key, 5))
-        }
-        params["q_norm"] = _ones(self.q_rank)
+        })
+        if self.gated:
+            shapes["gate"] = (d, h)
+        # (five keys whatever is asked: a leaf's draw is its place among them)
+        keys = jax.random.split(key, max(len(shapes), 5))
+        params = {n: _weight(k, s, s[0], INIT_STD)
+                  for (n, s), k in zip(shapes.items(), keys)}
+        if self.q_rank is not None:
+            params["q_norm"] = _ones(self.q_rank)
         params["kv_norm"] = _ones(self.kv_rank)
         return params, {}, in_shape
+
+    @property
+    def qk_width(self) -> int:
+        """A head's `q` and `k` as the core is handed them: `nope +
+        rope_dim`, and zero columns up to whole 128-lane registers where
+        that is none (192 -> 256: a zero column adds nothing to a score)."""
+        lanes = pallas_attention.LANES
+        return -(-(self.nope + self.rope_dim) // lanes) * lanes
 
     def core(self, s: int) -> Tuple[str, int]:
         """(`"fused"` | `"blocks"`, the tile's side) for `s` positions: what
         the shapes allow. The fused kernels run where the step is lowered
         for a TPU (ops/pallas_attention.py); elsewhere the same shapes run
         the blocks."""
-        t = pallas_attention.tile(s, self.nope + self.rope_dim, self.v_dim)
+        t = pallas_attention.tile(s, self.qk_width, self.v_dim)
         return ("blocks", self.q_block) if t is None else ("fused", t)
+
+    def _pe_order(self, w):
+        """The last axis' `rope_dim` features of a weight in the order
+        `rope` pairs them (`interleaved`: even ones, then odd ones)."""
+        if not self.interleaved:
+            return w
+        r = self.rope_dim
+        order = jnp.concatenate([jnp.arange(0, r, 2), jnp.arange(1, r, 2)])
+        return jnp.concatenate(
+            [w[..., :-r], jnp.take(w[..., -r:], order, axis=-1)], axis=-1)
 
     def _blocks(self, q, k, v):
         """(N, heads, S, ·) in and out: `q_block` queries at a time."""
@@ -177,11 +219,17 @@ class MLA(Module):
         n, s, _ = x.shape
         h, nope = self.heads, self.nope
         with jax.named_scope("q"):
-            q = jnp.einsum(
-                "nsr,rhd->nhsd", _norm(self.eps, w["q_norm"], x @ w["q_a"]),
-                w["q_b"].reshape(self.q_rank, h, nope + self.rope_dim))
+            if self.q_rank is None:
+                q = jnp.einsum("nsm,mhd->nhsd", x, self._pe_order(
+                    w["q"].reshape(-1, h, nope + self.rope_dim)))
+            else:
+                q = jnp.einsum(
+                    "nsr,rhd->nhsd",
+                    _norm(self.eps, w["q_norm"], x @ w["q_a"]),
+                    self._pe_order(w["q_b"].reshape(
+                        self.q_rank, h, nope + self.rope_dim)))
         with jax.named_scope("kv"):
-            ckv = x @ w["kv_a"]
+            ckv = x @ self._pe_order(w["kv_a"])
             k_pe = ckv[:, None, :, self.kv_rank:]
             c = _norm(self.eps, w["kv_norm"], ckv[..., : self.kv_rank])
             kv_b = w["kv_b"].reshape(self.kv_rank, h, nope + self.v_dim)
@@ -199,10 +247,17 @@ class MLA(Module):
             # log-sum-exp): recomputing it is a third pass over the scores.
             kind, t = self.core(s)
             if kind == "fused":
+                spare = self.qk_width - (nope + self.rope_dim)
+                if spare:
+                    q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, spare),))
+                            for a in (q, k))
                 out = pallas_attention.causal_attention(
                     q, k, v, (nope + self.rope_dim) ** -0.5, t, self._blocks)
             else:
                 out = checkpoint_name(self._blocks(q, k, v), "attn_core")
+        if self.gated:
+            with jax.named_scope("gate"):
+                out = _head_gated(out, jnp.einsum("nsm,mh->nhs", x, w["gate"]))
         with jax.named_scope("o"):
             return jnp.einsum("nhsd,hdm->nsm", out,
                               w["o"].reshape(h, self.v_dim, -1)), state
@@ -364,8 +419,14 @@ class ExpertLayer(Module):
     `"softmax"` over all `n_routed`; either in float32, the gates the
     chosen scores over their sum times `scaling`), `n_shared` (0: no
     shared expert, and no leaf for one), `bias_step` (0: the selection
-    bias stays where it is). The balance term is `sum_i f_i P_i` a
-    sequence either way — under softmax scores `P` is the scores' mean.
+    bias stays where it is), `n_group` / `topk_group` (the selection's
+    group limit, DeepSeek-V3's `noaux_tc`: the `n_routed` scores in
+    `n_group` runs of neighbours, a group scored by the sum of its two
+    largest `s + b`, only the `topk_group` best groups' experts can be
+    chosen; 1 / 1: no limit, and no op for one), `limit` / `shared_limit`
+    (`layers.gated_unit`'s clamp in the routed experts and in the shared
+    one; 0: none). The balance term is `sum_i f_i P_i` a sequence either
+    way — under softmax scores `P` is the scores' mean.
 
     `gate_grad=False` is for a share that trains: a gate's gradient is
     `<dL/dy, E_i(x)>`, and a share has that product for the experts it
@@ -387,10 +448,22 @@ class ExpertLayer(Module):
     balance: float = 1e-4
     gate_grad: bool = True
     scoring: str = "sigmoid"
+    n_group: int = 1
+    topk_group: int = 1
+    limit: float = 0.0
+    shared_limit: float = 0.0
 
     def __post_init__(self):
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring {self.scoring!r}: sigmoid or softmax")
+        if (self.n_routed % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.topk_group * (self.n_routed // self.n_group)
+                < self.per_token):
+            raise ValueError(
+                f"{self.topk_group} of {self.n_group} groups over "
+                f"{self.n_routed} experts, {self.per_token} a token: the "
+                f"groups divide the experts and those kept hold a token's")
         held = tuple(self.held)
         if (not held or len(set(held)) != len(held)
                 or min(held) < 0 or max(held) >= self.n_routed):
@@ -400,7 +473,18 @@ class ExpertLayer(Module):
         object.__setattr__(self, "held", held)
 
     def _shared(self) -> GatedMLP:
-        return GatedMLP(self.n_shared * self.width, INIT_STD)
+        return GatedMLP(self.n_shared * self.width, INIT_STD,
+                        self.shared_limit)
+
+    def _in_kept_groups(self, biased):
+        """`biased (T, n_routed)` with the experts of every group but the
+        `topk_group` best (by the sum of a group's two largest) at -inf."""
+        t = biased.shape[0]
+        groups = biased.reshape(t, self.n_group, -1)
+        best = lax.top_k(groups, min(2, groups.shape[-1]))[0].sum(axis=-1)
+        _, kept = lax.top_k(best, self.topk_group)
+        keep = jax.nn.one_hot(kept, self.n_group, dtype=jnp.bool_).any(axis=1)
+        return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(t, -1)
 
     def init(self, key, in_shape: Shape):
         d, f = in_shape[-1], self.width
@@ -441,7 +525,10 @@ class ExpertLayer(Module):
                  else functools.partial(jax.nn.softmax, axis=-1))
         s = score(jnp.dot(
             xt.astype(jnp.float32), router, precision=lax.Precision.HIGHEST))
-        _, ids = lax.top_k(s + bias, k)
+        biased = s + bias
+        if self.n_group > 1:
+            biased = self._in_kept_groups(biased)
+        _, ids = lax.top_k(biased, k)
         ids = _planned(ids)
         chosen = jnp.take_along_axis(s, ids, axis=1)
         gates = self.scaling * chosen / jnp.sum(chosen, axis=1, keepdims=True)
@@ -511,8 +598,9 @@ class ExpertLayer(Module):
         with jax.named_scope("experts"):
             w = {m: params["experts"][m].astype(x.dtype)
                  for m in ("gate", "up", "down")}
-            hidden = (jax.nn.silu(lax.ragged_dot(xs, w["gate"], plan.sizes))
-                      * lax.ragged_dot(xs, w["up"], plan.sizes))
+            hidden = gated_unit(
+                lax.ragged_dot(xs, w["gate"], plan.sizes),
+                lambda: lax.ragged_dot(xs, w["up"], plan.sizes), self.limit)
             ys = lax.ragged_dot(hidden, w["down"], plan.sizes)
         with jax.named_scope("combine"):
             y = _combine(ys, gates.astype(x.dtype), plan, self.gate_grad)

@@ -388,13 +388,25 @@ class Embedding(Module):
         return params["w"][x].astype(self.dtype), state
 
 
+def gated_unit(a, up, limit: float = 0.0):
+    """A gated MLP's inside, ``silu(a) * up()``; with a ``limit`` L > 0,
+    ``silu(min(a, L)) * clip(up(), -L, L)`` (0: no limit, and no op for
+    one). ``up`` is called after the gate's activation: the order the
+    layers' lowered text has had since before the clamp came."""
+    if limit > 0:
+        return jax.nn.silu(jnp.minimum(a, limit)) * jnp.clip(up(), -limit, limit)
+    return jax.nn.silu(a) * up()
+
+
 @dataclasses.dataclass(frozen=True)
 class GatedMLP(Module):
     """``down(silu(gate x) * up x)`` over the last axis (Shazeer 2020,
-    "GLU variants"), ``width`` wide inside, no biases."""
+    "GLU variants"), ``width`` wide inside, no biases; ``limit`` clamps the
+    inside (`gated_unit`)."""
 
     width: int
     init_std: Optional[float] = None
+    limit: float = 0.0
 
     def init(self, key, in_shape: Shape):
         d, f = in_shape[-1], self.width
@@ -409,7 +421,25 @@ class GatedMLP(Module):
     def apply(self, params, state, x, train: bool = False):
         gate, up, down = (
             params[n].astype(x.dtype) for n in ("gate", "up", "down"))
-        return (jax.nn.silu(x @ gate) * (x @ up)) @ down, state
+        return gated_unit(x @ gate, lambda: x @ up, self.limit) @ down, state
+
+
+def causal_conv(x, taps):
+    """Causal depthwise 1-D convolution along the axis before the last:
+    ``y[..., t, c] = sum_i taps[i, ..., c] * x[..., t - (K - 1) + i, c]``
+    with ``x`` read as 0 before position 0, for ``taps (K, ..., c)`` whose
+    middle axes broadcast against ``x``'s leading ones (a tap a channel,
+    no bias). ``K`` shifted copies and multiply-adds in float32, rounded
+    once: at a handful of taps that is all a ``lax.conv`` would do."""
+    k, s = taps.shape[0], x.shape[-2]
+    xf = x.astype(jnp.float32)
+    pad = [(0, 0)] * x.ndim
+    pad[-2] = (k - 1, 0)
+    back = jnp.pad(xf, pad)
+    y = sum(taps[i].astype(jnp.float32)[..., None, :]
+            * lax.slice_in_dim(back, i, i + s, axis=x.ndim - 2)
+            for i in range(k))
+    return y.astype(x.dtype)
 
 
 def row_major(x):

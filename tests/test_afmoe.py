@@ -521,3 +521,27 @@ def test_the_scopes_are_the_ones_the_catalog_reads():
     assert of("jit(step)/grad/transpose(jvp(l4))/grad/jvp(l4)/checkpoint/attn/core/"
               "cond/branch_0_fun/grouped_causal_attention_bwd/pallas_call") == (
         "l4/attn/core", "bwd")
+
+
+# --------------------------- what was there lowers to the program it was
+
+# sha256 of this file's own toy step (`build()`, four sequences of S tokens),
+# `make_train_step(...).lower(...).as_text()` as tests/test_sdar_moe.py pins
+# GLM's: pinned by PR 43, whose new family added arguments to `ExpertLayer`
+# and `GatedMLP` (a group limit, clamps) and a head-wise gate beside this
+# model's; read on PR 43's parent and on its change, the same text. Moves
+# with the expert layer, the grouped attention, the sandwich norms or the
+# loss.
+AFMOE_LOWERED = "6e2b82a8794d67b5bf77c73de5391244bcb828978bcd6b96e1ea31a3831463e4"
+
+
+def test_the_lowered_step_of_this_model_is_unchanged():
+    import hashlib
+
+    model, _ = build()
+    opt = zoo.make_optimizer(**HYPER)
+    state = jax.eval_shape(lambda k: zoo.init_state(model, k, (S,), opt),
+                           jax.random.key(0))
+    x = jax.ShapeDtypeStruct((4, S), jnp.int32)
+    text = zoo.make_train_step(model, opt, 1, None).lower(state, x, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == AFMOE_LOWERED

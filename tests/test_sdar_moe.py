@@ -625,3 +625,22 @@ def test_the_lowered_step_of_the_glm_model_is_unchanged():
     x = jax.ShapeDtypeStruct((4, 16), jnp.int32)
     text = zoo.make_train_step(model, opt, 1, None).lower(state, x, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == GLM_LOWERED
+
+
+# sha256 of this file's own toy step (`build()`, four sequences of L tokens),
+# lowered the same way: pinned by PR 43, whose new family added arguments to
+# `ExpertLayer` (a group limit, two clamps) and put `layers.gated_unit`
+# into the experts block that this model runs; read on PR 43's parent and
+# on its change, the same text. Moves with the expert layer, the grouped
+# attention, the noise or the loss, as GLM_LOWERED does with its own.
+SDAR_LOWERED = "9bb46a24da9804125f52158f24c6ef2cacd2fab84b265ad3b86d2a3e61f899a1"
+
+
+def test_the_lowered_step_of_this_model_is_unchanged():
+    model, _ = build()
+    opt = zoo.make_optimizer(**HYPER)
+    state = jax.eval_shape(lambda k: zoo.init_state(model, k, (L,), opt),
+                           jax.random.key(0))
+    x = jax.ShapeDtypeStruct((4, L), jnp.int32)
+    text = zoo.make_train_step(model, opt, 1, None).lower(state, x, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == SDAR_LOWERED

@@ -664,3 +664,90 @@ def test_the_rope_kernel_compiles_at_the_cells_shapes(topo, shape):
         compiled = pallas_rope.rotate.lower(x, theta=1e6, back=back).compile()
         assert pallas_rope.NAME in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes <= 2 * shape[-2] * 128 * 4
+
+
+# -- the delta rule's chunked scan, and a linear and a full layer's step (PR 43)
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_chunked_scan_compiles_at_the_cells_shapes(topo, direction):
+    """XLA takes the scan at 8,192 positions of 32 heads of 128, forward
+    and backward: 32 steps of 4 chunks; what the backward keeps beside its
+    inputs and outputs is the span-start states and one span's tables, no
+    state a chunk (268 MB) and none a position."""
+    from parallel_cnn_tpu.ops import kda
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    n, h, s, d = 1, 32, 8192, 128
+    like = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    q, g, beta = like(n, h, s, d), like(n, h, s, d, dtype=jnp.float32), like(
+        n, h, s, dtype=jnp.float32)
+    assert kda.spans(s) == (32, 4) and kda.state_bytes(s, h, d, d) == 64 << 20
+    fn = kda.chunked_kda
+    if direction == "bwd":
+        fn = jax.grad(lambda *a: jnp.sum(kda.chunked_kda(*a).astype(jnp.float32)),
+                      argnums=(0, 1, 2, 3, 4))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(q, q, q, g, beta).compile()
+    text = compiled.as_text()
+    assert "while" in text
+    # no state a position, and none a chunk
+    assert not re.search(r"f32\[8192,1,32,128,128\]|f32\[128,1,32,128,128\]", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (600 << 20 if direction == "fwd" else 1536 << 20), temp
+
+
+_bailing_step = {}
+
+
+def _bailing_program(topo):
+    """Ling-3.0-flash's GSPMD train step at published widths, two layers (a
+    dense linear one, a full one with experts), one sequence of 8,192
+    tokens, compiled for one described v5e: (the text, its catalog)."""
+    if not _bailing_step:
+        from parallel_cnn_tpu.nn import bailing_hybrid as bh
+        from parallel_cnn_tpu.obs import programs
+
+        model = bh.ling_3_0_flash(
+            layer_types=[bh.LINEAR, bh.FULL], num_dense_layers=1,
+            vocab_size=19648, held_experts=range(8), row_buffer=8192,
+            gate_gradient=False)
+        optimizer = zoo.make_optimizer(lr=2e-4, kind="adamw", b1=0.9, b2=0.95,
+                                       weight_decay=0.1)
+        with jax.default_matmul_precision("default"):
+            text = _step_text(topo, model, optimizer, (8192,), 1, None, tokens=True)
+        _bailing_step.update(text=text, catalog=programs.parse(text))
+    return _bailing_step
+
+
+def test_the_linear_and_full_layers_carry_their_scopes_and_the_core_is_fused(topo):
+    """The full layer's core is the causal kernel pair at 192 carried as
+    256 (one forward and one backward kernel under `l1/attn/core`, the
+    rematerialised backward re-runs no forward kernel); the linear layer's
+    scan, conv, gates and gate_norm are scopes the readers find, forward
+    and backward."""
+    catalog = _bailing_program(topo)["catalog"]
+    for kernel, phase in (("causal_attention_fwd", "fwd"),
+                          ("causal_attention_bwd", "bwd")):
+        ran = sorted((e.scope, e.phase) for n, e in catalog.items()
+                     if n.startswith(kernel) and e.opcode == "custom-call")
+        assert ran == [("l1/attn/core", phase)], (kernel, ran)
+    by_scope = collections.defaultdict(set)
+    for e in catalog.values():
+        by_scope[e.scope].add(e.phase)
+    for scope in ("l0/attn/qkv", "l0/attn/conv", "l0/attn/gates", "l0/attn/core",
+                  "l0/attn/gate_norm", "l0/attn/o", "l1/attn/q", "l1/attn/kv",
+                  "l1/attn/rope", "l1/attn/core", "l1/attn/gate", "l1/moe/route",
+                  "l1/moe/experts", "l1/moe/shared"):
+        assert {"fwd", "bwd"} <= by_scope[scope], (scope, by_scope[scope])
+    assert "l0/attn/rope" not in by_scope  # a linear layer carries no position
+
+
+def test_no_score_square_and_no_state_a_position_reaches_hbm(topo):
+    text = _bailing_program(topo)["text"]
+    assert not re.search(r"\[8192,8192\]", text)
+    per_head = [(int(q), int(k)) for q, k in re.findall(
+        r"(?:f32|bf16|pred)\[\d+,32,(\d+),(\d+)\]", text)]
+    assert (8192, 256) in per_head  # q and k, 192 carried as 256
+    assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
+    assert not re.search(r"f32\[8192,1,32,128,128\]", text)
