@@ -209,7 +209,9 @@ class BailingHybrid(GlmMoe):
                  platform: str) -> Dict[str, object]:
         """`GlmMoe.describe` (whose attention core, tile and tiles are the
         full layers') and the linear layers': the scan's chunk, sub-chunk
-        and the bytes of the states one sequence's backward keeps."""
+        and the bytes of the states one sequence's backward keeps, and
+        what runs it where the step is lowered for `platform` (`kda_core`:
+        `"pallas"` | `"xla"`, as `attention_core` says for the full layers)."""
         said = super().describe(tokens_per_step, seq_len, platform)
         lin = self.linear
         steps, span = kda.spans(seq_len)
@@ -220,6 +222,7 @@ class BailingHybrid(GlmMoe):
             attention_qk_width=self.attn.qk_width,
             kda_chunk=kda.CHUNK, kda_subchunk=kda.SUBCHUNK,
             kda_scan_steps=steps, kda_chunks_a_step=span,
+            kda_core=kda.core(seq_len, lin.head_dim, lin.head_dim, platform),
             kda_state_bytes=kda.state_bytes(
                 seq_len, lin.heads, lin.head_dim, lin.head_dim))
         return said

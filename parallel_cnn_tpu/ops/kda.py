@@ -1,7 +1,9 @@
 """The delta rule with a channel-wise decay (KDA: Kimi Linear
 arXiv:2510.26692 section 3; the chunked WY form is Gated DeltaNet's,
-arXiv:2412.06464) as a chunked scan in plain XLA. For head-major `q, k
-(N, H, S, Dk)`, `v (N, H, S, Dv)`, a log-decay `g (N, H, S, Dk)` <= 0 in
+arXiv:2412.06464) as a chunked scan: this module's plain XLA body
+anywhere, ops/pallas_kda.py's two kernels where the shapes tile and the
+program is lowered for a TPU ("Which body runs", below). For head-major
+`q, k (N, H, S, Dk)`, `v (N, H, S, Dv)`, a log-decay `g (N, H, S, Dk)` <= 0 in
 float32 and `beta (N, H, S)`, every (sequence, head) runs
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
@@ -42,18 +44,33 @@ and as many products, all matmuls, float32 at the highest precision (a
 rounding in `T` reaches every later position of the chunk).
 
 **The backward** is a `jax.custom_vjp`'s. The chunks are walked `SPAN`
-(4: measured beside 8 and 16, PERF.md section 6) at a time by one
+(4: measured beside 8 and 16, PERF.md section 6) at a time — by one
 `lax.scan` whose body is the tables of those chunks, then their state
-updates; the forward keeps the state each step started from (`S / (chunk
-* SPAN)` states of `H * Dk * Dv * 4` bytes a sequence: `state_bytes`),
-the backward walks the steps from the last, makes each one's tables and
-updates again from its kept state and transposes them (autodiff of the span's body), so one span's tables exist
-at a time and no state a chunk or a position ever does. The forward names
-its output and the kept states `"attn_core"`
+updates, or by a kernel's sequential grid axis; the forward keeps the
+state each step started from (`S / (chunk * SPAN)` states of `H * Dk * Dv
+* 4` bytes a sequence: `state_bytes`), the backward walks the steps from
+the last, makes each one's tables and updates again from its kept state
+and transposes them (autodiff of the span's body), so one span's tables
+exist at a time and no state a chunk or a position ever does. The forward
+names its output and the kept states `"attn_core"`
 (`jax.ad_checkpoint.checkpoint_name`, on the values it returns as
 residuals, as ops/pallas_attention.py's kernels name theirs): a layer
 rematerialised under `save_only_these_names("attn_core", ...)` keeps
 both, and its backward runs each span's forward once, not twice.
+
+**Which body runs** is what the code can see, never an option. Where
+`pallas_kda.tiles` takes the shapes (`Dk`, `Dv` whole 128-lane registers,
+chunk 64 and sub-chunk 16, `S` a whole number of spans) each rule hands
+its direction to `_either`, a `jax.jit` of its own around
+`lax.platform_dependent`: the kernel where the program is LOWERED for a
+TPU, the plain body for anything else; shapes that do not tile run the
+plain body everywhere (`core` says which, for `describe`). One algorithm
+and one set of constants: the kernels compute these same tables at these
+same precisions, a span a grid step, and what differs is where the
+intermediates live — in VMEM, where the plain body writes a span's
+float32 tables (≈ 0.25 GB) to HBM and reads them back op by op. The kept
+states and the names are the same in both, so a step's residuals do not
+depend on the platform.
 
 Shapes: `S` a multiple of `chunk`, `chunk` of `subchunk`, `chunk` a power
 of two; anything else is refused by name. `q`, `k`, `v` may be bfloat16:
@@ -69,6 +86,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from parallel_cnn_tpu.ops import pallas_kda
 
 CHUNK, SUBCHUNK, SPAN = 64, 16, 4
 RESIDUAL_NAME = "attn_core"  # ops/pallas_attention.py's: one policy keeps both
@@ -190,6 +209,15 @@ def _whole(a):
     return a.reshape(*a.shape[:2], -1, *a.shape[5:])
 
 
+def core(s: int, dk: int, dv: int, platform: str, chunk: int = CHUNK,
+         subchunk: int = SUBCHUNK) -> str:
+    """What runs `s` positions of heads `dk` and `dv` wide in a program
+    lowered for `platform`: `"pallas"` (ops/pallas_kda.py's kernels: a TPU
+    and shapes they take) or `"xla"` (this module's scan)."""
+    takes = pallas_kda.tiles(s, dk, dv, chunk, subchunk, SPAN)
+    return "pallas" if takes and platform == "tpu" else "xla"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def chunked_kda(q, k, v, g, beta, chunk: int = CHUNK,
                 subchunk: int = SUBCHUNK):
@@ -199,35 +227,27 @@ def chunked_kda(q, k, v, g, beta, chunk: int = CHUNK,
     return _forward(q, k, v, g, beta, chunk, subchunk)[0]
 
 
-def _forward(q, k, v, g, beta, chunk, subchunk):
+def _scan_forward(q, k, v, g, beta, *, chunk, subchunk):
+    """The plain body: (`o`, the states the scan's steps start from)."""
     n, h, s, dk = q.shape
-    if chunk % subchunk or chunk & (chunk - 1):
-        raise ValueError(
-            f"chunked_kda: a chunk of {chunk} positions is no power of two "
-            f"or no multiple of the sub-chunk ({subchunk})")
     steps, m = spans(s, chunk)
 
     def body(state, block):
         after, o = _span(subchunk, state, block)
         return after, (o.astype(v.dtype), state)
 
-    g = g.astype(jnp.float32)
     _, (o, starts) = lax.scan(
         body, jnp.zeros((n, h, dk, v.shape[-1]), jnp.float32),
         tuple(_blocks(a, steps, m, chunk) for a in (q, k, v, g, beta)))
-    # What a rematerialised layer keeps (`save_only_these_names`, as the
-    # attention kernels' output and log-sum-exp): its backward then runs no
-    # forward scan but the spans' own.
-    o = checkpoint_name(_whole(o), RESIDUAL_NAME)
-    starts = checkpoint_name(starts, RESIDUAL_NAME)
-    return o, (q, k, v, g, beta, starts)
+    return _whole(o), starts
 
 
-def _backward(chunk, subchunk, residuals, d_o):
-    """A span at a time from the last: its tables and state updates again
-    from the state it started from, then their transpose."""
-    *inputs, starts = residuals
-    steps, m = starts.shape[0], inputs[0].shape[2] // (starts.shape[0] * chunk)
+def _scan_backward(q, k, v, g, beta, starts, d_o, *, chunk, subchunk):
+    """The plain body's: a span at a time from the last, its tables and
+    state updates again from the state it started from, then their
+    transpose."""
+    inputs = (q, k, v, g, beta)
+    steps, m = starts.shape[0], q.shape[2] // (starts.shape[0] * chunk)
 
     def body(d_state, at):
         block, start, d_out = at
@@ -239,6 +259,53 @@ def _backward(chunk, subchunk, residuals, d_o):
         (tuple(_blocks(a, steps, m, chunk) for a in inputs), starts,
          _blocks(d_o, steps, m, chunk)), reverse=True)
     return tuple(_whole(d).astype(a.dtype) for d, a in zip(d_blocks, inputs))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "subchunk", "back"))
+def _either(*operands, chunk: int, subchunk: int, back: bool):
+    """One direction of shapes `pallas_kda.tiles` took: the kernel where
+    the program is lowered for a TPU, the plain body elsewhere. A
+    `jax.jit` of its own: both branches are traced once a signature, not
+    in every layer of a step."""
+    sizes = dict(chunk=chunk, subchunk=subchunk)
+    if back:
+        kernel = functools.partial(pallas_kda.backward, **sizes)
+        plain = functools.partial(_scan_backward, **sizes)
+    else:
+        kernel = functools.partial(pallas_kda.forward, span=SPAN, **sizes)
+        plain = functools.partial(_scan_forward, **sizes)
+    return lax.platform_dependent(*operands, tpu=kernel, default=plain)
+
+
+def _run(back: bool, chunk: int, subchunk: int, *operands):
+    """One direction on its operands (`q, k, v, g, beta`, and backward the
+    kept states and `d_o`): through `_either` where `pallas_kda.tiles`
+    takes the shapes, the plain body otherwise."""
+    (_, _, s, dk), dv = operands[0].shape, operands[2].shape[-1]
+    sizes = dict(chunk=chunk, subchunk=subchunk)
+    if pallas_kda.tiles(s, dk, dv, chunk, subchunk, SPAN):
+        return _either(*operands, back=back, **sizes)
+    return (_scan_backward if back else _scan_forward)(*operands, **sizes)
+
+
+def _forward(q, k, v, g, beta, chunk, subchunk):
+    if chunk % subchunk or chunk & (chunk - 1):
+        raise ValueError(
+            f"chunked_kda: a chunk of {chunk} positions is no power of two "
+            f"or no multiple of the sub-chunk ({subchunk})")
+    spans(q.shape[2], chunk)  # refuses positions off the chunk by name
+    g = g.astype(jnp.float32)
+    o, starts = _run(False, chunk, subchunk, q, k, v, g, beta)
+    # What a rematerialised layer keeps (`save_only_these_names`, as the
+    # attention kernels' output and log-sum-exp): its backward then runs no
+    # forward scan but the spans' own.
+    o = checkpoint_name(o, RESIDUAL_NAME)
+    starts = checkpoint_name(starts, RESIDUAL_NAME)
+    return o, (q, k, v, g, beta, starts)
+
+
+def _backward(chunk, subchunk, residuals, d_o):
+    return _run(True, chunk, subchunk, *residuals, d_o)
 
 
 chunked_kda.defvjp(_forward, _backward)

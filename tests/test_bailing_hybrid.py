@@ -680,12 +680,22 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     assert (event["kda_chunk"], event["kda_subchunk"], event["kda_scan_steps"],
             event["kda_chunks_a_step"], event["kda_state_bytes"]) == (
         64, 16, 1, 2, 2 * 16 * 16 * 4)
-    at_size = bh.ling_3_0_flash(layer_types=[LINEAR, FULL], num_dense_layers=1,
-                                vocab_size=8, held_experts=range(8),
-                                row_buffer=8192).describe(8192, 8192, "tpu")
+    assert event["kda_core"] == "xla"  # a CPU, and heads 16 wide
+    at_size_model = bh.ling_3_0_flash(
+        layer_types=[LINEAR, FULL], num_dense_layers=1, vocab_size=8,
+        held_experts=range(8), row_buffer=8192)
+    at_size = at_size_model.describe(8192, 8192, "tpu")
     assert (at_size["attention_core"], at_size["attention_tile"],
             at_size["attention_tiles_visited"], at_size["kda_scan_steps"],
             at_size["kda_state_bytes"]) == ("fused", 512, 136, 32, 64 << 20)
+    # the scan's kernels where the step is lowered for a TPU and the shapes
+    # tile (ops/pallas_kda.py), the plain body on a CPU or at 64-wide heads
+    assert at_size["kda_core"] == "pallas"
+    narrow = bh.ling_3_0_flash(layer_types=[LINEAR, FULL], num_dense_layers=1,
+                               vocab_size=8, held_experts=range(8),
+                               row_buffer=8192, head_dim=64)
+    assert [m.describe(8192, 8192, p)["kda_core"] for m, p in (
+        (at_size_model, "cpu"), (narrow, "tpu"))] == ["xla", "xla"]
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads():

@@ -668,12 +668,10 @@ def test_the_rope_kernel_compiles_at_the_cells_shapes(topo, shape):
 
 # -- the delta rule's chunked scan, and a linear and a full layer's step (PR 43)
 
-@pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_the_chunked_scan_compiles_at_the_cells_shapes(topo, direction):
-    """XLA takes the scan at 8,192 positions of 32 heads of 128, forward
-    and backward: 32 steps of 4 chunks; what the backward keeps beside its
-    inputs and outputs is the span-start states and one span's tables, no
-    state a chunk (268 MB) and none a position."""
+def _scan_compiled(topo, direction):
+    """`chunked_kda` (`direction` "fwd") or its five gradients ("bwd") at
+    the cell's 8,192 positions of 32 heads of 128, bf16 `q, k, v`,
+    compiled for one described v5e."""
     from parallel_cnn_tpu.ops import kda
 
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -683,18 +681,57 @@ def test_the_chunked_scan_compiles_at_the_cells_shapes(topo, direction):
     q, g, beta = like(n, h, s, d), like(n, h, s, d, dtype=jnp.float32), like(
         n, h, s, dtype=jnp.float32)
     assert kda.spans(s) == (32, 4) and kda.state_bytes(s, h, d, d) == 64 << 20
-    fn = kda.chunked_kda
+    # (a function of this call's own: jit keeps no trace from another test's)
+    fn = lambda *a: kda.chunked_kda(*a)  # noqa: E731
     if direction == "bwd":
         fn = jax.grad(lambda *a: jnp.sum(kda.chunked_kda(*a).astype(jnp.float32)),
                       argnums=(0, 1, 2, 3, 4))
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(fn).lower(q, q, q, g, beta).compile()
+        return jax.jit(fn).lower(q, q, q, g, beta).compile()
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_chunked_scan_compiles_at_the_cells_shapes(topo, direction, monkeypatch):
+    """XLA takes the plain body (what shapes the kernels refuse run, and
+    anything that is no TPU) at 8,192 positions of 32 heads of 128,
+    forward and backward: 32 steps of 4 chunks; what the backward keeps
+    beside its inputs and outputs is the span-start states and one span's
+    tables, no state a chunk (268 MB) and none a position."""
+    from parallel_cnn_tpu.ops import pallas_kda
+
+    monkeypatch.setattr(pallas_kda, "tiles", lambda *shapes: False)
+    compiled = _scan_compiled(topo, direction)
     text = compiled.as_text()
-    assert "while" in text
+    assert "while" in text and pallas_kda.NAME not in text
     # no state a position, and none a chunk
     assert not re.search(r"f32\[8192,1,32,128,128\]|f32\[128,1,32,128,128\]", text)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (600 << 20 if direction == "fwd" else 1536 << 20), temp
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_scans_kernels_compile_at_the_cells_shapes(topo, direction):
+    """Mosaic takes ops/pallas_kda.py's kernels at the same shapes where
+    `chunked_kda` is lowered for a TPU: the kernel by its name and no loop
+    around it; no table of a chunk (float32 `(..., 64, 64)` or `(..., 64,
+    128)`), no state a chunk or a position and no copy of an operand
+    chunk-major among the program's arrays; beside inputs and outputs the
+    program holds the span-start states (64 MB: kept for the backward, or
+    written and dropped by a forward alone) and `beta` and its gradient as
+    rows (67.1 and 68.3 MB read)."""
+    from parallel_cnn_tpu.ops import pallas_kda
+
+    compiled = _scan_compiled(topo, direction)
+    text = compiled.as_text()
+    kernels = re.findall(rf"{pallas_kda.NAME}_(?:fwd|bwd)", text)
+    assert set(kernels) == ({"kda_scan_fwd"} if direction == "fwd" else {
+        "kda_scan_fwd", "kda_scan_bwd"}), kernels
+    assert "while" not in text
+    assert not re.search(r"f32\[(\d+,)*64,(64|128)\]", text)
+    assert not re.search(r"f32\[(8192|128),1,32,128,128\]", text)
+    assert not re.search(r"\[32,1,32,4,64", text)  # `_blocks`' layout
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= (64 + 2) << 20, temp
 
 
 _bailing_step = {}
@@ -741,6 +778,20 @@ def test_the_linear_and_full_layers_carry_their_scopes_and_the_core_is_fused(top
                   "l1/moe/experts", "l1/moe/shared"):
         assert {"fwd", "bwd"} <= by_scope[scope], (scope, by_scope[scope])
     assert "l0/attn/rope" not in by_scope  # a linear layer carries no position
+
+
+def test_the_linear_layers_scan_is_one_kernel_a_direction_and_no_loop(topo):
+    """ops/pallas_kda.py's kernels under `l0/attn/core`, the forward's once
+    in the forward and the backward's once in the backward (the layer's
+    rematerialisation keeps `o` and the span-start states, so no forward
+    kernel runs again), and no `while` left under that scope."""
+    catalog = _bailing_program(topo)["catalog"]
+    for kernel, phase in (("kda_scan_fwd", "fwd"), ("kda_scan_bwd", "bwd")):
+        ran = sorted((e.scope, e.phase) for n, e in catalog.items()
+                     if n.startswith(kernel) and e.opcode == "custom-call")
+        assert ran == [("l0/attn/core", phase)], (kernel, ran)
+    assert [n for n, e in catalog.items()
+            if e.scope == "l0/attn/core" and e.opcode == "while"] == []
 
 
 def test_no_score_square_and_no_state_a_position_reaches_hbm(topo):
