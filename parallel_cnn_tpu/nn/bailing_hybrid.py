@@ -13,7 +13,9 @@ beside a shared one.
              (published: full where (l + 1) % layer_group_size == 0)
     KDA    : q, k, v = SiLU(Conv4(x W_q)), SiLU(Conv4(x W_k)),
              SiLU(Conv4(x W_v)): causal depthwise taps, no bias; q, k
-             L2-normalised over a head's features, q times D^-1/2;
+             L2-normalised over a head's features, q times D^-1/2
+             (`short_conv`: for a TPU one kernel a direction,
+             ops/pallas_shortconv.py);
              g = lower_bound * sigmoid(exp(A_h) (x W_f + b_f)) in float32,
              a log-decay a channel in [lower_bound, 0]; beta = sigmoid(x
              W_beta) a head; no position encoding
@@ -64,10 +66,10 @@ from parallel_cnn_tpu.nn.glm_moe import (
     _ones,
 )
 from parallel_cnn_tpu.nn.layers import GatedMLP, _weight, causal_conv
-from parallel_cnn_tpu.ops import kda
+from parallel_cnn_tpu.ops import kda, pallas_shortconv
 
 LINEAR, FULL = "linear_attention", "full_attention"
-L2_EPS = 1e-6
+L2_EPS = pallas_shortconv.L2_EPS
 
 
 def layer_kinds(layers: int, group: int) -> Tuple[str, ...]:
@@ -82,6 +84,35 @@ def _unit(x):
     xf = x.astype(jnp.float32)
     return (xf * jax.lax.rsqrt(
         jnp.sum(xf * xf, axis=-1, keepdims=True) + L2_EPS)).astype(x.dtype)
+
+
+def _short_conv_plain(x, taps, unit: bool, scale: float):
+    """`short_conv`'s plain body: the stages composed, each looked up in
+    this module when the layer is traced."""
+    y = jax.nn.silu(causal_conv(x, taps))
+    if unit:
+        y = _unit(y)
+    return y if scale == 1.0 else y * scale
+
+
+# The two stages ops/pallas_shortconv.py's kernels implement, as this module
+# had them when it was imported.
+_FUSED_STAGES = (causal_conv, _unit)
+
+
+def short_conv(x, taps, unit: bool = False, scale: float = 1.0):
+    """`scale * unit(SiLU(causal_conv(x, taps)))` of head-major `x (N, H,
+    S, D)` and `taps (K, H, D)`: `_short_conv_plain`, or — for shapes
+    `pallas_shortconv.tile` takes, where the step is lowered for a TPU —
+    one kernel a direction that rounds once. The kernels are those two
+    stages and no others: while `causal_conv` or `_unit` of this module is
+    another function than the one they were written from (a comparison's
+    planted fault replaces them), the stages compose plainly everywhere."""
+    fused = (causal_conv, _unit) == _FUSED_STAGES and pallas_shortconv.tile(
+        x.shape[-2], x.shape[-1], taps.shape[0]) is not None
+    if not fused:
+        return _short_conv_plain(x, taps, unit, scale)
+    return pallas_shortconv.short_conv(x, taps, unit, scale, _short_conv_plain)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,10 +170,11 @@ class KDA(Module):
         with jax.named_scope("qkv"):
             q, k, v = heads_of("q"), heads_of("k"), heads_of("v")
         with jax.named_scope("conv"):
-            q, k, v = (jax.nn.silu(causal_conv(
-                a, params[f"{name}_conv"].reshape(self.taps, h, d)))
-                for name, a in (("q", q), ("k", k), ("v", v)))
-            q, k = _unit(q) * d ** -0.5, _unit(k)
+            q, k, v = (short_conv(
+                a, params[f"{name}_conv"].reshape(self.taps, h, d), unit, scale)
+                for name, a, unit, scale in (
+                    ("q", q, True, d ** -0.5), ("k", k, True, 1.0),
+                    ("v", v, False, 1.0)))
         with jax.named_scope("gates"):
             g = self.log_decay(params, heads_of("f"))
             beta = jax.nn.sigmoid(jnp.einsum(
@@ -210,8 +242,9 @@ class BailingHybrid(GlmMoe):
         """`GlmMoe.describe` (whose attention core, tile and tiles are the
         full layers') and the linear layers': the scan's chunk, sub-chunk
         and the bytes of the states one sequence's backward keeps, and
-        what runs it where the step is lowered for `platform` (`kda_core`:
-        `"pallas"` | `"xla"`, as `attention_core` says for the full layers)."""
+        what runs it and the short convolutions ahead of it where the step
+        is lowered for `platform` (`kda_core`, `kda_short_conv`: `"pallas"`
+        | `"xla"`, as `attention_core` says for the full layers)."""
         said = super().describe(tokens_per_step, seq_len, platform)
         lin = self.linear
         steps, span = kda.spans(seq_len)
@@ -223,6 +256,8 @@ class BailingHybrid(GlmMoe):
             kda_chunk=kda.CHUNK, kda_subchunk=kda.SUBCHUNK,
             kda_scan_steps=steps, kda_chunks_a_step=span,
             kda_core=kda.core(seq_len, lin.head_dim, lin.head_dim, platform),
+            kda_short_conv=pallas_shortconv.core(
+                seq_len, lin.head_dim, lin.taps, platform),
             kda_state_bytes=kda.state_bytes(
                 seq_len, lin.heads, lin.head_dim, lin.head_dim))
         return said

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_pallas_shortconv import interpret
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -158,6 +159,54 @@ def test_the_linear_layer_agrees_with_the_reference_and_its_decay_is_inside_the_
     assert float(jnp.min(far)) == -5.0 and float(jnp.max(far)) <= 0.0
     # the gate is exercised: neither pinned at the floor nor at none
     assert -5.0 < float(jnp.min(g)) < -3.0 and -1.0 < float(jnp.max(g)) < 0.0
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """`small` with the linear layers' heads 128 wide: shapes
+    `pallas_shortconv.tile` takes (one tile of 128 positions)."""
+    s = _drawn(*build(head_dim=128))
+    s.want_loss = float(ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)[0])
+    return s
+
+
+@pytest.mark.parametrize("path", ["lowered_here", "kernels"])
+def test_the_linear_layer_agrees_with_the_reference_around_either_short_conv(
+        small, wide, path, monkeypatch):
+    """`KDA.apply` at heads that tile lowers to a program that holds both
+    forms of the short convolutions (`lax.platform_dependent`: the only
+    place the layer forks at 128 positions, and at 16-wide heads it does
+    not fork at all); this host takes the composition, and with the
+    kernels put where a TPU would take them (interpret mode) the layer is
+    the reference's as well: values and every gradient."""
+    att = wide.model.attention(LINEAR)
+    p = wide.params["layers"][0]["attn"]
+    x = jax.random.normal(jax.random.key(5), (2, S, D))
+    d_out = jax.random.normal(jax.random.key(6), (2, S, D))
+    run = lambda p, x: att.apply(p, {}, x)[0]  # noqa: E731
+    if path == "kernels":
+        ran = interpret(monkeypatch)
+    else:
+        assert "stablehlo.case" in jax.jit(run).lower(p, x).as_text()
+        narrow = small.model.attention(LINEAR)
+        assert "stablehlo.case" not in jax.jit(
+            lambda p, x: narrow.apply(p, {}, x)[0]).lower(
+                small.params["layers"][0]["attn"], x).as_text()
+    with jax.default_matmul_precision("highest"):
+        got, pull = jax.vjp(run, p, x)
+        want, want_pull = jax.vjp(
+            lambda p, x: ref.linear_attention(wide.arch, p, x), p, x)
+        grads, want_grads = pull(d_out), want_pull(d_out)
+    if path == "kernels":
+        shape = (2, 2, S, 128)
+        assert sorted(ran) == sorted(
+            (shape, unit, back) for unit in (True, True, False)
+            for back in (False, True))
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    _close(got, want)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads), strict=True):
+        _close(a, b)
 
 
 def test_the_full_layer_agrees_with_the_reference(small):
@@ -535,6 +584,32 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
     assert abs(loss / want - 1) > 10 * TOL, (fault, loss, want)
 
 
+@pytest.mark.parametrize("fault", ["conv_dropped", "l2_dropped",
+                                   "tap_order_reversed"])
+def test_a_fault_in_a_fused_stage_fails_the_comparison_where_the_shapes_tile(
+        wide, fault, monkeypatch):
+    """At heads 128 wide, with ops/pallas_shortconv.py's kernels put where
+    a TPU would take them (interpret mode): the clean model runs them and
+    is the reference's; a fault planted by replacing `bh.causal_conv` or
+    `bh._unit` — the tool's two and the reversed taps — still runs, because
+    the layer then composes its stages plainly, and fails the comparison."""
+    ran = interpret(monkeypatch)
+    want, model = wide.want_loss, lambda: build(head_dim=128)[0]  # noqa: E731
+    assert _loss(wide, model()) == pytest.approx(want, rel=TOL)
+    assert [(unit, back) for _, unit, back in ran] == 2 * [
+        (True, False), (True, False), (False, False)]
+    del ran[:]
+    if fault == "tap_order_reversed":
+        monkeypatch.setattr(bh, "causal_conv",
+                            lambda x, taps: layers.causal_conv(x, taps[::-1]))
+        loss = _loss(wide, model())
+    else:
+        with _planted(fault):
+            loss = _loss(wide, model())
+    assert ran == []
+    assert abs(loss / want - 1) > 10 * TOL, (fault, loss, want)
+
+
 def test_the_control_puts_everything_back(small):
     before = _loss(small, build()[0])
     for fault in tool.FAULTS:
@@ -680,7 +755,7 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     assert (event["kda_chunk"], event["kda_subchunk"], event["kda_scan_steps"],
             event["kda_chunks_a_step"], event["kda_state_bytes"]) == (
         64, 16, 1, 2, 2 * 16 * 16 * 4)
-    assert event["kda_core"] == "xla"  # a CPU, and heads 16 wide
+    assert event["kda_core"] == event["kda_short_conv"] == "xla"  # a CPU, and heads 16 wide
     at_size_model = bh.ling_3_0_flash(
         layer_types=[LINEAR, FULL], num_dense_layers=1, vocab_size=8,
         held_experts=range(8), row_buffer=8192)
@@ -696,6 +771,11 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
                                row_buffer=8192, head_dim=64)
     assert [m.describe(8192, 8192, p)["kda_core"] for m, p in (
         (at_size_model, "cpu"), (narrow, "tpu"))] == ["xla", "xla"]
+    # and the short convolutions ahead of it (ops/pallas_shortconv.py)
+    assert [m.describe(8192, s, p)["kda_short_conv"] for m, s, p in (
+        (at_size_model, 8192, "tpu"), (at_size_model, 8192, "cpu"),
+        (narrow, 8192, "tpu"), (at_size_model, 8192 + 64, "tpu"))] == [
+            "pallas", "xla", "xla", "xla"]
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads():

@@ -794,6 +794,50 @@ def test_the_linear_layers_scan_is_one_kernel_a_direction_and_no_loop(topo):
             if e.scope == "l0/attn/core" and e.opcode == "while"] == []
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_the_short_conv_kernels_compile_at_the_cells_shapes(topo, direction):
+    """Mosaic takes ops/pallas_shortconv.py's kernels at `q`'s shape in the
+    cell, `bf16[1, 32, 8192, 128]` under 4 taps, with the norm (`q`, `k`)
+    and without (`v`): a program that is the kernel, with nothing of the
+    array's size beside its operands and results — the backward's only
+    temporary is the taps' partial sums (eight a tap and head, 0.5 MB)."""
+    from parallel_cnn_tpu.ops import pallas_shortconv as sc
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    taps = jax.ShapeDtypeStruct((4, 32, 128), jnp.float32, sharding=one_chip)
+    assert sc.tile(8192, 128, 4) == 512
+    for unit in (True, False):
+        if direction == "fwd":
+            compiled = sc.forward.lower(x, taps, unit=unit, scale=0.5).compile()
+        else:
+            compiled = sc.backward.lower(x, taps, x, unit=unit, scale=0.5).compile()
+        text = compiled.as_text()
+        assert f"{sc.NAME}_{direction}" in text
+        assert not re.search(r"f32\[1,32,8192,128\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes <= 1 << 20
+
+
+def test_the_linear_layers_short_convolutions_are_one_kernel_a_direction(topo):
+    """Under `l0/attn/conv` the compiled step holds ops/pallas_shortconv.py's
+    kernels and nothing of an array's size besides: the forward's three
+    (`q`, `k`, `v`) in the forward and again in the backward (the layer is
+    rematerialised), the backward's three, no copy of `(1, 32, 8192, 128)`
+    ahead of or behind them — the projections write, and the scan reads,
+    head-major row-major — and no float32 of that size."""
+    step = _bailing_program(topo)
+    ran = collections.Counter(
+        (name.split(".")[0], e.phase) for name, e in step["catalog"].items()
+        if e.scope == "l0/attn/conv" and e.opcode == "custom-call")
+    assert ran == {("short_conv_fwd", "fwd"): 3, ("short_conv_fwd", "bwd"): 3,
+                   ("short_conv_bwd", "bwd"): 3}, ran
+    shapes = dict(re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) (?:copy|fusion)\(", step["text"], re.M))
+    whole = {name: shapes[name] for name, e in step["catalog"].items()
+             if e.scope == "l0/attn/conv" and "8192,128]" in shapes.get(name, "")}
+    assert whole == {}, whole
+
+
 def test_no_score_square_and_no_state_a_position_reaches_hbm(topo):
     text = _bailing_program(topo)["text"]
     assert not re.search(r"\[8192,8192\]", text)
