@@ -351,37 +351,6 @@ def _leg_kernels(ctx) -> dict:
         judged("lenet_fused_bf16_store_abs", max_abs(grads_bf, grads_f32),
                F32_TOL)
 
-    # -- The MXU conv engine: Mosaic refuses it (ops/pallas.py). Selected
-    # on chip it must raise the typed error; and the refusal must still be
-    # TRUE — clear the record, try the real compile, demand the compiler's
-    # error. The day it compiles this leg fails: delete the guard.
-    if on_chip:
-        pk._MXU_CONV = True
-        try:
-            try:
-                pk.fused_value_and_ref_grads(params, xs, ys)
-                _check(False, "MXU conv engine selected on chip did not "
-                              "raise MosaicRefusal")
-            except pk.MosaicRefusal as e:
-                facts["mxu_conv_typed_refusal"] = str(e)
-            recorded, pk._MXU_CONV_REFUSED = pk._MXU_CONV_REFUSED, None
-            try:
-                jax.block_until_ready(jax.jit(
-                    lambda p, x, y: pk.fused_value_and_ref_grads(p, x, y)
-                )(params, xs, ys))
-                _check(False, "Mosaic now COMPILES the MXU conv engine: "
-                              "delete _MXU_CONV_REFUSED and measure it")
-            except SmokeFailure:
-                raise
-            except Exception as e:  # noqa: BLE001 — the compiler's error
-                _check("unsupported shape cast" in str(e),
-                       f"MXU conv engine failed differently: {e}"[:1500])
-                facts["mxu_conv_compiler_says"] = str(e).splitlines()[0]
-            finally:
-                pk._MXU_CONV_REFUSED = recorded
-        finally:
-            pk._MXU_CONV = False
-
     # -- Zoo conv: forward, dgrad, wgrad vs XLA's conv and its autodiff, at
     # ResNet-50 (CLI widths) layer shapes.
     cb = sz["conv_batch"]

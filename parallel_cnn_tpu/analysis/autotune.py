@@ -566,45 +566,6 @@ def choose_for_trace(mp: ModelProfile, *, n_dev: int,
 
 
 # ---------------------------------------------------------------------------
-# Ranking validation (the comparison's pure core; ROADMAP Design 9)
-# ---------------------------------------------------------------------------
-
-def pairwise_agreement(predicted: Sequence[float],
-                       measured: Sequence[float], *,
-                       min_ratio: float = 1.10) -> Tuple[int, int]:
-    """(agreeing, total) over candidate pairs the MODEL separates by at
-    least ``min_ratio`` — pairs the model calls a near-tie don't vote,
-    because CPU noise can't adjudicate them (docs/autotuning.md
-    "Ranking validation")."""
-    if len(predicted) != len(measured):
-        raise ValueError("predicted/measured length mismatch")
-    agree = total = 0
-    for i, j in itertools.combinations(range(len(predicted)), 2):
-        hi, lo = (i, j) if predicted[i] >= predicted[j] else (j, i)
-        if predicted[lo] <= 0 or predicted[hi] < min_ratio * predicted[lo]:
-            continue
-        total += 1
-        if measured[hi] > measured[lo]:
-            agree += 1
-    return agree, total
-
-
-def order_gate(predicted: Sequence[float], measured: Sequence[float], *,
-               min_ratio: float = 1.10,
-               threshold: float = 0.75) -> Tuple[bool, str]:
-    """The pairwise-order check: the measured ordering must agree with
-    the model on ≥ ``threshold`` of the model-separated pairs.  Returns
-    (ok, human summary).  A doctored table that inverts the model's
-    ranking fails this by construction (tests/test_autotune.py)."""
-    agree, total = pairwise_agreement(predicted, measured,
-                                      min_ratio=min_ratio)
-    frac = 1.0 if total == 0 else agree / total
-    ok = frac >= threshold
-    return ok, (f"{agree}/{total} separated pairs agree "
-                f"(ratio>={min_ratio:.2f}, threshold={threshold:.2f})")
-
-
-# ---------------------------------------------------------------------------
 # Report persistence (the cost_report.json "autotune" section)
 # ---------------------------------------------------------------------------
 

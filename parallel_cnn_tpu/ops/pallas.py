@@ -55,7 +55,6 @@ reference uses for l_s1.output → fp_preact_f (Sequential/layer.h:184-198).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -68,12 +67,6 @@ from parallel_cnn_tpu.ops import reference as ref_ops
 from parallel_cnn_tpu.ops.activations import error_norm, make_error
 
 Params = ref_ops.Params
-
-
-class MosaicRefusal(RuntimeError):
-    """A Pallas kernel family was selected on a TPU where the Mosaic
-    compiler is known to refuse it. Raised at selection time, naming the
-    compiler's reason — never replaced by an interpret or XLA run."""
 
 
 def _interpret() -> bool:
@@ -90,26 +83,6 @@ def _interpret() -> bool:
 # on an XLA lowering detail — see fused_value_and_ref_grads). Monkeypatched
 # by test_fused_bf16_store_vs_f32_store to diff the two stores on-chip.
 _FORCE_X25_F32 = False
-
-# Forward-conv engine inside the fused megakernel: one rank-2×rank-3 MXU
-# dot (6,25)@(25,Bb,576) → (6,Bb,576) in place of the 150-FMA VPU loop — a
-# drop-in swap for the per-filter tap loop that needs no relayout in the
-# SOURCE. Env-gated (read at import); tests flip the module attribute via
-# monkeypatch instead (test_fused_mxu_conv_engine_matches — the kernel
-# reads this global at trace time, so a fresh jit after patching picks it
-# up).
-_MXU_CONV = os.environ.get("PCNN_FUSED_MXU_CONV", "0") == "1"  # graftcheck: disable=env-outside-config -- import-time kernel gate read into a trace-time global by design (see comment above)
-
-# …but Mosaic lowers that dot through a lane-merging reshape it then
-# rejects. Recorded on TPU v5 lite, jax/jaxlib 0.9.0, libtpu 0.0.34 (chip
-# run, PR 21); selecting the engine on a TPU raises MosaicRefusal with
-# this text. chip_smoke.py clears the record to re-try the real compile
-# on every run and fails the day it succeeds — the signal to delete this
-# guard (and then measure the engine). None = not known to be refused.
-_MXU_CONV_REFUSED = (
-    "Mosaic failed to compile TPU kernel: infer-vector-layout: unsupported "
-    "shape cast (tpu.reshape vector<6x73728xf32> -> vector<6x128x576xf32>)"
-)
 
 
 def _batch_block(n: int, want: int = 128) -> int:
@@ -644,25 +617,17 @@ def _fused_kernel(
         precision=lax.Precision.DEFAULT,
     )
 
-    # Forward: conv → pool (Mp matmul) → FC. Conv engine: one
-    # (6,25)@(25,Bb,576) MXU dot when _MXU_CONV (r5 probe: 7× the VPU
-    # loop, same operand layouts), else 25 tap-FMAs/filter on the VPU.
+    # Forward: conv → pool (Mp matmul) → FC. The conv is 25 tap-FMAs a
+    # filter on the VPU (the one rank-2×rank-3 MXU dot in its place is a
+    # shape cast Mosaic refuses on the v5e: docs/kernel_authoring.md).
     bb = y1h_ref.shape[0]
     outs_c1 = []
     outs_s1 = []
-    if _MXU_CONV:
-        x25 = x25_ref[:]
-        pre_c1 = dot(
-            w_c1_ref[:].astype(x25.dtype), x25, (((1,), (0,)), ((), ()))
-        )                                                       # (6, Bb, 576)
     pre_f = jnp.broadcast_to(b_f_ref[:], (bb, 10))
     for m in range(6):
-        if _MXU_CONV:
-            acc = pre_c1[m] + b_c1_ref[m, 0]
-        else:
-            acc = jnp.full((bb, 576), b_c1_ref[m, 0], f32)
-            for t in range(25):
-                acc += w_c1_ref[m, t] * x25_ref[t]
+        acc = jnp.full((bb, 576), b_c1_ref[m, 0], f32)
+        for t in range(25):
+            acc += w_c1_ref[m, t] * x25_ref[t]
         out_m = _sigmoid(acc)                                   # (Bb, 576)
         outs_c1.append(out_m)
         pre_s1_m = dot(out_m, mp, (((1,), (0,)), ((), ()))) + b_s1_ref[0, 0]
@@ -717,11 +682,6 @@ FUSED_VMEM_LIMIT = 100 * 1024 * 1024
 
 
 def _fused_call(x25, y1h, params, n_pad: int):
-    if _MXU_CONV and _MXU_CONV_REFUSED and not _interpret():
-        raise MosaicRefusal(
-            "the fused megakernel's MXU conv engine (PCNN_FUSED_MXU_CONV=1) "
-            f"does not compile on this TPU: {_MXU_CONV_REFUSED}"
-        )
     bb = _batch_block(n_pad, FUSED_BLOCK)
     f32 = jnp.float32
     outs = pl.pallas_call(

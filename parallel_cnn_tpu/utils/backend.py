@@ -42,13 +42,6 @@ def is_tpu(devices: Optional[Sequence] = None) -> bool:
     return bool(ds) and ds[0].platform == "tpu"
 
 
-def canonical_platform(devices: Optional[Sequence] = None) -> str:
-    """The first device's platform name ("tpu", "cpu", "gpu"); the label
-    every benchmark line carries."""
-    ds = list(devices) if devices is not None else jax.devices()
-    return ds[0].platform if ds else "unknown"
-
-
 def peak_flops(device_kind: str) -> float:
     """Published bf16 peak FLOP/s of one chip of this kind."""
     try:

@@ -13,7 +13,6 @@ import math
 import os
 import re
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -25,11 +24,12 @@ sys.path.insert(0, ROOT)
 
 from benchmark.reference import afmoe as ref  # noqa: E402
 from benchmark.tools import compare_afmoe  # noqa: E402
-from benchmark.tools.compare_glm_moe import random_leaves  # noqa: E402
 from benchmark.tools.compare_reference import leaf_gaps  # noqa: E402
 from parallel_cnn_tpu import config as config_lib, plan as plan_lib  # noqa: E402
 from parallel_cnn_tpu.nn import afmoe, glm_moe  # noqa: E402
 from parallel_cnn_tpu.train import zoo  # noqa: E402
+from token_family import (HYPER, highest, jitted, logits, loss as loss_of,  # noqa: E402
+                          steps, system, toy)
 
 S, VOCAB, WINDOW = 32, 96, 8
 KINDS = [afmoe.SLIDING, afmoe.SLIDING, afmoe.FULL, afmoe.SLIDING]
@@ -43,7 +43,6 @@ ARCH = {
     "held_experts": [0, 1, 2], "row_buffer": None, "balance_weight": 0.0,
     "gate_gradient": True, "embed_scale": 32 ** 0.5,
 }
-HYPER = dict(lr=1e-3, kind="adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
 # float32 on both sides at the highest matmul precision: what differs is the
 # order of float32 sums. Every fault below moves 100 x TOL.
 TOL = 2e-5
@@ -71,38 +70,13 @@ def build(**over):
         loss_block=16), arch
 
 
-def _drawn(model, arch):
-    params, state, _ = model.init(jax.random.key(1), (S,))
-    params, state = random_leaves(params, state, jax.random.key(2))
-    x = jax.random.randint(jax.random.key(3), (4, S), 0, VOCAB)
-    return types.SimpleNamespace(model=model, arch=arch, params=params,
-                                 state=state, x=x, y=jnp.roll(x, -1, axis=1))
-
-
 @pytest.fixture(scope="module")
 def small():
     """The toy model with every PARAMETER leaf drawn at random (weights of
     std 1 / sqrt(fan_in), gains 1 + 0.1 n) and every selection bias 0.01 n."""
-    s = _drawn(*build())
+    s = toy(*build(), seq=S)
     s.want_loss = float(ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)[0])
     return s
-
-
-def _highest(fn, *args):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args)
-
-
-def _system(s, model=None):
-    (loss, new), grads = _highest(jax.jit(jax.value_and_grad(
-        zoo._build_loss_fn(model or s.model, None), has_aux=True)),
-        s.params, s.state, s.x, s.y)
-    return float(loss), grads, new
-
-
-def _loss(s, model):
-    return float(_highest(jax.jit(zoo._build_loss_fn(model, None)),
-                          s.params, s.state, s.x, s.y)[0])
 
 
 # ------------------------------------------------------------ the pieces
@@ -113,8 +87,8 @@ def test_attention_of_either_kind_agrees_with_the_reference(small, kind):
     p = small.params["layers"][KINDS.index(kind)]["attn"]
     assert set(p) == {"q", "k", "v", "gate", "o", "q_norm", "k_norm"}
     x = jax.random.normal(jax.random.key(5), (2, S, 32))
-    got = _highest(lambda: att.apply(p, {}, x)[0])
-    want = _highest(ref.attention, small.arch, kind, p, x)
+    got = jitted(lambda p, x: att.apply(p, {}, x)[0], p, x)
+    want = jitted(lambda p, x: ref.attention(small.arch, kind, p, x), p, x)
     np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
 
 
@@ -133,15 +107,14 @@ def test_the_layer_kind_table_rope_and_a_window_only_where_sliding(small):
     x = jax.random.normal(jax.random.key(6), (1, S, 32))
     order = jnp.concatenate([jax.random.permutation(jax.random.key(7), S - 1),
                              jnp.array([S - 1])])
+    last = lambda att: jax.jit(lambda p, x: att.apply(p, {}, x)[0][:, -1])  # noqa: E731
     for att, moves in ((full, False), (dataclasses.replace(full, rotary=True), True),
                        (dataclasses.replace(local, window=None), True)):
-        a = _highest(lambda: att.apply(p, {}, x)[0])[:, -1]
-        b = _highest(lambda: att.apply(p, {}, x[:, order])[0])[:, -1]
+        a, b = highest(last(att), p, x), highest(last(att), p, x[:, order])
         assert (float(jnp.max(jnp.abs(a - b))) > 1e-3) == moves
     # under the window the last position does not see the first ones at all
     far = x.at[:, : S - WINDOW].set(0.0)
-    a = _highest(lambda: local.apply(p, {}, x)[0])[:, -1]
-    b = _highest(lambda: local.apply(p, {}, far)[0])[:, -1]
+    a, b = highest(last(local), p, x), highest(last(local), p, far)
     np.testing.assert_allclose(a, b, atol=1e-6)
     with pytest.raises(ValueError, match="one of"):
         build(layer_types=KINDS[:3])
@@ -156,14 +129,15 @@ def test_the_expert_layer_under_this_router_agrees_with_the_reference(small):
     assert (layer.scoring, layer.scaling, layer.n_shared, layer.balance,
             layer.bias_step) == ("sigmoid", 2.826, 1, 0.0, 1e-3)
     x = jax.random.normal(jax.random.key(6), (4, S, 32))
-    got, new = _highest(lambda: layer.apply(p, st, x, train=True))
-    want, balance, load = _highest(ref.experts, small.arch, p, st["bias"], x)
+    got, new = jitted(lambda p, st, x: layer.apply(p, st, x, train=True), p, st, x)
+    want, balance, load = jitted(
+        lambda p, b, x: ref.experts(small.arch, p, b, x), p, st["bias"], x)
     np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
     np.testing.assert_allclose(new["load"], load)
     assert float(new["balance"]) == float(balance) == 0.0
     # the gates of a token sum to route_scale over its chosen, held or not
-    _, gates, _, _ = _highest(layer.route, p["router"], st["bias"],
-                              x.reshape(-1, 32), 4)
+    _, gates, _, _ = jitted(lambda r, b, x: layer.route(r, b, x, 4),
+                            p["router"], st["bias"], x.reshape(-1, 32))
     np.testing.assert_allclose(gates.sum(axis=1), 2.826, rtol=1e-6)
     # the bias moves by the step's load, as the reference moves it
     done = layer.finish_step(new)
@@ -186,8 +160,8 @@ def test_the_eight_shares_routed_parts_and_the_shared_expert_once_add_up():
     x = jax.random.normal(jax.random.key(9), (2, S, 32)) * 4.0
     arch = dict(ARCH, router_experts=16, num_experts_per_tok=4,
                 held_experts=list(range(16)))
-    want, _, _ = _highest(ref.experts, arch, p, st["bias"], x)
-    shared = _highest(ref.gated_mlp, p["shared"], x)
+    want, _, _ = jitted(lambda p, b, x: ref.experts(arch, p, b, x), p, st["bias"], x)
+    shared = jitted(ref.gated_mlp, p["shared"], x)
     total = shared
     for i in range(8):
         share = dataclasses.replace(whole, held=(2 * i, 2 * i + 1))
@@ -197,17 +171,17 @@ def test_the_eight_shares_routed_parts_and_the_shared_expert_once_add_up():
         np.testing.assert_array_equal(sp["router"], p["router"])
         for m in ("gate", "up", "down"):
             np.testing.assert_array_equal(sp["shared"][m], p["shared"][m])
-        total = total + _highest(lambda: share.apply(sp, st, x))[0] - shared
+        total = total + jitted(share.apply, sp, st, x)[0] - shared
     assert float(jnp.max(jnp.abs(want - shared))) > 0.01
     np.testing.assert_allclose(total, want, atol=2e-7)
-    uncut, _ = _highest(lambda: whole.apply(p, st, x))
+    uncut, _ = jitted(whole.apply, p, st, x)
     np.testing.assert_allclose(uncut, want, atol=2e-7)
 
 
 def test_the_embedding_leaves_times_the_square_root_of_the_width(small):
     emb = small.model._embed()
     assert emb.scale == pytest.approx(32 ** 0.5)
-    got = emb.apply(small.params["embed"], {}, small.x)[0]
+    got = jitted(emb.apply, small.params["embed"], {}, small.x)[0]
     np.testing.assert_allclose(
         got, small.params["embed"]["w"][small.x] * 32 ** 0.5, rtol=1e-6)
     assert build()[0].embed_scale == pytest.approx(5.656854)
@@ -216,7 +190,7 @@ def test_the_embedding_leaves_times_the_square_root_of_the_width(small):
 # ------------------------------------------------------- the whole model
 
 def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
-    loss, grads, new = _system(small)
+    loss, grads, new = system(small)
     want, want_grads = ref.loss_and_grads(
         small.arch, small.params, small.state, small.x, small.y)
     assert loss == pytest.approx(float(want), rel=TOL)
@@ -224,14 +198,14 @@ def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
     assert len(gaps) == 4 * 11 + 3 + 3 * 7 + 3  # every leaf has a gradient
     assert max(gaps.values()) < TOL, max(gaps, key=gaps.get)
     # the mean next-token cross-entropy and nothing else
-    z = _highest(small.model.apply, small.params, small.state, small.x)[0]
+    z = logits(small)
     nll = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(z, small.y[..., None], -1)[..., 0]
     assert loss == pytest.approx(float(jnp.mean(nll)), rel=1e-6)
 
 
 def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference():
-    s = _drawn(*build(gate_gradient=False))
-    loss, grads, _ = _system(s)
+    s = toy(*build(gate_gradient=False), seq=S)
+    loss, grads, _ = system(s)
     want, want_grads = ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)
     assert loss == pytest.approx(float(want), rel=TOL)
     assert max(leaf_gaps(grads, want_grads).values()) < TOL
@@ -242,28 +216,15 @@ def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference():
 
 def test_logits_and_hidden_states_agree_with_the_reference(small):
     want = ref.eval_logits(small.arch, small.params, small.state, small.x)
-    got, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    got = logits(small)
     assert got.shape == (4, S, VOCAB) and got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
-    hidden, _ = _highest(small.model.hidden_states, small.params, small.state,
-                         small.x)
+    hidden, _ = jitted(small.model.hidden_states, small.params, small.state,
+                       small.x)
     for a, b in zip(hidden, ref.hidden_states(
             small.arch, small.params, small.state, small.x), strict=True):
         assert a.shape == (4, S, 32)
         np.testing.assert_allclose(a, b, atol=TOL * float(jnp.max(jnp.abs(b))))
-
-
-def _steps(s, n=3):
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
-    state = zoo.ZooState(copy(s.params), copy(s.state), opt.init(s.params))
-    step = zoo.make_train_step(s.model, opt, 1, None)
-    losses, rows = [], []
-    for _ in range(n):
-        state, loss = _highest(step, state, s.x, s.y)
-        losses.append(float(loss))
-        rows.append(s.model.counters(state.model_state)["moe_rows_held"])
-    return losses, rows, state
 
 
 def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
@@ -271,7 +232,8 @@ def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
     losses, through `zoo.make_train_step` (the GSPMD step)."""
     want = ref.train_report(small.arch, small.params, small.state, small.x,
                             small.y, steps=3, first_grads=True, **HYPER)
-    losses, rows, state = _steps(small)
+    losses, seen, state = steps(small)
+    rows = [c["moe_rows_held"] for c in seen]
     assert losses == pytest.approx(want["losses"], rel=TOL)
     # step 1's gradient, handed over as a direction (bfloat16) on request
     first = want.pop("first_grads")
@@ -287,8 +249,7 @@ def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
     two = ref.train_losses(small.arch, small.params, small.state, small.x,
                            small.y, steps=2, **HYPER)
     assert two == pytest.approx(want["losses"][:2], rel=1e-6)
-    seen = small.model.counters(state.model_state)
-    assert sum(seen["moe_overflow_rows"]) == 0
+    assert sum(seen[-1]["moe_overflow_rows"]) == 0
     assert [t["balance"] for t in want["terms"]] == [0.0] * 3
 
 
@@ -314,32 +275,32 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
     if fault == "embed_scale_off":
         with _planted(fault) as faulty:
             assert faulty.embed_scale == 1.0
-        loss = _loss(small, dataclasses.replace(small.model, embed_scale=1.0))
+        loss = loss_of(small, dataclasses.replace(small.model, embed_scale=1.0))
     elif fault in compare_afmoe.FAULTS:
         with _planted(fault):
             if fault == "absent_gates":  # moves the loss by 9e-4: the gradients
-                loss, grads, _ = _system(small, build()[0])
+                loss, grads, _ = system(small, build()[0], fresh=True)
                 _, want_grads = ref.loss_and_grads(
                     small.arch, small.params, small.state, small.x, small.y)
                 assert max(leaf_gaps(grads, want_grads).values()) > 100 * TOL
                 return
-            loss = _loss(small, build()[0])
+            loss = loss_of(small, build()[0], fresh=True)
     elif fault == "window_off_by_one":
-        loss = _loss(small, dataclasses.replace(
+        loss = loss_of(small, dataclasses.replace(
             small.model, attn=dataclasses.replace(small.model.attn,
                                                   window=WINDOW + 1)))
     else:
         monkeypatch.setattr(afmoe, "rope", lambda x, theta: x)
-        loss = _loss(small, build()[0])
+        loss = loss_of(small, build()[0], fresh=True)
     assert abs(loss / want - 1) > 100 * TOL, (fault, loss, want)
 
 
 def test_the_control_puts_everything_back(small):
-    before = _loss(small, build()[0])
+    before = loss_of(small, build()[0], fresh=True)
     for fault in compare_afmoe.FAULTS:
         with _planted(fault):
             pass
-    assert _loss(small, build()[0]) == before
+    assert loss_of(small, build()[0], fresh=True) == before
     assert before == pytest.approx(small.want_loss, rel=TOL)
     from benchmark.reference import glm_moe as rounded
 
@@ -357,7 +318,7 @@ def test_a_float8_reference_fails_the_comparison(small):
 
 
 def test_bfloat16_activations_change_rounding_only(small):
-    loss = _loss(small, dataclasses.replace(small.model, dtype="bfloat16"))
+    loss = loss_of(small, dataclasses.replace(small.model, dtype="bfloat16"))
     assert 1e-7 < abs(loss / small.want_loss - 1) < 2e-2
 
 

@@ -4,7 +4,7 @@ Three layers under one marker:
 
 - the search (analysis/autotune.py): legality/canonicalization of the
   plan space, the admissible prune (brute-force equality), the hard HBM
-  budget, deterministic ranking, the order gate;
+  budget, deterministic ranking;
 - the artifacts: cost_report.json autotune section round-trip, the
   schema-version ratchet (stale artifacts fail loudly), AutotuneConfig
   env layering, the hardware-profile registry;
@@ -142,44 +142,6 @@ class TestSearch:
         assert env.plan == base.plan
         assert env.img_s == base.img_s
         assert env.plan.stages == 1 and env.plan.zero == 0
-
-
-# ---------------------------------------------------------------------------
-# the order gate
-
-
-class TestOrderGate:
-    def test_true_ranking_passes(self):
-        ok, msg = autotune.order_gate([100.0, 50.0, 20.0],
-                                      [90.0, 45.0, 19.0])
-        assert ok and "3/3" in msg
-
-    def test_inverted_ranking_fails(self):
-        ok, _ = autotune.order_gate([20.0, 50.0, 100.0],
-                                    [90.0, 45.0, 19.0])
-        assert not ok
-
-    def test_doctored_reciprocal_table_fails(self):
-        """The anti-vacuity transform: 1/x keeps separation
-        ratios but inverts every ordering."""
-        pred = [100.0, 50.0, 20.0]
-        meas = [90.0, 45.0, 19.0]
-        assert autotune.order_gate(pred, meas)[0]
-        assert not autotune.order_gate([1.0 / v for v in pred], meas)[0]
-
-    def test_near_ties_do_not_vote(self):
-        """Pairs the model separates by < min_ratio are noise on CPU —
-        they must not vote in either direction."""
-        agree, total = autotune.pairwise_agreement(
-            [100.0, 95.0], [1.0, 2.0], min_ratio=1.10
-        )
-        assert (agree, total) == (0, 0)
-        ok, msg = autotune.order_gate([100.0, 95.0], [1.0, 2.0])
-        assert ok and "0/0" in msg  # vacuously true, and says so
-
-    def test_length_mismatch_raises(self):
-        with pytest.raises(ValueError):
-            autotune.pairwise_agreement([1.0], [1.0, 2.0])
 
 
 # ---------------------------------------------------------------------------

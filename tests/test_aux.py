@@ -434,8 +434,6 @@ def test_is_tpu_is_the_platform_and_nothing_else():
     from parallel_cnn_tpu.utils import backend
 
     assert backend.is_tpu([_StubDevice("tpu", "TPU v5 lite")])
-    assert backend.canonical_platform(
-        [_StubDevice("tpu", "TPU v5 lite")]) == "tpu"
     # No other platform name fronts a TPU, and a kind string does not
     # make one: Pallas compiles only where platform == "tpu".
     assert not backend.is_tpu([_StubDevice("cpu", "cpu")])

@@ -15,7 +15,6 @@ import math
 import os
 import re
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -28,11 +27,12 @@ sys.path.insert(0, ROOT)
 
 from benchmark.reference import bailing_hybrid as ref  # noqa: E402
 from benchmark.tools import compare_bailing_hybrid as tool  # noqa: E402
-from benchmark.tools.compare_glm_moe import random_leaves  # noqa: E402
 from benchmark.tools.compare_reference import leaf_gaps  # noqa: E402
 from parallel_cnn_tpu import config as config_lib, plan as plan_lib  # noqa: E402
 from parallel_cnn_tpu.nn import bailing_hybrid as bh, glm_moe, layers  # noqa: E402
 from parallel_cnn_tpu.train import zoo  # noqa: E402
+from token_family import (HYPER, jitted, logits, loss as loss_of, pulled,  # noqa: E402
+                          steps, system, toy)
 
 S, VOCAB, D = 128, 96, 32
 LINEAR, FULL = bh.LINEAR, bh.FULL
@@ -52,7 +52,6 @@ ARCH = {
     "router_experts": 16, "held_experts": [0, 1, 5, 9], "row_buffer": None,
     "balance_weight": 0.0, "gate_gradient": True,
 }
-HYPER = dict(lr=1e-3, kind="adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
 # float32 on both sides at the highest matmul precision: what differs is the
 # order of float32 sums (the chunked scan's against a position at a time).
 # Every fault below moves the loss by 10 x TOL or a gradient by 100 x TOL
@@ -97,19 +96,14 @@ def _drawn(model, arch):
     1 / sqrt(fan_in), gains 1 + 0.1 n, selection biases 0.01 n); the
     decay's own two leaves back inside their range (A around 0, b around
     -1), so that the log-decay covers (-5, 0) and is pinned at neither end."""
-    params, state, _ = model.init(jax.random.key(1), (S,))
-    params, state = random_leaves(params, state, jax.random.key(2))
-
     def inside(path, leaf):
         name = getattr(path[-1], "key", None)
         if name == "a_log":
             return 3.0 * (leaf - 1.0)
         return leaf - 2.0 if name == "f_bias" else leaf
 
-    params = jax.tree_util.tree_map_with_path(inside, params)
-    x = jax.random.randint(jax.random.key(3), (2, S), 0, VOCAB)
-    return types.SimpleNamespace(model=model, arch=arch, params=params,
-                                 state=state, x=x, y=jnp.roll(x, -1, axis=1))
+    return toy(model, arch, seq=S, batch=2, adjust=lambda params:
+               jax.tree_util.tree_map_with_path(inside, params))
 
 
 @pytest.fixture(scope="module")
@@ -117,23 +111,6 @@ def small():
     s = _drawn(*build())
     s.want_loss = float(ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)[0])
     return s
-
-
-def _highest(fn, *args):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args)
-
-
-def _system(s, model=None):
-    (loss, new), grads = _highest(jax.jit(jax.value_and_grad(
-        zoo._build_loss_fn(model or s.model, None), has_aux=True)),
-        s.params, s.state, s.x, s.y)
-    return float(loss), grads, new
-
-
-def _loss(s, model):
-    return float(_highest(jax.jit(zoo._build_loss_fn(model, None)),
-                          s.params, s.state, s.x, s.y)[0])
 
 
 def _close(got, want, tol=TOL):
@@ -148,14 +125,14 @@ def test_the_linear_layer_agrees_with_the_reference_and_its_decay_is_inside_the_
     assert set(p) == {"q", "k", "v", "f", "o", "beta", "gate", "q_conv", "k_conv",
                       "v_conv", "a_log", "f_bias", "o_norm"}
     x = jax.random.normal(jax.random.key(5), (2, S, D))
-    got, state = _highest(lambda: att.apply(p, {}, x))
+    got, state = jitted(lambda p, x: att.apply(p, {}, x), p, x)
     assert state == {}
-    _close(got, _highest(ref.linear_attention, small.arch, p, x))
-    g = _highest(ref.log_decay, small.arch, p, x)
+    _close(got, jitted(lambda p, x: ref.linear_attention(small.arch, p, x), p, x))
+    g = jitted(lambda p, x: ref.log_decay(small.arch, p, x), p, x)
     z = jnp.einsum("nsm,mhd->nhsd", x, p["f"].reshape(D, att.heads, att.head_dim))
-    _close(_highest(att.log_decay, p, z), jnp.swapaxes(g, 1, 2))
+    _close(jitted(att.log_decay, p, z), jnp.swapaxes(g, 1, 2))
     # the bound times a sigmoid: no projection takes the decay past it
-    far = _highest(att.log_decay, p, 1e4 * jnp.sign(z))
+    far = jitted(att.log_decay, p, 1e4 * jnp.sign(z))
     assert float(jnp.min(far)) == -5.0 and float(jnp.max(far)) <= 0.0
     # the gate is exercised: neither pinned at the floor nor at none
     assert -5.0 < float(jnp.min(g)) < -3.0 and -1.0 < float(jnp.max(g)) < 0.0
@@ -192,11 +169,9 @@ def test_the_linear_layer_agrees_with_the_reference_around_either_short_conv(
         assert "stablehlo.case" not in jax.jit(
             lambda p, x: narrow.apply(p, {}, x)[0]).lower(
                 small.params["layers"][0]["attn"], x).as_text()
-    with jax.default_matmul_precision("highest"):
-        got, pull = jax.vjp(run, p, x)
-        want, want_pull = jax.vjp(
-            lambda p, x: ref.linear_attention(wide.arch, p, x), p, x)
-        grads, want_grads = pull(d_out), want_pull(d_out)
+    got, grads = pulled(run, d_out, p, x)
+    want, want_grads = pulled(
+        lambda p, x: ref.linear_attention(wide.arch, p, x), d_out, p, x)
     if path == "kernels":
         shape = (2, 2, S, 128)
         assert sorted(ran) == sorted(
@@ -216,9 +191,9 @@ def test_the_full_layer_agrees_with_the_reference(small):
     assert (att.q_rank, att.gated, att.interleaved, att.qk_width) == (
         None, True, True, 128)
     x = jax.random.normal(jax.random.key(5), (2, S, D))
-    got, seen = _highest(lambda: att.apply(p, {}, x))
+    got, seen = jitted(lambda p, x: att.apply(p, {}, x), p, x)
     assert seen == {}
-    _close(got, _highest(ref.full_attention, small.arch, p, x))
+    _close(got, jitted(lambda p, x: ref.full_attention(small.arch, p, x), p, x))
 
 
 def test_no_query_latent_is_glm_moes_algebra_with_the_latent_folded(small):
@@ -233,8 +208,8 @@ def test_no_query_latent_is_glm_moes_algebra_with_the_latent_folded(small):
     with_latent = dataclasses.replace(att, q_rank=D)
     q = dict(p, q_a=jnp.eye(D), q_norm=jnp.ones((D,)), q_b=p["q"])
     del q["q"]
-    got = _highest(lambda: att.apply(p, {}, x)[0])
-    want = _highest(lambda: with_latent.apply(q, {}, x)[0])
+    got = jitted(lambda p, x: att.apply(p, {}, x)[0], p, x)
+    want = jitted(lambda q, x: with_latent.apply(q, {}, x)[0], q, x)
     _close(got, want, 1e-5)
     # the published GLM layer draws what it drew before this family came
     glm = glm_moe.MLA(4, 12, 8, 8, 4, 8)
@@ -256,9 +231,10 @@ def test_interleaved_rope_is_rotate_half_over_a_fixed_order_of_the_features(smal
     k = jax.random.normal(jax.random.key(8), (1, S, 2, r))
     order = jnp.concatenate([jnp.arange(0, r, 2), jnp.arange(1, r, 2)])
     heads_first = lambda a: jnp.moveaxis(a, 2, 1)  # noqa: E731
-    a = layers.rope(heads_first(q[..., order]), theta)
-    b = layers.rope(heads_first(k[..., order]), theta)
-    want_q, want_k = ref.rotary_pairs(q, theta), ref.rotary_pairs(k, theta)
+    turn = jax.jit(lambda a: layers.rope(a, theta))
+    a, b = turn(heads_first(q[..., order])), turn(heads_first(k[..., order]))
+    pairs = jax.jit(lambda a: ref.rotary_pairs(a, theta))
+    want_q, want_k = pairs(q), pairs(k)
     np.testing.assert_allclose(a, heads_first(want_q[..., order]), atol=1e-5)
     np.testing.assert_allclose(
         jnp.einsum("nhqd,nhkd->nhqk", a, b),
@@ -289,7 +265,8 @@ def test_the_group_limited_top_k_is_the_brute_force(small):
     xt = jax.random.normal(jax.random.key(9), (64, D)) * 3.0
     router = small.params["layers"][1]["ffn"]["router"]
     bias = small.state["layers"][1]["bias"]
-    ids, gates, load, _ = _highest(layer.route, router, bias, xt, 1)
+    ids, gates, load, _ = jitted(lambda r, b, x: layer.route(r, b, x, 1),
+                                 router, bias, xt)
     score = jax.nn.sigmoid(jnp.dot(xt, router, precision="highest"))
     want = _brute_force(score + bias, 4, 2, 4)
     np.testing.assert_array_equal(np.sort(np.asarray(ids), axis=1),
@@ -333,8 +310,8 @@ def test_one_group_lowers_to_todays_routing_bit_for_bit():
     assert clamped.count("stablehlo.maximum") == text.count("stablehlo.maximum") + 1
     # and the routing itself: the same ids and gates, bit for bit
     xt = jax.random.normal(jax.random.key(1), (64, D))
-    a = _highest(then.route, p["router"], st["bias"], xt, 1)
-    b = _highest(now.route, p["router"], st["bias"], xt, 1)
+    a = jitted(lambda r, b, x: then.route(r, b, x, 1), p["router"], st["bias"], xt)
+    b = jitted(lambda r, b, x: now.route(r, b, x, 1), p["router"], st["bias"], xt)
     for u, v in zip(a, b, strict=True):
         np.testing.assert_array_equal(u, v)
 
@@ -344,29 +321,29 @@ def test_the_clamp_on_a_gated_mlp(limit):
     mlp = layers.GatedMLP(16, 0.5, limit)
     p = mlp.init(jax.random.key(0), (D,))[0]
     x = jax.random.normal(jax.random.key(1), (8, D))
-    got = _highest(lambda: mlp.apply(p, {}, x)[0])
+    got = jitted(lambda p, x: mlp.apply(p, {}, x)[0], p, x)
     a, u = x @ p["gate"], x @ p["up"]
     if limit:
         assert float(jnp.max(a)) > limit and float(jnp.max(jnp.abs(u))) > limit
         a, u = jnp.minimum(a, limit), jnp.clip(u, -limit, limit)
-    _close(got, _highest(lambda: (jax.nn.silu(a) * u) @ p["down"]), 1e-5)
-    _close(got, _highest(ref.gated_mlp, p, x, limit), 1e-5)
+    _close(got, jitted(lambda a, u, w: (jax.nn.silu(a) * u) @ w, a, u, p["down"]),
+           1e-5)
+    _close(got, jitted(lambda p, x: ref.gated_mlp(p, x, limit), p, x), 1e-5)
     assert layers.GatedMLP(16, 0.5) == layers.GatedMLP(16, 0.5, 0.0)
 
 
-def test_a_late_layers_clamps_reach_its_experts_and_its_shared_expert():
+def test_a_late_layers_clamps_reach_its_experts_and_its_shared_expert(small):
     s = _drawn(*build(expert_swiglu_limit_list=[0, 0.05, 0],
                       share_expert_swiglu_limit_list=[0, 0, 0.07]))
     made = s.model._layers()
     assert [(l.ffn.limit, l.ffn.shared_limit) for l in made[1:]] == [
         (0.05, 0.0), (0.0, 0.07)]
     assert made[0].ffn == layers.GatedMLP(48, glm_moe.INIT_STD)
-    loss, grads, _ = _system(s)
+    loss, grads, _ = system(s)
     want, want_grads = ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)
     assert loss == pytest.approx(float(want), rel=TOL)
     assert max(leaf_gaps(grads, want_grads).values()) < 3 * TOL
-    free = _drawn(*build())
-    assert abs(_loss(free, free.model) / loss - 1) > 10 * TOL
+    assert abs(loss_of(small) / loss - 1) > 10 * TOL  # the same draws, no clamp
     with pytest.raises(ValueError, match="limits for 3 layers"):
         build(expert_swiglu_limit_list=[0, 4])
 
@@ -410,8 +387,9 @@ def test_the_sixty_four_shares_parts_and_the_shared_expert_once_add_up():
     st = dict(st, bias=0.01 * jax.random.normal(jax.random.key(8), (16,)))
     x = jax.random.normal(jax.random.key(9), (2, S, D)) * 4.0
     arch = dict(ARCH, held_experts=list(range(16)))
-    want, _, _ = _highest(ref.experts, arch, p, st["bias"], x, 0.0, 0.0)
-    shared = _highest(ref.gated_mlp, p["shared"], x)
+    want, _, _ = jitted(lambda p, b, x: ref.experts(arch, p, b, x, 0.0, 0.0),
+                        p, st["bias"], x)
+    shared = jitted(ref.gated_mlp, p["shared"], x)
     total = shared
     for i in range(8):
         share = dataclasses.replace(whole, held=(2 * i, 2 * i + 1))
@@ -421,17 +399,17 @@ def test_the_sixty_four_shares_parts_and_the_shared_expert_once_add_up():
                 sp["experts"][m], p["experts"][m][2 * i: 2 * i + 2])
             np.testing.assert_array_equal(sp["shared"][m], p["shared"][m])
         np.testing.assert_array_equal(sp["router"], p["router"])
-        total = total + _highest(lambda: share.apply(sp, st, x))[0] - shared
+        total = total + jitted(share.apply, sp, st, x)[0] - shared
     assert float(jnp.max(jnp.abs(want - shared))) > 0.01
     np.testing.assert_allclose(total, want, atol=2e-7)
-    uncut, _ = _highest(lambda: whole.apply(p, st, x))
+    uncut, _ = jitted(whole.apply, p, st, x)
     np.testing.assert_allclose(uncut, want, atol=2e-7)
 
 
 # ------------------------------------------------------- the whole model
 
 def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
-    loss, grads, new = _system(small)
+    loss, grads, new = system(small)
     want, want_grads = ref.loss_and_grads(
         small.arch, small.params, small.state, small.x, small.y)
     assert loss == pytest.approx(float(want), rel=TOL)
@@ -441,7 +419,7 @@ def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
     assert len(gaps) == 2 * 13 + 6 + 3 * 2 + 3 + 2 * 7 + 3
     assert max(gaps.values()) < TOL, max(gaps, key=gaps.get)
     # the mean next-token cross-entropy and nothing else
-    z = _highest(small.model.apply, small.params, small.state, small.x)[0]
+    z = logits(small)
     nll = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(z, small.y[..., None], -1)[..., 0]
     assert loss == pytest.approx(float(jnp.mean(nll)), rel=1e-6)
     # the state is the expert layers' alone, as the siblings'
@@ -450,7 +428,7 @@ def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
 
 def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference():
     s = _drawn(*build(gate_gradient=False))
-    loss, grads, _ = _system(s)
+    loss, grads, _ = system(s)
     want, want_grads = ref.loss_and_grads(s.arch, s.params, s.state, s.x, s.y)
     assert loss == pytest.approx(float(want), rel=TOL)
     assert max(leaf_gaps(grads, want_grads).values()) < TOL
@@ -460,12 +438,12 @@ def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference():
 
 def test_logits_and_hidden_states_agree_with_the_reference(small):
     want = ref.eval_logits(small.arch, small.params, small.state, small.x)
-    got, new = _highest(small.model.apply, small.params, small.state, small.x)
+    got, new = jitted(small.model.apply, small.params, small.state, small.x)
     assert got.shape == (2, S, VOCAB) and got.dtype == jnp.float32
     _close(got, want)
     assert set(new) == set(small.state)  # no step: the lows stay
-    hidden, _ = _highest(small.model.hidden_states, small.params, small.state,
-                         small.x)
+    hidden, _ = jitted(small.model.hidden_states, small.params, small.state,
+                       small.x)
     for a, b in zip(hidden, ref.hidden_states(
             small.arch, small.params, small.state, small.x), strict=True):
         assert a.shape == (2, S, D)
@@ -477,32 +455,18 @@ def test_the_mtp_module_at_a_weight_is_an_mla_layer_and_agrees_with_the_referenc
     assert isinstance(s.model._mtp_layer().attn, glm_moe.MLA)
     assert set(s.params["mtp"]["layer"]["attn"]) == {
         "q", "kv_a", "kv_b", "kv_norm", "o", "gate"}
-    loss, grads, new = _system(s)
-    (want, (terms, loads)), want_grads = _highest(jax.value_and_grad(
-        lambda p: ref.loss_fn(s.arch, p, s.state, s.x, s.y), has_aux=True),
-        s.params)
+    loss, grads, new = system(s)
+    (want, (terms, loads)), want_grads = jitted(jax.value_and_grad(
+        lambda p, st, x, y: ref.loss_fn(s.arch, p, st, x, y), has_aux=True),
+        s.params, s.state, s.x, s.y)
     assert loss == pytest.approx(float(want), rel=TOL)
     assert float(terms["mtp"]) > 1.0 and len(loads) == 3
     assert max(leaf_gaps(grads, want_grads).values()) < 2 * TOL
     assert float(jnp.max(jnp.abs(grads["mtp"]["proj"]))) > 0
     off = _drawn(*build(num_nextn_predict_layers=1, mtp_weight=0.0))
-    assert _loss(off, off.model) == pytest.approx(
-        float(terms["main"]), rel=TOL)
+    assert loss_of(off) == pytest.approx(float(terms["main"]), rel=TOL)
     assert "mtp" not in build()[0].init(jax.random.key(0), (S,))[0]
     assert bh.ling_3_0_flash().mtp_modules == 0
-
-
-def _steps(s, n=3):
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
-    state = zoo.ZooState(copy(s.params), copy(s.state), opt.init(s.params))
-    step = zoo.make_train_step(s.model, opt, 1, None)
-    losses, rows = [], []
-    for _ in range(n):
-        state, loss = _highest(step, state, s.x, s.y)
-        losses.append(float(loss))
-        rows.append(s.model.counters(state.model_state))
-    return losses, rows, state
 
 
 def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
@@ -510,7 +474,7 @@ def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
     losses, through `zoo.make_train_step` (the GSPMD step)."""
     want = ref.train_report(small.arch, small.params, small.state, small.x,
                             small.y, steps=3, first_grads=True, **HYPER)
-    losses, seen, state = _steps(small)
+    losses, seen, state = steps(small)
     assert losses == pytest.approx(want["losses"], rel=TOL)
     first = want.pop("first_grads")
     assert jax.tree_util.tree_structure(first) == jax.tree_util.tree_structure(
@@ -557,22 +521,22 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
     if fault == "scaling_dropped":
         with _planted(fault) as faulty:
             assert faulty.experts.scaling == 1.0
-        loss = _loss(small, dataclasses.replace(
+        loss = loss_of(small, dataclasses.replace(
             small.model, experts=dataclasses.replace(small.model.experts,
                                                      scaling=1.0)))
     elif fault in tool.FAULTS:
         with _planted(fault):
             if fault in BY_GRADIENT:
-                loss, grads, _ = _system(small, build()[0])
+                loss, grads, _ = system(small, build()[0], fresh=True)
                 _, want_grads = ref.loss_and_grads(
                     small.arch, small.params, small.state, small.x, small.y)
                 assert max(leaf_gaps(grads, want_grads).values()) > 100 * TOL
                 return
-            loss = _loss(small, build()[0])
+            loss = loss_of(small, build()[0], fresh=True)
     elif fault == "tap_order_reversed":
         monkeypatch.setattr(bh, "causal_conv",
                             lambda x, taps: layers.causal_conv(x, taps[::-1]))
-        loss = _loss(small, build()[0])
+        loss = loss_of(small, build()[0], fresh=True)
     else:  # the decay applied to the values' side of the state
         from parallel_cnn_tpu.ops import kda
 
@@ -580,7 +544,7 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
         monkeypatch.setattr(kda, "chunked_kda", lambda q, k, v, g, b, *a: scan(
             q, k, v, jnp.swapaxes(jnp.swapaxes(g, -1, -2)[..., ::-1, :], -1, -2),
             b, *a))
-        loss = _loss(small, build()[0])
+        loss = loss_of(small, build()[0], fresh=True)
     assert abs(loss / want - 1) > 10 * TOL, (fault, loss, want)
 
 
@@ -594,28 +558,29 @@ def test_a_fault_in_a_fused_stage_fails_the_comparison_where_the_shapes_tile(
     `bh._unit` — the tool's two and the reversed taps — still runs, because
     the layer then composes its stages plainly, and fails the comparison."""
     ran = interpret(monkeypatch)
+    # a new model a call, and every trace under the patches of its moment
     want, model = wide.want_loss, lambda: build(head_dim=128)[0]  # noqa: E731
-    assert _loss(wide, model()) == pytest.approx(want, rel=TOL)
+    assert loss_of(wide, model(), fresh=True) == pytest.approx(want, rel=TOL)
     assert [(unit, back) for _, unit, back in ran] == 2 * [
         (True, False), (True, False), (False, False)]
     del ran[:]
     if fault == "tap_order_reversed":
         monkeypatch.setattr(bh, "causal_conv",
                             lambda x, taps: layers.causal_conv(x, taps[::-1]))
-        loss = _loss(wide, model())
+        loss = loss_of(wide, model(), fresh=True)
     else:
         with _planted(fault):
-            loss = _loss(wide, model())
+            loss = loss_of(wide, model(), fresh=True)
     assert ran == []
     assert abs(loss / want - 1) > 10 * TOL, (fault, loss, want)
 
 
 def test_the_control_puts_everything_back(small):
-    before = _loss(small, build()[0])
+    before = loss_of(small, build()[0], fresh=True)
     for fault in tool.FAULTS:
         with _planted(fault):
             pass
-    assert _loss(small, build()[0]) == before
+    assert loss_of(small, build()[0], fresh=True) == before
     assert before == pytest.approx(small.want_loss, rel=TOL)
     from benchmark.reference import glm_moe as rounded
 
@@ -632,7 +597,7 @@ def test_a_float8_reference_fails_the_comparison(small):
 
 
 def test_bfloat16_activations_change_rounding_only(small):
-    loss = _loss(small, dataclasses.replace(small.model, dtype="bfloat16"))
+    loss = loss_of(small, dataclasses.replace(small.model, dtype="bfloat16"))
     assert 1e-7 < abs(loss / small.want_loss - 1) < 2e-2
 
 

@@ -2,7 +2,7 @@
 (`layers._batch_moments`): E[x] and E[x^2] of every sample over its
 positions, combined over the batch without further cancellation, so that
 the reductions fuse into the conv that produces `x`
-(tests/test_zoo_loader_compile.py holds the compiled form). What one read
+(tests/test_compiled_conv_programs.py holds the compiled form). What one read
 costs is the cancellation in E[x^2] - E[x]^2 inside a sample, which grows
 with a channel's |mean| / std: here the layer is held to the two-pass
 float32 formula `mean((x - mean)^2)` over a grid of that ratio, for

@@ -7,6 +7,7 @@ The comparison with the plain reference is tests/benchmark/
 test_convnext_reference.py."""
 
 import collections
+import functools
 import hashlib
 import math
 import re
@@ -324,40 +325,44 @@ def _keys(model_state):
             if isinstance(s, dict) and "drop" in s]
 
 
-def test_the_train_step_threads_the_keys_and_keeps_its_signature():
+@pytest.fixture(scope="module")
+def stepped():
+    """`keys(accum, steps)`: the DropPaths' keys of `tiny()` as `init_state`
+    drew them (`steps` 0) or after `steps` train steps of `accum`
+    microbatches on one batch: each run made once, and one step program an
+    `accum`, for the tests that read them."""
     model = tiny()
     opt = zoo.make_optimizer(lr=1e-3, kind="adamw")
-    state = zoo.init_state(model, jax.random.key(1), (32, 32, 3), opt)
-    start = _keys(state.model_state)
-    assert len(start) == 5 and has_random_state(state.model_state)
     x = jax.random.normal(jax.random.key(2), (8, 32, 32, 3))
     y = jnp.arange(8) % 10
-    step = zoo.make_train_step(model, opt, 1, None)
-    state, _ = step(state, x, y)  # (state, x, y): no key argument
-    after = _keys(state.model_state)
+    program = functools.lru_cache(maxsize=None)(
+        lambda accum: zoo.make_train_step(model, opt, accum, None))
+
+    @functools.lru_cache(maxsize=None)
+    def keys(accum, steps):
+        state = zoo.init_state(model, jax.random.key(1), (32, 32, 3), opt)
+        assert has_random_state(state.model_state)
+        for _ in range(steps):
+            state, _ = program(accum)(state, x, y)  # (state, x, y): no key argument
+        return _keys(state.model_state)
+
+    return keys
+
+
+def test_the_train_step_threads_the_keys_and_keeps_its_signature(stepped):
+    start, after = stepped(1, 0), stepped(1, 1)
+    assert len(start) == 5
     np.testing.assert_array_equal(after[0], start[0])  # block 1: rate 0
     assert all(not np.array_equal(a, b) for a, b in zip(after[1:], start[1:]))
 
 
-def test_accumulation_draws_a_fresh_mask_for_each_microbatch():
+def test_accumulation_draws_a_fresh_mask_for_each_microbatch(stepped):
     """accum_steps=2 advances every key twice: the second microbatch is
     drawn from the state the first one returned."""
-    model = tiny()
-    opt = zoo.make_optimizer(lr=1e-3, kind="adamw")
-    x = jax.random.normal(jax.random.key(2), (8, 32, 32, 3))
-    y = jnp.arange(8) % 10
-
-    def keys_after(accum, steps):
-        state = zoo.init_state(model, jax.random.key(1), (32, 32, 3), opt)
-        step = zoo.make_train_step(model, opt, accum, None)
-        for _ in range(steps):
-            state, _ = step(state, x, y)
-        return _keys(state.model_state)
-
-    twice = keys_after(1, 2)
-    for a, b, once in zip(keys_after(2, 1), twice, keys_after(1, 1)):
+    twice = stepped(1, 2)
+    for a, b in zip(stepped(2, 1), twice):
         np.testing.assert_array_equal(a, b)
-    assert not np.array_equal(twice[1], keys_after(1, 1)[1])
+    assert not np.array_equal(twice[1], stepped(1, 1)[1])
 
 
 def test_the_keys_round_trip_through_a_checkpoint(tmp_path):

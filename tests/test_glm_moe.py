@@ -19,11 +19,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark.reference import glm_moe as ref  # noqa: E402
-from benchmark.tools.compare_glm_moe import random_leaves  # noqa: E402
 from benchmark.tools.compare_reference import leaf_gaps  # noqa: E402
 from parallel_cnn_tpu import config as config_lib, nn, plan as plan_lib  # noqa: E402
 from parallel_cnn_tpu.nn import glm_moe  # noqa: E402
 from parallel_cnn_tpu.train import zoo  # noqa: E402
+from token_family import (HYPER, highest, jitted, logits as logits_of,  # noqa: E402
+                          loss as loss_of, pulled, stepped, steps, system, toy)
 
 S, VOCAB = 16, 96
 ARCH = {
@@ -37,7 +38,6 @@ ARCH = {
     "router_experts": 8, "held_experts": [0, 1, 2], "row_buffer": None,
     "bias_update_speed": 1e-3, "balance_weight": 1e-4, "mtp_weight": 0.3,
 }
-HYPER = dict(lr=1e-3, kind="adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
 # float32 on both sides, the highest matmul precision: what differs is the
 # order of float32 sums (a grouped matmul over sorted rows against a loop
 # over experts, blocked against whole softmax). Seen: 1e-7 on the loss,
@@ -59,26 +59,16 @@ def small():
     """The toy model with EVERY leaf drawn at random (weights of std
     1 / sqrt(fan_in), gains 1 + 0.1 n, selection biases 0.01 n): at the
     published init of std 0.02 a 32-wide model is all embedding."""
-    import types
-
-    model, arch = build()
-    params, state, _ = model.init(jax.random.key(1), (S,))
-    params, state = random_leaves(params, state, jax.random.key(2))
-    tokens = jax.random.randint(jax.random.key(3), (4, S + 1), 0, VOCAB)
-    return types.SimpleNamespace(model=model, arch=arch, params=params,
-                                 state=state, x=tokens[:, :-1], y=tokens[:, 1:])
+    return toy(*build(), seq=S, shifted=True)
 
 
-def _highest(fn, *args):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args)
-
-
-def _system(s, model=None):
-    (loss, new), grads = _highest(jax.value_and_grad(
-        zoo._build_loss_fn(model or s.model, None), has_aux=True),
-        s.params, s.state, s.x, s.y)
-    return float(loss), grads, new
+def _reference_terms(s):
+    """The reference's loss, term by term, on the toy: read by two tests."""
+    if "terms" not in s.memo:
+        s.memo["terms"] = jitted(
+            lambda p, st, x, y: ref.loss_fn(s.arch, p, st, x, y)[1][0],
+            s.params, s.state, s.x, s.y)
+    return s.memo["terms"]
 
 
 # ------------------------------------------------------------- the pieces
@@ -87,13 +77,14 @@ def test_latent_attention_agrees_with_the_reference(small):
     attn = small.model.attn
     p = small.params["layers"][0]["attn"]
     x = jax.random.normal(jax.random.key(5), (2, S, 32))
-    got = _highest(lambda: attn.apply(p, {}, x)[0])
-    want = _highest(ref.attention, small.arch, p, x)
+    run = jax.jit(lambda p, x: attn.apply(p, {}, x)[0])
+    got = highest(run, p, x)
+    want = jitted(lambda p, x: ref.attention(small.arch, p, x), p, x)
     assert float(jnp.max(jnp.abs(want))) > 0.1
     np.testing.assert_allclose(got, want, atol=TOL)
     # causal: position i does not see what follows it
     later = x.at[:, S // 2:].add(1.0)
-    moved = _highest(lambda: attn.apply(p, {}, later)[0])
+    moved = highest(run, p, later)
     np.testing.assert_allclose(moved[:, : S // 2], got[:, : S // 2], atol=1e-6)
 
 
@@ -116,8 +107,9 @@ def test_the_expert_layer_agrees_with_the_reference(small):
     layer = small.model.experts
     p, st = small.params["layers"][1]["ffn"], small.state["layers"][1]
     x = jax.random.normal(jax.random.key(6), (4, S, 32))
-    got, new = _highest(lambda: layer.apply(p, st, x, train=True))
-    want, balance, load = _highest(ref.experts, small.arch, p, st["bias"], x)
+    got, new = jitted(lambda p, st, x: layer.apply(p, st, x, train=True), p, st, x)
+    want, balance, load = jitted(
+        lambda p, b, x: ref.experts(small.arch, p, b, x), p, st["bias"], x)
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_allclose(new["load"], load)
     assert float(new["balance"]) == pytest.approx(float(balance), rel=1e-5)
@@ -137,8 +129,8 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
     st = dict(st, bias=0.01 * jax.random.normal(jax.random.key(8), (8,)))
     x = jax.random.normal(jax.random.key(9), (2, S, 32)) * 4.0
     arch = dict(ARCH, held_experts=list(range(8)))
-    want, _, _ = _highest(ref.experts, arch, p, st["bias"], x)
-    shared = _highest(ref.gated_mlp, p["shared"], x)
+    want, _, _ = jitted(lambda p, b, x: ref.experts(arch, p, b, x), p, st["bias"], x)
+    shared = jitted(ref.gated_mlp, p["shared"], x)
     total = shared
     for i in range(8):
         share = dataclasses.replace(whole, held=(i,))
@@ -146,7 +138,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer()
         for m in ("gate", "up", "down"):
             np.testing.assert_array_equal(sp["experts"][m][0], p["experts"][m][i])
         np.testing.assert_array_equal(sp["router"], p["router"])
-        y, _ = _highest(lambda: share.apply(sp, st, x))
+        y, _ = jitted(share.apply, sp, st, x)
         total = total + (y - shared)
     assert float(jnp.max(jnp.abs(want - shared))) > 0.05  # the experts matter
     np.testing.assert_allclose(total, want, atol=TOL)
@@ -160,13 +152,14 @@ def test_all_tokens_routed_to_one_held_expert_lose_none():
     p, st, _ = layer.init(jax.random.key(1), (S, 32))
     st = dict(st, bias=st["bias"].at[5].set(10.0).at[3].set(9.0))
     x = jax.random.normal(jax.random.key(2), (2, S, 32))
-    y, new = layer.apply(p, st, x, train=True)
+    train = lambda layer: jitted(  # noqa: E731
+        lambda p, st, x: layer.apply(p, st, x, train=True), p, st, x)
+    y, new = train(layer)
     assert float(new["load"][5]) == 2 * S and int(new["overflow_rows"]) == 0
     arch = dict(ARCH, held_experts=[0, 5])
-    want, _, _ = ref.experts(arch, p, st["bias"], x)
+    want, _, _ = jitted(lambda p, b, x: ref.experts(arch, p, b, x), p, st["bias"], x)
     np.testing.assert_allclose(y, want, atol=1e-4)
-    small_buffer = dataclasses.replace(layer, rows=20)
-    _, new = small_buffer.apply(p, st, x, train=True)
+    _, new = train(dataclasses.replace(layer, rows=20))
     assert int(new["overflow_rows"]) == 2 * S - 20
 
 
@@ -278,12 +271,13 @@ def test_the_combines_gradients_are_autodiffs_made_in_buffer_space(dtype, rows):
     ys = jnp.where(plan.row_live[:, None], ys, jnp.nan)
     gates = jax.random.uniform(jax.random.key(2), (t, k)).astype(dtype)
     dy = jax.random.normal(jax.random.key(3), (t, d)).astype(dtype)
-    y, vjp = jax.vjp(lambda ys, g: glm_moe._combine(ys, g, plan), ys, gates)
-    want_y, want_vjp = jax.vjp(lambda ys, g: _plain_combine(ys, g, plan), ys, gates)
+    y, (d_ys, d_gates) = pulled(
+        lambda ys, g: glm_moe._combine(ys, g, plan), dy, ys, gates)
+    want_y, (want_ys, want_gates) = pulled(
+        lambda ys, g: _plain_combine(ys, g, plan), dy, ys, gates)
     assert y.dtype == want_y.dtype and not bool(jnp.isnan(y).any())
     np.testing.assert_allclose(y.astype(jnp.float32), want_y.astype(jnp.float32),
                                **one_rounding(dtype))
-    (d_ys, d_gates), (want_ys, want_gates) = vjp(dy), want_vjp(dy)
     assert d_ys.dtype == want_ys.dtype == ys.dtype and d_gates.dtype == gates.dtype
     np.testing.assert_array_equal(d_ys, want_ys)
     np.testing.assert_array_equal(d_gates == 0, want_gates == 0)
@@ -309,11 +303,16 @@ def test_the_layers_gradients_are_those_of_the_plain_combine(
     x = jax.random.normal(jax.random.key(2), (4, S, 32)).astype(dtype)
     cot = jax.random.normal(jax.random.key(3), (4, S, 32)).astype(dtype)
 
-    def grads():
+    # float32 as one compiled program; bfloat16 an operation at a time, as
+    # the claim is made: a compiled program keeps float32 where two
+    # operations round to bfloat16 between them, and "to the bit" is 4e-3 off
+    run = jitted if dtype == "float32" else highest
+
+    def grads():  # a new function a call: traced under what is patched now
         def loss(p, x):
             y, new = layer.apply(p, st, x, train=True)
             return jnp.sum((y * cot).astype(jnp.float32)) + new["balance"], new
-        return _highest(jax.grad(loss, argnums=(0, 1), has_aux=True), p, x)
+        return run(jax.grad(loss, argnums=(0, 1), has_aux=True), p, x)
 
     got, new = grads()
     assert int(new["overflow_rows"]) > 0
@@ -354,7 +353,7 @@ def test_a_rematerialised_layer_plans_once_and_combines_back_in_buffer_space(
     d)`, no `(T, k, d)`: the sum of a token's rows, `moe/combine` forward
     and `moe/dispatch` backward, is one scatter-add of the buffer's rows
     each (at these widths; the fused kernel where the shapes tile:
-    tests/test_pallas_rowsum.py, tests/test_zoo_loader_compile.py)."""
+    tests/test_pallas_rowsum.py, tests/test_compiled_glm_sdar_programs.py)."""
     model, _ = build(gate_gradient=gate_gradient, row_buffer=48)
     layer = model._mtp_layer()
     p, st, _ = layer.init(jax.random.key(0), (S, 32))
@@ -397,7 +396,7 @@ def test_a_rematerialised_layer_plans_once_and_combines_back_in_buffer_space(
 
 def test_the_bias_moves_by_u_toward_balance_and_takes_no_gradient(small):
     model = small.model
-    loss, grads, new = _system(small)
+    loss, grads, new = system(small)
     assert set(small.params["layers"][1]["ffn"]) == {"router", "experts", "shared"}
     done = model.finish_step(new)
     for before, mid, after in zip(
@@ -417,41 +416,37 @@ def test_the_bias_moves_by_u_toward_balance_and_takes_no_gradient(small):
     # bias too small to flip a choice leaves it where it was
     nudged = jax.tree_util.tree_map_with_path(
         lambda path, a: a + 1e-9 if path[-1].key == "bias" else a, small.state)
-    assert _highest(model.loss, small.params, nudged, small.x, small.y)[0] == \
-        pytest.approx(loss, rel=1e-6)
+    assert loss_of(small, state=nudged) == pytest.approx(loss, rel=1e-6)
 
 
 def test_a_sliced_vocabulary_is_a_smaller_vocabulary(small):
     """The head and the embedding hold `vocab_size` rows and nothing else:
     the loss of a model built with the slice is the cross-entropy over the
     slice's logits alone."""
-    logits, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    logits = logits_of(small)
     assert logits.shape == (4, S, VOCAB) and logits.dtype == jnp.float32
     assert small.params["embed"]["w"].shape == (VOCAB, 32)
     assert small.params["head"].shape == (32, VOCAB)
     main = float(jnp.mean(ref.nll(logits, small.y)))
-    terms = _highest(ref.loss_fn, small.arch, small.params, small.state,
-                     small.x, small.y)[1][0]
+    terms = _reference_terms(small)
     assert main == pytest.approx(float(terms["main"]), rel=TOL)
 
 
 def test_the_mtp_module_scores_position_i_against_token_i_plus_two(small):
-    terms = _highest(ref.loss_fn, small.arch, small.params, small.state,
-                     small.x, small.y)[1][0]
+    terms = _reference_terms(small)
     off, _ = build(mtp_weight=0.0)
-    with_mtp = _system(small)[0]
-    without = _system(small, off)[0]
+    with_mtp = system(small)[0]
+    without = system(small, off)[0]
     assert with_mtp - without == pytest.approx(0.3 * float(terms["mtp"]), rel=1e-4)
     # the last position has no target: its token changes nothing of the term
     y2 = small.y.at[:, 0].set((small.y[:, 0] + 1) % VOCAB)  # token 1: an input
-    moved = _highest(small.model.loss, small.params, small.state, small.x, y2)[0]
-    assert abs(float(moved) - with_mtp) > 1e-4
+    assert abs(loss_of(small, y=y2) - with_mtp) > 1e-4
 
 
 # --------------------------------------------------------- the whole model
 
 def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
-    loss, grads, _ = _system(small)
+    loss, grads, _ = system(small)
     want, want_grads = ref.loss_and_grads(
         small.arch, small.params, small.state, small.x, small.y)
     assert loss == pytest.approx(float(want), rel=TOL)
@@ -467,8 +462,8 @@ def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference(sm
     leaf's gradient the reference's under the same key of `arch`; what the
     router keeps is the balance term's gradient alone."""
     model, arch = build(gate_gradient=False)
-    loss, grads, _ = _system(small, model)
-    whole, whole_grads, _ = _system(small)
+    loss, grads, _ = system(small, model)
+    whole, whole_grads, _ = system(small)
     assert loss == whole
     want, want_grads = ref.loss_and_grads(
         arch, small.params, small.state, small.x, small.y)
@@ -513,35 +508,21 @@ def test_a_share_that_trains_its_gates_pulls_the_tokens_onto_what_it_holds():
 
 def test_logits_and_hidden_states_agree_with_the_reference(small):
     want = ref.eval_logits(small.arch, small.params, small.state, small.x)
-    got, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    got = logits_of(small)
     np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
-    hidden, _ = _highest(small.model.hidden_states, small.params, small.state,
-                         small.x)
+    hidden, _ = jitted(small.model.hidden_states, small.params, small.state,
+                       small.x)
     for a, b in zip(hidden, ref.hidden_states(
             small.arch, small.params, small.state, small.x), strict=True):
         np.testing.assert_allclose(a, b, atol=TOL * float(jnp.max(jnp.abs(b))))
 
 
-def _two_steps(s, model=None):
-    model = model or s.model
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
-    state = zoo.ZooState(copy(s.params), copy(s.state), opt.init(s.params))
-    step = zoo.make_train_step(model, opt, 1, None)
-    losses, rows = [], []
-    for _ in range(3):
-        state, loss = _highest(step, state, s.x, s.y)
-        losses.append(float(loss))
-        rows.append(model.counters(state.model_state)["moe_rows_held"])
-    return losses, rows, state
-
-
 def test_three_steps_losses_and_held_rows_agree_with_the_reference(small):
     want = ref.train_report(small.arch, small.params, small.state, small.x,
                             small.y, steps=3, **HYPER)
-    losses, rows, state = _two_steps(small)
+    losses, seen, state = steps(small)
     assert losses == pytest.approx(want["losses"], rel=TOL)
-    assert rows == want["rows_held"]
+    assert [c["moe_rows_held"] for c in seen] == want["rows_held"]
     assert want["losses"][2] < want["losses"][1] < want["losses"][0]
     # two steps alone keep no moment: the same first two losses
     two = ref.train_losses(small.arch, small.params, small.state, small.x,
@@ -557,14 +538,10 @@ def model_overflow(model, state):
 def test_accumulation_settles_the_bias_once_a_step_over_all_its_tokens(small):
     """Two microbatches of two sequences: the same loads as one batch of
     four, one move of the bias."""
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
     out = []
     for accum in (1, 2):
-        state = zoo.ZooState(copy(small.params), copy(small.state),
-                             opt.init(small.params))
-        state, _ = _highest(zoo.make_train_step(small.model, opt, accum, None),
-                            state, small.x, small.y)
+        state, step = stepped(small, accum=accum)
+        state, _ = highest(step, state, small.x, small.y)
         out.append(state.model_state)
     for a, b in zip(small.model._expert_states(out[0]),
                     small.model._expert_states(out[1])):
@@ -578,7 +555,8 @@ FAULTS = ["shared_dropped", "scaling_skipped", "softmax_for_sigmoid",
 
 @pytest.mark.parametrize("fault", FAULTS)
 def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
-    model = small.model
+    # a patch is seen by a new trace alone; another model is another program
+    model, fresh = small.model, fault not in ("scaling_skipped", "mtp_off")
     # before any patch: some of them reach the reference's `jax.numpy` too
     want, want_grads = ref.loss_and_grads(
         small.arch, small.params, small.state, small.x, small.y)
@@ -609,7 +587,7 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
             glm_moe.jnp, "take_along_axis",
             lambda a, i, axis: real_take(a, i, axis) + 0.01 if a.shape[-1] == 8
             else real_take(a, i, axis))
-    loss, grads, _ = _system(small, model)
+    loss, grads, _ = system(small, model, fresh=fresh)
     loss_gap = abs(loss / float(want) - 1)
     grad_gap = max(leaf_gaps(grads, want_grads).values())
     assert max(loss_gap, grad_gap) > 100 * TOL, (loss_gap, grad_gap)
@@ -634,9 +612,9 @@ def test_a_float8_reference_fails_the_comparison(small, monkeypatch):
 def test_bfloat16_activations_change_rounding_only(small):
     """The cell's precision: bf16 activations over float32 masters, whose
     gradients stay float32."""
-    loss, _, _ = _system(small)
+    loss, _, _ = system(small)
     half = dataclasses.replace(small.model, dtype="bfloat16")
-    loss3, grads3, _ = _system(small, half)
+    loss3, grads3, _ = system(small, half)
     assert abs(loss3 / loss - 1) < 5e-3
     assert all(g.dtype == jnp.float32 for g in jax.tree_util.tree_leaves(grads3))
 

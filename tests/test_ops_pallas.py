@@ -109,19 +109,6 @@ def test_staged_tier_matches_fused_tier(params, batch):
     tree_allclose(grads_s, grads_f, atol=1e-5)
 
 
-def test_fused_mxu_conv_engine_matches(params, batch, monkeypatch):
-    """The r5 MXU forward-conv engine ((6,25)@(25,Bb,576) dot, gated by
-    _MXU_CONV) must produce the same error/grads as the VPU tap-FMA
-    engine — the kernel reads the flag at trace time, so a fresh call
-    after the patch traces the dot variant."""
-    xs, ys = batch
-    err_v, grads_v = pk.fused_value_and_ref_grads(params, xs, ys)
-    monkeypatch.setattr(pk, "_MXU_CONV", True)
-    err_m, grads_m = pk.fused_value_and_ref_grads(params, xs, ys)
-    np.testing.assert_allclose(float(err_m), float(err_v), atol=1e-6)
-    tree_allclose(grads_m, grads_v, atol=1e-5)
-
-
 def test_fused_multi_grid_step_accumulation(monkeypatch):
     """Shrink FUSED_BLOCK so the fused tier runs a MULTI-step grid with a
     padded tail (grid=3 with 2 pad rows) — exercising the cross-grid-step
@@ -189,17 +176,3 @@ def test_fused_bf16_store_vs_f32_store(monkeypatch):
     err_f32, grads_f32 = pk.fused_value_and_ref_grads(params, xs, ys)
     np.testing.assert_allclose(float(err_bf16), float(err_f32), atol=1e-5)
     tree_allclose(grads_bf16, grads_f32, atol=1e-4)
-
-
-def test_mxu_conv_engine_refusal_is_typed(params, batch, monkeypatch):
-    """Selecting the MXU conv engine where Mosaic is known to refuse it
-    (a TPU — faked here by flipping the interpret switch) raises the typed
-    MosaicRefusal naming the compiler's reason BEFORE any kernel is
-    built: never a quiet interpret or XLA substitute. Whether Mosaic
-    STILL refuses is chip_smoke.py's check (it clears the record and
-    re-tries the real compile on the chip)."""
-    xs, ys = batch
-    monkeypatch.setattr(pk, "_MXU_CONV", True)
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    with pytest.raises(pk.MosaicRefusal, match="unsupported shape cast"):
-        pk.fused_value_and_ref_grads(params, xs, ys)

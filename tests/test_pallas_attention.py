@@ -4,7 +4,7 @@ blocked `_attend` and against a one-shot float32 masked softmax; the
 `custom_vjp` as a CPU host lowers it (the caller's plain form, by
 `lax.platform_dependent`); which shapes tile, and that a model whose
 shapes do not says so. The compiled program is held in
-tests/test_zoo_loader_compile.py."""
+tests/test_compiled_glm_sdar_programs.py."""
 
 import os
 import sys
@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import glm_moe as ref  # noqa: E402
 from parallel_cnn_tpu.nn import glm_moe  # noqa: E402
 from parallel_cnn_tpu.ops import pallas_attention as pa  # noqa: E402
+from token_family import pulled  # noqa: E402
 
 # (N, S, H, D, tile): the issue's shape at the tile the module picks for it
 # (one tile, all diagonal), and three tiles a side (tiles under, on and
@@ -199,10 +200,8 @@ def test_latent_attention_agrees_with_the_reference_around_either_core(widths):
     forks = "stablehlo.case" in jax.jit(
         lambda p, x: mla.apply(p, {}, x)[0]).lower(params, x).as_text()
     assert forks == (widths == "tile")
-    with jax.default_matmul_precision("highest"):
-        got, vjp = jax.vjp(lambda p, x: mla.apply(p, {}, x)[0], params, x)
-        want, vjp_want = jax.vjp(lambda p, x: ref.attention(arch, p, x), params, x)
-        grads, grads_want = vjp(d_out), vjp_want(d_out)
+    got, grads = pulled(lambda p, x: mla.apply(p, {}, x)[0], d_out, params, x)
+    want, grads_want = pulled(lambda p, x: ref.attention(arch, p, x), d_out, params, x)
     assert float(jnp.max(jnp.abs(want))) > 0.1
     np.testing.assert_allclose(got, want, atol=2e-5)
     for g, w in zip(jax.tree_util.tree_leaves(grads),
@@ -222,10 +221,10 @@ def test_the_custom_vjp_on_a_cpu_host_runs_the_callers_plain_form():
         return pa.causal_attention(q, k, v, 128 ** -0.5, 128, blocks)
 
     assert "tpu_custom_call" not in jax.jit(fused).lower(q, k, v).as_text()
-    got, vjp = jax.vjp(fused, q, k, v)
-    want, vjp_want = jax.vjp(blocks, q, k, v)
+    got, grads = pulled(fused, d_out, q, k, v)
+    want, want_grads = pulled(blocks, d_out, q, k, v)
     assert float(jnp.max(jnp.abs(got - want))) == 0.0
-    for g, w in zip(vjp(d_out), vjp_want(d_out)):
+    for g, w in zip(grads, want_grads):
         assert _gap(g, w) < 1e-6
 
 
@@ -307,10 +306,10 @@ def test_the_block_diffusion_kernels_agree(shape, reference):
     q, k, v, d_out = _bd_draw(shape)
     plain = (_bd_plain if reference == "blocks" else _bd_one_shot)(shape)
     out, lse, got = _bd_kernels(q, k, v, d_out, shape)
-    want, vjp = jax.vjp(plain, q, k, v)
+    want, want_grads = pulled(plain, d_out, q, k, v)
     assert out.shape == q.shape and lse.shape == q.shape[:3]
     assert _gap(out, want) < 2e-6
-    for name, g, w in zip(("dq", "dk", "dv"), got, vjp(d_out)):
+    for name, g, w in zip(("dq", "dk", "dv"), got, want_grads):
         assert g.shape == w.shape and g.dtype == w.dtype
         assert _gap(g, w) < 5e-6, name
     # the rows' log-sum-exp is that of the scores the mask allows
@@ -404,10 +403,10 @@ def test_the_block_diffusion_custom_vjp_on_a_cpu_host_runs_the_plain_form():
         return pa.block_diffusion_attention(q, k, v, 128 ** -0.5, 256, 4, 128, plain)
 
     assert "tpu_custom_call" not in jax.jit(fused).lower(q, k, v).as_text()
-    got, vjp = jax.vjp(fused, q, k, v)
-    want, vjp_want = jax.vjp(plain, q, k, v)
+    got, grads = pulled(fused, d_out, q, k, v)
+    want, want_grads = pulled(plain, d_out, q, k, v)
     assert float(jnp.max(jnp.abs(got - want))) == 0.0
-    for g, w in zip(vjp(d_out), vjp_want(d_out)):
+    for g, w in zip(grads, want_grads):
         assert _gap(g, w) < 1e-6
 
 

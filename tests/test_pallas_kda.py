@@ -6,7 +6,7 @@ the plain body to) and against the plain body (float32 rounding); two
 spans, so the carried state and `d_state` cross a grid step. What
 `tiles` refuses, and that `chunked_kda` then runs the plain body. That
 Mosaic takes the kernels at the cell's shapes, and what surrounds them in
-a compiled step, is tests/test_zoo_loader_compile.py's."""
+a compiled step, is tests/test_compiled_trinity_ling_programs.py's."""
 
 import jax
 import jax.numpy as jnp

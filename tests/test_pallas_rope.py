@@ -3,7 +3,7 @@ in interpret mode against `nn/layers.py:rope`'s plain body, values and
 gradients, alone and through the two attention layers that take it; what
 `tile` refuses, and that `rope` then runs the plain body. That Mosaic
 takes the kernel at the cells' shapes, and what surrounds it in a
-compiled layer, is tests/test_zoo_loader_compile.py's."""
+compiled layer, is tests/test_compiled_glm_sdar_programs.py's."""
 
 import jax
 import jax.numpy as jnp

@@ -4,7 +4,7 @@ sizes, through the two `custom_vjp`s of nn/glm_moe.py that run it
 (`_combine` forward, `_gather_rows` backward), against the plain
 gather-and-sum the layer ran until PR 37 and autodiff of `x[idx]`. That
 Mosaic takes the kernel at the cells' shapes, and where it sits in a
-compiled step, is tests/test_zoo_loader_compile.py's."""
+compiled step, is tests/test_compiled_glm_sdar_programs.py's."""
 
 import dataclasses
 

@@ -5,7 +5,7 @@ and the three gradients, alone and through a `KDA` layer; causality to the
 position across a tile's and a chunk's edge; what `tile` refuses, and that
 `short_conv` then composes plainly — as it does while a stage's name holds
 another function. That Mosaic takes the kernels at the cell's shapes, and
-what surrounds them in a compiled step, is tests/test_zoo_loader_compile.py's."""
+what surrounds them in a compiled step, is tests/test_compiled_trinity_ling_programs.py's."""
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +14,7 @@ import pytest
 
 from parallel_cnn_tpu.nn import bailing_hybrid as bh, layers
 from parallel_cnn_tpu.ops import pallas_shortconv as sc
+from token_family import jitted
 
 K = 4
 # (N, H, S, D): three tiles of 128, a chunk each; three blocks of three
@@ -269,11 +270,12 @@ def test_a_linear_layer_is_the_same_layer_on_either_path(interpreted):
     params = att.init(jax.random.key(3), (s, 32))[0]
     x = jax.random.normal(jax.random.key(4), (2, s, 32), jnp.float32)
 
-    def run():
+    def run():  # a new function a call: traced under what is patched now
         def loss(p, x):
             out = jax.checkpoint(lambda p, x: att.apply(p, {}, x, True)[0])(p, x)
             return jnp.sum(out ** 2), out
-        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+        (_, out), grads = jitted(
+            jax.value_and_grad(loss, (0, 1), has_aux=True), params, x)
         return out, grads
 
     got = run()

@@ -12,7 +12,6 @@ import hashlib
 import math
 import os
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +23,12 @@ sys.path.insert(0, ROOT)
 
 from benchmark.reference import sdar_moe as ref  # noqa: E402
 from benchmark.tools import compare_sdar_moe  # noqa: E402
-from benchmark.tools.compare_glm_moe import random_leaves  # noqa: E402
 from benchmark.tools.compare_reference import leaf_gaps  # noqa: E402
 from parallel_cnn_tpu import config as config_lib, plan as plan_lib  # noqa: E402
 from parallel_cnn_tpu.nn import glm_moe, sdar_moe  # noqa: E402
 from parallel_cnn_tpu.train import zoo  # noqa: E402
+from token_family import (HYPER, highest, jitted, loss as loss_of, pulled,  # noqa: E402
+                          stepped, steps, system, toy)
 
 L, B, VOCAB = 16, 4, 96
 ARCH = {
@@ -39,7 +39,6 @@ ARCH = {
     "row_buffer": None, "balance_weight": 1e-3, "gate_gradient": True,
     "block_length": B, "noise_eps": 1e-3, "mask_token_id": VOCAB - 1,
 }
-HYPER = dict(lr=1e-3, kind="adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
 # float32 on both sides at the highest matmul precision: what differs is the
 # order of float32 sums. Seen: 1e-7 on the loss, 1.5e-6 on the worst leaf's
 # gradient; every fault below moves 100 x TOL.
@@ -67,24 +66,7 @@ def build(**over):
 def small():
     """The toy model with every PARAMETER leaf drawn at random (weights of
     std 1 / sqrt(fan_in), gains 1 + 0.1 n); its state as `init` made it."""
-    model, arch = build()
-    params, state, _ = model.init(jax.random.key(1), (L,))
-    params = random_leaves(params, state, jax.random.key(2))[0]
-    x = jax.random.randint(jax.random.key(3), (4, L), 0, VOCAB)
-    return types.SimpleNamespace(model=model, arch=arch, params=params,
-                                 state=state, x=x, y=jnp.roll(x, -1, axis=1))
-
-
-def _highest(fn, *args):
-    with jax.default_matmul_precision("highest"):
-        return fn(*args)
-
-
-def _system(s, model=None):
-    (loss, new), grads = _highest(jax.value_and_grad(
-        zoo._build_loss_fn(model or s.model, None), has_aux=True),
-        s.params, s.state, s.x, s.y)
-    return float(loss), grads, new
+    return toy(*build(), seq=L, draw_state=False)
 
 
 # ------------------------------------------------------------ the mask
@@ -135,11 +117,11 @@ def _attention(seed=0):
 def test_grouped_query_attention_agrees_with_the_reference():
     att, p, x = _attention()
     d_out = jax.random.normal(jax.random.key(10), x.shape)
-    got, vjp = _highest(jax.vjp, lambda p, x: att.apply(p, {}, x)[0], p, x)
-    want, vjp_want = _highest(jax.vjp, lambda p, x: ref.attention(ARCH, p, x), p, x)
+    got, grads = pulled(lambda p, x: att.apply(p, {}, x)[0], d_out, p, x)
+    want, want_grads = pulled(lambda p, x: ref.attention(ARCH, p, x), d_out, p, x)
     assert float(jnp.max(jnp.abs(want))) > 0.1
     np.testing.assert_allclose(got, want, atol=TOL)
-    gaps = leaf_gaps(_highest(vjp, d_out), _highest(vjp_want, d_out))
+    gaps = leaf_gaps(grads, want_grads)
     assert len(gaps) == 7 and max(gaps.values()) < TOL, max(gaps, key=gaps.get)
     assert set(p) == {"q", "k", "v", "o", "q_norm", "k_norm"}
     assert p["k"].shape == (32, 2 * 8) and p["q"].shape == (32, 4 * 8)
@@ -147,15 +129,16 @@ def test_grouped_query_attention_agrees_with_the_reference():
 
 def test_what_a_position_may_not_see_does_not_move_it():
     att, p, x = _attention()
-    base = _highest(lambda: att.apply(p, {}, x)[0])
+    run = jax.jit(lambda x: att.apply(p, {}, x)[0])
+    base = highest(run, x)
     # the noised half moved: no clean position moves
-    moved = _highest(lambda: att.apply(p, {}, x.at[:, :L].add(1.0))[0])
+    moved = highest(run, x.at[:, :L].add(1.0))
     np.testing.assert_allclose(moved[:, L:], base[:, L:], atol=1e-6)
     assert float(jnp.max(jnp.abs(moved[:, :L] - base[:, :L]))) > 0.01
     # clean block 2 moved: noised blocks 0..2 and clean blocks 0, 1 stay —
     # a noised block never sees its OWN clean block
     at = slice(L + 2 * B, L + 3 * B)
-    moved = _highest(lambda: att.apply(p, {}, x.at[:, at].add(1.0))[0])
+    moved = highest(run, x.at[:, at].add(1.0))
     np.testing.assert_allclose(moved[:, : 3 * B], base[:, : 3 * B], atol=1e-6)
     np.testing.assert_allclose(moved[:, L: L + 2 * B], base[:, L: L + 2 * B],
                                atol=1e-6)
@@ -168,7 +151,7 @@ def test_both_halves_count_their_positions_from_zero():
     same q and k, which a RoPE running on to 2L - 1 would turn apart."""
     att, p, x = _attention()
     twice = jnp.concatenate([x[:, :L], x[:, :L]], axis=1)
-    out = _highest(lambda: att.apply(p, {}, twice)[0])
+    out = jitted(lambda p, x: att.apply(p, {}, x)[0], p, twice)
     # block 0: noised sees noised block 0, clean sees clean block 0 — the same
     np.testing.assert_allclose(out[:, :B], out[:, L: L + B], atol=1e-5)
 
@@ -178,15 +161,15 @@ def test_the_expert_layer_under_a_softmax_router_agrees_with_the_reference(small
     p, st = small.params["layers"][1]["ffn"], small.state["layers"][1]
     assert set(p) == {"router", "experts"}  # no shared expert, no leaf for one
     x = jax.random.normal(jax.random.key(6), (4, 2 * L, 32))
-    got, new = _highest(lambda: layer.apply(p, st, x, train=True))
-    want, balance, load = _highest(ref.experts, small.arch, p, x)
+    got, new = jitted(lambda p, st, x: layer.apply(p, st, x, train=True), p, st, x)
+    want, balance, load = jitted(lambda p, x: ref.experts(small.arch, p, x), p, x)
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_allclose(new["load"], load)
     assert float(new["balance"]) == pytest.approx(float(balance), rel=1e-5)
     assert float(load.sum()) == 4 * 2 * L * 2 and int(new["overflow_rows"]) == 0
     # the gates of a token sum to one over its chosen, held or not
-    ids, gates, _, _ = _highest(layer.route, p["router"], st["bias"],
-                                x.reshape(-1, 32), 4)
+    ids, gates, _, _ = jitted(lambda r, b, x: layer.route(r, b, x, 4),
+                              p["router"], st["bias"], x.reshape(-1, 32))
     np.testing.assert_allclose(gates.sum(axis=1), 1.0, atol=1e-6)
     # and no selection bias ever moves under this router
     done = layer.finish_step(new)
@@ -205,7 +188,7 @@ def test_the_eight_shares_routed_parts_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.key(9), (2, 2 * L, 32)) * 4.0
     arch = dict(ARCH, router_experts=16, num_experts_per_tok=4,
                 held_experts=list(range(16)))
-    want, _, _ = _highest(ref.experts, arch, p, x)
+    want, _, _ = jitted(lambda p, x: ref.experts(arch, p, x), p, x)
     total = jnp.zeros_like(want)
     for i in range(8):
         share = dataclasses.replace(whole, held=(2 * i, 2 * i + 1))
@@ -213,11 +196,11 @@ def test_the_eight_shares_routed_parts_add_up_to_the_uncut_layer():
         for m in ("gate", "up", "down"):
             np.testing.assert_array_equal(sp["experts"][m], p["experts"][m][2 * i: 2 * i + 2])
         np.testing.assert_array_equal(sp["router"], p["router"])
-        total = total + _highest(lambda: share.apply(sp, st, x))[0]
+        total = total + jitted(share.apply, sp, st, x)[0]
     # (at the published init of std 0.02 a 16-wide expert gives 0.04 at most)
     assert float(jnp.max(jnp.abs(want))) > 0.02
     np.testing.assert_allclose(total, want, atol=1e-7)
-    uncut, _ = _highest(lambda: whole.apply(p, st, x))
+    uncut, _ = jitted(whole.apply, p, st, x)
     np.testing.assert_allclose(uncut, want, atol=1e-7)
 
 
@@ -258,7 +241,7 @@ def test_a_block_is_masked_at_its_own_rate():
 
 
 def test_the_state_advances_once_a_forward_and_counts_the_masked(small):
-    _, _, new = _system(small)
+    _, _, new = system(small)
     assert int(new["noise"]["draws"]) == 1
     np.testing.assert_array_equal(new["noise"]["key"], small.state["noise"]["key"])
     _, m, _ = small.model.noise(small.state["noise"], small.x)
@@ -266,25 +249,20 @@ def test_the_state_advances_once_a_forward_and_counts_the_masked(small):
     done = small.model.finish_step(new)
     assert small.model.counters(done)["bd_masked_tokens"] == int(m.sum())
     # two microbatches: two draws, the second's are not the first's
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
-    state = zoo.ZooState(copy(small.params), copy(small.state), opt.init(small.params))
-    state, _ = _highest(zoo.make_train_step(small.model, opt, 2, None), state,
-                        small.x, small.y)
+    state, step = stepped(small, accum=2)
+    state, _ = highest(step, state, small.x, small.y)
     assert int(state.model_state["noise"]["draws"]) == 2
 
 
 def test_the_targets_argument_is_not_read(small):
-    loss, _, _ = _system(small)
-    other = _highest(small.model.loss, small.params, small.state, small.x,
-                     (small.y + 7) % VOCAB)[0]
-    assert float(other) == loss
+    assert loss_of(small, y=(small.y + 7) % VOCAB) == loss_of(small)
+    assert loss_of(small) == system(small)[0]
 
 
 # --------------------------------------------------------- the whole model
 
 def test_loss_and_every_leafs_gradient_agree_with_the_reference(small):
-    loss, grads, _ = _system(small)
+    loss, grads, _ = system(small)
     want, want_grads = ref.loss_and_grads(
         small.arch, small.params, small.state, small.x, small.y)
     assert loss == pytest.approx(float(want), rel=TOL)
@@ -301,21 +279,21 @@ def test_the_loss_is_the_weighted_cross_entropy_of_the_noised_half(small):
     model = small.model
     xt, m, t = model.noise(small.state["noise"], small.x)
     stream = jnp.concatenate([xt, small.x], axis=1)
-    hidden, layers = _highest(model.hidden_states, small.params, small.state,
-                              stream, True)
-    z = _highest(model._logits, small.params, hidden[-1][:, :L])
+    hidden, layers = jitted(lambda p, st, x: model.hidden_states(p, st, x, True),
+                            small.params, small.state, stream)
+    z = jitted(model._logits, small.params, hidden[-1][:, :L])
     ce = jax.nn.logsumexp(z, -1) - jnp.take_along_axis(
         z, small.x[..., None], -1)[..., 0]
     want = jnp.sum(jnp.where(m, ce / t, 0.0)) / (4 * L) + sum(
         s["balance"] for s in layers)
-    assert _system(small)[0] == pytest.approx(float(want), rel=1e-5)
+    assert system(small)[0] == pytest.approx(float(want), rel=1e-5)
     assert all(float(s["balance"]) > 0 for s in layers)
 
 
 def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference(small):
     model, arch = build(gate_gradient=False)
-    loss, grads, _ = _system(small, model)
-    whole, whole_grads, _ = _system(small)
+    loss, grads, _ = system(small, model)
+    whole, whole_grads, _ = system(small)
     assert loss == whole
     want, want_grads = ref.loss_and_grads(
         arch, small.params, small.state, small.x, small.y)
@@ -327,47 +305,32 @@ def test_a_share_that_leaves_the_gates_gradient_out_agrees_with_the_reference(sm
 
 def test_logits_and_hidden_states_agree_with_the_reference(small):
     want = ref.eval_logits(small.arch, small.params, small.state, small.x)
-    got, _ = _highest(small.model.apply, small.params, small.state, small.x)
+    got, _ = jitted(small.model.apply, small.params, small.state, small.x)
     assert got.shape == (4, L, VOCAB) and got.dtype == jnp.float32
     np.testing.assert_allclose(got, want, atol=TOL * float(jnp.max(jnp.abs(want))))
     stream = jnp.concatenate(
         [small.model.noise(small.state["noise"], small.x)[0], small.x], axis=1)
-    hidden, _ = _highest(small.model.hidden_states, small.params, small.state,
-                         stream)
+    hidden, _ = jitted(small.model.hidden_states, small.params, small.state,
+                       stream)
     for a, b in zip(hidden, ref.hidden_states(
             small.arch, small.params, small.state, stream), strict=True):
         assert a.shape == (4, 2 * L, 32)
         np.testing.assert_allclose(a, b, atol=TOL * float(jnp.max(jnp.abs(b))))
 
 
-def _steps(s, n=3, model=None):
-    model = model or s.model
-    opt = zoo.make_optimizer(**HYPER)
-    copy = lambda t: jax.tree_util.tree_map(lambda a: a + 0, t)  # noqa: E731
-    state = zoo.ZooState(copy(s.params), copy(s.state), opt.init(s.params))
-    step = zoo.make_train_step(model, opt, 1, None)
-    losses, rows, masked = [], [], []
-    for _ in range(n):
-        state, loss = _highest(step, state, s.x, s.y)
-        losses.append(float(loss))
-        seen = model.counters(state.model_state)
-        rows.append(seen["moe_rows_held"])
-        masked.append(seen["bd_masked_tokens"])
-    return losses, rows, masked, state
-
-
 def test_three_steps_losses_held_rows_and_noise_agree_with_the_reference(small):
     want = ref.train_report(small.arch, small.params, small.state, small.x,
                             small.y, steps=3, **HYPER)
-    losses, rows, masked, state = _steps(small)
+    losses, seen, state = steps(small)
+    rows = [c["moe_rows_held"] for c in seen]
+    masked = [c["bd_masked_tokens"] for c in seen]
     assert losses == pytest.approx(want["losses"], rel=TOL)
     assert rows == want["rows_held"] and masked == want["masked"]
     assert len(set(masked)) == 3  # every step under its own noise
     two = ref.train_losses(small.arch, small.params, small.state, small.x,
                            small.y, steps=2, **HYPER)
     assert two == pytest.approx(want["losses"][:2], rel=1e-6)
-    seen = small.model.counters(state.model_state)
-    assert sum(seen["moe_overflow_rows"]) == 0
+    assert sum(seen[-1]["moe_overflow_rows"]) == 0
     assert int(state.model_state["noise"]["draws"]) == 3
 
 
@@ -384,7 +347,7 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
         small.arch, small.params, small.state, small.x, small.y)
     if fault in compare_sdar_moe.FAULTS:
         with _planted(fault):
-            loss, grads, _ = _system(small)
+            loss, grads, _ = system(small, fresh=True)
     else:
         if fault == "rope_runs_on":
             monkeypatch.setattr(
@@ -397,7 +360,7 @@ def test_a_fault_in_the_system_fails_the_comparison(small, fault, monkeypatch):
             monkeypatch.setattr(jax.nn, "softmax",
                                 lambda a, axis=-1: jax.nn.sigmoid(a)
                                 if a.shape[-1] == 8 else _softmax(a, axis))
-        loss, grads, _ = _system(small)
+        loss, grads, _ = system(small, fresh=True)
     loss_gap = abs(loss / float(want) - 1)
     grad_gap = max(leaf_gaps(grads, want_grads).values())
     assert max(loss_gap, grad_gap) > 100 * TOL, (loss_gap, grad_gap)
@@ -416,11 +379,11 @@ def _planted(fault):
 
 
 def test_the_control_puts_everything_back(small):
-    before = _system(small)[0]
+    before = system(small, fresh=True)[0]
     for fault in compare_sdar_moe.FAULTS:
         with _planted(fault):
             pass
-    assert _system(small)[0] == before
+    assert system(small, fresh=True)[0] == before
 
 
 def test_a_float8_reference_fails_the_comparison(small):
@@ -438,9 +401,9 @@ def test_a_float8_reference_fails_the_comparison(small):
 # ------------------------------------------------- bf16, the step factories
 
 def test_bfloat16_activations_change_rounding_only(small):
-    loss, _, _ = _system(small)
+    loss, _, _ = system(small)
     half = dataclasses.replace(small.model, dtype="bfloat16")
-    loss16, grads16, _ = _system(small, half)
+    loss16, grads16, _ = system(small, half)
     assert abs(loss16 / loss - 1) < 1e-2
     assert all(g.dtype == jnp.float32 for g in jax.tree_util.tree_leaves(grads16))
 

@@ -6,7 +6,7 @@ tile and of four, with and without grouped key/value heads; the schedule
 against a brute-force count of the tiles that hold an allowed pair; the
 window's far edge to the key; which shapes tile; the `custom_vjp` as a CPU
 host lowers it; and that the block-diffusion call's tables are what they
-were. The compiled program is held in tests/test_zoo_loader_compile.py's
+were. The compiled program is held in tests/test_compiled_trinity_ling_programs.py's
 neighbours and on the chip (PERF.md section 6, PR 41)."""
 
 import os
@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.reference import afmoe as ref  # noqa: E402
 from parallel_cnn_tpu.nn import afmoe  # noqa: E402
 from parallel_cnn_tpu.ops import pallas_attention as pa  # noqa: E402
+from token_family import pulled  # noqa: E402
 
 T = 128
 # name: (S, window) at tiles of 128 — no window; a window of one tile (the
@@ -74,10 +75,10 @@ def test_the_kernels_agree_with_the_plain_rule(window, group):
     (s, w), (h, kv) = WINDOWS[window], GROUPS[group]
     q, k, v, d_out = _draw(s, h, kv)
     out, lse, got = _kernels(q, k, v, d_out, w)
-    want, vjp = jax.vjp(_one_shot(w), q, k, v)
+    want, want_grads = pulled(_one_shot(w), d_out, q, k, v)
     assert out.shape == q.shape and lse.shape == q.shape[:3]
     assert _gap(out, want) < 2e-6
-    for name, g, x in zip(("dq", "dk", "dv"), got, vjp(d_out)):
+    for name, g, x in zip(("dq", "dk", "dv"), got, want_grads):
         assert g.shape == x.shape and g.dtype == x.dtype
         assert _gap(g, x) < 5e-6, name
     scores = jnp.einsum("ncgqd,nckd->ncgqk", q.reshape(1, kv, h // kv, s, 128), k,
@@ -94,10 +95,10 @@ def test_the_models_plain_path_is_the_same_rule(window):
     s, w = WINDOWS[window]
     q, k, v, d_out = _draw(s, 4, 2, seed=1)
     att = afmoe.GatedGQA(heads=4, kv_heads=2, head_dim=128, window=w, q_block=64)
-    got, vjp = jax.vjp(att._blocks, q, k, v)
-    want, vjp_want = jax.vjp(_one_shot(w), q, k, v)
+    got, grads = pulled(att._blocks, d_out, q, k, v)
+    want, want_grads = pulled(_one_shot(w), d_out, q, k, v)
     assert _gap(got, want) < 2e-6
-    for g, x in zip(vjp(d_out), vjp_want(d_out)):
+    for g, x in zip(grads, want_grads):
         assert _gap(g, x) < 5e-6
     assert np.array_equal(np.asarray(ref.seen(s, w, 0, s)), _rule(s, w))
 
@@ -261,10 +262,10 @@ def test_the_custom_vjp_on_a_cpu_host_runs_the_plain_form():
 
     text = jax.jit(fused).lower(q, k, v).as_text()
     assert "tpu_custom_call" not in text and "stablehlo.case" in text
-    got, vjp = jax.vjp(fused, q, k, v)
-    want, vjp_want = jax.vjp(plain, q, k, v)
+    got, grads = pulled(fused, d_out, q, k, v)
+    want, want_grads = pulled(plain, d_out, q, k, v)
     assert float(jnp.max(jnp.abs(got - want))) == 0.0
-    for g, x in zip(vjp(d_out), vjp_want(d_out)):
+    for g, x in zip(grads, want_grads):
         assert _gap(g, x) < 1e-6
 
 

@@ -15,13 +15,25 @@ from parallel_cnn_tpu.parallel import mesh as mesh_lib
 from parallel_cnn_tpu.train import zoo
 
 
+def _shapes(model, in_shape):
+    """`init` traced, nothing drawn: the parameters and the state as shapes,
+    and the output's shape."""
+    said = []
+
+    def init(key):
+        params, state, out_shape = model.init(key, in_shape)
+        said.append(out_shape)
+        return params, state
+
+    return (*jax.eval_shape(init, jax.random.key(0)), said[0])
+
+
 def test_layer_shapes():
-    key = jax.random.key(0)
     model = cifar.cifar_cnn()
-    params, state, out_shape = model.init(key, cifar.IN_SHAPE)
+    params, state, out_shape = _shapes(model, cifar.IN_SHAPE)
     assert out_shape == (10,)
-    x = jnp.zeros((4, 32, 32, 3), jnp.float32)
-    logits, _ = model.apply(params, state, x)
+    x = jax.ShapeDtypeStruct((4, 32, 32, 3), jnp.float32)
+    logits, _ = jax.eval_shape(model.apply, params, state, x)
     assert logits.shape == (4, 10)
 
 
@@ -37,17 +49,18 @@ def test_layer_shapes():
     ],
 )
 def test_resnet_param_counts_match_torchvision(factory, in_shape, expected_params):
-    model = factory()
-    params, state, out_shape = model.init(jax.random.key(0), in_shape)
+    params, state, out_shape = _shapes(factory(), in_shape)
     assert out_shape == (1000,)
     assert resnet.num_params(params) == expected_params
 
 
 def test_resnet18_cifar_forward_and_bn_state():
     model = resnet.resnet18(10, cifar_stem=True)
-    params, state, _ = model.init(jax.random.key(0), (32, 32, 3))
+    params, state = jax.jit(lambda k: model.init(k, (32, 32, 3))[:2])(
+        jax.random.key(0))
     x = jnp.asarray(np.random.default_rng(0).uniform(size=(2, 32, 32, 3)), jnp.float32)
-    logits, new_state = model.apply(params, state, x, train=True)
+    apply = jax.jit(model.apply, static_argnames="train")
+    logits, new_state = apply(params, state, x, train=True)
     assert logits.shape == (2, 10)
     # train=True must move BN running stats; train=False must not
     before = jax.tree_util.tree_leaves(state)
@@ -56,7 +69,7 @@ def test_resnet18_cifar_forward_and_bn_state():
         not np.allclose(np.asarray(a), np.asarray(b))
         for a, b in zip(before, after, strict=True)
     )
-    _, frozen_state = model.apply(params, new_state, x, train=False)
+    _, frozen_state = apply(params, new_state, x, train=False)
     for a, b in zip(
         jax.tree_util.tree_leaves(new_state),
         jax.tree_util.tree_leaves(frozen_state),
