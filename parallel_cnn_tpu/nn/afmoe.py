@@ -101,10 +101,12 @@ def _gated(out, gate):
 class GatedGQA(Module):
     """Grouped-query attention of one layer kind in its training form:
     `kv_heads` key/value heads, each read by `heads / kv_heads` query
-    heads; an RMSNorm over each head's features of q and of k; RoPE where
-    `rotary`; each query over the `window` keys that end with its own
-    (None: every key up to its own); the output times the sigmoid of a
-    projection of the layer's own input, head for head, before `o`."""
+    heads; an RMSNorm over each head's features of q and of k (`qk_norm`);
+    RoPE where `rotary`; each query over the `window` keys that end with
+    its own (None: every key up to its own); the output times the sigmoid
+    of a projection of the layer's own input, head for head, before `o`
+    (`gated`). Without `qk_norm` or `gated` the layer has no leaf and no
+    op for it (nn/ouro.py: plain multi-head attention)."""
 
     heads: int = 32
     kv_heads: int = 4
@@ -114,6 +116,8 @@ class GatedGQA(Module):
     theta: float = 1e4
     eps: float = 1e-5
     q_block: int = 512
+    qk_norm: bool = True
+    gated: bool = True
 
     def __post_init__(self):
         if self.heads % self.kv_heads:
@@ -127,10 +131,13 @@ class GatedGQA(Module):
         shapes = {"q": (d, self.heads * wide), "k": (d, self.kv_heads * wide),
                   "v": (d, self.kv_heads * wide), "gate": (d, self.heads * wide),
                   "o": (self.heads * wide, d)}
+        # (five keys whatever is asked: a leaf's draw is its place among them)
         params = {n: _weight(k, s, s[0], INIT_STD)
-                  for (n, s), k in zip(shapes.items(), jax.random.split(key, 5))}
-        params["q_norm"] = _ones(wide)
-        params["k_norm"] = _ones(wide)
+                  for (n, s), k in zip(shapes.items(), jax.random.split(key, 5))
+                  if self.gated or n != "gate"}
+        if self.qk_norm:
+            params["q_norm"] = _ones(wide)
+            params["k_norm"] = _ones(wide)
         return params, {}, in_shape
 
     @property
@@ -179,14 +186,16 @@ class GatedGQA(Module):
         w = {k: v.astype(x.dtype) for k, v in params.items()}
         s, wide = x.shape[1], self.head_dim
         with jax.named_scope("qkv"):
-            q, k, v, gate = (
+            q, k, v, *gate = (
                 jnp.einsum("nsm,mhd->nhsd", x, w[name].reshape(-1, heads, wide))
                 for name, heads in (("q", self.heads), ("k", self.kv_heads),
-                                    ("v", self.kv_heads), ("gate", self.heads)))
+                                    ("v", self.kv_heads), ("gate", self.heads))
+                if name in w)
             q, k = row_major(q), row_major(k)
-        with jax.named_scope("qk_norm"):
-            q = _norm(self.eps, w["q_norm"], q)
-            k = _norm(self.eps, w["k_norm"], k)
+        if self.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = _norm(self.eps, w["q_norm"], q)
+                k = _norm(self.eps, w["k_norm"], k)
         if self.rotary:
             with jax.named_scope("rope"):
                 q, k = rope(q, self.theta), rope(k, self.theta)
@@ -197,8 +206,9 @@ class GatedGQA(Module):
                     q, k, v, wide ** -0.5, self.window, t, self._blocks)
             else:
                 out = checkpoint_name(self._blocks(q, k, v), "attn_core")
-        with jax.named_scope("gate"):
-            out = _gated(out, gate)
+        if self.gated:
+            with jax.named_scope("gate"):
+                out = _gated(out, gate[0])
         with jax.named_scope("o"):
             return jnp.einsum("nhsd,hdm->nsm", out,
                               w["o"].reshape(self.heads, wide, -1)), state

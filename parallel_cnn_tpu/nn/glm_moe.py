@@ -775,12 +775,16 @@ class GlmMoe(Module):
         h, layers = self._trunk(params, state, x, train)
         return self._logits(params, h), dict(state, layers=layers)
 
-    def _cross_entropy(self, params, h, y, keep, weight=None):
+    def _cross_entropy(self, params, h, y, keep, weight=None,
+                       summed: bool = True):
         """Sum over the kept positions of the cross-entropy of `h`
         (N, S, d) through the final norm and the head against `y` (times
         `weight`, a float32 a position, where one is given), a block of
         `loss_block` positions at a time: the logits of all positions
-        never exist at once, forward or backward."""
+        never exist at once, forward or backward. Not `summed`: every
+        position's own, float32 `(N, S)`, 0 where it is not kept — for a
+        loss whose weights are themselves functions of the parameters
+        (nn/ouro.py)."""
 
         @jax.checkpoint
         def block(head, h, y, keep, *weight):
@@ -790,15 +794,17 @@ class GlmMoe(Module):
                        - jnp.take_along_axis(z, y[:, None], axis=1)[:, 0])
                 for w in weight:
                     nll = nll * w
-                return jnp.sum(jnp.where(keep, nll, 0.0))
+                nll = jnp.where(keep, nll, 0.0)
+                return jnp.sum(nll) if summed else nll
 
         head = {"norm": params["norm"], "head": params["head"]}
         rows = [h.reshape(-1, h.shape[-1]), y.reshape(-1), keep.reshape(-1)]
         if weight is not None:
             rows.append(weight.reshape(-1))
-        return sum(
-            block(head, *(r[a: a + self.loss_block] for r in rows))
-            for a in range(0, rows[0].shape[0], self.loss_block))
+        blocks = (block(head, *(r[a: a + self.loss_block] for r in rows))
+                  for a in range(0, rows[0].shape[0], self.loss_block))
+        return sum(blocks) if summed else jnp.concatenate(
+            list(blocks)).reshape(y.shape)
 
     def loss(self, params, state, x, y):
         """(loss, new state) of a training forward: the mean next-token

@@ -108,7 +108,10 @@ def scope_of(op_name: str) -> Tuple[str, str]:
         return "optimizer", "opt"
     if path[0] != "grad":
         return "", ""
-    path = _collapse(_layers(path))
+    first, *called = _bodies(path)
+    path = _collapse(_layers(first))
+    for body in called:
+        path += _collapse(_restarted(_layers(body)))
     return "/".join(path) or "grad", "bwd" if "transpose(" in op_name else "fwd"
 
 
@@ -118,6 +121,22 @@ def scope_of(op_name: str) -> Tuple[str, str]:
 # the subscripts `jnp.einsum` opens a scope with.
 _TRANSFORM_SCOPES = frozenset({"grad", "checkpoint", "rematted_computation"})
 _BRANCH = re.compile(r"^branch_\d+_fun$")
+# A body that is traced once and called where it is used (nn/ouro.py's
+# passes: one `lax.scan` body emitted four times in a row) names its ops
+# behind this, and inside it the names start over.
+_CALLED = "closed_call"
+
+
+def _bodies(path: List[str]) -> List[List[str]]:
+    """`path` cut at every `_CALLED`: what lies outside any called body,
+    then each body's own names. A path without one is itself, whole."""
+    out: List[List[str]] = [[]]
+    for c in path:
+        if c == _CALLED:
+            out.append([])
+        else:
+            out[-1].append(c)
+    return out
 
 
 def _layers(path: List[str]) -> List[str]:
@@ -141,6 +160,15 @@ def _collapse(path: List[str]) -> List[str]:
     for n in range(len(path) // 2, 0, -1):
         if path[:n] == path[n:2 * n]:
             return path[:n] + path[2 * n:]
+    return path
+
+
+def _restarted(path: List[str]) -> List[str]:
+    """Inside a called body a `cond` says the body's names so far again
+    behind itself (`l1/attn/core/cond/l1/attn/core`): they are said once."""
+    for i, c in enumerate(path):
+        if c == "cond" and i and path[i + 1:2 * i + 1] == path[:i]:
+            return _restarted(path[:i] + path[2 * i + 1:])
     return path
 
 

@@ -1792,9 +1792,11 @@ def train(
         if hasattr(model, "describe"):
             held_on = next(iter(
                 jax.tree_util.tree_leaves(state.params)[0].devices()))
-            obs.event("zoo_moe", **model.describe(
-                batch_size * math.prod(in_shape), in_shape[-1],
-                held_on.platform))
+            # (a model may name the event itself: nn/ouro.py, `zoo_loop`)
+            obs.event(
+                getattr(model, "setup_event", "zoo_moe"), **model.describe(
+                    batch_size * math.prod(in_shape), in_shape[-1],
+                    held_on.platform))
     aug_fn = None
     if augment:
         from parallel_cnn_tpu.data import augment as aug_lib

@@ -232,6 +232,17 @@ _RESNET_ONLY_CASES = (
     "[ling_3_0_flash_ep64]",
     "test_config_entries[ling_3_0_flash_ep64]",
     "test_what_pr41_left_is_a_prefix_with_closed_slices",
+    # PR 48's configuration, `ouro_2_6b_stage`, is an eighth configuration, a
+    # ninth cell and six more metrics: the same three per-configuration cases
+    # again (no ResNet reference, AdamW's arguments, a `reduced` that is not
+    # empty). PR 43's tests read the manifest with closed indices, so none
+    # pins its end. tests/benchmark/test_ouro_config.py holds what each of
+    # the three held.
+    "test_the_listed_configurations_name_the_resnet_reference"
+    "[ouro_2_6b_stage]",
+    "test_optimizer_args_of_the_listed_configurations_are_sgds_three"
+    "[ouro_2_6b_stage]",
+    "test_config_entries[ouro_2_6b_stage]",
 )
 
 

@@ -1,9 +1,10 @@
-"""Trinity-Mini's and Ling-3.0-flash's programs compiled for a described
-v5e (no chip, no run) at published widths: a window is a schedule of the
-grouped causal kernel pair, the delta rule's chunked scan and the short
-convolutions ahead of it are one kernel a direction, and no score square
-and no state a position reaches HBM; and the kernels those steps call,
-compiled at the cells' shapes. Each step is compiled once a file
+"""Trinity-Mini's, Ling-3.0-flash's and Ouro-2.6B's programs compiled for a
+described v5e (no chip, no run) at published widths: a window is a schedule
+of the grouped causal kernel pair, the delta rule's chunked scan and the
+short convolutions ahead of it are one kernel a direction, no score square
+and no state a position reaches HBM, and a stack run four times is one
+straight program whose every core runs once forward; and the kernels those
+steps call, compiled at the cells' shapes. Each step is compiled once a file
 (tests/compiled_programs.py has what the files share;
 tests/test_compiled_glm_sdar_programs.py the other two families)."""
 
@@ -26,22 +27,25 @@ def topo():
 
 # -- a window is a schedule of the second attention kernel pair (PR 41)
 
-@pytest.mark.parametrize("window,visited", [(2048, 150), (None, 528)],
-                         ids=["window_2048", "full"])
+@pytest.mark.parametrize("shape,window,visited", [
+    ((1, 16384, 32, 4), 2048, 150), ((1, 16384, 32, 4), None, 528),
+    ((2, 4096, 16, 16), None, 36)], ids=["window_2048", "full", "ungrouped_4096"])
 def test_the_grouped_causal_kernels_compile_at_the_cells_shapes(
-        topo, window, visited):
+        topo, shape, window, visited):
     """Mosaic takes both directions at 16,384 positions of 32 query heads
     over 4 key/value heads of 128 (`dk` and `dv` of one (sequence,
     key/value head) fill their VMEM buffers exactly), with the window and
-    without; the grid's last axis is the schedule's length."""
+    without, and at the looped model's 2 x 4,096 positions of 16 heads
+    over 16 (a group of one); the grid's last axis is the schedule's
+    length."""
     from parallel_cnn_tpu.ops import pallas_attention as pa
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    s, h, kv, d = 16384, 32, 4, 128
+    (n, s, h, kv), d = shape, 128
     t = pa.causal_tile(s, window, d)
     assert t == 512 and pa.causal_tiles_visited(s, t, window) == visited
     like = lambda heads, *rest, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
-        (1, heads, s, *rest), dtype, sharding=one_chip)
+        (n, heads, s, *rest), dtype, sharding=one_chip)
     q, k = like(h, d), like(kv, d)
     kw = dict(scale=d ** -0.5, window=window, t=t)
     fwd = jax.jit(lambda q, k, v: pa.gc_forward(q, k, v, **kw)).lower(
@@ -50,7 +54,7 @@ def test_the_grouped_causal_kernels_compile_at_the_cells_shapes(
         q, k, k, q, like(h, dtype=jnp.float32), q).compile().as_text()
     assert "grouped_causal_attention_fwd" in fwd
     assert "grouped_causal_attention_bwd" in bwd
-    assert not re.search(r"\[16384,16384\]", fwd + bwd)
+    assert not re.search(rf"\[{s},{s}\]", fwd + bwd)
 
 
 _afmoe_step = {}
@@ -290,3 +294,43 @@ def test_no_score_square_and_no_state_a_position_reaches_hbm(topo):
     assert (8192, 256) in per_head  # q and k, 192 carried as 256
     assert [qk for qk in per_head if qk[1] >= 512 and qk[0] >= 128] == []
     assert not re.search(r"f32\[8192,1,32,128,128\]", text)
+
+
+# -- a stack run several times a step is one straight program (PR 48)
+
+def test_the_looped_models_step_runs_every_pass_and_keeps_every_core(topo):
+    """Ouro-2.6B's GSPMD train step at published widths, two layers, two
+    sequences of 4,096 tokens, compiled for one described v5e: no loop is
+    left of the four passes; a layer's ops of all four passes lie under
+    `ut/l<i>`; each of the 4 x 2 cores is one forward kernel in the forward
+    pass and one backward kernel, and no backward runs a core again (the
+    rematerialised layer keeps it); RoPE's turn is the kernel; the four
+    exits never hold more than a block's logits."""
+    from parallel_cnn_tpu.nn import ouro
+    from parallel_cnn_tpu.obs import programs
+
+    model = ouro.ouro_2_6b(num_hidden_layers=2)
+    optimizer = zoo.make_optimizer(lr=2e-4, kind="adamw", b1=0.9, b2=0.95,
+                                   weight_decay=0.1)
+    with jax.default_matmul_precision("default"):
+        text = _step_text(topo, model, optimizer, (4096,), 2, None, tokens=True)
+    catalog = programs.parse(text)
+    assert not any(e.opcode == "while" for e in catalog.values())
+    calls = collections.Counter(
+        (e.scope, e.phase) for e in catalog.values() if e.opcode == "custom-call")
+    for i in (0, 1):
+        assert calls[(f"ut/l{i}/attn/core", "fwd")] == 4
+        assert calls[(f"ut/l{i}/attn/core", "bwd")] == 4
+        # q and k, forward, rematerialised forward and backward, four passes
+        assert calls[(f"ut/l{i}/attn/rope", "fwd")] == 8
+        assert calls[(f"ut/l{i}/attn/rope", "bwd")] == 16
+    # (a custom-call without a name stack is the compiler's own)
+    assert set(s for s, _ in calls) - {""} == {
+        f"ut/l{i}/attn/{what}" for i in (0, 1) for what in ("core", "rope")}
+    scopes = {e.scope for e in catalog.values()}
+    for want in ("embed", "ut/l0/attn/qkv", "ut/l1/mlp", "ut/l1/mlp/post_norm",
+                 "ut/exit/norm", "ut/exit/head", "ut/exit/loss", "ut/exit/gate",
+                 "mix", "optimizer"):
+        assert want in scopes, (want, sorted(scopes))
+    assert not re.search(r"f32\[8192,49152\]|\[4096,4096\]", text)
+    assert re.search(r"f32\[2048,49152\]", text)
