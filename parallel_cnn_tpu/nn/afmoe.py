@@ -169,6 +169,17 @@ class GatedGQA(Module):
         t = self._q_block(s)
         return sum(-(-(b - lo) // t) for _, b, lo in self._spans(s, t)), t
 
+    def pairs_computed(self, s: int, platform: str, backward: bool) -> int:
+        """The (query, key) pairs one core executes in one direction, one
+        (sequence, head), on `platform`: the kernels' whole tiles and the
+        sub-squares they keep of a tile the mask's edge crosses, or the
+        plain path's turns, each a block of queries by the keys it reads."""
+        kind, t = self.core(s)
+        if kind == "fused" and platform == "tpu":
+            return pallas_attention.pairs_computed(
+                pallas_attention.causal_schedule(s, t, self.window), 1, t, backward)
+        return sum((b - a) * (b - lo) for a, b, lo in self._spans(s, self._q_block(s)))
+
     def _blocks(self, q, k, v):
         """`q (N, H, S, D)`, `k, v (N, KV, S, D)` in, `(N, H, S, D)` out: a
         block of queries at a time against the keys it may see, from the
@@ -317,6 +328,14 @@ class AfMoe(GlmMoe):
             attention_tiles_visited=visited[FULL][0],
             attention_tiles_visited_by_kind={
                 kind: got[0] for kind, got in visited.items()},
+            attention_pairs_computed=by_kind[FULL].pairs_computed(
+                seq_len, platform, True),
+            attention_pairs_computed_by_kind={
+                kind: att.pairs_computed(seq_len, platform, True)
+                for kind, att in by_kind.items()},
+            attention_pairs_computed_forward_by_kind={
+                kind: att.pairs_computed(seq_len, platform, False)
+                for kind, att in by_kind.items()},
             attention_pairs_allowed_by_kind={
                 kind: pairs_allowed(seq_len, att.window)
                 for kind, att in by_kind.items()})

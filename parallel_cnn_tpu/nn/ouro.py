@@ -250,6 +250,10 @@ class Ouro(GlmMoe):
             entropy_weight=self.entropy_weight,
             attention_core=kind, attention_tile=tile,
             attention_tiles_visited=visited,
+            attention_pairs_computed=self.attn.pairs_computed(
+                seq_len, platform, True),
+            attention_pairs_computed_forward=self.attn.pairs_computed(
+                seq_len, platform, False),
             attention_tiles_total=(-(-seq_len // tile)) ** 2,
             attention_pairs_allowed=pairs_allowed(seq_len, None),
             rope_turn="kernel" if turn is not None and platform == "tpu"

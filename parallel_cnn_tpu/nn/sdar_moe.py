@@ -275,9 +275,17 @@ class SdarMoe(GlmMoe):
         said = super().describe(2 * tokens_per_step, seq_len, platform)
         t = (att.core(seq_len)[1] if said["attention_core"] == "fused"
              else att._q_block(seq_len))
-        # (the plain path's turns are tiles of `t` queries in this count)
+        # (the plain path's turns are tiles of `t` queries in this count, and
+        # whole ones: only the kernels cut a tile a boundary crosses)
+        steps = pallas_attention.schedule(seq_len, t)
+        forward, backward = (
+            pallas_attention.pairs_computed(steps, att.block, t, direction)
+            if said["attention_core"] == "fused" else len(steps) * t * t
+            for direction in (False, True))
         said.update(
-            attention_tiles_visited=pallas_attention.bd_tiles_visited(seq_len, t),
+            attention_tiles_visited=len(steps),
+            attention_pairs_computed=backward,
+            attention_pairs_computed_forward=forward,
             attention_tiles_total=(2 * seq_len // t) ** 2, attention_tile=t,
             attention_pairs_allowed=seq_len * (seq_len + att.block),
             block_length=att.block, tokens_per_step=tokens_per_step,

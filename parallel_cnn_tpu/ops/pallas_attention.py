@@ -56,12 +56,15 @@ CPU host with tiling shapes still runs.
 Tiles were swept on the v5e once, at 4 x 20 x 4,096 x 256 (PERF.md section
 6, PR 33: forward / backward 9.64 / 15.54 ms at 256, 6.26 / 12.07 at 512,
 5.99 / 12.32 at 1,024; the blocked XLA form 22.2 / 47.1 forward / both),
-and are fixed here as a function of `(S, head widths)`.
+and are fixed here as a function of `(S, head widths)`. The grid stays at
+that tile under every mask; what a step of the second pair computes of a
+tile that the mask's edge crosses is under that pair's heading.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
@@ -332,7 +335,19 @@ causal_attention.defvjp(_forward_rule, _backward_rule)
 # fetch from them — a tile the mask excludes is no grid step at all. A tile
 # that a block boundary crosses is masked in the kernel from the positions
 # of its rows and columns (`t % block == 0`, so a tile's own corner starts a
-# block and local indices do).
+# block and local indices do) — and computed in part: such a step cuts its
+# tile into squares of 128 (`sub_squares`, static like the kinds), runs the
+# tile's body over the squares that hold an allowed pair alone, gathered
+# into a few rectangles (`_parts`) that it lays side by side — one pass of
+# the arithmetic between the products, whatever the cut — and masks only the
+# squares the boundary crosses. A skipped square's rows keep their
+# statistics and their accumulators, which is what its `exp(MASKED - m) = 0`
+# left of them at a whole product's cost; every allowed pair's `p` is the
+# number it was. The backward cuts every kind but FULL (4 squares of 16
+# under SAME, 10 under the triangular kinds), the forward SAME alone: where
+# cutting pays was timed on the v5e a step of each kind and direction
+# (PERF.md section 6, PR 49) and is a function of the kind, the direction
+# and the shapes here, no option.
 #
 #   forward   grid (N, H, step): the online softmax over a query tile's
 #             listed key tiles; `out` and `lse` written at its last one.
@@ -419,6 +434,91 @@ def _each_kind(what, kinds, step):
         pl.when((what & 3) == i)(functools.partial(step, kind))
 
 
+def sub_squares(kind: int, block: int, t: int, backward: bool):
+    """(sub, {(a, b): masked}): the side of the squares a step of `kind`
+    cuts its `t x t` tile into, and of those squares (query group a, key
+    group b) the ones that hold an allowed pair, which are all it computes
+    — `masked` where one holds an excluded pair as well, which are all it
+    masks. `sub` is 128 (lanes and sublanes; a block if that is wider),
+    or `t`, the tile whole, where cutting does not pay: a FULL step, and
+    forward every kind but SAME — timed on the v5e a step of each kind
+    and direction (PERF.md section 6, PR 49): the forward's floor a step
+    is above what the triangular kinds' three eighths would save."""
+    if kind == FULL:
+        return t, {(0, 0): False}
+    sub = min(t, max(LANES, block)) if backward or kind == SAME else t
+    lo = lambda g: g * sub // block  # noqa: E731 a group's first block, and
+    hi = lambda g: ((g + 1) * sub - 1) // block  # noqa: E731 its last
+    some = {SAME: lambda a, b: lo(b) <= hi(a) and lo(a) <= hi(b),
+            BEFORE: lambda a, b: lo(b) < hi(a),
+            UPTO: lambda a, b: lo(b) <= hi(a),
+            AFTER: lambda a, b: hi(b) > lo(a)}[kind]
+    every = {SAME: lambda a, b: lo(a) == hi(a) == lo(b) == hi(b),
+             BEFORE: lambda a, b: hi(b) < lo(a),
+             UPTO: lambda a, b: hi(b) <= lo(a),
+             AFTER: lambda a, b: lo(b) > hi(a)}[kind]
+    groups = range(t // sub)
+    return sub, {(a, b): not every(a, b)
+                 for a in groups for b in groups if some(a, b)}
+
+
+def pairs_computed(steps, block: int, t: int, backward: bool) -> int:
+    """The (query, key) pairs a kernel executes under the schedule `steps`,
+    one (sequence, head): the squares `sub_squares` keeps of each step's
+    tile. (`bd_tiles_visited` and `causal_tiles_visited` count grid
+    steps.)"""
+    cut = (sub_squares(kind, block, t, backward) for _, _, kind in steps)
+    return sum(len(kept) * sub * sub for sub, kept in cut)
+
+
+def _parts(kind: int, block: int, t: int, backward: bool):
+    """(sub, [(queries, keys, masked)]) — the kept squares of a step of
+    `kind` gathered into rectangles, one product each: two slices of the
+    tile's rows, and the corners (query, key) inside of the squares that
+    `_bd_mask` has to see. Backward a rectangle a key group (the group's
+    `sub` keys by the queries that see one of them), which the kernel lays
+    side by side along the lanes; forward a rectangle a query group — the
+    tile itself, or a SAME tile's squares, of one width, which it lays
+    side by side down the rows."""
+    sub, kept = sub_squares(kind, block, t, backward)
+    span = lambda g0, g1: slice(g0 * sub, (g1 + 1) * sub)  # noqa: E731
+    parts = []
+    for g in range(t // sub):
+        along = sorted(a if backward else b for a, b in kept
+                       if (b if backward else a) == g)
+        if not along:
+            continue  # a group with no key, or no query, in this tile
+        first, last = along[0], along[-1]
+        assert along == list(range(first, last + 1)), (kind, block, t)
+        if backward:
+            parts.append((span(first, last), span(g, g),
+                          [((a - first) * sub, 0) for a in along if kept[a, g]]))
+        else:
+            parts.append((span(g, g), span(first, last),
+                          [(0, (b - first) * sub) for b in along if kept[g, b]]))
+    return sub, parts
+
+
+def _beside(xs, axis: int):
+    """The arrays `xs` laid side by side along `axis` (one: itself)."""
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=axis)
+
+
+def _masked_at(s, corner, sub: int, kind: int, block: int, rows_are_keys: bool):
+    """`s` — a rectangle of `_parts`, one square high or one wide — with
+    its `sub`-square at `corner` (row, column) under the kind's rule; every
+    other entry as it is."""
+    if s.shape == (sub, sub):
+        return _bd_mask(s, kind, block, rows_are_keys)
+    axis = 0 if s.shape[1] == sub else 1  # the side the squares lie along
+    at, end = corner[axis], s.shape[axis]
+    cut = functools.partial(lax.slice_in_dim, s, axis=axis)
+    square = _bd_mask(cut(at, at + sub), kind, block, rows_are_keys)
+    return jnp.concatenate(
+        ([cut(0, at)] if at else []) + [square]
+        + ([cut(at + sub, end)] if at + sub < end else []), axis=axis)
+
+
 def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
                    lse_ref, m_ref, l_ref, acc_ref, *, scale: float, t: int,
                    block: int, kinds):
@@ -431,18 +531,28 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def step(kind: int):
-        s = _dot(q_ref[0, 0], k_ref[0, 0], _NT) * scale
-        if kind != FULL:
-            s = _bd_mask(s, kind, block, rows_are_keys=False)
-        m_prev, l_prev = m_ref[...], l_ref[...]
+        sub, parts = _parts(kind, block, t, backward=False)
+        # the rectangles' scores side by side down the rows (one rectangle,
+        # or squares of one width on rows that follow one another): one
+        # update of the statistics whatever the cut
+        squares = []
+        for rows, keys, masked in parts:
+            s = _dot(q_ref[0, 0, rows], k_ref[0, 0, keys], _NT) * scale
+            for corner in masked:
+                s = _masked_at(s, corner, sub, kind, block, rows_are_keys=False)
+            squares.append(s)
+        s = _beside(squares, axis=0)
+        rows = slice(parts[0][0].start, parts[-1][0].stop)
+        m_prev, l_prev = m_ref[rows], l_ref[rows]
         m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
-        p = jnp.exp(s - _lanes(m_next, t))
+        p = jnp.exp(s - _lanes(m_next, s.shape[-1]))
         alpha = jnp.exp(m_prev - m_next)
-        l_ref[...] = alpha * l_prev + p.sum(axis=-1)[:, None]
-        m_ref[...] = m_next
-        v = v_ref[0, 0]
-        acc_ref[...] = (_lanes(alpha, acc_ref.shape[-1]) * acc_ref[...]
-                        + _dot(p.astype(v.dtype), v, _NN))
+        l_ref[rows] = alpha * l_prev + p.sum(axis=-1)[:, None]
+        m_ref[rows] = m_next
+        p = p.astype(v_ref.dtype)
+        pv = _beside([_dot(p[r.start - rows.start:r.stop - rows.start],
+                           v_ref[0, 0, keys], _NN) for r, keys, _ in parts], axis=0)
+        acc_ref[rows] = _lanes(alpha, acc_ref.shape[-1]) * acc_ref[rows] + pv
 
     _each_kind(what, kinds, step)
 
@@ -492,9 +602,13 @@ def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
     return out, lse.reshape(n, h, s)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "l", "block", "t", "interpret"))
 def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
                interpret: bool = False):
-    """(out (N, H, 2l, D) in `v.dtype`, lse (N, H, 2l) float32)."""
+    """(out (N, H, 2l, D) in `v.dtype`, lse (N, H, 2l) float32). A `jax.jit`
+    of its own, as the three below: a model calls the pair once a layer
+    with one signature, and a body of rectangles traced and lowered at
+    every call site is set-up time (PERF.md section 6, PR 49)."""
     return scheduled_forward(q, k, v, schedule(l, t), scale=scale, block=block,
                              t=t, interpret=interpret)
 
@@ -516,17 +630,32 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def step(kind: int):
-        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        # keys on the sublanes: (keys, queries)
-        s = _dot(k, q, _NT) * scale
-        if kind != FULL:
-            s = _bd_mask(s, kind, block, rows_are_keys=True)
-        p = jnp.exp(s - lse_ref[0, 0])
-        rows = pl.ds(pl.multiple_of(kt_ref[i] * t, t), t)
-        dv_acc[rows, :] += _dot(p.astype(do.dtype), do, _NN)
-        ds = (p * (_dot(v, do, _NT) - delta_ref[0, 0]) * scale).astype(q.dtype)
-        dk_acc[rows, :] += _dot(ds, q, _NN)
-        dq_acc[...] += _dot(ds, k, _TN)
+        sub, parts = _parts(kind, block, t, backward=True)
+        # keys on the sublanes: (keys, queries), the rectangles (a key group
+        # each, or the tile) side by side along the lanes for one pass of
+        # the arithmetic between the products
+        squares, dps, cols, width = [], [], [], 0
+        for rows, keys, masked in parts:
+            s = _dot(k_ref[0, 0, keys], q_ref[0, 0, rows], _NT) * scale
+            for r, c in masked:
+                s = _masked_at(s, (c, r), sub, kind, block, rows_are_keys=True)
+            squares.append(s)
+            dps.append(_dot(v_ref[0, 0, keys], do_ref[0, 0, rows], _NT))
+            cols.append(slice(width, width + s.shape[1]))
+            width += s.shape[1]
+        along = lambda ref: _beside(  # noqa: E731
+            [ref[0, 0, :, rows] for rows, _, _ in parts], axis=1)
+        p = jnp.exp(_beside(squares, axis=1) - along(lse_ref))
+        ds = (p * (_beside(dps, axis=1) - along(delta_ref)) * scale
+              ).astype(q_ref.dtype)
+        p = p.astype(do_ref.dtype)
+        for (rows, keys, _), c in zip(parts, cols):
+            at = kt_ref[i] * t + keys.start if keys.start else kt_ref[i] * t
+            at = pl.ds(pl.multiple_of(at, math.gcd(t, keys.start)),
+                       keys.stop - keys.start)
+            dv_acc[at, :] += _dot(p[:, c], do_ref[0, 0, rows], _NN)
+            dk_acc[at, :] += _dot(ds[:, c], q_ref[0, 0, rows], _NN)
+            dq_acc[rows] += _dot(ds[:, c], k_ref[0, 0, keys], _TN)
 
     _each_kind(what, kinds, step)
 
@@ -586,6 +715,7 @@ def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
     )(*tables, q, k, v, d_out, lse.reshape(n, h, 1, s), delta)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "l", "block", "t", "interpret"))
 def bd_backward(q, k, v, out, lse, d_out, *, scale: float, l: int, block: int,
                 t: int, interpret: bool = False):
     """(dq, dk, dv) in the dtypes of `q, k, v`; `dk`, `dv` summed over each
@@ -691,6 +821,7 @@ def _causal_call(s: int, t: int, window: Optional[int]):
         t=t, block=1, kinds=tuple(sorted({kind for _, _, kind in steps})))
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "window", "t", "interpret"))
 def gc_forward(q, k, v, *, scale: float, window: Optional[int], t: int,
                interpret: bool = False):
     """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32)."""
@@ -699,6 +830,7 @@ def gc_forward(q, k, v, *, scale: float, window: Optional[int], t: int,
                              name="grouped_causal_attention_fwd", **call)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "window", "t", "interpret"))
 def gc_backward(q, k, v, out, lse, d_out, *, scale: float,
                 window: Optional[int], t: int, interpret: bool = False):
     """(dq, dk, dv) in the dtypes of `q, k, v`."""

@@ -451,6 +451,27 @@ def test_the_gspmd_step_runs_the_model_on_a_mesh_and_zoo_train_records_it(
     assert event["attention_tiles_visited"] == 10 and event["attention_tiles_total"] == 16
     assert event["attention_pairs_allowed_by_kind"] == {
         afmoe.SLIDING: 8 * 9 // 2 + 24 * 8, afmoe.FULL: 32 * 33 // 2}
+    # what the turns execute: 8 queries by the keys from the window's far end
+    # of the first to the last one's own (8, then 15 three times; 8 + 16 + 24 + 32)
+    assert event["attention_pairs_computed_by_kind"] == {
+        afmoe.SLIDING: 8 * (8 + 3 * 15), afmoe.FULL: 8 * 80}
+    assert event["attention_pairs_computed"] == 8 * 80
+    assert (event["attention_pairs_computed_forward_by_kind"]
+            == event["attention_pairs_computed_by_kind"])
+    at_size = afmoe.trinity_mini(
+        layer_types=[afmoe.SLIDING, afmoe.FULL], num_dense_layers=1,
+        vocab_size=25024, held_experts=range(16), row_buffer=32768,
+        gate_gradient=False).describe(16384, 16384, "tpu")
+    # the kernels at the cell's shape (PR 49): 127.5 tile areas of 150 steps
+    # under the window, 516 of 528 without
+    assert at_size["attention_tiles_visited_by_kind"] == {
+        afmoe.SLIDING: 150, afmoe.FULL: 528}
+    assert at_size["attention_pairs_computed_by_kind"] == {
+        afmoe.SLIDING: int(127.5 * 512 * 512), afmoe.FULL: 516 * 512 * 512}
+    assert at_size["attention_pairs_computed"] == 516 * 512 * 512
+    # ... backward; forward every step's tile whole
+    assert at_size["attention_pairs_computed_forward_by_kind"] == {
+        afmoe.SLIDING: 150 * 512 * 512, afmoe.FULL: 528 * 512 * 512}
 
 
 def test_the_scopes_are_the_ones_the_catalog_reads():

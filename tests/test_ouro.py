@@ -416,6 +416,13 @@ def test_zoo_train_lowers_the_loss_and_records_the_loop(host_devices):
             at_size["attention_tiles_visited"], at_size["attention_tiles_total"],
             at_size["rope_turn"], at_size["layer_applications_per_token"]) == (
         "fused", 512, 36, 64, "kernel", 32)
+    # 28 whole tiles and 10 sub-squares of 16 of each of the diagonal's 8 (PR 49)
+    # backward; forward every step's tile whole
+    assert (at_size["attention_pairs_computed"],
+            at_size["attention_pairs_computed_forward"]) == (
+        33 * 512 * 512, 36 * 512 * 512)
+    assert (event["attention_pairs_computed"]
+            == event["attention_pairs_computed_forward"] == 8 * (8 + 16 + 24 + 32))
 
 
 def test_a_model_without_a_name_for_it_keeps_the_zoo_moe_event():

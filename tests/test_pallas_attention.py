@@ -263,7 +263,15 @@ from parallel_cnn_tpu.nn import sdar_moe  # noqa: E402
 # tile the module picks
 BD_SHAPES = {"grouped": (1, 4, 2, 256, 128, 4, 128),
              "ungrouped-b32": (2, 2, 2, 256, 128, 32, 128),
-             "one-tile-a-half": (1, 2, 1, 512, 128, 4, pa.bd_tile(512, 4, 128))}
+             "one-tile-a-half": (1, 2, 1, 512, 128, 4, pa.bd_tile(512, 4, 128)),
+             # tiles cut into sub-squares of 128 (PR 49): two 512-wide tiles a
+             # half at blocks of 32; blocks as wide as a sub-square, so a SAME
+             # tile's kept squares need no mask, a BEFORE tile's diagonal ones
+             # hold nothing and its first row group has no key in that tile;
+             # four sub-squares a 256-wide tile
+             "two-tiles-b32-t512": (1, 2, 1, 1024, 128, 32, 512),
+             "blocks-of-128-t512": (1, 2, 1, 512, 128, 128, 512),
+             "grouped-t256": (1, 4, 2, 256, 128, 4, 256)}
 
 
 def _bd_draw(shape, dtype=jnp.float32, seed=0):
@@ -392,6 +400,81 @@ def test_the_schedule_holds_every_allowed_pair_and_no_other_tile():
                 assert part.all() == (tiles[qi, ki] == pa.FULL)
     assert pa.bd_tiles_visited(4096, 512) == 80
     assert 80 * 512 * 512 / (4096 * 4100) == pytest.approx(1.2488, abs=1e-4)
+
+
+# ------------------- a boundary tile's allowed sub-squares only (PR 49)
+
+def _tile_rule(kind, block, t):
+    """bool (t, t), queries down: a tile's own mask, from its local blocks."""
+    qb, kb = np.arange(t)[:, None] // block, np.arange(t)[None, :] // block
+    return {pa.FULL: np.ones((t, t), bool), pa.SAME: kb == qb, pa.BEFORE: kb < qb,
+            pa.UPTO: kb <= qb, pa.AFTER: kb > qb}[kind] & np.ones((t, t), bool)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+@pytest.mark.parametrize("t", [128, 256, 512])
+@pytest.mark.parametrize("block", [1, 4, 32, 128])
+@pytest.mark.parametrize("kind", [pa.FULL, pa.SAME, pa.BEFORE, pa.UPTO, pa.AFTER],
+                         ids=["FULL", "SAME", "BEFORE", "UPTO", "AFTER"])
+def test_the_kept_sub_squares_are_the_ones_the_mask_reaches(kind, block, t, backward):
+    """Against the mask pair by pair: no allowed pair lies in a sub-square
+    the step skips, no excluded pair in one it computes unmasked; the
+    rectangles a step computes are those squares, each once."""
+    sub, kept = pa.sub_squares(kind, block, t, backward)
+    assert t % sub == 0 and sub % 128 == 0 and (sub % block == 0 or kind == pa.FULL)
+    # cut at 128 backward, and forward where a quarter of the tile is left
+    assert sub == (128 if kind != pa.FULL and (backward or kind == pa.SAME) else t)
+    seen = _tile_rule(kind, block, t)
+    for a in range(t // sub):
+        for b in range(t // sub):
+            part = seen[a * sub:(a + 1) * sub, b * sub:(b + 1) * sub]
+            assert part.any() == ((a, b) in kept), (a, b)
+            if (a, b) in kept:
+                assert kept[a, b] == (not part.all()), (a, b)
+    if block < 128 and sub < t:  # the issue's table
+        n = t // sub
+        want = {pa.SAME: {(a, a) for a in range(n)},
+                pa.AFTER: {(a, b) for a in range(n) for b in range(a, n)}}.get(
+            kind, {(a, b) for a in range(n) for b in range(a + 1)})
+        assert set(kept) == want
+        assert {ab for ab, masked in kept.items() if masked} == {
+            (a, a) for a in range(n)}
+    covered = np.zeros((t, t), int)
+    side, parts = pa._parts(kind, block, t, backward)
+    assert side == sub and len(parts) <= t // sub
+    for rows, keys, masked in parts:
+        covered[rows, keys] += 1
+        assert all(kept[(rows.start + r) // sub, (keys.start + c) // sub]
+                   for r, c in masked)
+    assert sum(len(masked) for _, _, masked in parts) == sum(kept.values())
+    assert np.array_equal(covered > 0, np.kron(
+        np.array([[(a, b) in kept for b in range(t // sub)]
+                  for a in range(t // sub)]), np.ones((sub, sub), bool)))
+    assert covered.max() == (1 if kept else 0)  # (a BEFORE tile of one block: nothing)
+
+
+@pytest.mark.parametrize("cell,steps,block,areas,forward,allowed", [
+    ("sdar_bd_train", pa.schedule(4096, 512), 4, 68, 74, 4096 * 4100),
+    ("trinity_mini_train-window", pa.causal_schedule(16384, 512, 2048), 1, 127.5,
+     150, 31_458_304),
+    ("trinity_mini_train-full", pa.causal_schedule(16384, 512), 1, 516, 528,
+     134_225_920),
+    ("ouro_loop_train", pa.causal_schedule(4096, 512), 1, 33, 36, 4096 * 4097 // 2),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_pairs_the_kernels_compute_at_the_cells_shapes(cell, steps, block, areas,
+                                                           forward, allowed):
+    """Tile areas of 512 x 512 a (sequence, head), backward: 56 + 8 x 4/16
+    + 16 x 10/16 of the 80 steps of the block-diffusion schedule, 127.5 of
+    150 under the window, 516 of 528 and 33 of 36 under the causal rule.
+    Forward only the SAME tiles are cut: 56 + 8 x 4/16 + 16, and every
+    step of a causal schedule whole."""
+    got = pa.pairs_computed(steps, block, 512, backward=True)
+    assert got == areas * 512 * 512
+    assert allowed <= got <= len(steps) * 512 * 512
+    assert got / allowed == pytest.approx(
+        {68: 1.0615, 127.5: 1.0625, 516: 1.0078, 33: 1.031}[areas], abs=1e-4)
+    assert pa.pairs_computed(steps, block, 512, backward=False) == forward * 512 * 512
+    assert forward == (len(steps) if block == 1 else len(steps) - 8 * 12 // 16)
 
 
 def test_the_block_diffusion_custom_vjp_on_a_cpu_host_runs_the_plain_form():

@@ -426,11 +426,19 @@ def test_the_published_model_has_the_counted_parameters():
                 block_length=4, experts_held=16, experts_published=128,
                 experts_per_token=8, expert_layers=6, row_buffer=65536,
                 tokens_per_step=16384, stream_rows_per_step=32768)
-    # each half's 4,096 positions of 128-wide heads tile: the kernel turns them
+    # each half's 4,096 positions of 128-wide heads tile: the kernel turns them,
+    # and its backward computes 68 tile areas of its 80 steps (PR 49: 56 whole,
+    # 8 SAME tiles 4 sub-squares of 16, 16 triangular ones 10), its forward 74
+    # (the triangular ones whole); the plain path whole turns
     assert model.describe(4 * 4096, 4096, "tpu") == dict(
-        said, attention_core="fused", rope_turn="kernel")
+        said, attention_core="fused", rope_turn="kernel",
+        attention_pairs_computed=68 * 512 * 512,
+        attention_pairs_computed_forward=74 * 512 * 512)
     assert model.describe(4 * 4096, 4096, "cpu") == dict(
-        said, attention_core="blocks", rope_turn="plain")
+        said, attention_core="blocks", rope_turn="plain",
+        attention_pairs_computed=80 * 512 * 512,
+        attention_pairs_computed_forward=80 * 512 * 512)
+    assert 68 * 512 * 512 / (4096 * 4100) == pytest.approx(1.0615, abs=1e-4)
     assert 80 * 512 * 512 / (4096 * 4100) == pytest.approx(1.249, abs=1e-3)
     whole = sdar_moe.sdar_30b_a3b()
     assert (whole.n_layers, whole.vocab, len(whole.experts.held)) == (48, 151936, 128)

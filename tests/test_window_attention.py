@@ -29,6 +29,10 @@ T = 128
 # diagonal and the trailing edge, nothing between); a window of four
 WINDOWS = {"none": (512, None), "one-tile": (512, 128), "four-tiles": (768, 512)}
 GROUPS = {"group-1": (2, 2), "group-8": (8, 1)}  # (H, KV)
+# name: (S, window, tile) — tiles the kernels cut into sub-squares of 128 (PR
+# 49): the diagonal's and the trailing edge's are computed in part
+CUT = {"cut-256-two-tiles": (1024, 512, 256), "cut-512-two-tiles": (1536, 1024, 512),
+       "cut-512-none": (1024, None, 512)}
 
 
 def _rule(s, window):
@@ -69,12 +73,12 @@ def _gap(got, want):
 
 
 @pytest.mark.parametrize("group", list(GROUPS))
-@pytest.mark.parametrize("window", list(WINDOWS))
+@pytest.mark.parametrize("window", list(WINDOWS) + list(CUT))
 def test_the_kernels_agree_with_the_plain_rule(window, group):
     """Forward and all three gradients; `dk`, `dv` summed over the group."""
-    (s, w), (h, kv) = WINDOWS[window], GROUPS[group]
+    (s, w, *tile), (h, kv) = {**WINDOWS, **CUT}[window], GROUPS[group]
     q, k, v, d_out = _draw(s, h, kv)
-    out, lse, got = _kernels(q, k, v, d_out, w)
+    out, lse, got = _kernels(q, k, v, d_out, w, *tile)
     want, want_grads = pulled(_one_shot(w), d_out, q, k, v)
     assert out.shape == q.shape and lse.shape == q.shape[:3]
     assert _gap(out, want) < 2e-6
@@ -101,6 +105,41 @@ def test_the_models_plain_path_is_the_same_rule(window):
     for g, x in zip(grads, want_grads):
         assert _gap(g, x) < 5e-6
     assert np.array_equal(np.asarray(ref.seen(s, w, 0, s)), _rule(s, w))
+
+
+def test_a_trailing_tile_that_leaves_whole_row_groups_without_a_key():
+    """The pair under the window's schedule at blocks of 128, the side of a
+    sub-square: query block I sees key block J iff I - 8 < J <= I. An
+    AFTER tile's last row group has no key in it (the step skips the group:
+    its statistics and accumulator stay), its diagonal sub-squares hold
+    nothing, and an UPTO tile's are whole."""
+    s, t, window, block = 1536, 512, 1024, 128
+    steps = pa.causal_schedule(s, t, window)
+    sub, kept = pa.sub_squares(pa.AFTER, block, t, backward=True)
+    assert sub == 128 and not any(a == 3 for a, _ in kept) and len(kept) == 6
+    assert not any(kept.values())
+    assert pa.sub_squares(pa.UPTO, block, t, backward=True)[1] == {
+        (a, b): False for a in range(4) for b in range(a + 1)}
+    # (forward both tiles are computed whole, under the mask)
+    assert pa.sub_squares(pa.AFTER, block, t, backward=False) == (512, {(0, 0): True})
+    q, k, v, d_out = _draw(s, 4, 2, seed=7)
+    kw = dict(scale=128 ** -0.5, block=block, t=t, interpret=True,
+              kinds=(pa.FULL, pa.UPTO, pa.AFTER))
+    out, lse = pa.scheduled_forward(q, k, v, steps, **kw)
+    got = pa.scheduled_backward(q, k, v, out, lse, d_out, steps, **kw)
+    ib, jb = np.arange(s)[:, None] // block, np.arange(s)[None, :] // block
+    rule = (jb <= ib) & (ib - window // block < jb)
+
+    def plain(q, k, v):
+        qg = q.reshape(1, 2, 2, s, 128)
+        sc = jnp.einsum("ncgqd,nckd->ncgqk", qg, k, precision="highest") * 128 ** -0.5
+        p = jax.nn.softmax(jnp.where(rule, sc, -jnp.inf), axis=-1)
+        return jnp.einsum("ncgqk,nckd->ncgqd", p, v, precision="highest").reshape(q.shape)
+
+    want, want_grads = pulled(plain, d_out, q, k, v)
+    assert _gap(out, want) < 2e-6
+    for name, g, x in zip(("dq", "dk", "dv"), got, want_grads):
+        assert _gap(g, x) < 5e-6, name
 
 
 def test_bfloat16_inputs_are_accumulated_in_float32():
