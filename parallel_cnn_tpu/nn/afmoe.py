@@ -169,6 +169,15 @@ class GatedGQA(Module):
         t = self._q_block(s)
         return sum(-(-(b - lo) // t) for _, b, lo in self._spans(s, t)), t
 
+    def heads_a_step(self, s: int, platform: str) -> int:
+        """The query heads of one key/value head a grid step of the kernels
+        carries on `platform` (the plain path has no grid: 1)."""
+        kind, t = self.core(s)
+        if kind == "fused" and platform == "tpu":
+            return pallas_attention.heads_a_step(
+                self.heads // self.kv_heads, t, self.head_dim, s)
+        return 1
+
     def pairs_computed(self, s: int, platform: str, backward: bool) -> int:
         """The (query, key) pairs one core executes in one direction, one
         (sequence, head), on `platform`: the kernels' whole tiles and the
@@ -325,6 +334,10 @@ class AfMoe(GlmMoe):
             attention_layer_kinds=list(self.layer_types),
             attention_window=self.attn.window,
             attention_tile=visited[FULL][1],
+            attention_heads_a_step=by_kind[FULL].heads_a_step(seq_len, platform),
+            attention_heads_a_step_by_kind={
+                kind: att.heads_a_step(seq_len, platform)
+                for kind, att in by_kind.items()},
             attention_tiles_visited=visited[FULL][0],
             attention_tiles_visited_by_kind={
                 kind: got[0] for kind, got in visited.items()},

@@ -249,6 +249,7 @@ class Ouro(GlmMoe):
             layer_applications_per_token=self.passes * self.n_layers,
             entropy_weight=self.entropy_weight,
             attention_core=kind, attention_tile=tile,
+            attention_heads_a_step=self.attn.heads_a_step(seq_len, platform),
             attention_tiles_visited=visited,
             attention_pairs_computed=self.attn.pairs_computed(
                 seq_len, platform, True),

@@ -139,6 +139,16 @@ class GQA(Module):
         t = pallas_attention.bd_tile(l, self.block, self.head_dim)
         return ("blocks", self._q_block(l)) if t is None else ("fused", t)
 
+    def heads_a_step(self, l: int, platform: str) -> int:
+        """The query heads of one key/value head a grid step of the kernels
+        carries for a stream of `2 l` positions on `platform` (the plain
+        path has no grid: 1)."""
+        kind, t = self.core(l)
+        if kind == "fused" and platform == "tpu":
+            return pallas_attention.heads_a_step(
+                self.heads // self.kv_heads, t, self.head_dim, 2 * l)
+        return 1
+
     def _q_block(self, l: int) -> int:
         """Queries a turn of the plain path: whole blocks, within a half."""
         whole = self.q_block - self.q_block % self.block
@@ -287,6 +297,7 @@ class SdarMoe(GlmMoe):
             attention_pairs_computed=backward,
             attention_pairs_computed_forward=forward,
             attention_tiles_total=(2 * seq_len // t) ** 2, attention_tile=t,
+            attention_heads_a_step=att.heads_a_step(seq_len, platform),
             attention_pairs_allowed=seq_len * (seq_len + att.block),
             block_length=att.block, tokens_per_step=tokens_per_step,
             stream_rows_per_step=2 * tokens_per_step)

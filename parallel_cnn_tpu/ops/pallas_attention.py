@@ -349,15 +349,48 @@ causal_attention.defvjp(_forward_rule, _backward_rule)
 # (PERF.md section 6, PR 49) and is a function of the kind, the direction
 # and the shapes here, no option.
 #
-#   forward   grid (N, H, step): the online softmax over a query tile's
-#             listed key tiles; `out` and `lse` written at its last one.
-#   backward  grid (N, KV, head of the group, step), the same list: scores
-#             transposed (keys on the sublanes) as in the causal backward;
-#             `dq` of a query tile accumulates in VMEM over its key tiles;
-#             `dk`, `dv` of one (sequence, key/value head) accumulate in two
-#             float32 VMEM buffers `(2l, D)` over every step of every query
-#             head of the group — the sum over the group is the
-#             accumulator's — and are written once.
+# A grid step carries `G = heads_a_step(group, t, D, S)` query heads of ONE
+# key/value head (blocks `(1, G, t, D)` of `q`, `out`, `d_out`, `dq`, `(1, G,
+# 1, t)` of `lse`, `delta`; the K/V tile fetched once for the `G`): what a
+# step costs before it computes a pair — about 0.9 us of the forward's 1.12
+# and 1 of the backward's 2.18 at one head a step — is paid once for the
+# heads that share its key tile. `G` is the largest of 8, 4, 2 that divides
+# the group and keeps the backward step inside `VMEM_LIMIT_BYTES`, from the
+# v5e's timings at the three cells' shapes (PERF.md section 6, PR 50: a
+# call at `sdar_bd_train`'s shape 11.36 / 19.88 ms forward / backward at one
+# head a step, 9.81 / 18.07 at two, 8.85 / 17.04 at four, 8.46 / 16.53 at
+# eight); a group of one gets 1 and, equation for equation, the kernels of
+# one head a step. How the heads share a step was timed too, a direction at
+# a time:
+#
+#   forward   grid (N, H / G, step): the online softmax over a query tile's
+#             listed key tiles, A HEAD AFTER A HEAD in one step — `G` chains
+#             of product, row maximum, `exp`, row sum, product, each on its
+#             head's rows of the statistics `(G, t, .)`. A FULL step (most
+#             steps are) is `G` bodies, which the scheduler runs one under
+#             another (8.46 ms with every kind so; the heads stacked down
+#             the rows of ONE chain read 9.73, a loop over them 9.53); a
+#             boundary's kinds are one body in a `fori_loop` over the heads
+#             (as bodies they were three quarters of the program's
+#             equations, which set-up traces and lowers, for a thirtieth of
+#             the forward's time: 8.88 ms as it ships). `out` and `lse`
+#             written at the tile's last step.
+#   backward  grid (N, KV, group / G, step), the same list: scores
+#             transposed (keys on the sublanes) as in the causal backward,
+#             THE HEADS SIDE BY SIDE ALONG THE LANES — `s` and `dp` `(t, G
+#             t)` from one product each, one pass of `exp`, `dp - delta`,
+#             `ds`, and `dv += p dO`, `dk += ds q` ONE product each over the
+#             `G t` queries: the sum over the step's heads is the
+#             contraction's, one read-modify-write of the accumulators where
+#             there were `G` (16.53 ms stacked, 16.53 as `G` bodies a step:
+#             the smaller program ships). `dq` of a query tile accumulates
+#             `(G, t, D)` over its key tiles; `dk`, `dv` of one (sequence,
+#             key/value head) accumulate in two float32 VMEM buffers `(S,
+#             D)` over every step of every query head of the group and are
+#             written once.
+#
+# `attention_tiles_visited` and `pairs_computed` go on counting one head's
+# schedule steps and pairs, whatever `G` is.
 
 FULL, SAME, BEFORE, UPTO, AFTER = 0, 1, 2, 3, 4
 BD_KINDS = (FULL, SAME, BEFORE, UPTO)
@@ -377,6 +410,25 @@ def bd_tile(l: int, block: int, width: int) -> Optional[int]:
         if l % t == 0 and t % block == 0:
             return t
     return None
+
+
+def heads_a_step(group: int, t: int, width: int, s: int) -> int:
+    """The query heads of one key/value head that a grid step of the pair
+    carries (`group` of them read it; tiles of `t`, heads `width` wide, `s`
+    positions): as many as divide the group and leave the backward step —
+    the hungrier direction — inside `VMEM_LIMIT_BYTES`, up to the 8 the
+    v5e was timed at (PERF.md section 6, PR 50: every doubling gained,
+    both directions, all three cells' shapes). A group of one: 1, and the
+    kernels a head a step."""
+    # the float32 `dk`, `dv` accumulators and their two-deep bf16 output blocks
+    whole = 2 * s * width * 4 + 2 * 2 * s * width * 2
+    # a head's `q`, `d_out`, `dq` blocks two deep and float32 `dq` accumulator,
+    # and its scores, `dp` and `p` / `ds` of one pass
+    a_head = 3 * 2 * t * width * 2 + t * width * 4 + 3 * t * t * 4
+    for g in (8, 4, 2):
+        if group % g == 0 and whole + g * a_head <= VMEM_LIMIT_BYTES:
+            return g
+    return 1
 
 
 def schedule(l: int, t: int):
@@ -415,16 +467,15 @@ def _tables(steps, kinds):
             as_i32(what))
 
 
-def _bd_mask(s, kind: int, block: int, rows_are_keys: bool):
-    """A tile whose corner starts a block on both sides: `s` where the
-    kind's rule holds between the query's block and the key's (blocks of
-    1: between the query's offset in its tile and the key's in its own)."""
+def _seen(shape, kind: int, block: int, rows_are_keys: bool):
+    """bool `shape`, a tile whose corner starts a block on both sides: where
+    the kind's rule holds between the query's block and the key's (blocks
+    of 1: between the query's offset in its tile and the key's in its own)."""
     shift = block.bit_length() - 1  # a power of two: it divides the tile
-    r = lax.broadcasted_iota(jnp.int32, s.shape, 0) >> shift
-    c = lax.broadcasted_iota(jnp.int32, s.shape, 1) >> shift
+    r = lax.broadcasted_iota(jnp.int32, shape, 0) >> shift
+    c = lax.broadcasted_iota(jnp.int32, shape, 1) >> shift
     qb, kb = (c, r) if rows_are_keys else (r, c)
-    seen = {SAME: kb == qb, BEFORE: kb < qb, UPTO: kb <= qb, AFTER: kb > qb}[kind]
-    return jnp.where(seen, s, MASKED)
+    return {SAME: kb == qb, BEFORE: kb < qb, UPTO: kb <= qb, AFTER: kb > qb}[kind]
 
 
 def _each_kind(what, kinds, step):
@@ -475,7 +526,7 @@ def _parts(kind: int, block: int, t: int, backward: bool):
     """(sub, [(queries, keys, masked)]) — the kept squares of a step of
     `kind` gathered into rectangles, one product each: two slices of the
     tile's rows, and the corners (query, key) inside of the squares that
-    `_bd_mask` has to see. Backward a rectangle a key group (the group's
+    the mask (`_seen`) has to see. Backward a rectangle a key group (the group's
     `sub` keys by the queries that see one of them), which the kernel lays
     side by side along the lanes; forward a rectangle a query group — the
     tile itself, or a SAME tile's squares, of one width, which it lays
@@ -504,24 +555,43 @@ def _beside(xs, axis: int):
     return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=axis)
 
 
-def _masked_at(s, corner, sub: int, kind: int, block: int, rows_are_keys: bool):
-    """`s` — a rectangle of `_parts`, one square high or one wide — with
-    its `sub`-square at `corner` (row, column) under the kind's rule; every
-    other entry as it is."""
-    if s.shape == (sub, sub):
-        return _bd_mask(s, kind, block, rows_are_keys)
-    axis = 0 if s.shape[1] == sub else 1  # the side the squares lie along
-    at, end = corner[axis], s.shape[axis]
-    cut = functools.partial(lax.slice_in_dim, s, axis=axis)
-    square = _bd_mask(cut(at, at + sub), kind, block, rows_are_keys)
-    return jnp.concatenate(
-        ([cut(0, at)] if at else []) + [square]
-        + ([cut(at + sub, end)] if at + sub < end else []), axis=axis)
+def _masked_at(s, at: int, sub: int, seen, heads: int = 1):
+    """`s` — a rectangle of `_parts`, one square high, of each of `heads`
+    heads side by side along the lanes — with every head's `sub`-square
+    that starts at its column `at` under the kind's rule, which `seen(shape)`
+    says from the square's own corner; every other entry as it is."""
+    if s.shape[1] == sub:
+        return jnp.where(seen(s.shape), s, MASKED)
+    cut = functools.partial(lax.slice_in_dim, s, axis=1)
+    pieces, done = [], 0
+    for lo in range(at, s.shape[1], s.shape[1] // heads):
+        square = cut(lo, lo + sub)
+        square = jnp.where(seen(square.shape), square, MASKED)
+        pieces += ([cut(done, lo)] if done < lo else []) + [square]
+        done = lo + sub
+    if done < s.shape[1]:
+        pieces.append(cut(done, s.shape[1]))
+    return jnp.concatenate(pieces, axis=1)
+
+
+def _of_heads(ref, heads: int, rows: slice):
+    """Rows `rows` of every one of the `heads` heads of a block `(1, G, t,
+    d)`, a head after a head: `(heads * rows, d)`."""
+    if heads == 1:
+        return ref[0, 0, rows]
+    x = ref[0, :, rows]
+    return x.reshape(heads * x.shape[1], x.shape[2])
+
+
+def _buffer(heads: int, *shape):
+    """A float32 VMEM buffer of `shape` a head of a step's `heads`: with a
+    leading axis of heads, but for one head (the buffer it had)."""
+    return pltpu.VMEM(shape if heads == 1 else (heads, *shape), jnp.float32)
 
 
 def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
                    lse_ref, m_ref, l_ref, acc_ref, *, scale: float, t: int,
-                   block: int, kinds):
+                   block: int, kinds, heads: int):
     what = what_ref[pl.program_id(2)]
 
     @pl.when((what & _FIRST) != 0)
@@ -530,67 +600,95 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def step(kind: int):
+    def each_head(body, unrolled: bool):
+        if heads == 1 or unrolled:
+            for g in range(heads):
+                body(g)
+        else:
+            lax.fori_loop(0, heads, lambda g, _: body(g), None)
+
+    def head(kind: int, g):
         sub, parts = _parts(kind, block, t, backward=False)
+        seen = functools.partial(_seen, kind=kind, block=block, rows_are_keys=False)
         # the rectangles' scores side by side down the rows (one rectangle,
         # or squares of one width on rows that follow one another): one
         # update of the statistics whatever the cut
         squares = []
         for rows, keys, masked in parts:
-            s = _dot(q_ref[0, 0, rows], k_ref[0, 0, keys], _NT) * scale
-            for corner in masked:
-                s = _masked_at(s, corner, sub, kind, block, rows_are_keys=False)
+            s = _dot(q_ref[0, g, rows], k_ref[0, 0, keys], _NT) * scale
+            for _, at in masked:
+                s = _masked_at(s, at, sub, seen)
             squares.append(s)
         s = _beside(squares, axis=0)
         rows = slice(parts[0][0].start, parts[-1][0].stop)
-        m_prev, l_prev = m_ref[rows], l_ref[rows]
+        mine = rows if heads == 1 else (g, rows)  # its rows of the statistics
+        m_prev, l_prev = m_ref[mine], l_ref[mine]
         m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
         p = jnp.exp(s - _lanes(m_next, s.shape[-1]))
         alpha = jnp.exp(m_prev - m_next)
-        l_ref[rows] = alpha * l_prev + p.sum(axis=-1)[:, None]
-        m_ref[rows] = m_next
+        l_ref[mine] = alpha * l_prev + p.sum(axis=-1)[:, None]
+        m_ref[mine] = m_next
         p = p.astype(v_ref.dtype)
         pv = _beside([_dot(p[r.start - rows.start:r.stop - rows.start],
                            v_ref[0, 0, keys], _NN) for r, keys, _ in parts], axis=0)
-        acc_ref[rows] = _lanes(alpha, acc_ref.shape[-1]) * acc_ref[rows] + pv
+        acc_ref[mine] = _lanes(alpha, acc_ref.shape[-1]) * acc_ref[mine] + pv
+
+    def step(kind: int):
+        # a head after a head, each its own chain of product, maximum, `exp`,
+        # sum, product. FULL — the kind most steps are — as `G` bodies, which
+        # the scheduler runs one under another; a boundary's kinds as one body
+        # in a loop, whose iterations do not overlap: `G` bodies of each were
+        # three quarters of the program for a thirtieth of its time, and
+        # set-up traces and lowers a program's equations (PERF.md section 6,
+        # PR 50)
+        each_head(functools.partial(head, kind), unrolled=kind == FULL)
 
     _each_kind(what, kinds, step)
 
     @pl.when((what & _LAST) != 0)
     def _():
-        l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
-                       ).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).T[:1]
+        def close(g):
+            mine = Ellipsis if heads == 1 else g
+            l = l_ref[mine]
+            o_ref[0, g] = (acc_ref[mine] / _lanes(l, acc_ref.shape[-1])
+                           ).astype(o_ref.dtype)
+            # the statistics are columns, `lse` is stored as a row
+            lse_ref[0, g] = (m_ref[mine] + jnp.log(l)).T[:1]
+
+        each_head(close, unrolled=False)
 
 
 def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
                       kinds=BD_KINDS, name: str = "block_diffusion_attention_fwd",
-                      interpret: bool = False):
+                      heads: Optional[int] = None, interpret: bool = False):
     """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32) of `q (N, H,
     S, D)` over `k, v (N, KV, S, D)` under the schedule `steps` ([(query
     tile, key tile, kind)], query-major, a query tile's first entry
-    leaving no row without a visible key), its kinds among `kinds`."""
+    leaving no row without a visible key), its kinds among `kinds`; a grid
+    step carries `heads` query heads of one key/value head (what
+    `heads_a_step` says of the shapes, unless a test says)."""
     n, h, s, d = q.shape
     group = h // k.shape[1]
+    heads = heads or heads_a_step(group, t, d, s)
+    assert group % heads == 0, (group, heads)
     tables = _tables(steps, kinds)
+    # `h` counts blocks of `heads` heads, `group // heads` of them a key/value head
     at_q = lambda n, h, i, qt, kt, what: (n, h, qt[i], 0)  # noqa: E731
-    at_k = lambda n, h, i, qt, kt, what: (n, h // group, kt[i], 0)  # noqa: E731
+    at_k = lambda n, h, i, qt, kt, what: (n, h // (group // heads), kt[i], 0)  # noqa: E731
     out, lse = pl.pallas_call(
         functools.partial(_bd_fwd_kernel, scale=scale, t=t, block=block,
-                          kinds=kinds),
+                          kinds=kinds, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n, h, tables[0].shape[0]),
-            in_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+            grid=(n, h // heads, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                       pl.BlockSpec((1, 1, t, d), at_k),
                       pl.BlockSpec((1, 1, t, d), at_k)],
-            out_specs=[pl.BlockSpec((1, 1, t, d), at_q),
-                       pl.BlockSpec((1, 1, 1, t),
+            out_specs=[pl.BlockSpec((1, heads, t, d), at_q),
+                       pl.BlockSpec((1, heads, 1, t),
                                     lambda n, h, i, qt, kt, what: (n, h, 0, qt[i]))],
-            scratch_shapes=[pltpu.VMEM((t, LANES), jnp.float32),
-                            pltpu.VMEM((t, LANES), jnp.float32),
-                            pltpu.VMEM((t, d), jnp.float32)]),
+            scratch_shapes=[_buffer(heads, t, LANES), _buffer(heads, t, LANES),
+                            _buffer(heads, t, d)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, v.dtype),
                    jax.ShapeDtypeStruct((n, h, 1, s), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -615,7 +713,8 @@ def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
 
 def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
-                   dv_acc, *, scale: float, t: int, block: int, kinds):
+                   dv_acc, *, scale: float, t: int, block: int, kinds,
+                   heads: int):
     g, i = pl.program_id(2), pl.program_id(3)
     what = what_ref[i]
     end = (g == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
@@ -631,20 +730,28 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
 
     def step(kind: int):
         sub, parts = _parts(kind, block, t, backward=True)
+        seen = functools.partial(_seen, kind=kind, block=block, rows_are_keys=True)
+        if heads > 1:
+            # one mask for every square of the step that a boundary crosses,
+            # whichever head's (a head a step keeps a mask a square: the
+            # program it had)
+            seen = functools.cache(seen)
         # keys on the sublanes: (keys, queries), the rectangles (a key group
-        # each, or the tile) side by side along the lanes for one pass of
-        # the arithmetic between the products
+        # each, or the tile) side by side along the lanes, each the step's
+        # heads' queries a head after a head, for one pass of the arithmetic
+        # between the products
         squares, dps, cols, width = [], [], [], 0
         for rows, keys, masked in parts:
-            s = _dot(k_ref[0, 0, keys], q_ref[0, 0, rows], _NT) * scale
-            for r, c in masked:
-                s = _masked_at(s, (c, r), sub, kind, block, rows_are_keys=True)
+            s = _dot(k_ref[0, 0, keys], _of_heads(q_ref, heads, rows), _NT) * scale
+            for at, _ in masked:
+                s = _masked_at(s, at, sub, seen, heads)
             squares.append(s)
-            dps.append(_dot(v_ref[0, 0, keys], do_ref[0, 0, rows], _NT))
+            dps.append(_dot(v_ref[0, 0, keys], _of_heads(do_ref, heads, rows), _NT))
             cols.append(slice(width, width + s.shape[1]))
             width += s.shape[1]
         along = lambda ref: _beside(  # noqa: E731
-            [ref[0, 0, :, rows] for rows, _, _ in parts], axis=1)
+            [ref[0, j, :, rows] for rows, _, _ in parts for j in range(heads)],
+            axis=1)
         p = jnp.exp(_beside(squares, axis=1) - along(lse_ref))
         ds = (p * (_beside(dps, axis=1) - along(delta_ref)) * scale
               ).astype(q_ref.dtype)
@@ -653,15 +760,21 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
             at = kt_ref[i] * t + keys.start if keys.start else kt_ref[i] * t
             at = pl.ds(pl.multiple_of(at, math.gcd(t, keys.start)),
                        keys.stop - keys.start)
-            dv_acc[at, :] += _dot(p[:, c], do_ref[0, 0, rows], _NN)
-            dk_acc[at, :] += _dot(ds[:, c], q_ref[0, 0, rows], _NN)
-            dq_acc[rows] += _dot(ds[:, c], k_ref[0, 0, keys], _TN)
+            # the sum over the step's heads is the products' own: one
+            # contraction over every head's queries, one read-modify-write
+            dv_acc[at, :] += _dot(p[:, c], _of_heads(do_ref, heads, rows), _NN)
+            dk_acc[at, :] += _dot(ds[:, c], _of_heads(q_ref, heads, rows), _NN)
+            if heads == 1:
+                dq_acc[rows] += _dot(ds[:, c], k_ref[0, 0, keys], _TN)
+            else:  # a head after a head, as the product's rows are
+                dq_acc[:, rows] += _dot(ds[:, c], k_ref[0, 0, keys], _TN).reshape(
+                    heads, rows.stop - rows.start, -1)
 
     _each_kind(what, kinds, step)
 
     @pl.when((what & _LAST) != 0)
     def _():
-        dq_ref[0, 0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[(0, 0) if heads == 1 else 0] = dq_acc[...].astype(dq_ref.dtype)
 
     @pl.when(end)
     def _():
@@ -672,36 +785,39 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
 def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
                        block: int, t: int, kinds=BD_KINDS,
                        name: str = "block_diffusion_attention_bwd",
-                       interpret: bool = False):
+                       heads: Optional[int] = None, interpret: bool = False):
     """(dq, dk, dv) in the dtypes of `q, k, v` under the schedule `steps`
     (`scheduled_forward`); `dk`, `dv` summed over each key/value head's
-    group of query heads."""
+    group of query heads, `heads` of them a grid step."""
     n, h, s, d = q.shape
     kv = k.shape[1]
     group = h // kv
+    heads = heads or heads_a_step(group, t, d, s)
+    assert group % heads == 0, (group, heads)
+    blocks = group // heads  # of `heads` query heads, a key/value head
     delta = jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32),
                     axis=-1).reshape(n, h, 1, s)
     tables = _tables(steps, kinds)
-    at_q = lambda n, c, g, i, qt, kt, what: (n, c * group + g, qt[i], 0)  # noqa: E731
-    at_row = lambda n, c, g, i, qt, kt, what: (n, c * group + g, 0, qt[i])  # noqa: E731
+    at_q = lambda n, c, g, i, qt, kt, what: (n, c * blocks + g, qt[i], 0)  # noqa: E731
+    at_row = lambda n, c, g, i, qt, kt, what: (n, c * blocks + g, 0, qt[i])  # noqa: E731
     at_k = lambda n, c, g, i, qt, kt, what: (n, c, kt[i], 0)  # noqa: E731
     whole = lambda n, c, g, i, qt, kt, what: (n, c, 0, 0)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_bd_bwd_kernel, scale=scale, t=t, block=block,
-                          kinds=kinds),
+                          kinds=kinds, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(n, kv, group, tables[0].shape[0]),
-            in_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+            grid=(n, kv, blocks, tables[0].shape[0]),
+            in_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                       pl.BlockSpec((1, 1, t, d), at_k),
                       pl.BlockSpec((1, 1, t, d), at_k),
-                      pl.BlockSpec((1, 1, t, d), at_q),
-                      pl.BlockSpec((1, 1, 1, t), at_row),
-                      pl.BlockSpec((1, 1, 1, t), at_row)],
-            out_specs=[pl.BlockSpec((1, 1, t, d), at_q),
+                      pl.BlockSpec((1, heads, t, d), at_q),
+                      pl.BlockSpec((1, heads, 1, t), at_row),
+                      pl.BlockSpec((1, heads, 1, t), at_row)],
+            out_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                        pl.BlockSpec((1, 1, s, d), whole),
                        pl.BlockSpec((1, 1, s, d), whole)],
-            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+            scratch_shapes=[_buffer(heads, t, d),
                             pltpu.VMEM((s, d), jnp.float32),
                             pltpu.VMEM((s, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),

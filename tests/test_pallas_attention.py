@@ -6,6 +6,7 @@ blocked `_attend` and against a one-shot float32 masked softmax; the
 shapes do not says so. The compiled program is held in
 tests/test_compiled_glm_sdar_programs.py."""
 
+import functools
 import os
 import sys
 
@@ -329,6 +330,75 @@ def test_the_block_diffusion_kernels_agree(shape, reference):
     assert float(jnp.max(jnp.abs(lse - lse_want.reshape(lse.shape)))) < 1e-5
 
 
+# (query heads a key/value head, of them a grid step): a step's arithmetic
+# runs once for the heads it carries (PR 50)
+HEADS_A_STEP = [(1, 1), (2, 1), (2, 2), (4, 1), (4, 2), (4, 4)]
+
+
+@functools.lru_cache(maxsize=None)
+def _bd_by_heads(group, heads):
+    """(shape, inputs, the kernels' out, lse, (dq, dk, dv)) at two 256-wide
+    tiles a half, each cut into sub-squares of 128: every kind of step."""
+    shape = (1, group, 1, 512, 128, 4, 256)
+    q, k, v, d_out = _bd_draw(shape, seed=5)
+    steps = pa.schedule(512, 256)
+    assert {kind for _, _, kind in steps} == set(pa.BD_KINDS)
+    kw = dict(scale=128 ** -0.5, block=4, t=256, heads=heads, interpret=True)
+    out, lse = pa.scheduled_forward(q, k, v, steps, **kw)
+    return shape, (q, k, v, d_out), out, lse, pa.scheduled_backward(
+        q, k, v, out, lse, d_out, steps, **kw)
+
+
+@pytest.mark.parametrize("group,heads", HEADS_A_STEP, ids=lambda v: str(v))
+def test_the_block_diffusion_kernels_agree_whatever_heads_a_step(group, heads):
+    """SAME, FULL, BEFORE and UPTO steps, both directions, the heads of a
+    step a head after a head down the rows (forward) and along the lanes
+    (backward). Forward and `dq` are the one-head-a-step kernel's to the
+    bit — a row's arithmetic is what it was; `dk`, `dv` are one contraction
+    over the step's heads where there was one a head."""
+    shape, (q, k, v, d_out), out, lse, got = _bd_by_heads(group, heads)
+    want, want_grads = pulled(_bd_one_shot(shape), d_out, q, k, v)
+    assert _gap(out, want) < 2e-6
+    for name, g, w in zip(("dq", "dk", "dv"), got, want_grads):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _gap(g, w) < 5e-6, name
+    _, _, one, lse_one, (dq_one, _, _) = _bd_by_heads(group, 1)
+    assert bool(jnp.all(out == one)) and bool(jnp.all(lse == lse_one))
+    assert bool(jnp.all(got[0] == dq_one))
+
+
+# name: (the call, its arguments, sha256 of `jax.make_jaxpr`'s text forward and
+# backward AT THE PARENT, PR 49's tree `cee35c4`, whose grid steps carried one
+# head each). To re-derive after a change that means to move the kernels:
+# `git archive` the tree to compare with and run this test there.
+ONE_HEAD_A_STEP = {
+    "block-diffusion": ("bd", dict(l=1024, block=4, t=512),
+                        "0a953e869abccdbb", "e644e5a405aa4ab8"),
+    "causal": ("gc", dict(window=None, t=512), "5cca8685b629b984", "044c0b1e0d5167af"),
+    "window": ("gc", dict(window=1024, t=512), "ca8f38a631ccbca3", "f0643b30646ad640"),
+}
+
+
+@pytest.mark.parametrize("name", list(ONE_HEAD_A_STEP))
+def test_a_group_of_one_traces_to_the_kernels_of_one_head_a_step(name):
+    """Key/value heads that one query head reads each (`ouro_loop_train`'s
+    layout) get the program they had before a step could carry several
+    heads: equation for equation, the grid and the index maps included."""
+    import hashlib
+    call, kw, forward, backward = ONE_HEAD_A_STEP[name]
+    q = jax.ShapeDtypeStruct((1, 2, 2048, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, 2, 2048), jnp.float32)
+    f, b = {"bd": (pa.bd_forward, pa.bd_backward),
+            "gc": (pa.gc_forward, pa.gc_backward)}[call]
+    kw = dict(kw, scale=128 ** -0.5)
+    assert pa.heads_a_step(1, 512, 128, 2048) == 1
+    texts = (str(jax.make_jaxpr(lambda q, k, v: f(q, k, v, **kw))(q, q, q)),
+             str(jax.make_jaxpr(lambda *a: b(*a, **kw))(q, q, q, q, lse, q)))
+    assert all("pallas_call" in text for text in texts)
+    got = tuple(hashlib.sha256(text.encode()).hexdigest()[:16] for text in texts)
+    assert got == (forward, backward)
+
+
 def test_the_first_noised_block_sees_itself_alone():
     """Noised block 0 has no clean block before it: its rows are a softmax
     over their own B noised keys, whatever the clean half holds, and the
@@ -475,6 +545,74 @@ def test_the_pairs_the_kernels_compute_at_the_cells_shapes(cell, steps, block, a
         {68: 1.0615, 127.5: 1.0625, 516: 1.0078, 33: 1.031}[areas], abs=1e-4)
     assert pa.pairs_computed(steps, block, 512, backward=False) == forward * 512 * 512
     assert forward == (len(steps) if block == 1 else len(steps) - 8 * 12 // 16)
+
+
+@pytest.mark.parametrize("group,t,width,s,want", [
+    (8, 512, 128, 8192, 8),    # sdar_bd_train
+    (8, 512, 128, 16384, 8),   # trinity_mini_train, either kind of layer: 64 MB to the byte
+    (1, 512, 128, 4096, 1),    # ouro_loop_train: a head a step, the kernels it had
+    (8, 512, 256, 8192, 4),    # heads twice as wide leave room for four
+    (8, 512, 256, 4096, 8), (16, 512, 128, 8192, 8), (4, 512, 128, 8192, 4),
+    (6, 512, 128, 8192, 2), (3, 512, 128, 8192, 1), (8, 128, 128, 512, 8),
+], ids=str)
+def test_heads_a_step_is_a_function_of_the_shapes(group, t, width, s, want):
+    """The largest of 8, 4, 2 that divides the group and whose backward step
+    — the `dk`, `dv` accumulators and their output blocks, the heads' `q`,
+    `d_out`, `dq` blocks, the pass's scores — fits `VMEM_LIMIT_BYTES`."""
+    assert pa.heads_a_step(group, t, width, s) == want
+    for g in range(1, 17):
+        got = pa.heads_a_step(g, t, width, s)
+        assert g % got == 0 and 1 <= got <= 8
+    assert pa.heads_a_step(1, t, width, s) == 1
+
+
+def _cells_models():
+    from parallel_cnn_tpu.nn import afmoe, ouro
+    return {
+        "sdar_bd_train": (lambda: sdar_moe.sdar_30b_a3b(
+            num_hidden_layers=6, vocab_size=18992, held_experts=range(16),
+            row_buffer=65536, gate_gradient=False), (4 * 4096, 4096)),
+        "trinity_mini_train": (lambda: afmoe.trinity_mini(
+            layer_types=[afmoe.SLIDING, afmoe.FULL], num_dense_layers=1,
+            vocab_size=25024, held_experts=range(16), row_buffer=32768,
+            gate_gradient=False), (16384, 16384)),
+        "ouro_loop_train": (lambda: ouro.ouro_2_6b(num_hidden_layers=8), (8192, 4096)),
+    }
+
+
+@pytest.mark.parametrize("cell,heads,steps,backward,forward", [
+    ("sdar_bd_train", 8, 80, 68, 74),
+    ("trinity_mini_train", {"sliding_attention": 8, "full_attention": 8},
+     {"sliding_attention": 150, "full_attention": 528},
+     {"sliding_attention": 127.5, "full_attention": 516},
+     {"sliding_attention": 150, "full_attention": 528}),
+    ("ouro_loop_train", 1, 36, 33, 36),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_describe_says_the_heads_a_step_and_counts_steps_a_head(cell, heads, steps,
+                                                               backward, forward):
+    """`attention_heads_a_step` is new (PR 50); the steps stay schedule steps
+    a (sequence, head) and the pairs what one head's kernels execute,
+    however many heads a grid step carries: the benchmark's readers and
+    rooflines read the same work."""
+    make, sizes = _cells_models()[cell]
+    said, plain = (make().describe(*sizes, platform) for platform in ("tpu", "cpu"))
+    area = 512 * 512
+    if isinstance(heads, dict):
+        assert said["attention_heads_a_step_by_kind"] == heads
+        assert said["attention_heads_a_step"] == heads["full_attention"]
+        assert said["attention_tiles_visited_by_kind"] == steps
+        assert said["attention_pairs_computed_by_kind"] == {
+            kind: int(n * area) for kind, n in backward.items()}
+        assert said["attention_pairs_computed_forward_by_kind"] == {
+            kind: n * area for kind, n in forward.items()}
+        assert set(plain["attention_heads_a_step_by_kind"].values()) == {1}
+    else:
+        assert (said["attention_heads_a_step"], said["attention_tiles_visited"],
+                said["attention_pairs_computed"],
+                said["attention_pairs_computed_forward"]) == (
+            heads, steps, backward * area, forward * area)
+    assert said["attention_tile"] == 512 and said["attention_core"] == "fused"
+    assert plain["attention_heads_a_step"] == 1  # no grid on the plain path
 
 
 def test_the_block_diffusion_custom_vjp_on_a_cpu_host_runs_the_plain_form():

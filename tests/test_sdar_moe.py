@@ -430,12 +430,13 @@ def test_the_published_model_has_the_counted_parameters():
     # and its backward computes 68 tile areas of its 80 steps (PR 49: 56 whole,
     # 8 SAME tiles 4 sub-squares of 16, 16 triangular ones 10), its forward 74
     # (the triangular ones whole); the plain path whole turns
+    # a grid step of the kernels carries the 8 query heads of a key/value head
     assert model.describe(4 * 4096, 4096, "tpu") == dict(
-        said, attention_core="fused", rope_turn="kernel",
+        said, attention_core="fused", rope_turn="kernel", attention_heads_a_step=8,
         attention_pairs_computed=68 * 512 * 512,
         attention_pairs_computed_forward=74 * 512 * 512)
     assert model.describe(4 * 4096, 4096, "cpu") == dict(
-        said, attention_core="blocks", rope_turn="plain",
+        said, attention_core="blocks", rope_turn="plain", attention_heads_a_step=1,
         attention_pairs_computed=80 * 512 * 512,
         attention_pairs_computed_forward=80 * 512 * 512)
     assert 68 * 512 * 512 / (4096 * 4100) == pytest.approx(1.0615, abs=1e-4)

@@ -9,6 +9,7 @@ host lowers it; and that the block-diffusion call's tables are what they
 were. The compiled program is held in tests/test_compiled_trinity_ling_programs.py's
 neighbours and on the chip (PERF.md section 6, PR 41)."""
 
+import functools
 import os
 import sys
 
@@ -89,6 +90,37 @@ def test_the_kernels_agree_with_the_plain_rule(window, group):
                         precision="highest") * 128 ** -0.5
     lse_want = jax.nn.logsumexp(jnp.where(_rule(s, w), scores, -jnp.inf), axis=-1)
     assert float(jnp.max(jnp.abs(lse - lse_want.reshape(lse.shape)))) < 1e-5
+
+
+@functools.lru_cache(maxsize=None)
+def _by_heads(group, heads):
+    """(inputs, the kernels' out, lse, (dq, dk, dv)) under a window of two
+    256-wide tiles over four, each boundary tile cut into sub-squares of
+    128: every kind of step."""
+    q, k, v, d_out = _draw(1024, group, 1, seed=6)
+    steps, call = pa._causal_call(1024, 256, 512)
+    assert call["kinds"] == (pa.FULL, pa.UPTO, pa.AFTER)
+    kw = dict(call, scale=128 ** -0.5, heads=heads, interpret=True)
+    out, lse = pa.scheduled_forward(q, k, v, steps, **kw)
+    return (q, k, v, d_out), out, lse, pa.scheduled_backward(
+        q, k, v, out, lse, d_out, steps, **kw)
+
+
+@pytest.mark.parametrize("group,heads", [(1, 1), (2, 1), (2, 2), (4, 1), (4, 2), (4, 4)],
+                         ids=lambda v: str(v))
+def test_the_kernels_agree_whatever_heads_a_step(group, heads):
+    """UPTO, FULL and AFTER steps, both directions, `heads` query heads of
+    the key/value head a grid step (PR 50). Forward and `dq` are the
+    one-head-a-step kernel's to the bit."""
+    (q, k, v, d_out), out, lse, got = _by_heads(group, heads)
+    want, want_grads = pulled(_one_shot(512), d_out, q, k, v)
+    assert _gap(out, want) < 2e-6
+    for name, g, x in zip(("dq", "dk", "dv"), got, want_grads):
+        assert g.shape == x.shape and g.dtype == x.dtype
+        assert _gap(g, x) < 5e-6, name
+    _, one, lse_one, (dq_one, _, _) = _by_heads(group, 1)
+    assert bool(jnp.all(out == one)) and bool(jnp.all(lse == lse_one))
+    assert bool(jnp.all(got[0] == dq_one))
 
 
 @pytest.mark.parametrize("window", list(WINDOWS))
