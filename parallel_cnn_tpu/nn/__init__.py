@@ -40,6 +40,7 @@ from parallel_cnn_tpu.nn import (  # noqa: F401
     cifar,
     convnext,
     glm_moe,
+    keye_vl,
     ouro,
     resnet,
     sdar_moe,

@@ -73,7 +73,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import ClassVar, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -682,6 +682,9 @@ class GlmMoe(Module):
     dtype: str = "bfloat16"
     loss_block: int = 2048
 
+    # what a rematerialised layer keeps of its forward, by name
+    kept_names: ClassVar[Tuple[str, ...]] = ("attn_core", "moe_plan")
+
     def __post_init__(self):
         if self.mtp_modules not in (0, 1):
             raise ValueError(
@@ -728,7 +731,7 @@ class GlmMoe(Module):
         if train:
             fn = jax.checkpoint(
                 fn, policy=jax.checkpoint_policies.save_only_these_names(
-                    "attn_core", "moe_plan"))
+                    *self.kept_names))
         return fn(params, state, x)
 
     def hidden_states(self, params, state, x, train: bool = False):

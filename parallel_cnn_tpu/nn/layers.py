@@ -453,23 +453,25 @@ def row_major(x):
     return with_layout_constraint(x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, positions: Optional[pallas_rope.Axes] = None):
     """Rotary position embedding (Su et al. 2021) of ``x`` ``(..., S, d)``
-    at positions 0..S-1 along the axis before the last: feature ``i`` is
-    paired with ``i + d/2`` (the "rotate half" convention) and the pair
-    turned by ``position * theta ** (-2i / d)``. Angles and the turn are
-    float32, the result is cast back to ``x.dtype``. Shapes that
-    ``pallas_rope.tile`` takes are turned by its kernel where the program
-    is lowered for a TPU, and by the plain body below everywhere else."""
+    along the axis before the last: feature ``i`` is paired with
+    ``i + d/2`` (the "rotate half" convention) and the pair turned by
+    ``position * theta ** (-2i / d)`` — the position 0..S-1, or, with
+    ``positions`` (ops/pallas_rope.py:Axes, static), the one of the axis
+    that pair i turns by. Angles and the turn are float32, the result is
+    cast back to ``x.dtype``. Shapes that ``pallas_rope.tile`` takes are
+    turned by its kernel where the program is lowered for a TPU, and by
+    the plain body below everywhere else."""
     if pallas_rope.tile(*x.shape[-2:]) is None:
-        return _rope(x, theta)
-    return pallas_rope.turn(x, theta, _rope)
+        return _rope(x, theta, positions)
+    return pallas_rope.turn(x, theta, _rope, positions)
 
 
-def _rope(x, theta: float):
+def _rope(x, theta: float, positions: Optional[pallas_rope.Axes] = None):
     """`rope`'s plain body: two halves turned and joined, in plain XLA."""
     d = x.shape[-1]
-    cos, sin = pallas_rope.cos_sin(x.shape[-2], d, theta)
+    cos, sin = pallas_rope.cos_sin(x.shape[-2], d, theta, positions)
     xf = x.astype(jnp.float32)
     a, b = xf[..., : d // 2], xf[..., d // 2:]
     return jnp.concatenate(

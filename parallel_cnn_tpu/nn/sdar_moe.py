@@ -66,7 +66,7 @@ from parallel_cnn_tpu.nn.glm_moe import (
     _ones,
 )
 from parallel_cnn_tpu.nn.layers import _weight, rope, row_major
-from parallel_cnn_tpu.ops import pallas_attention
+from parallel_cnn_tpu.ops import pallas_attention, pallas_rope
 
 NOISE_EPS = 1e-3
 
@@ -96,14 +96,37 @@ def _attend(q, k, v, q_at, k_at, l: int, block: int, scale: float):
     return jnp.einsum("ncgqk,nckd->ncgqd", p.astype(v.dtype), v)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _attend_chosen(q, k, v, bias, scale: float):
+    """`q (N, KV, G, q, D)` against `k, v (N, KV, k, D)` under `bias (N, q,
+    k)` (0 where a pair is allowed, a large negative number elsewhere; one
+    for all heads): (out, the rows' log-sum-exp), scores and softmax in
+    float32. Rematerialised, as `_attend`."""
+    s = jnp.einsum("ncgqd,nckd->ncgqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    s = s + bias.astype(jnp.float32)[:, None, None]
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    return jnp.einsum("ncgqk,nckd->ncgqd", p.astype(v.dtype), v), lse
+
+
 @dataclasses.dataclass(frozen=True)
 class GQA(Module):
-    """Grouped-query attention over the two-copy stream `(N, 2L, d)` of a
-    block-diffusion model, in its training form: `kv_heads` key/value
+    """Grouped-query attention in its training form: `kv_heads` key/value
     heads, each read by `heads / kv_heads` query heads; an RMSNorm over
-    each head's features of q and of k before RoPE; the block-diffusion
-    mask of blocks of `block`. A clean sequence alone is the stream of it
-    twice (`SdarMoe.apply`)."""
+    each head's features of q and of k before RoPE. What a query may see
+    is one of two things. Without `select`: the two-copy stream `(N, 2L,
+    d)` of a block-diffusion model under the block-diffusion mask of blocks
+    of `block` (a clean sequence alone is the stream of it twice:
+    `SdarMoe.apply`). With `select` (nn/keye_vl.py:Indexer, a module of its
+    own parameters under `params["indexer"]`): ONE copy of the sequence,
+    each query over the keys up to its own that `select` chose for it —
+    the choice is data, made from the layer's input with no gradient to or
+    from the trunk — and `apply` hands back, as the layer's state, what the
+    selection has to say (`Indexer.report`: its own loss term, which is the
+    one thing here that carries a gradient to it, and two counters).
+    `positions` (ops/pallas_rope.py:Axes, static): where RoPE's pairs take
+    their positions from when it is not 0..S-1."""
 
     heads: int = 32
     kv_heads: int = 4
@@ -112,6 +135,8 @@ class GQA(Module):
     theta: float = 1e6
     eps: float = 1e-6
     q_block: int = 512
+    select: Optional[Module] = None
+    positions: Optional[pallas_rope.Axes] = None
 
     def __post_init__(self):
         if self.heads % self.kv_heads:
@@ -126,7 +151,12 @@ class GQA(Module):
                   for (n, s), k in zip(shapes.items(), jax.random.split(key, 4))}
         params["q_norm"] = _ones(wide)
         params["k_norm"] = _ones(wide)
-        return params, {}, in_shape
+        if self.select is None:
+            return params, {}, in_shape
+        # (a fold of the key: the four leaves above keep their draws)
+        params["indexer"], state, _ = self.select.init(
+            jax.random.fold_in(key, 4), in_shape)
+        return params, state, in_shape
 
     @property
     def rope_dim(self) -> int:
@@ -135,7 +165,11 @@ class GQA(Module):
 
     def core(self, l: int) -> Tuple[str, int]:
         """(`"fused"` | `"blocks"`, the tile's side) for a stream of `2 l`
-        positions: what the shapes allow (`MLA.core`)."""
+        positions (under `select`: a sequence of `l`): what the shapes
+        allow (`MLA.core`)."""
+        if self.select is not None:
+            t = pallas_attention.causal_tile(l, None, self.head_dim)
+            return ("blocks", min(self.q_block, l)) if t is None else ("fused", t)
         t = pallas_attention.bd_tile(l, self.block, self.head_dim)
         return ("blocks", self._q_block(l)) if t is None else ("fused", t)
 
@@ -146,7 +180,8 @@ class GQA(Module):
         kind, t = self.core(l)
         if kind == "fused" and platform == "tpu":
             return pallas_attention.heads_a_step(
-                self.heads // self.kv_heads, t, self.head_dim, 2 * l)
+                self.heads // self.kv_heads, t, self.head_dim,
+                l if self.select else 2 * l, data=self.select is not None)
         return 1
 
     def _q_block(self, l: int) -> int:
@@ -177,9 +212,62 @@ class GQA(Module):
                                seen(at, 0), l, self.block, d ** -0.5))
         return jnp.concatenate(out, axis=3).reshape(n, h, s, d)
 
+    def _chosen(self, q, k, v, bias):
+        """(`(N, H, S, D)`, the rows' log-sum-exp `(N, H, S)` float32) of `q
+        (N, H, S, D)`, `k, v (N, KV, S, D)` under `bias (N, S, S)`: a block
+        of queries at a time against the keys up to its last."""
+        n, h, s, d = q.shape
+        step = s if s % self.q_block else self.q_block
+        q = q.reshape(n, self.kv_heads, h // self.kv_heads, s, d)
+        out, lse = zip(*(
+            _attend_chosen(q[:, :, :, a:a + step], k[:, :, :a + step],
+                           v[:, :, :a + step], bias[:, a:a + step, :a + step],
+                           d ** -0.5) for a in range(0, s, step)))
+        return (jnp.concatenate(out, axis=3).reshape(n, h, s, d),
+                jnp.concatenate(lse, axis=3).reshape(n, h, s))
+
+    def _over_blocks(self, q, k, v):
+        """What follows the norms of q and k under the block-diffusion mask:
+        the heads' outputs `(N, H, 2L, D)`."""
+        n, _, s, wide = q.shape
+        l = s // 2
+        with jax.named_scope("rope"):
+            # both halves count their positions from 0
+            q, k = (rope(a.reshape(n, -1, 2, l, wide), self.theta
+                         ).reshape(a.shape) for a in (q, k))
+        with jax.named_scope("core"):
+            kind, t = self.core(l)
+            if kind == "fused":
+                return pallas_attention.block_diffusion_attention(
+                    q, k, v, wide ** -0.5, l, self.block, t, self._blocks)
+            return checkpoint_name(self._blocks(q, k, v), "attn_core")
+
+    def _over_chosen(self, params, x, q, k, v):
+        """What follows the norms of q and k under `select`: (the heads'
+        outputs `(N, H, S, D)`, the selection's report)."""
+        s, wide = x.shape[1], self.head_dim
+        with jax.named_scope("rope"):
+            q = rope(q, self.theta, self.positions)
+            k = rope(k, self.theta, self.positions)
+        with jax.named_scope("indexer"):
+            index = self.select.project(
+                params["indexer"], lax.stop_gradient(x), self.positions)
+            kind, t = self.core(s)
+            bias, counts = self.select.choose(index, t)
+        with jax.named_scope("core"):
+            if kind == "fused":
+                out, lse = pallas_attention.selected_attention(
+                    q, k, v, bias, wide ** -0.5, t, self._chosen)
+            else:
+                out, lse = (checkpoint_name(a, "attn_core")
+                            for a in self._chosen(q, k, v, bias))
+        with jax.named_scope("indexer"):
+            report = self.select.report(index, q, k, lse, bias, wide ** -0.5, counts)
+        return out, report
+
     def apply(self, params, state, x, train: bool = False):
         """Heads ahead of positions throughout, as `MLA.apply`."""
-        w = {k: v.astype(x.dtype) for k, v in params.items()}
+        w = {k: v.astype(x.dtype) for k, v in params.items() if k != "indexer"}
         n, s, _ = x.shape
         l, wide = s // 2, self.head_dim
         with jax.named_scope("qkv"):
@@ -191,17 +279,10 @@ class GQA(Module):
         with jax.named_scope("qk_norm"):
             q = _norm(self.eps, w["q_norm"], q)
             k = _norm(self.eps, w["k_norm"], k)
-        with jax.named_scope("rope"):
-            # both halves count their positions from 0
-            q, k = (rope(a.reshape(n, -1, 2, l, wide), self.theta
-                         ).reshape(a.shape) for a in (q, k))
-        with jax.named_scope("core"):
-            kind, t = self.core(l)
-            if kind == "fused":
-                out = pallas_attention.block_diffusion_attention(
-                    q, k, v, wide ** -0.5, l, self.block, t, self._blocks)
-            else:
-                out = checkpoint_name(self._blocks(q, k, v), "attn_core")
+        if self.select is not None:
+            out, state = self._over_chosen(params, x, q, k, v)
+        else:
+            out = self._over_blocks(q, k, v)
         with jax.named_scope("o"):
             return jnp.einsum("nhsd,hdm->nsm", out,
                               w["o"].reshape(self.heads, wide, -1)), state

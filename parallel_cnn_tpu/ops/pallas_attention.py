@@ -392,7 +392,7 @@ causal_attention.defvjp(_forward_rule, _backward_rule)
 # `attention_tiles_visited` and `pairs_computed` go on counting one head's
 # schedule steps and pairs, whatever `G` is.
 
-FULL, SAME, BEFORE, UPTO, AFTER = 0, 1, 2, 3, 4
+FULL, SAME, BEFORE, UPTO, AFTER, DATA = 0, 1, 2, 3, 4, 5
 BD_KINDS = (FULL, SAME, BEFORE, UPTO)
 # An entry of the third table: the kind's place among the kinds its call
 # dispatches over (two bits: a schedule holds at most four), and two flags.
@@ -412,19 +412,22 @@ def bd_tile(l: int, block: int, width: int) -> Optional[int]:
     return None
 
 
-def heads_a_step(group: int, t: int, width: int, s: int) -> int:
+def heads_a_step(group: int, t: int, width: int, s: int,
+                 data: bool = False) -> int:
     """The query heads of one key/value head that a grid step of the pair
     carries (`group` of them read it; tiles of `t`, heads `width` wide, `s`
     positions): as many as divide the group and leave the backward step —
     the hungrier direction — inside `VMEM_LIMIT_BYTES`, up to the 8 the
     v5e was timed at (PERF.md section 6, PR 50: every doubling gained,
     both directions, all three cells' shapes). A group of one: 1, and the
-    kernels a head a step."""
+    kernels a head a step. `data`: the schedule's tiles are masked by an
+    array (`DATA`), whose tile the backward lays beside each head's scores."""
     # the float32 `dk`, `dv` accumulators and their two-deep bf16 output blocks
     whole = 2 * s * width * 4 + 2 * 2 * s * width * 2
     # a head's `q`, `d_out`, `dq` blocks two deep and float32 `dq` accumulator,
     # and its scores, `dp` and `p` / `ds` of one pass
-    a_head = 3 * 2 * t * width * 2 + t * width * 4 + 3 * t * t * 4
+    a_head = (3 * 2 * t * width * 2 + t * width * 4
+              + (4 if data else 3) * t * t * 4)
     for g in (8, 4, 2):
         if group % g == 0 and whole + g * a_head <= VMEM_LIMIT_BYTES:
             return g
@@ -495,7 +498,7 @@ def sub_squares(kind: int, block: int, t: int, backward: bool):
     forward every kind but SAME — timed on the v5e a step of each kind
     and direction (PERF.md section 6, PR 49): the forward's floor a step
     is above what the triangular kinds' three eighths would save."""
-    if kind == FULL:
+    if kind in (FULL, DATA):  # (a DATA tile's mask is an array's, all of it)
         return t, {(0, 0): False}
     sub = min(t, max(LANES, block)) if backward or kind == SAME else t
     lo = lambda g: g * sub // block  # noqa: E731 a group's first block, and
@@ -589,9 +592,10 @@ def _buffer(heads: int, *shape):
     return pltpu.VMEM(shape if heads == 1 else (heads, *shape), jnp.float32)
 
 
-def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
-                   lse_ref, m_ref, l_ref, acc_ref, *, scale: float, t: int,
-                   block: int, kinds, heads: int):
+def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, *rest,
+                   scale: float, t: int, block: int, kinds, heads: int):
+    # (a schedule with DATA tiles brings the array that masks them)
+    *bias_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
     what = what_ref[pl.program_id(2)]
 
     @pl.when((what & _FIRST) != 0)
@@ -618,6 +622,8 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
             s = _dot(q_ref[0, g, rows], k_ref[0, 0, keys], _NT) * scale
             for _, at in masked:
                 s = _masked_at(s, at, sub, seen)
+            if kind == DATA:
+                s = s + bias_ref[0][0].astype(jnp.float32)
             squares.append(s)
         s = _beside(squares, axis=0)
         rows = slice(parts[0][0].start, parts[-1][0].stop)
@@ -641,7 +647,7 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
         # three quarters of the program for a thirtieth of its time, and
         # set-up traces and lowers a program's equations (PERF.md section 6,
         # PR 50)
-        each_head(functools.partial(head, kind), unrolled=kind == FULL)
+        each_head(functools.partial(head, kind), unrolled=kind in (FULL, DATA))
 
     _each_kind(what, kinds, step)
 
@@ -660,18 +666,24 @@ def _bd_fwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, o_ref,
 
 def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
                       kinds=BD_KINDS, name: str = "block_diffusion_attention_fwd",
-                      heads: Optional[int] = None, interpret: bool = False):
+                      heads: Optional[int] = None, interpret: bool = False,
+                      bias=None):
     """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32) of `q (N, H,
     S, D)` over `k, v (N, KV, S, D)` under the schedule `steps` ([(query
     tile, key tile, kind)], query-major, a query tile's first entry
     leaving no row without a visible key), its kinds among `kinds`; a grid
     step carries `heads` query heads of one key/value head (what
-    `heads_a_step` says of the shapes, unless a test says)."""
+    `heads_a_step` says of the shapes, unless a test says). `bias (N, S, S)`
+    (queries, keys) is added to the scores of the tiles of kind DATA: 0
+    where a pair is allowed, `MASKED` where it is not."""
     n, h, s, d = q.shape
     group = h // k.shape[1]
-    heads = heads or heads_a_step(group, t, d, s)
+    heads = heads or heads_a_step(group, t, d, s, bias is not None)
     assert group % heads == 0, (group, heads)
+    assert (bias is not None) == (DATA in kinds), kinds
     tables = _tables(steps, kinds)
+    masks, mask_specs = ((), ()) if bias is None else ((bias,), (pl.BlockSpec(
+        (1, t, t), lambda n, h, i, qt, kt, what: (n, qt[i], kt[i])),))
     # `h` counts blocks of `heads` heads, `group // heads` of them a key/value head
     at_q = lambda n, h, i, qt, kt, what: (n, h, qt[i], 0)  # noqa: E731
     at_k = lambda n, h, i, qt, kt, what: (n, h // (group // heads), kt[i], 0)  # noqa: E731
@@ -683,7 +695,7 @@ def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
             grid=(n, h // heads, tables[0].shape[0]),
             in_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                       pl.BlockSpec((1, 1, t, d), at_k),
-                      pl.BlockSpec((1, 1, t, d), at_k)],
+                      pl.BlockSpec((1, 1, t, d), at_k), *mask_specs],
             out_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                        pl.BlockSpec((1, heads, 1, t),
                                     lambda n, h, i, qt, kt, what: (n, h, 0, qt[i]))],
@@ -696,7 +708,7 @@ def scheduled_forward(q, k, v, steps, *, scale: float, block: int, t: int,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name=name,
-    )(*tables, q, k, v)
+    )(*tables, q, k, v, *masks)
     return out, lse.reshape(n, h, s)
 
 
@@ -712,9 +724,11 @@ def bd_forward(q, k, v, *, scale: float, l: int, block: int, t: int,
 
 
 def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
-                   dv_acc, *, scale: float, t: int, block: int, kinds,
-                   heads: int):
+                   lse_ref, delta_ref, *rest, scale: float, t: int, block: int,
+                   kinds, heads: int):
+    # (a schedule with DATA tiles brings the array that masks them, keys down
+    # its rows)
+    *bias_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     g, i = pl.program_id(2), pl.program_id(3)
     what = what_ref[i]
     end = (g == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
@@ -745,6 +759,9 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
             s = _dot(k_ref[0, 0, keys], _of_heads(q_ref, heads, rows), _NT) * scale
             for at, _ in masked:
                 s = _masked_at(s, at, sub, seen, heads)
+            if kind == DATA:  # the one tile, beside every head's scores
+                s = s + _beside([bias_ref[0][0].astype(jnp.float32)] * heads,
+                                axis=1)
             squares.append(s)
             dps.append(_dot(v_ref[0, 0, keys], _of_heads(do_ref, heads, rows), _NT))
             cols.append(slice(width, width + s.shape[1]))
@@ -785,15 +802,21 @@ def _bd_bwd_kernel(qt_ref, kt_ref, what_ref, q_ref, k_ref, v_ref, do_ref,
 def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
                        block: int, t: int, kinds=BD_KINDS,
                        name: str = "block_diffusion_attention_bwd",
-                       heads: Optional[int] = None, interpret: bool = False):
+                       heads: Optional[int] = None, interpret: bool = False,
+                       bias_t=None):
     """(dq, dk, dv) in the dtypes of `q, k, v` under the schedule `steps`
     (`scheduled_forward`); `dk`, `dv` summed over each key/value head's
-    group of query heads, `heads` of them a grid step."""
+    group of query heads, `heads` of them a grid step. `bias_t (N, S, S)`:
+    the forward's `bias` with the keys ahead of the queries, as the
+    backward's scores lie."""
     n, h, s, d = q.shape
     kv = k.shape[1]
     group = h // kv
-    heads = heads or heads_a_step(group, t, d, s)
+    heads = heads or heads_a_step(group, t, d, s, bias_t is not None)
     assert group % heads == 0, (group, heads)
+    assert (bias_t is not None) == (DATA in kinds), kinds
+    masks, mask_specs = ((), ()) if bias_t is None else ((bias_t,), (pl.BlockSpec(
+        (1, t, t), lambda n, c, g, i, qt, kt, what: (n, kt[i], qt[i])),))
     blocks = group // heads  # of `heads` query heads, a key/value head
     delta = jnp.sum(out.astype(jnp.float32) * d_out.astype(jnp.float32),
                     axis=-1).reshape(n, h, 1, s)
@@ -813,7 +836,7 @@ def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
                       pl.BlockSpec((1, 1, t, d), at_k),
                       pl.BlockSpec((1, heads, t, d), at_q),
                       pl.BlockSpec((1, heads, 1, t), at_row),
-                      pl.BlockSpec((1, heads, 1, t), at_row)],
+                      pl.BlockSpec((1, heads, 1, t), at_row), *mask_specs],
             out_specs=[pl.BlockSpec((1, heads, t, d), at_q),
                        pl.BlockSpec((1, 1, s, d), whole),
                        pl.BlockSpec((1, 1, s, d), whole)],
@@ -828,7 +851,7 @@ def scheduled_backward(q, k, v, out, lse, d_out, steps, *, scale: float,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name=name,
-    )(*tables, q, k, v, d_out, lse.reshape(n, h, 1, s), delta)
+    )(*tables, q, k, v, d_out, lse.reshape(n, h, 1, s), delta, *masks)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "l", "block", "t", "interpret"))
@@ -990,3 +1013,82 @@ def _gc_backward_rule(scale, window, t, otherwise, residuals, d_out):
 
 
 grouped_causal_attention.defvjp(_gc_forward_rule, _gc_backward_rule)
+
+
+# ======================================= a selection over grouped heads
+#
+# The mask that is data (nn/keye_vl.py: a learned indexer chooses, for every
+# query, the keys it attends to): the causal schedule again, every tile of it
+# of one more kind, DATA, whose rule is no arithmetic on the tile's place but
+# an array the call brings — `bias (N, S, S)`, queries by keys, one for all
+# heads, 0 where the pair is allowed and `MASKED` where it is not (the causal
+# rule folded in), added to the tile's scores; the backward reads it with the
+# keys ahead (`bias_t`), as its scores lie. A row whose tile holds none of
+# its keys passes through `exp(MASKED - m) = 0` like any masked stretch, and a
+# row that has seen no key YET carries sums that the first allowed key's
+# `alpha = 0` wipes. Every causal tile is a grid step: which tiles a
+# selection leaves empty is known on the device alone (PERF.md section 7).
+
+def selected_schedule(s: int, t: int):
+    """[(query tile, key tile, DATA)] of the causal tiles, query-major."""
+    return [(i, j, DATA) for i, j, _ in causal_schedule(s, t)]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "t", "interpret"))
+def sel_forward(q, k, v, bias, *, scale: float, t: int, interpret: bool = False):
+    """(out (N, H, S, D) in `v.dtype`, lse (N, H, S) float32)."""
+    return scheduled_forward(
+        q, k, v, selected_schedule(q.shape[2], t), scale=scale, block=1, t=t,
+        kinds=(DATA,), name="selected_attention_fwd", interpret=interpret,
+        bias=bias)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "t", "interpret"))
+def sel_backward(q, k, v, bias_t, out, lse, d_out, *, scale: float, t: int,
+                 interpret: bool = False):
+    """(dq, dk, dv) in the dtypes of `q, k, v`."""
+    return scheduled_backward(
+        q, k, v, out, lse, d_out, selected_schedule(q.shape[2], t), scale=scale,
+        block=1, t=t, kinds=(DATA,), name="selected_attention_bwd",
+        interpret=interpret, bias_t=bias_t)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def selected_attention(q, k, v, bias, scale: float, t: int, otherwise: Callable):
+    """(`out (N, H, S, D)`, `lse (N, H, S)` float32 — the rows' log-sum-exp
+    over their allowed keys, for a caller that wants the probabilities
+    again; no gradient passes it) of head-major `q (N, H, S, D)`, `k, v (N,
+    KV, S, D)` whose shapes `causal_tile(S, None, D)` accepted (`t`) under
+    `bias (N, S, S)` (above; no gradient either): the kernels where the
+    program is lowered for a TPU, `otherwise(q, k, v, bias)` — the caller's
+    plain-XLA form, which returns both — elsewhere. Residuals as
+    `causal_attention` names them."""
+    return _sel_forward_rule(q, k, v, bias, scale, t, otherwise)[0]
+
+
+def _sel_forward_rule(q, k, v, bias, scale, t, otherwise):
+    out, lse = lax.platform_dependent(
+        q, k, v, bias, default=otherwise,
+        tpu=functools.partial(sel_forward, scale=scale, t=t))
+    out = checkpoint_name(out, RESIDUAL_NAME)
+    lse = checkpoint_name(lse, RESIDUAL_NAME)
+    return (out, lse), (q, k, v, bias, out, lse)
+
+
+def _sel_backward_rule(scale, t, otherwise, residuals, cotangents):
+    q, k, v, bias, out, lse = residuals
+    d_out, _ = cotangents
+
+    def plain(q, k, v, bias, out, lse, d_out):
+        return jax.vjp(lambda q, k, v: otherwise(q, k, v, bias)[0], q, k, v)[1](d_out)
+
+    def kernels(q, k, v, bias, out, lse, d_out):
+        return sel_backward(q, k, v, jnp.swapaxes(bias, 1, 2), out, lse, d_out,
+                            scale=scale, t=t)
+
+    return (*lax.platform_dependent(
+        q, k, v, bias, out, lse, d_out, default=plain, tpu=kernels),
+        jnp.zeros_like(bias))
+
+
+selected_attention.defvjp(_sel_forward_rule, _sel_backward_rule)

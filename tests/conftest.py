@@ -243,6 +243,16 @@ _RESNET_ONLY_CASES = (
     "test_optimizer_args_of_the_listed_configurations_are_sgds_three"
     "[ouro_2_6b_stage]",
     "test_config_entries[ouro_2_6b_stage]",
+    # PR 51's configuration, `keye_vl2_30b_a3b_ep8`, is a ninth configuration,
+    # a tenth cell and eleven more metrics: the same three per-configuration
+    # cases again. PR 48's tests read the manifest with closed indices, so
+    # none pins its end. tests/benchmark/test_keye_vl_config.py holds what
+    # each of the three held.
+    "test_the_listed_configurations_name_the_resnet_reference"
+    "[keye_vl2_30b_a3b_ep8]",
+    "test_optimizer_args_of_the_listed_configurations_are_sgds_three"
+    "[keye_vl2_30b_a3b_ep8]",
+    "test_config_entries[keye_vl2_30b_a3b_ep8]",
 )
 
 

@@ -33,9 +33,10 @@ def interpreted(monkeypatch):
     to `otherwise`)."""
     ran = []
 
-    def either(x, *, theta, back, otherwise):
+    def either(x, *, theta, back, otherwise, positions=None):
         ran.append((x.shape, back))
-        return pallas_rope.rotate(x, theta=theta, back=back, interpret=True)
+        return pallas_rope.rotate(x, theta=theta, back=back, interpret=True,
+                                  positions=positions)
 
     monkeypatch.setattr(pallas_rope, "either", either)
     return ran

@@ -52,16 +52,22 @@ def pulled(fn, cotangent, *args):
 
 
 def toy(model, arch, *, seq, batch=4, shifted=False, draw_state=True,
-        adjust=None):
+        adjust=None, compiled=False):
     """A file's `small`: `model` initialised for `seq` positions, every
     parameter leaf redrawn (`random_leaves`: weights of std 1 / sqrt(fan_in),
     gains 1 + 0.1 n) and, with `draw_state`, every selection bias 0.01 n;
     `adjust(params)` has the last word. `batch` sequences of random tokens,
     the targets the tokens rolled by one (`shifted`: one token more drawn,
     inputs and targets its two ends). The keys are the files' own since
-    their first PR: 1 to initialise, 2 to redraw, 3 for the tokens."""
-    params, state, _ = model.init(jax.random.key(1), (seq,))
-    params, drawn = random_leaves(params, state, jax.random.key(2))
+    their first PR: 1 to initialise, 2 to redraw, 3 for the tokens.
+    `compiled`: initialised and redrawn in one program, not leaf by leaf (a
+    model of forty leaves: 1 s, not 8)."""
+    def draw(first, second):
+        params, state, _ = model.init(first, (seq,))
+        return (state, *random_leaves(params, state, second))
+
+    state, params, drawn = (jax.jit(draw) if compiled else draw)(
+        jax.random.key(1), jax.random.key(2))
     if draw_state:
         state = drawn
     if adjust is not None:
