@@ -39,7 +39,14 @@ scalar trains the trunk on the cross-entropy (and the balance terms) alone
 and the indexer's four leaves on `L^I` alone.
 
 How the selection reaches the core: as an array. `Indexer.choose` makes the
-scores a block of queries at a time (never `(S, S)` in float32 at once),
+scores a block of queries at a time (never `(S, S)` in float32 at once) —
+`Indexer.block_scores`: where the shapes tile and the step is lowered for a
+TPU one kernel a direction that forms the sixteen heads' products, their
+ReLU, the weighing and the sum over the heads in VMEM and writes `I (rows,
+keys)` alone (ops/pallas_index.py:index_scores; it skips the key tiles past
+the block's last query, which `choose` and the objective mask anyway), else
+`Indexer.scores`, the equation in plain XLA, whose float32 `(H^I, rows,
+keys)` products cross HBM —,
 finds every row's exact `topk`-th largest as a threshold `tau` with the tie
 rule as a second number (`cut`: the last index taken among the scores that
 equal `tau`) by bisection over the scores' bits (`_best`: what a stable
@@ -96,7 +103,7 @@ from parallel_cnn_tpu.nn.glm_moe import (
 )
 from parallel_cnn_tpu.nn.layers import LayerNorm, _weight, rope
 from parallel_cnn_tpu.nn.sdar_moe import GQA
-from parallel_cnn_tpu.ops import pallas_attention, pallas_rope
+from parallel_cnn_tpu.ops import pallas_attention, pallas_index, pallas_rope
 
 SELECTION_NAME = "dsa_select"
 
@@ -178,8 +185,11 @@ class Indexer(Module):
     keys kept a query. `GQA` calls `project` (the layer's detached input to
     `q^I, k^I, w`), `choose` (the selection, as the core's `bias`) and
     `report` (`L^I` and the counters); `scores` is the one place the
-    equation of `I` is written. `rows`: the queries a block of any of them
-    holds."""
+    equation of `I` is written, and `block_scores` what `choose` and the
+    objective call for a block of queries: ops/pallas_index.py's kernel pair
+    where the shapes tile and the step is lowered for a TPU, with `scores`
+    as its plain form everywhere else. `rows`: the queries a block of any
+    of them holds."""
 
     heads: int = 16
     head_dim: int = 64
@@ -233,6 +243,32 @@ class Indexer(Module):
             return jnp.sum(jax.nn.relu(z) * weight[..., None], axis=1) * (
                 self.heads * self.head_dim) ** -0.5
 
+    def scores_tile(self, s: int) -> Optional[int]:
+        """The key tile ops/pallas_index.py's kernels run a sequence of `s`
+        positions at, or None where they do not take the shapes of one of
+        its bands."""
+        rows = self._rows(s)
+        tiles = {pallas_index.tile(rows, keys, self.head_dim)
+                 for _, keys in _bands(s, rows)}
+        return None if None in tiles else min(tiles)
+
+    def block_scores(self, q, weight, k, at):
+        """`scores` of the block of queries whose first stands at `at` (an
+        integer scalar), of which the callers read no key after the block's
+        last query: for shapes `pallas_index.tile` takes, where the step is
+        lowered for a TPU, one kernel a direction that keeps the heads'
+        products in VMEM and skips the key tiles past the block
+        (ops/pallas_index.py); else `scores` — and `scores` too while the
+        class holds another function under that name than at import (a
+        test's or a comparison tool's own equation runs as it is written)."""
+        t = pallas_index.tile(q.shape[2], k.shape[1], q.shape[3])
+        if t is None or Indexer.scores is not _SCORES:
+            return self.scores(q, weight, k)
+        with jax.named_scope("scores"):
+            return pallas_index.index_scores(
+                q, weight, k, at, (self.heads * self.head_dim) ** -0.5, t,
+                self.scores)
+
     def choose(self, index, tile: int):
         """(`bias (N, S, S)` in `q^I`'s dtype — queries by keys, 0 where the
         query attends to the key, `pallas_attention.MASKED` where not — and
@@ -256,8 +292,8 @@ class Indexer(Module):
         bit = jnp.arange(32, dtype=jnp.uint32)[:, None]
 
         def block(_, at, keys):
-            i = self.scores(_rows_of(q, at, rows, 2), _rows_of(weight, at, rows, 1),
-                            k[:, :keys])
+            i = self.block_scores(_rows_of(q, at, rows, 2),
+                                  _rows_of(weight, at, rows, 1), k[:, :keys], at)
             with jax.named_scope("select"):
                 at_key = jnp.arange(keys)[None, :]
                 at_query = at + jnp.arange(rows)[:, None]
@@ -302,14 +338,14 @@ class Indexer(Module):
         return {"kl": kl, "keys_selected_mean": lax.stop_gradient(kept),
                 "tiles_touched_ratio": lax.stop_gradient(touched)}
 
-    def kl_of_block(self, scale, qi, weight, ki, q, k, lse, bias):
+    def kl_of_block(self, scale, qi, weight, ki, q, k, lse, bias, at):
         """Sum over a block's queries of `sum_s P (ln P - ln softmax(I))`
         over their selected keys: index queries `qi (N, H^I, q, d^I)`,
         weights `weight (N, q, H^I)`, index keys `ki (N, k, d^I)`; the
         core's `q (N, KV, G, q, D)`, `k (N, KV, k, D)`, `lse (N, KV, G, q)`;
-        `bias (N, q, k)`."""
+        `bias (N, q, k)`; `at`: the block's first position."""
         taken = bias == 0
-        score = jnp.where(taken, self.scores(qi, weight, ki), -jnp.inf)
+        score = jnp.where(taken, self.block_scores(qi, weight, ki, at), -jnp.inf)
         log_q = jax.nn.log_softmax(score, axis=-1)
 
         def of_group(p, group):
@@ -328,6 +364,11 @@ class Indexer(Module):
                        - jnp.where(seen, log_q, 0.0)), 0.0))
 
 
+# The equation of `I` as this module has it: what the kernels implement
+# (`Indexer.block_scores`).
+_SCORES = Indexer.scores
+
+
 def _kl_blocks(indexer: Indexer, scale, index, q, k, lse, bias, fn, carry):
     """`fn(carry, block's arguments of Indexer.kl_of_block)` over every
     block of queries (`_blocks`)."""
@@ -341,7 +382,8 @@ def _kl_blocks(indexer: Indexer, scale, index, q, k, lse, bias, fn, carry):
     def block(carry, at, keys):
         return fn(carry, _rows_of(qi, at, rows, 2), _rows_of(weight, at, rows, 1),
                   ki[:, :keys], _rows_of(q, at, rows, 3), k[:, :, :keys],
-                  _rows_of(lse, at, rows, 3), _rows_of(bias, at, rows, 1)[..., :keys])
+                  _rows_of(lse, at, rows, 3), _rows_of(bias, at, rows, 1)[..., :keys],
+                  at)
 
     return _blocks(block, carry, s, rows)
 
@@ -472,7 +514,9 @@ class KeyeVL(GlmMoe):
         head), the pairs it allows and the pairs the core executes in each
         direction on `platform` — every pair of the causal visit list's
         tiles under the kernels, every pair of a block of queries by the
-        keys up to its end on the plain path."""
+        keys up to its end on the plain path — and what makes the index
+        scores there (`index_scores_core`: `"pallas"`, ops/pallas_index.py's
+        kernels at `index_scores_tile` keys a grid step, or `"xla"`)."""
         att, pick = self.attn, self.attn.select
         said = super().describe(tokens_per_step, seq_len, platform)
         fused = said["attention_core"] == "fused"
@@ -486,7 +530,10 @@ class KeyeVL(GlmMoe):
             forward = backward = sum(
                 t * (a + t) for a in range(0, seq_len, t))
         axes = att.positions
+        scores_tile = pick.scores_tile(seq_len) if platform == "tpu" else None
         said.update(
+            index_scores_core="xla" if scores_tile is None else "pallas",
+            index_scores_tile=scores_tile,
             layers=self.n_layers, topk=pick.topk, index_heads=pick.heads,
             index_head_dim=pick.head_dim, index_weight=self.index_weight,
             attention_core_kind=(

@@ -256,3 +256,25 @@ def test_zoo_train_lowers_both_loss_parts_and_records_the_counters():
     # two turns of 32 queries against the keys up to their end
     assert event["attention_pairs_computed"] == 32 * 32 + 32 * 64
     assert "a bit a pair" in event["selection_saved"]
+    # the toy's index heads are 8 wide and its blocks 32 queries: the plain
+    # form of the scores, on any platform
+    assert (event["index_scores_core"], event["index_scores_tile"]) == ("xla", None)
+
+
+@pytest.mark.parametrize("platform,seq,core,tile", [
+    ("tpu", 16384, "pallas", 1024),  # the cell: bands of 4,096 ... 16,384 keys
+    ("tpu", 2048, "pallas", 512),    # bands of 512 ... 2,048
+    ("tpu", 1024, "pallas", 256),    # bands of 256 ... 1,024
+    ("tpu", 320, "xla", None),       # one band, 320 keys: no tile divides them
+    ("cpu", 16384, "xla", None),
+])
+def test_describe_says_what_makes_the_index_scores(platform, seq, core, tile):
+    """At published widths (sixteen index heads of 64, blocks of 256
+    queries), beside what it says of the attention's core: the kernels
+    where the program is lowered for a TPU and every band's keys tile, the
+    narrowest band's tile."""
+    model = keye_vl.keye_vl2_30b_a3b(num_hidden_layers=1, vocab_size=64)
+    said = model.describe(seq, seq, platform)
+    assert (said["index_scores_core"], said["index_scores_tile"]) == (core, tile)
+    fused = platform == "tpu" and seq % 128 == 0
+    assert said["attention_core"] == ("fused" if fused else "blocks")
